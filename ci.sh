@@ -680,26 +680,6 @@ if [[ "${1:-fast}" == "full" ]]; then
     python tools/make_anchor_v2.py | python -c \
     "import json,sys; d=json.loads(sys.stdin.read().splitlines()[-1]); \
 assert d['gates']['parity_ok'], d; print('anchor_v2 parity OK')"
-  # bench/tpu_smoke intentionally exit 0 on failure (one-JSON-line
-  # driver contract), so they must run as SUBPROCESSES with the check
-  # in a separate process — an in-process runpy assert would be skipped
-  # by their sys.exit(0) error paths
-  SMOKE_OUT=/tmp/ci_tpu_smoke_light.json SMOKE_LIGHT=1 SMOKE_INIT_TIMEOUT=30 \
-    SMOKE_PLATFORM=cpu python tools/tpu_smoke.py > /dev/null
-  python -c "
-import json
-d = json.load(open('/tmp/ci_tpu_smoke_light.json')); assert d['ok'], d
-print('tpu_smoke (light) OK')"
-  # BENCH_SPARSE_HOT=0: the dedicated sparse_hot gate below already
-  # runs (and asserts on) the hot-tier bench — the embedded emission
-  # would pay two more PS clusters + 4 DeepFM epochs here, unasserted
-  BENCH_STEPS=5 BENCH_WARMUP=1 BENCH_PASS_KEYS=$((1 << 14)) \
-    BENCH_INIT_TIMEOUT=60 BENCH_PLATFORM=cpu BENCH_SPARSE_HOT=0 \
-    python bench.py | python -c "
-import json, sys
-line = [l for l in sys.stdin.read().splitlines() if l.startswith('{')][-1]
-d = json.loads(line); assert d['value'] > 0 and 'error' not in d, d
-print('bench (cpu) OK')"
   # sparse push-wire ladder: the int8 wire must actually shrink the
   # SPARSE RPC push stream — ≥3× fewer bytes than fp32, asserted from
   # the PR 8 per-table byte counters (steady-state wire; the terminal
@@ -758,16 +738,6 @@ assert d['warm']['rpc_per_request'] == 0.0, d['warm']
 assert d['freshness_failures'] == 0, d['freshness']
 print('serving OK: warm p99=%.1fms, push→servable p95=%.1fms, 0 rpc warm'
       % (d['warm']['request_ms']['p99_ms'], d['freshness']['p95_ms']))"
-  # the graceful-degradation ladder must actually engage (a hardware
-  # compile failure in a new hot path costs an attempt, not the metric)
-  BENCH_STEPS=3 BENCH_WARMUP=1 BENCH_BATCH=256 BENCH_PASS_KEYS=$((1 << 13)) \
-    BENCH_INIT_TIMEOUT=60 BENCH_PLATFORM=cpu BENCH_SPARSE_HOT=0 \
-    BENCH_FORCE_FAIL=amp+dense,dense python bench.py | python -c "
-import json, sys
-line = [l for l in sys.stdin.read().splitlines() if l.startswith('{')][-1]
-d = json.loads(line)
-assert d['value'] > 0 and d['mode'] == 'sparse' and d['degraded_from'], d
-print('bench degradation ladder OK')"
 
   echo "== TSAN sweep (table/RPC/graph concurrency surfaces) =="
   # gate: OUR instrumented .so must stay report-free; third-party libs
@@ -776,8 +746,13 @@ print('bench degradation ladder OK')"
   # not silently swallowed — the log files stay in /tmp for inspection.
   # The EXIT trap restores the normal flavor even when the sweep fails
   # (a leftover TSAN .so breaks every later non-preloaded import).
-  trap 'make -C paddle_tpu/csrc -s' EXIT
-  make -C paddle_tpu/csrc SANITIZE=thread -s
+  # SANITIZE is EXPORTED, not passed on make's command line: the
+  # package runs `make` itself when it loads the library
+  # (ps/native.load_native), and make must see the same flavor there or
+  # it would rebuild the plain library over the instrumented one.
+  trap 'unset SANITIZE; make -C paddle_tpu/csrc -s' EXIT
+  export SANITIZE=thread
+  make -C paddle_tpu/csrc -s
   rm -f /tmp/ci_tsan_report*
   # exitcode=0: TSAN's default exit-66-if-anything-reported would mask
   # pytest's own status behind unavoidable third-party noise — the grep
@@ -825,7 +800,8 @@ print('sync shim pass-through OK (sanitizer sees raw primitives)')"
   # same contract as TSAN: detect_leaks=0 because the uninstrumented
   # Python/jax runtime "leaks" by design at interpreter exit; exitcode=0
   # so pytest's status gates the tests and the grep gates OUR .so
-  make -C paddle_tpu/csrc SANITIZE=address -s
+  export SANITIZE=address
+  make -C paddle_tpu/csrc -s
   rm -f /tmp/ci_asan_report*
   LD_PRELOAD="$(gcc -print-file-name=libasan.so)" OPENBLAS_NUM_THREADS=1 \
     ASAN_OPTIONS="detect_leaks=0,halt_on_error=0,exitcode=0,log_path=/tmp/ci_asan_report" \
@@ -860,7 +836,8 @@ print('sync shim pass-through OK (sanitizer sees raw primitives)')"
   echo "== UBSAN sweep (same surfaces; UB: overflow/alignment/bounds) =="
   # UBSAN's runtime is linked into the sanitized .so itself, so no
   # LD_PRELOAD; halt_on_error=0 collects every report into the log
-  make -C paddle_tpu/csrc SANITIZE=undefined -s
+  export SANITIZE=undefined
+  make -C paddle_tpu/csrc -s
   rm -f /tmp/ci_ubsan_report*
   OPENBLAS_NUM_THREADS=1 \
     UBSAN_OPTIONS="print_stacktrace=1,halt_on_error=0,log_path=/tmp/ci_ubsan_report" \
@@ -892,6 +869,7 @@ print('sync shim pass-through OK (sanitizer sees raw primitives)')"
   fi
   echo "UBSAN sweep OK (no reports in our .so)"
 
+  unset SANITIZE
   make -C paddle_tpu/csrc -s   # restore the normal flavor now
   trap - EXIT
 fi

@@ -10,68 +10,6 @@ feasign sharding, host tables). See SURVEY.md for the reference map.
 
 __version__ = "0.3.0"  # round 3
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 exposes shard_map only under experimental (where the
-    # replication-check kwarg is still named check_rep, not check_vma);
-    # publish a translating wrapper at the stable path so
-    # `from jax import shard_map` works tree-wide
-    import functools as _functools
-    import inspect as _inspect
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if "check_vma" in _inspect.signature(_shard_map).parameters:
-        _jax.shard_map = _shard_map
-    else:
-        @_functools.wraps(_shard_map)
-        def _shard_map_compat(*args, **kwargs):
-            if "check_vma" in kwargs:
-                kwargs["check_rep"] = kwargs.pop("check_vma")
-            # this tree annotates replication with the vma system
-            # (lax.pcast), which old jax's check_rep cannot see — its
-            # checker would reject valid programs, so default it off.
-            # AD CAVEAT (jax 0.4.x, either check_rep setting): the
-            # transpose of lax.psum inside shard_map is another psum,
-            # NOT the vma-era identity-on-replicated-cotangents — any
-            # loss that differentiates THROUGH a cross-shard psum comes
-            # back scaled by the axis size unless the site pins its own
-            # VJP (see ParallelCrossEntropy._psum_replicated) or reduces
-            # grads explicitly outside AD (see the pipeline trainers).
-            kwargs.setdefault("check_rep", False)
-            return _shard_map(*args, **kwargs)
-
-        _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "axis_size"):
-    # jax < 0.5: psum of a unit weight is the axis size (concrete when
-    # the axis binding is known, same as the later lax.axis_size)
-    def _axis_size(axis_name):
-        return _jax.lax.psum(1, axis_name)
-
-    _jax.lax.axis_size = _axis_size
-
-if not hasattr(_jax.lax, "pcast"):
-    # jax < 0.6 has no varying-manual-axes (vma) type system, so the
-    # replication cast is a no-op there — shard_map runs with the
-    # check disabled (check_rep=False via the check_vma translation)
-    def _pcast(x, axis_name=None, *, to=None):
-        del axis_name, to
-        return x
-
-    _jax.lax.pcast = _pcast
-
-if not hasattr(_jax, "export"):
-    # jax 0.4.x ships jax.export but does not import the submodule from
-    # jax/__init__; bind it so `jax.export.export(...)` works. Guarded:
-    # older jax has no export module at all, and inference/export
-    # surfaces degrade there rather than breaking the whole package.
-    try:
-        import jax.export as _jax_export  # noqa: F401
-    except ImportError:
-        pass
-
 from . import core, data, io, metrics, models, nn, optimizer
 from .core import (
     CPUPlace,

@@ -1016,7 +1016,7 @@ class Engine:
         # _place_state chose — for EVERY engine, not just stage 1.
         # Without the pin the compiler is free to re-lay-out params and
         # slots after the first step (stage 1: gathers the slots and
-        # un-does ZeRO; annotated engines under jax≥0.4.37: GSPMD drifts
+        # un-does ZeRO; annotated engines under this jax: GSPMD drifts
         # params off param_specs, so a later save→load→fit would land on
         # different placements than the run it resumed and retrace)
         sharding_of = lambda t: jax.tree_util.tree_map(

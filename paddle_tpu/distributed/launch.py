@@ -11,6 +11,13 @@ PADDLE_TRAINERS_NUM, TRAINING_ROLE, PADDLE_PORT …), multi-host bootstrap
 via ``jax.distributed.initialize`` coordinates over DCN. For the PS mode
 it spawns server + trainer processes on localhost exactly like the
 reference's test harness (test_dist_fleet_base.py:311 _run_cluster).
+
+A chip belongs to one process. The launcher itself never imports jax;
+with ``nproc > 1`` on one host each trainer is handed ITS OWN chip
+(libtpu's ``TPU_VISIBLE_DEVICES=<rank>`` with 1x1x1 process bounds — a
+rank beyond the host's chips fails in libtpu, loudly), and PS servers,
+which hold host tables only, are pinned to ``JAX_PLATFORMS=cpu`` so they
+never claim one.
 """
 
 from __future__ import annotations
@@ -56,7 +63,16 @@ def _proc_env(spec: JobSpec, role: str, rank: int) -> Dict[str, str]:
     if role == "TRAINER":
         env["PADDLE_TRAINER_ID"] = str(rank)
         env["PADDLE_RANK"] = str(rank)
+        if spec.nproc > 1:
+            # one chip per local trainer (ignored off-TPU); a caller
+            # that partitions the chips itself says so in spec.env
+            for k, v in (("TPU_VISIBLE_DEVICES", str(rank)),
+                         ("TPU_CHIPS_PER_PROCESS_BOUNDS", "1,1,1"),
+                         ("TPU_PROCESS_BOUNDS", "1,1,1")):
+                if k not in spec.env:
+                    env[k] = v
     else:
+        env["JAX_PLATFORMS"] = "cpu"
         env["PADDLE_PORT"] = str(spec.coordinator_port + 100 + rank)
         env["POD_IP"] = "127.0.0.1"
         env["PADDLE_SERVER_ID"] = str(rank)

@@ -100,16 +100,13 @@ def parallel_cross_entropy(logits: jax.Array, labels: jax.Array,
     full logits too (serial path).
 
     ``pinned_vjp``: the two differentiated mp reductions use the
-    pinned-identity-VJP psum (the PR-2 mp_layers treatment). REQUIRED
-    inside a ``check_rep=False``/``check_vma=False`` shard_map where all
-    cross-rank reductions are explicit (hybrid's step): there, jax
-    0.4.x's plain psum→psum transpose would scale the logits gradient —
-    and everything upstream — by the mp size (the exact constant-×mp
-    gradient error test_hybrid_grads_match_serial pins down). Leave
-    False under a rep-tracking shard_map (the default ``check_rep=True``
-    harnesses, e.g. test_ernie's TP parity), where the tracker pairs
-    the plain psum with the correct transpose itself and a pinned VJP
-    would break that pairing."""
+    identity-VJP psum (``coll.psum_replicated``). REQUIRED inside a
+    ``check_vma=False`` shard_map where all cross-rank reductions are
+    explicit (hybrid's step): there a plain psum transposes into
+    another psum and would scale the logits gradient — and everything
+    upstream — by the mp size (the exact constant-×mp gradient error
+    test_hybrid_grads_match_serial pins down). Under the default
+    ``check_vma=True`` both forms give the same gradient."""
     per = logits.shape[-1]
     if not _axis_active(axis) or per == vocab_size:
         return nn.functional.cross_entropy(logits, labels, reduction="none")

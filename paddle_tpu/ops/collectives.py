@@ -22,6 +22,7 @@ The ``ProcessGroup`` class offers the reference's eager API shape
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence, Union
 
 import jax
@@ -101,29 +102,42 @@ def ppermute(x: jax.Array, axis: str, perm: Sequence[tuple]) -> jax.Array:
     return lax.ppermute(x, axis, perm)
 
 
-def _psum_replicated_impl(x, axis_name):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _psum_identity_vjp(x, axes, varying):
+    return lax.psum(x, axes)
+
+
+def _psum_identity_bwd(axes, varying, _, ct):
+    # the cotangent arrives typed like the (replicated) output; hand it
+    # back typed like the input — varying over the axes the input varied
+    return (lax.pcast(ct, varying, to="varying") if varying else ct,)
+
+
+_psum_identity_vjp.defvjp(
+    lambda x, axes, varying: (lax.psum(x, axes), None), _psum_identity_bwd)
+
+
+def psum_replicated(x: jax.Array, axis_name: AxisName) -> jax.Array:
     """psum of a value whose DOWNSTREAM cotangent is replicated over
     ``axis_name`` (every shard computes the same loss from the summed
-    result): the correct per-shard gradient is that cotangent unscaled.
-    jax 0.4.x shard_map transposes a plain psum into another psum (with
-    either check_rep setting), which would scale such gradients by the
-    axis size — the custom VJP pins the identity backward, and stays
-    correct under the vma-era semantics too. ``axis_name`` may be one
-    axis or a tuple of axes (the mp CE reductions and the hybrid loss
-    reduction both route through here — shared by mp_layers/hybrid)."""
-    return lax.psum(x, axis_name)
-
-
-# axis_name is static (a string or tuple), not a differentiable input
-psum_replicated = jax.custom_vjp(_psum_replicated_impl, nondiff_argnums=(1,))
-psum_replicated.defvjp(
-    lambda x, axis_name: (lax.psum(x, axis_name), None),
-    lambda axis_name, _, ct: (ct,))
+    result), differentiated INSIDE the shard_map body: the correct
+    per-shard gradient is that cotangent unscaled, so the backward is
+    pinned to the identity. Under ``check_vma=True`` that is also what
+    jax derives for psum (varying → invariant transposes to a cast
+    back to varying), and the pinned rule returns the cotangent with
+    the input's varying-axes type; under ``check_vma=False`` jax
+    transposes a plain psum into another psum, which would scale such
+    gradients by the axis size. ``axis_name`` may be one axis or a
+    tuple (the mp CE reductions, the pipeline's masked output psum and
+    the hybrid loss reduction all route through here)."""
+    axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
+    varying = tuple(a for a in axes if a in jax.typeof(x).vma)
+    return _psum_identity_vjp(x, axes, varying)
 
 
 def spec_reduced_grads(grads, specs, mesh_shape) -> jax.Array:
-    """Explicit spec-driven gradient reduction for a ``check_rep=False``
-    / ``check_vma=False`` shard_map step where autodiff inserts NO
+    """Explicit spec-driven gradient reduction for a ``check_vma=False``
+    shard_map step where autodiff inserts NO
     cross-rank reductions (every differentiated psum pinned via
     :func:`psum_replicated`): each rank then holds only its own partial
     contribution, and the true gradient of a param is the psum over
@@ -132,7 +146,7 @@ def spec_reduced_grads(grads, specs, mesh_shape) -> jax.Array:
     disjoint contributions (pipeline-stage-owned aux params) are zero
     off their owning rank. Axes IN the param's spec hold that rank's
     own shard and are left alone. Shared by the hybrid trainer and the
-    TP parity tests (one definition for the next jax-drift fix)."""
+    TP parity tests."""
     def reduce_one(g, spec):
         in_spec = {a for e in tuple(spec)
                    for a in (e if isinstance(e, tuple) else (e,)) if a}

@@ -29,20 +29,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):
-    # jax < 0.6 names the pallas params class TPUCompilerParams
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 __all__ = ["flash_attention", "flash_attention_with_lse"]
 
 NEG = -1e30
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def _round_up(x: int, m: int) -> int:
@@ -52,13 +41,8 @@ def _round_up(x: int, m: int) -> int:
 def _out_struct(shape, dtype, *inputs):
     """ShapeDtypeStruct carrying the union of the inputs' varying-manual-
     axes type — required for pallas_call under shard_map (check_vma)."""
-    vma = frozenset()
-    try:
-        for x in inputs:
-            vma = vma | jax.typeof(x).vma
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except (AttributeError, TypeError):
-        return jax.ShapeDtypeStruct(shape, dtype)
+    vma = frozenset().union(*(jax.typeof(x).vma for x in inputs))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 # ---------------------------------------------------------------------------
@@ -439,7 +423,7 @@ def flash_attention(
 def _run_padded(q, k, v, causal, q_offset, k_offset, block_q, block_k,
                 interpret, precision, with_lse):
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = jax.default_backend() != "tpu"
     B, Lq, H, D = q.shape
     Lk = k.shape[1]
     scale = 1.0 / math.sqrt(D)

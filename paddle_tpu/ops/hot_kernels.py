@@ -24,22 +24,22 @@ update_value analogues, optimizer.cuh.h one-thread-per-row shape):
   definition), write row — so only O(batch) rows cross HBM and the
   gathered/updated [n, width] intermediates never materialize.
 
-Both kernels run ``interpret=True`` off-TPU (the CPU CI fallback — the
-kernel body is staged as ordinary jax ops, so it compiles and stays
-bit-identical); the jnp formulation remains the default off-TPU AND the
-reference oracle behind ``HotTierConfig.kernels`` ("auto" | "pallas" |
-"jnp"). Bit-parity contract: the kernels share the hash math
-(``dynamic_probe_buckets``) and the rule math (``fused_row_update``)
-with the jnp path by IMPORT, not by copy — tests/test_hot_kernels.py
-pins Pallas(interpret) ≡ jnp ≡ the host engines for adagrad and adam,
-unaligned n included.
-
-Known TPU caveat (MEASURED.md discipline): the in-kernel gathers and
-the per-row ``fori_loop`` in the scatter kernel are Mosaic
-dynamic-indexing paths whose relative cost is unmeasured on real
-silicon — the CPU CI box only proves correctness (interpret mode). Keep
-``kernels="auto"`` (jnp off-TPU) for performance work until the chip
-rung lands.
+STATUS: these kernels are proven in interpret mode only. Mosaic (jax
+0.9.0 / libtpu 0.0.34, compiled for the v5e topology — see
+tests/test_tpu_lowering.py, which pins each refusal) rejects all three:
+``hot_probe_gather`` and ``hot_probe`` on the in-kernel ``jnp.take``
+row gathers ("Shape mismatch in input, indices and output"),
+``hot_scatter_apply`` on the scalar ``rows_ref[i]`` read from a VMEM
+vector ("cannot statically prove that index in dimension 0 is a
+multiple of …"); their BlockSpecs also hold whole table columns in
+VMEM. So ``HotTierConfig.kernels="auto"`` resolves to the jnp
+formulation on EVERY backend, and ``kernels="pallas"`` is an explicit
+request: interpret mode off-TPU (the parity configuration), a loud
+Mosaic compile error on the chip. Bit-parity contract: the kernels
+share the hash math (``dynamic_probe_buckets``) and the rule math
+(``fused_row_update``) with the jnp path by IMPORT, not by copy —
+tests/test_hot_kernels.py pins Pallas(interpret) ≡ jnp ≡ the host
+engines for adagrad and adam, unaligned n included.
 """
 
 from __future__ import annotations
@@ -60,14 +60,12 @@ __all__ = ["hot_probe_gather", "hot_probe", "hot_scatter_apply",
 
 def resolve_hot_kernels(mode: str) -> bool:
     """Resolve HotTierConfig.kernels → use the Pallas kernels? "auto"
-    picks Pallas on TPU (the chip the kernels exist for) and the jnp
-    reference path elsewhere; "pallas" forces the kernels (interpret
-    mode off-TPU — the parity/CI configuration); "jnp" forces the
-    reference path (the oracle)."""
+    is the jnp formulation on every backend — the one that compiles for
+    the chip (module docstring STATUS); "pallas" is the explicit
+    request for the kernels (interpret mode off-TPU, Mosaic on it);
+    "jnp" names the reference path outright."""
     enforce(mode in ("auto", "pallas", "jnp"),
             f"kernels must be 'auto', 'pallas' or 'jnp', got {mode!r}")
-    if mode == "auto":
-        return jax.default_backend() == "tpu"
     return mode == "pallas"
 
 

@@ -42,11 +42,14 @@ def rule_init_state(rule: str, n: int, dim: int, *, beta1: float,
                     beta2: float):
     """Fresh-feature optimizer state (zeros; Adam's beta powers start at
     beta1/beta2 — sparse_sgd_rule.cc InitValueWork)."""
-    sd = rule_state_dim(rule, dim)
-    st = jnp.zeros((n, sd), jnp.float32)
     if rule == "adam":
-        st = st.at[:, 2 * dim].set(beta1).at[:, 2 * dim + 1].set(beta2)
-    return st
+        # built by concatenation, not .at[].set: Mosaic has no scatter
+        # lowering and this runs inside the Pallas rule kernel
+        return jnp.concatenate(
+            [jnp.zeros((n, 2 * dim), jnp.float32),
+             jnp.full((n, 1), beta1, jnp.float32),
+             jnp.full((n, 1), beta2, jnp.float32)], axis=1)
+    return jnp.zeros((n, rule_state_dim(rule, dim)), jnp.float32)
 
 
 def _m32(a, b):
@@ -121,13 +124,6 @@ def rule_update(rule: str, w, state, g, scale, *, lr, initial_g2sum,
         return w2, jnp.concatenate(
             [m2, v2, _m32(b1p, b1f), _m32(b2p, b2f)], axis=1)
     raise KeyError(f"unknown sparse sgd rule {rule!r}")
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def fused_row_update(show, click, ew, estate, xw, xstate, has,
@@ -231,7 +227,7 @@ def ctr_sparse_rows(
             f"optimizer-state width mismatch: estate {estate.shape} vs "
             f"{es}, xstate {xstate.shape} vs {xs}")
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = jax.default_backend() != "tpu"
     # zero-width state -> one dummy column through the kernel
     estate_k = estate if es > 0 else jnp.zeros((n, 1), jnp.float32)
     xstate_k = xstate if xs > 0 else jnp.zeros((n, 1), jnp.float32)

@@ -183,10 +183,9 @@ class HybridParallelTrainer:
             # mean over the (dp×sh)×cp token grid (equal shard sizes).
             # The loss psum is DIFFERENTIATED (value_and_grad below) and
             # its cotangent is replicated over these axes, so it must be
-            # the pinned-VJP psum: jax 0.4.x shard_map transposes a plain
-            # psum into another psum, scaling every grad by the axis-size
-            # product (the latent issue flagged in CHANGES.md PR 2 — the
-            # slow hybrid parity tests failed at baseline because of it).
+            # the identity-VJP psum: under check_vma=False a plain psum
+            # transposes into another psum, scaling every grad by the
+            # axis-size product.
             return coll.psum_replicated(local / (batch_n * cp_n),
                                         batch_axes + ("cp",) + mp_extra)
 
@@ -209,10 +208,9 @@ class HybridParallelTrainer:
         data_spec = P(None, batch_axes, "cp")
         self._data_spec = data_spec
         # check_vma=False: every reduction in this step is EXPLICIT
-        # (pinned-VJP psums in the loss, the pipe's masked psum and the
-        # PCE internals) — jax 0.4.x's rep-tracking rewrite must not
-        # second-guess the backward (it misrouted it; see pipeline.py's
-        # masked-psum note and test_hybrid_grads_match_serial)
+        # (identity-VJP psums in the loss, the pipe's masked psum and
+        # the PCE internals, then spec_reduced_grads) — oracle:
+        # test_hybrid_grads_match_serial
         grad_fn = shard_map(
             spmd_step,
             mesh=mesh,
@@ -247,8 +245,8 @@ class HybridParallelTrainer:
         # PIN carried-state shardings on the step (the Engine treatment
         # from PR 2): without them the first call compiles against
         # uncommitted inputs while later calls compile against whatever
-        # output layout GSPMD chose, and on jax 0.4.37 those two
-        # executables COMPUTE DIFFERENT VALUES (the steady-state one
+        # output layout GSPMD chose, and those two executables were
+        # seen to COMPUTE DIFFERENT VALUES (the steady-state one
         # disagreed with the serial forward oracle by ~5%, which is what
         # actually failed test_hybrid_save_load_resume — a resumed
         # trainer starts on the fresh executable while the donor

@@ -178,9 +178,9 @@ class ParallelCrossEntropy(Layer):
         # correctness of the softmax grad and because pmax lacks a VJP
         local_max = lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
         global_max = lax.pmax(local_max, axis)
-        # the two reductions below are DIFFERENTIATED — they use the
-        # pinned-VJP psum (see _psum_replicated_impl) so the loss grad
-        # does not come back scaled by the mp size under jax 0.4.x
+        # the two reductions below are DIFFERENTIATED inside the
+        # shard_map body — identity-VJP psum (coll.psum_replicated), so
+        # the loss grad is right under either check_vma setting
         sumexp = jnp.sum(jnp.exp(logits - global_max), axis=-1, keepdims=True)
         lse = jnp.log(_psum_replicated(sumexp, axis)) + global_max  # [..., 1]
         # picked logit: only the owning shard contributes

@@ -151,15 +151,12 @@ def pipeline_spmd_fn(
         # psum. The psum is DIFFERENTIATED by callers (hybrid's
         # value_and_grad runs straight through the pipe), and its
         # downstream cotangent is replicated over pp (every rank computes
-        # the same loss from the replicated output) — so it must be the
-        # pinned-VJP psum: jax 0.4.x transposes a plain psum into another
-        # psum, and with the no-op pcast shim the rep-tracker misroutes
-        # the backward entirely (head grads came back ZERO, stage grads
-        # ~2x — caught against the serial-grad oracle, see
-        # test_hybrid_grads_match_serial). The is_last mask then hands
-        # the unscaled cotangent to the last rank's path only, which is
-        # also exactly what the f_then_b trainer's masked local loss
-        # seeds, so both callers stay correct.
+        # the same loss from the replicated output) — so it is the
+        # identity-VJP psum, correct under both check_vma settings
+        # (oracle: test_hybrid_grads_match_serial). The is_last mask
+        # then hands the unscaled cotangent to the last rank's path
+        # only, which is also exactly what the f_then_b trainer's masked
+        # local loss seeds, so both callers stay correct.
         is_last = (stage == num_stages - 1).astype(y.dtype)
         y = coll.psum_replicated(y * is_last, pp_axis)
         return y
@@ -264,11 +261,9 @@ class PipelineTrainer:
                 with nn.rng_guard(key):
                     preds = pipe(params["stages"], params["aux"], x_micro)
                 # mean over micro-batches of per-micro loss, COUNTED ON
-                # THE LAST pp RANK ONLY. preds are pp-replicated, but
-                # under jax 0.4.x the transpose of pipe's masked psum
-                # delivers the SUM of every seeding rank's cotangent
-                # (see the __init__ shim note) — letting all S ranks
-                # seed an identical loss would scale every gradient by S
+                # THE LAST pp RANK ONLY: the grads below are reduced
+                # explicitly over pp, so letting all S ranks seed an
+                # identical loss would scale every gradient by S
                 losses = jax.vmap(loss_fn)(preds, y_micro)
                 r = lax.axis_index(pp_axis)
                 return jnp.where(r == lax.axis_size(pp_axis) - 1,
@@ -278,9 +273,7 @@ class PipelineTrainer:
                 loss, grads = jax.value_and_grad(spmd_local_loss)(
                     params, x_micro, y_micro, rng)
                 # explicit cross-rank reductions, NOT autodiff through a
-                # psum'd loss (whose 0.4.x transpose would hand every dp
-                # rank its own unreduced gradient, silently training on
-                # one shard's data). aux grads live on single pp ranks —
+                # psum'd loss. aux grads live on single pp ranks —
                 # embed's chain ends on rank 0, head's on rank S-1 — so
                 # they replicate by pp-psum exactly as the 1f1b branch
                 # does below; the loss value does the same.
@@ -300,11 +293,15 @@ class PipelineTrainer:
             aux_specs = jax.tree_util.tree_map(lambda _: P(), aux)
             param_specs = {"stages": stage_specs, "aux": aux_specs}
 
+            # check_vma=False like the 1f1b branch: every cross-rank
+            # reduction above is explicit, and a vma-typed autodiff
+            # would add its own on top of them
             grad_fn = shard_map(
                 spmd_vg,
                 mesh=mesh,
                 in_specs=(param_specs, data_spec, data_spec, P()),
                 out_specs=(P(), param_specs),
+                check_vma=False,
             )
 
             def step(params, opt_state, x_micro, y_micro, rng):

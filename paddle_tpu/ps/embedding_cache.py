@@ -79,15 +79,15 @@ class CacheConfig:
     pallas_update: Optional[bool] = None
     #: push formulation. "sparse": the reference's merge_grad shape —
     #: sorted-unique dedup, gather touched rows, rule kernel, scatter
-    #: back (O(batch) HBM traffic but sort/gather/scatter-bound on TPU:
-    #: measured 25 ms at batch 4096x26, BENCH_DECOMP.md). "dense": one
+    #: back (O(batch) HBM traffic, sort/gather/scatter-bound). "dense": one
     #: duplicate-safe 2-D scatter-add of [grads|show|click] into a
     #: [C+1, 3+dim] accumulator, then the SAME fused_row_update math
     #: streamed over the whole table with a touched-row mask — no sort,
     #: no unique, no row gather/scatter; pure sequential HBM traffic
-    #: O(capacity·width) that XLA fuses into one pass (~0.7 ms at
-    #: C=2M). "auto": dense on TPU, sparse elsewhere (keeps CPU-path
-    #: tests bit-identical to the reference formulation).
+    #: O(capacity·width). "auto": dense on TPU, sparse elsewhere (keeps
+    #: CPU-path tests bit-identical to the reference formulation).
+    #: Which is faster on the chip, at which capacity: not measured
+    #: (ROADMAP S2).
     push_mode: str = "auto"
 
 
@@ -145,10 +145,10 @@ def cache_push_dense(
 
     Rationale: the reference's merge_grad (cub sort + reduce,
     heter_comm_inl.h:388) exists because GPUs update rows one-thread-
-    per-row; on TPU a sort + row gather/scatter of ~100k rows costs
-    ~25 ms while streaming the whole 2M-row table through the VPU costs
-    <1 ms (BENCH_DECOMP.md) — so the TPU shape of "merge then update
-    touched rows" is "scatter-add then masked dense update". "Touched"
+    per-row; the TPU shape of "merge then update touched rows" is
+    "scatter-add then masked dense update" — cost O(capacity), against
+    the sparse shape's sort + row gather/scatter at O(batch) (their
+    crossover on the chip: not measured, ROADMAP S2). "Touched"
     means PRESENT IN THE BATCH (an occurrence count rides the
     accumulator), exactly the sparse path's `uniq` membership — so a
     row whose occurrences all carry show=0 still gets the rule applied

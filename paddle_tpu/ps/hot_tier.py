@@ -104,14 +104,14 @@ class HotTierConfig:
     #: tight can prefer "dense" even off-TPU: its capacity-stream can
     #: undercut the sparse mode's per-key sort at large batches.
     push_mode: str = "auto"
-    #: sparse-kernel implementation (ops/hot_kernels.py): "pallas" runs
-    #: the fused probe+gather and scatter+apply kernels (interpret mode
-    #: off-TPU — the CI/parity configuration), "jnp" the reference
-    #: formulation (two bucket gathers + separate gather + unique/
-    #: gather/update/scatter), "auto" = pallas on TPU, jnp elsewhere.
-    #: The pallas push is the SPARSE (merge_grad) formulation — pair it
-    #: with push_mode="sparse" (or "auto" off-TPU) when pinning parity
-    #: against the jnp oracle.
+    #: sparse-kernel implementation (ops/hot_kernels.py): "jnp" is the
+    #: XLA formulation (two bucket gathers + separate gather + the
+    #: push_mode push), "pallas" the fused probe+gather and
+    #: scatter+apply kernels — interpret mode off-TPU (the parity
+    #: configuration); on the chip Mosaic refuses them today, loudly.
+    #: "auto" = jnp on every backend. The pallas push is the SPARSE
+    #: (merge_grad) formulation — pair it with push_mode="sparse" when
+    #: pinning parity against the jnp oracle.
     kernels: str = "auto"
     #: NUMA-style bucket/row banks (ps/device_hash.py): keys hash to a
     #: bank with a FIXED seed; a bank's rows live in one contiguous HBM

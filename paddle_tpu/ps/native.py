@@ -1,9 +1,13 @@
 """ctypes bindings for the native library (csrc/).
 
-Builds ``libpaddle_tpu_native.so`` on first use (make, cached); if the
-toolchain is unavailable, ``FeasignIndex`` falls back to a pure-Python
-dict implementation with identical semantics so the framework stays
-importable (slower, flagged via ``native_available()``).
+``load_native`` runs ``make`` in csrc/ on first use — make decides what
+is stale (sources, headers, the Makefile and a build stamp that covers
+the sanitizer flavor AND this host's CPU, because the library is built
+``-march=native``) — then loads ``libpaddle_tpu_native.so``. A build or
+load that FAILS raises with the compiler's output. Only a host with no
+``make`` at all gets ``None``: there ``FeasignIndex`` and the tables run
+their pure-Python implementations (slower, flagged via
+``native_available()``).
 """
 
 from __future__ import annotations
@@ -17,45 +21,45 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 __all__ = ["FeasignIndex", "NativeSparseTableEngine", "SsdTableEngine",
-           "native_available", "load_native", "dedup_u64"]
+           "native_available", "load_native", "build_native", "dedup_u64"]
 
-_CSRC = os.path.join(os.path.dirname(__file__), "..", "csrc")
+_CSRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "csrc"))
 _LIB_PATH = os.path.join(_CSRC, "libpaddle_tpu_native.so")
 _LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
-_TRIED = False
+_NO_TOOLCHAIN = False
+
+
+def build_native(force: bool = False) -> bool:
+    """``make`` the library from csrc/ (``force`` rebuilds everything —
+    chip_smoke.py uses it so the library it loads was compiled in that
+    run, on that machine). Returns False when the host has no ``make``;
+    raises RuntimeError with the build output when the build fails."""
+    try:
+        out = subprocess.run(
+            ["make", "-s"] + (["-B"] if force else []), cwd=_CSRC,
+            capture_output=True, text=True, timeout=600)
+    except FileNotFoundError:
+        return False
+    if out.returncode != 0:
+        raise RuntimeError(
+            f"native build failed (make rc={out.returncode} in {_CSRC}):\n"
+            f"{out.stdout[-2000:]}{out.stderr[-4000:]}")
+    return True
 
 
 def load_native() -> Optional[ctypes.CDLL]:
-    global _LIB, _TRIED
+    global _LIB, _NO_TOOLCHAIN
     with _LOCK:
-        if _LIB is not None or _TRIED:
+        if _LIB is not None or _NO_TOOLCHAIN:
             return _LIB
-        _TRIED = True
-        try:
-            if not os.path.exists(_LIB_PATH) or _stale():
-                subprocess.run(
-                    ["make", "-s"], cwd=os.path.abspath(_CSRC), check=True,
-                    capture_output=True, timeout=120,
-                )
-            lib = ctypes.CDLL(os.path.abspath(_LIB_PATH))
-            _configure(lib)
-            _LIB = lib
-        except Exception:
-            _LIB = None
+        if not build_native():
+            _NO_TOOLCHAIN = True
+            return None
+        lib = ctypes.CDLL(_LIB_PATH)
+        _configure(lib)
+        _LIB = lib
         return _LIB
-
-
-def _stale() -> bool:
-    try:
-        lib_m = os.path.getmtime(_LIB_PATH)
-        return any(
-            os.path.getmtime(os.path.join(_CSRC, f)) > lib_m
-            for f in os.listdir(_CSRC)
-            if f.endswith(".cc")
-        )
-    except OSError:
-        return True
 
 
 def _configure(lib: ctypes.CDLL) -> None:

@@ -17,7 +17,13 @@ multi-host (ISSUE 18):
   retrieval fan-out wants embedding rows, scoring happens upstream),
   and a :class:`~.rollout.DenseModel` rollout identity. It then serves
   a length-prefixed binary TCP protocol and prints
-  ``MEMBER_READY <lease_endpoint> <serve_addr>``.
+  ``MEMBER_READY <lease_endpoint> <serve_addr> <jax platform>``. The
+  child jits on ITS default device and inherits the parent's
+  environment: a chip belongs to one process, so a parent that has
+  touched JAX on the chip cannot give a child the same chip — spawn
+  members from a JAX-free parent, or export ``JAX_PLATFORMS=cpu`` for
+  them. The child's stderr rides its stdout pipe, so that failure is
+  reported, not waited out.
 - **parent** — :func:`spawn_member` launches the child and wraps it in
   a standard :class:`~.fleet.FleetMember` whose pieces are proxies:
   :class:`RemoteFrontend` (socket-per-worker thread pool satisfying the
@@ -414,11 +420,15 @@ def spawn_member(store_spec: str, job_id: str, *, shard: int = 0,
            "prime_pow2_max": int(prime_pow2_max),
            "hb_interval": float(hb_interval), "hb_ttl": float(hb_ttl),
            "host": host}
+    # stderr rides the same pipe as stdout: a child that dies before
+    # MEMBER_READY (it cannot have the chip this process holds, a bad
+    # import, ...) is reported with its own words instead of a silent
+    # wait for ready_timeout_s
     proc = subprocess.Popen(
         [sys.executable, "-m", "paddle_tpu.serving.member_host",
          json.dumps(cfg)],
         stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-        stderr=subprocess.DEVNULL, text=True)
+        stderr=subprocess.STDOUT, text=True)
     lines: "queue.Queue[str]" = _sync.Queue(maxsize=256)
     log: deque = deque(maxlen=64)
 
@@ -434,20 +444,22 @@ def spawn_member(store_spec: str, job_id: str, *, shard: int = 0,
                           name=f"member-stdout:{job_id}/{shard}")
     reader.start()
     deadline = time.perf_counter() + float(ready_timeout_s)
-    lease_ep = serve_addr = None
+    lease_ep = serve_addr = platform = None
     while True:
         rem = deadline - time.perf_counter()
         if rem <= 0 or proc.poll() is not None:
             proc.kill()
+            proc.wait(timeout=10)
+            reader.join(timeout=5)   # the pipe closed — drain its tail
             raise TimeoutError(
                 f"member child never became ready (rc={proc.poll()}); "
-                f"last output: {list(log)[-5:]}")
+                "last output:\n" + "\n".join(log))
         try:
             line = lines.get(timeout=min(rem, 0.5))
         except queue.Empty:
             continue
         if line.startswith("MEMBER_READY "):
-            _, lease_ep, serve_addr = line.split()
+            _, lease_ep, serve_addr, platform = line.split()
             break
         if line.startswith("MEMBER_FAILED"):
             proc.kill()
@@ -471,6 +483,9 @@ def spawn_member(store_spec: str, job_id: str, *, shard: int = 0,
     member = FleetMember(replica, None, frontend, model=model,
                          extra_close=_reap)
     member.serve_addr = serve_addr
+    #: the jax platform the CHILD's lookups run on, as the child reports
+    #: it — never assumed from this process's environment
+    member.platform = platform
     member.warm = lambda keys: json.loads(ctl.call(
         _OP_WARM, struct.pack("<I", len(keys))
         + np.ascontiguousarray(keys, np.uint64).tobytes(),
@@ -484,6 +499,9 @@ def spawn_member(store_spec: str, job_id: str, *, shard: int = 0,
 
 def _child_main(cfg: Dict[str, Any]) -> int:
     # heavyweight imports live here: the parent pays none of them
+    import jax
+
+    from ..core.compile_cache import enable_compile_cache
     from ..distributed.elastic import store_from_spec
     from ..ps.ha import RoutingTable
     from ..ps.hot_tier import HotEmbeddingTier, HotTierConfig
@@ -494,6 +512,10 @@ def _child_main(cfg: Dict[str, Any]) -> int:
     from .replica import ServingReplica
     from .rollout import DenseModel
 
+    enable_compile_cache()
+    # touch the backend NOW: a child that cannot have its device (the
+    # parent holds the chip) fails here, on stderr, before any lease
+    platform = jax.devices()[0].platform
     store = store_from_spec(cfg["store"])
     job_id = str(cfg["job_id"])
     shard = int(cfg.get("shard", 0))
@@ -653,7 +675,7 @@ def _child_main(cfg: Dict[str, Any]) -> int:
         finally:
             conn.close()
 
-    print(f"MEMBER_READY {rep.endpoint} {serve_addr}", flush=True)
+    print(f"MEMBER_READY {rep.endpoint} {serve_addr} {platform}", flush=True)
     srv.settimeout(0.2)
     handlers: List[threading.Thread] = []
     while not stop_ev.is_set():

@@ -123,10 +123,8 @@ def test_moe_ep_matches_serial():
 
 def test_tp_grads_match_serial():
     """TP+DP gradients vs jax.grad of the serial model — written in the
-    sanctioned explicit-reduction pattern (the hybrid trainer's): jax
-    0.4.x shard_map cannot be trusted to transpose psums through this
-    model (this test failed at PR-2 baseline with the rep-tracking
-    form), so the loss psum and the PCE reductions are pinned-VJP
+    sanctioned explicit-reduction pattern (the hybrid trainer's): the
+    loss psum and the PCE reductions are identity-VJP
     (``pinned_vjp=True``), the shard_map runs ``check_vma=False``, and
     each param's grad is explicitly psum'd over every mesh axis it is
     NOT sharded on."""

@@ -198,13 +198,12 @@ def test_hybrid_sharding_matches_unsharded_and_restores(tmp_path):
 
 @pytest.mark.slow
 def test_hybrid_grads_match_serial():
-    """The serial-gradient oracle that caught PR 3's fix targets: under
-    jax 0.4.x the un-pinned psums (hybrid loss, pipe masked psum, the
-    standalone parallel_cross_entropy) plus the rep-tracker's confusion
-    over the no-op pcast shim produced grads that were ×mp on aux
-    params and ZERO on the head — while every loss-only test passed.
-    One SGD(lr=1) step must now reproduce jax.grad of the equivalent
-    serial model to fp32 roundoff on every parameter."""
+    """The serial-gradient oracle for the hybrid step's explicit
+    reductions (hybrid loss, pipe masked psum, parallel_cross_entropy):
+    un-pinned psums once produced grads that were ×mp on aux params and
+    ZERO on the head while every loss-only test passed. One SGD(lr=1)
+    step must reproduce jax.grad of the equivalent serial model to fp32
+    roundoff on every parameter."""
     pt.seed(0)
     mesh = mesh_mod.make_mesh({"dp": 2, "pp": 2, "cp": 1, "mp": 2})
     tr = HybridParallelTrainer(CFG, mesh, optimizer.SGD(1.0), num_micro=2)

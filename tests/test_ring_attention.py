@@ -71,11 +71,8 @@ def test_ring_attention_backward_matches_full(mesh, qkv):
 
     def ring_loss(q, k, v):
         out = ring_attention(q, k, v, axis="cp", causal=True)
-        # pinned-VJP psum: the loss cotangent is replicated over cp, and
-        # jax-0.4.x shard_map transposes a plain psum into another psum,
-        # scaling every grad by the axis size (the parallel_cross_entropy
-        # drift fixed in PR 2/3) — psum_replicated pins the identity
-        # backward so per-rank cotangents stay unscaled
+        # the loss cotangent is replicated over cp: psum_replicated
+        # pins the identity backward so per-rank cotangents stay unscaled
         return coll.psum_replicated(jnp.sum(out ** 2), "cp")
 
     grads = shard_map(

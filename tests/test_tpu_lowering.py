@@ -1,0 +1,282 @@
+"""Compile for the chip without a chip.
+
+``jax.experimental.topologies.get_topology_desc(platform="tpu",
+topology_name="v5e:2x2")`` hands back four ``TPU v5 lite`` devices that
+need no hardware, and ``jit(f).lower(<ShapeDtypeStruct on them>).compile()``
+runs XLA:TPU and the real Mosaic compiler. So every Pallas entry point is
+compiled here with ``interpret=False`` on every PR (tier-1, ~1 s each): a
+kernel Mosaic refuses fails without spending chip time. The three
+hot-tier kernels ARE refused today; their tests pin the compiler's words
+(strict xfail) and are the reason ``HotTierConfig.kernels="auto"`` means
+jnp — a rewrite that compiles turns them into failures that say so.
+
+Marked ``slow``: the four steps ``chip_smoke.py`` runs, at its widths,
+with ``jax.default_backend`` patched to "tpu" so every ``auto`` switch
+takes the branch the chip takes.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+pytest.importorskip("libtpu", reason="compiling for TPU needs libtpu")
+
+from paddle_tpu.ops import hot_kernels  # noqa: E402
+from paddle_tpu.ops.flash_attention import flash_attention  # noqa: E402
+from paddle_tpu.ops.sparse_optimizer import (ctr_sparse_rows,  # noqa: E402
+                                             rule_state_dim)
+from paddle_tpu.ps.embedding_cache import CacheConfig  # noqa: E402
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402  (the widths and the DeepFM the smoke runs)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    devices = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices
+    assert len(devices) == 4 and devices[0].device_kind == "TPU v5 lite"
+    return devices
+
+
+def _shapes(tree, sharding=None):
+    """The arrays of ``tree`` as ShapeDtypeStructs (placed by ``sharding``):
+    compiling needs shapes alone."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=sharding), tree)
+
+
+def _compile(fn, sharding, *args):
+    return jax.jit(fn).lower(*_shapes(args, sharding)).compile()
+
+
+def _rng_key(sharding=None):
+    return jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=sharding)
+
+
+def _z(*shape, dtype=jnp.float32):
+    return np.zeros(shape, dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_fwd_bwd_compiles(v5e, dtype):
+    q = _z(2, 512, 12, 64, dtype=dtype)   # ERNIE-1.0 base's head shape
+
+    def fwd_bwd(q, k, v):
+        loss = lambda q, k, v: flash_attention(
+            q, k, v, interpret=False).astype(jnp.float32).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    hlo = _compile(fwd_bwd, SingleDeviceSharding(v5e[0]), q, q, q).as_text()
+    assert hlo.count("tpu_custom_call") == 3   # fwd, dq, dk/dv
+
+
+@pytest.mark.parametrize("rule", ["naive", "adagrad", "std_adagrad", "adam"])
+def test_ctr_sparse_rows_compiles(v5e, rule):
+    n, dim = 2048, 8
+    es, xs = rule_state_dim(rule, 1), rule_state_dim(rule, dim)
+
+    def update(*cols):
+        return ctr_sparse_rows(
+            cols[:7], *cols[7:], embed_rule=rule, embedx_rule=rule, lr=0.05,
+            initial_g2sum=3.0, weight_bounds=(-10.0, 10.0), beta1=0.9,
+            beta2=0.999, eps=1e-8, nonclk_coeff=0.1, click_coeff=1.0,
+            embedx_threshold=0.0, interpret=False)
+
+    hlo = _compile(update, SingleDeviceSharding(v5e[0]),
+                   _z(n), _z(n), _z(n, 1), _z(n, es), _z(n, dim), _z(n, xs),
+                   _z(n), _z(n), _z(n), _z(n, 1), _z(n, dim)).as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def _hot_args(C=4096, n=512, nb=1024):
+    u32 = lambda *s: _z(*s, dtype=jnp.uint32)
+    map_state = {"hi": u32(nb, 8), "lo": u32(nb, 8),
+                 "row": _z(nb, 8, dtype=jnp.int32), "seed": np.uint32(1)}
+    tier = {"show": _z(C), "click": _z(C), "embed_w": _z(C, 1),
+            "embed_state": _z(C, 1), "embedx_w": _z(C, 8),
+            "embedx_state": _z(C, 1), "has_embedx": _z(C)}
+    return map_state, tier, u32(n), n, C
+
+
+@pytest.mark.xfail(strict=True, raises=ValueError,
+                   reason="Mosaic: in-kernel jnp.take row gather — 'Shape "
+                          "mismatch in input, indices and output'")
+def test_hot_probe_gather_compiles(v5e):
+    map_state, tier, keys, _, _ = _hot_args()
+    _compile(lambda m, hi, lo, t: hot_kernels.hot_probe_gather(
+        m, hi, lo, t, probe_buckets=2, interpret=False),
+        SingleDeviceSharding(v5e[0]), map_state, keys, keys, tier)
+
+
+@pytest.mark.xfail(strict=True, raises=ValueError,
+                   reason="Mosaic: in-kernel jnp.take row gather — 'Shape "
+                          "mismatch in input, indices and output'")
+def test_hot_probe_compiles(v5e):
+    map_state, _, keys, _, _ = _hot_args()
+    _compile(lambda m, hi, lo: hot_kernels.hot_probe(
+        m, hi, lo, probe_buckets=2, interpret=False),
+        SingleDeviceSharding(v5e[0]), map_state, keys, keys)
+
+
+@pytest.mark.xfail(strict=True, raises=Exception,
+                   reason="Mosaic: scalar rows_ref[i] read from a VMEM vector "
+                          "— 'cannot statically prove that index in "
+                          "dimension 0 is a multiple of …'")
+def test_hot_scatter_apply_compiles(v5e):
+    _, tier, _, n, C = _hot_args()
+    cfg = CacheConfig(capacity=C, embedx_dim=8, embedx_threshold=0.0)
+    _compile(lambda t, r, g, s, c: hot_kernels.hot_scatter_apply(
+        t, r, g, s, c, cfg, interpret=False),
+        SingleDeviceSharding(v5e[0]), tier, _z(n, dtype=jnp.int32),
+        _z(n, 9), _z(n), _z(n))
+
+
+# ---------------------------------------------------------------------------
+# the steps chip_smoke.py runs, at its widths, as the chip compiles them
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Every ``auto`` switch keys on ``jax.default_backend()``."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+SZ = chip_smoke.Sizes()
+
+
+def _deepfm_and_adam():
+    from paddle_tpu import optimizer
+
+    model = chip_smoke._deepfm(SZ)
+    return (model, optimizer.Adam(learning_rate=1e-3),
+            {"params": dict(model.named_parameters()), "buffers": {}})
+
+
+def _ctr_state(n_keys):
+    """Shapes of a pass cache + cuckoo map at the smoke's widths."""
+    C, xd = SZ.capacity, SZ.embedx_dim
+    nb = 64
+    while nb * 4 < 2 * n_keys:
+        nb <<= 1
+    cache = {"show": _z(C), "click": _z(C), "embed_w": _z(C, 1),
+             "embed_state": _z(C, 1), "embedx_w": _z(C, xd),
+             "embedx_state": _z(C, 1), "has_embedx": _z(C)}
+    cmap = {"hi": _z(nb, 4, dtype=jnp.uint32), "lo": _z(nb, 4, dtype=jnp.uint32),
+            "row": _z(nb, 4, dtype=jnp.int32), "seed": np.uint32(0)}
+    return cache, cmap
+
+
+@pytest.mark.slow
+def test_pass_step_compiles(v5e, as_tpu):
+    from paddle_tpu.models.ctr import _packed_layout, make_ctr_train_step_slab
+
+    model, opt, params = _deepfm_and_adam()
+    step = make_ctr_train_step_slab(
+        model, opt, chip_smoke._cache_cfg(SZ), slot_ids=np.arange(SZ.slots),
+        batch_size=SZ.batch, num_dense=SZ.dense, slab=SZ.slab,
+        with_weights=True, amp=True)
+    cache, cmap = _ctr_state(1 << 20)
+    total = _packed_layout(SZ.batch, SZ.slots, SZ.dense, True)[3]
+    _compile(step, SingleDeviceSharding(v5e[0]), params, opt.init(params),
+             cache, cmap, _z(SZ.slab, total, dtype=jnp.uint8))
+
+
+@pytest.mark.slow
+def test_stream_step_compiles(v5e, as_tpu):
+    from paddle_tpu.ps.device_hash import DynamicDeviceKeyMap
+    from paddle_tpu.ps.hot_tier import make_hot_ctr_train_step
+
+    model, opt, params = _deepfm_and_adam()
+    cfg = CacheConfig(capacity=SZ.capacity, embedx_dim=SZ.embedx_dim)
+    dmap = DynamicDeviceKeyMap(SZ.capacity)
+    step = make_hot_ctr_train_step(
+        model, opt, cfg, slot_ids=np.arange(SZ.slots),
+        probe_buckets=dmap.probe_buckets, banks=dmap.banks)   # kernels="auto"
+    tier, _ = _ctr_state(1)
+    hlo = _compile(step, SingleDeviceSharding(v5e[0]), params, opt.init(params),
+                   tier, dmap.device_state(),
+                   _z(SZ.batch, SZ.slots, dtype=jnp.uint32),
+                   _z(SZ.batch, SZ.dense), _z(SZ.batch, dtype=jnp.int32)
+                   ).as_text()
+    assert "tpu_custom_call" not in hlo   # auto = the jnp formulation
+
+
+@pytest.mark.slow
+def test_dense_step_compiles(v5e, as_tpu):
+    from paddle_tpu import nn, optimizer
+    from paddle_tpu.executor import make_train_step
+    from paddle_tpu.models.ernie import Ernie, ErnieConfig
+
+    model = Ernie(ErnieConfig(
+        vocab_size=SZ.vocab, hidden_size=SZ.hidden, num_heads=SZ.heads,
+        ffn_size=SZ.ffn, num_layers=SZ.layers, max_seq_len=SZ.seq))
+    opt = optimizer.Adam(learning_rate=1e-4)
+    step = make_train_step(model, opt, nn.functional.cross_entropy, amp=True)
+    state = nn.get_state(model)
+    ids = (_z(SZ.ernie_batch, SZ.seq, dtype=jnp.int32),)
+    s = SingleDeviceSharding(v5e[0])
+    compiled = step.lower(
+        _shapes(state, s), _shapes(opt.init(state["params"]), s), _rng_key(s),
+        _shapes(ids, s), _shapes(ids, s)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3 * SZ.layers
+    assert compiled.memory_analysis().temp_size_in_bytes < 12 * 2**30
+
+
+@pytest.mark.slow
+def test_four_chip_step_compiles(v5e, as_tpu):
+    from jax.sharding import Mesh
+
+    from paddle_tpu.ps.sharded_cache import (
+        make_sharded_ctr_train_step_from_keys)
+
+    mesh = Mesh(np.asarray(v5e), ("ps",))
+    model, opt, params = _deepfm_and_adam()
+    step = make_sharded_ctr_train_step_from_keys(
+        model, opt, chip_smoke._cache_cfg(SZ), mesh,
+        slot_ids=np.arange(SZ.slots), axis="ps")
+    cache, cmap = _ctr_state(2 * SZ.batch * SZ.slots)
+    rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P("ps"))
+    batch = (_z(SZ.batch, SZ.slots, dtype=jnp.uint32),
+             _z(SZ.batch, SZ.dense), _z(SZ.batch, dtype=jnp.int32))
+    hlo = step.lower(
+        *_shapes((params, opt.init(params)), rep), _shapes(cache, row),
+        _shapes(cmap, rep), *_shapes(batch, row)).compile().as_text()
+    assert "all-to-all" in hlo   # K=4: auto routes
+
+
+@pytest.mark.slow
+def test_hybrid_step_compiles(v5e, as_tpu):
+    """The dp×pp×cp×mp step of ``__graft_entry__.run_hybrid_step`` on
+    the 2x2: flash attention under shard_map, ppermute, TP psums."""
+    from __graft_entry__ import _factorize
+
+    from paddle_tpu import optimizer
+    from paddle_tpu.core import mesh as mesh_mod
+    from paddle_tpu.models.ernie import ErnieConfig
+    from paddle_tpu.parallel.hybrid import HybridParallelTrainer
+
+    sizes = _factorize(len(v5e))
+    pp, mp = sizes["pp"], sizes["mp"]
+    mesh = mesh_mod.make_mesh(sizes, devices=v5e)
+    cfg = ErnieConfig(vocab_size=64 * mp, hidden_size=8 * mp,
+                      num_heads=2 * mp, ffn_size=16 * mp, num_layers=pp,
+                      max_seq_len=64)
+    tr = HybridParallelTrainer(cfg, mesh, optimizer.Adam(learning_rate=1e-3),
+                               num_micro=2)
+    ids = jax.ShapeDtypeStruct((2, 2 * sizes["dp"], 4 * sizes["cp"]),
+                               jnp.int32)
+    hlo = tr._step.lower(_shapes(tr.params), _shapes(tr.opt_state), ids, ids,
+                         _rng_key()).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 3 * cfg.num_layers // pp

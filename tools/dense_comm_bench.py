@@ -14,8 +14,7 @@ quantized path costs a rung, not the number); every rung's result (or
 error) is recorded under ``ladder``.
 
 Standalone: prints exactly ONE JSON line (driver contract). Importable:
-``run()`` returns the record — bench.py embeds it in its single
-emission under ``dense_comm``. Env knobs: DCB_BATCH, DCB_STEPS,
+``run()`` returns the record. Env knobs: DCB_BATCH, DCB_STEPS,
 DCB_WARMUP, DCB_HIDDEN, DCB_LAYERS, DCB_BUCKET_MB, DCB_BLOCK.
 """
 

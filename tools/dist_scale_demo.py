@@ -23,7 +23,7 @@ DIST_DIR (tmp), DIST_CHUNK (4M rows per load_cold wave),
 DIST_CONVERTER (gzip | raw — the committed artifact used gzip; raw is
 ~6x faster at ~2x the bytes, see the save_local docstring).
 
-Single-core host caveat (MEASURED.md): run ALONE in the foreground;
+Single-core host caveat: run ALONE in the foreground;
 rates measured under concurrent load are garbage.
 """
 

@@ -39,15 +39,7 @@ def main() -> None:
     platform = os.environ.get("RG_PLATFORM", "cpu")
     jax.config.update("jax_platforms", platform)
     if platform == "cpu":  # before any backend-initializing jax call
-        try:
-            jax.config.update("jax_num_cpu_devices", 8)
-        except AttributeError:  # older jax: XLA_FLAGS fallback below
-            flags = os.environ.get("XLA_FLAGS", "")
-            if "xla_force_host_platform_device_count" not in flags:
-                os.environ["XLA_FLAGS"] = (
-                    flags + " --xla_force_host_platform_device_count=8"
-                ).strip()
-    import paddle_tpu  # noqa: F401  (installs jax compat shims)
+        jax.config.update("jax_num_cpu_devices", 8)
     import jax.numpy as jnp
     from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P

@@ -20,10 +20,6 @@ import sys
 import time
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-flags = os.environ.get("XLA_FLAGS", "")
-if "host_platform_device_count" not in flags:
-    os.environ["XLA_FLAGS"] = (
-        flags + " --xla_force_host_platform_device_count=8").strip()
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np  # noqa: E402
@@ -33,11 +29,7 @@ def main() -> None:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 8)
-    except AttributeError:
-        pass  # older jax: module top already set the XLA device-count flag
-    import paddle_tpu  # noqa: F401  (installs jax compat shims)
+    jax.config.update("jax_num_cpu_devices", 8)
     import jax.numpy as jnp
     from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P

@@ -14,7 +14,7 @@ closed loop self-throttles around it). Phases:
    ONE-member fleet at the steady rate: the apples-to-apples p99
    baseline for the "fleet p99 within 2× of single-replica" prong.
    The committed SERVING.json p99 is a closed-loop number from a
-   different host generation (2 cores then, 1 now — MEASURED.md rule:
+   different host generation (2 cores then, 1 now —
    cross-record ratios are not comparable, same-box re-measurement
    is), so the fleet tax must be measured against a same-box,
    same-driver single member.

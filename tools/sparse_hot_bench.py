@@ -28,8 +28,7 @@ counter), and the tier's hit-rate/occupancy stats. The headline
 0-RPC claim ride the record for the CI full gate.
 
 Standalone: prints exactly ONE JSON line (driver contract). Importable:
-``run()`` returns the record — bench.py embeds it in its single
-emission under ``sparse_hot``. Env knobs: SHB_BATCH, SHB_SAMPLES,
+``run()`` returns the record. Env knobs: SHB_BATCH, SHB_SAMPLES,
 SHB_NID, SHB_CAPACITY, SHB_SLOTS, SHB_SHARDED (0 skips the rung),
 SHB_KERNELS (hot-tier kernels knob: auto|pallas|jnp).
 """
@@ -224,7 +223,7 @@ def _run_sharded(p):
 
 def _sharded_rung(p):
     """In-process on a multi-device backend; otherwise a subprocess with
-    8 virtual CPU devices (the bench.py dense_comm pattern)."""
+    8 virtual CPU devices."""
     if os.environ.get("SHB_SHARDED", "1") != "1":
         return None
     try:

@@ -13,7 +13,7 @@ One seeded CTR push workload (merged-duplicate batches against a real
 - int8 additionally reports the residual rows drained at the end (the
   error-feedback store's quiesce contract).
 
-Baseline-comparability note (the PR 12 lesson, MEASURED.md): every
+Baseline-comparability note (the PR 12 lesson): every
 ratio in this record is against THIS record's own fp32 rung — same
 transport, same PR-2 overlapped client, same host. Ratios are not
 comparable across records from different client eras; the committed
