@@ -1,0 +1,7 @@
+"""``dispatch_ms_p50`` for cells whose rate is ``tokens_per_s_per_chip`` (a
+per-layer metric names ONE end-to-end metric it moves, so the reader has a
+second name)."""
+
+from harness import spec
+
+read = spec.load_module("metrics", "dispatch_ms_p50").read
