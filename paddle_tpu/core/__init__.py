@@ -50,13 +50,11 @@ from .places import (
     set_device,
 )
 from .profiler import (
-    CostTimer,
     RecordEvent,
     host_event_stats,
+    host_spans,
     record_event,
     reset_host_events,
-    start_profiler,
-    stop_profiler,
 )
 
 # The bare `enforce` check function shadows the submodule name on the

@@ -1,20 +1,31 @@
-"""Host-side profiling annotations.
+"""Host-side profiling annotations, and the names of the program's work.
 
 Analogue of the reference's two-generation profiler
 (``platform/profiler.cc`` RecordEvent scopes; ``platform/profiler/``
 HostTracer + ChromeTracingLogger): a ``RecordEvent`` scope API that feeds
-both (a) ``jax.profiler`` trace annotations (→ XPlane/perfetto, the TPU
-replacement for CUPTI+chrome://tracing) and (b) a lightweight in-process
-host-event aggregator for per-scope wall-time statistics, mirroring the
-reference's CostProfiler (``distributed/common/cost_timer.h``).
+(a) ``jax.profiler`` trace annotations (so a span sits on the device
+trace's clock whenever a profile is being taken — ``jax.profiler.
+start_trace`` is the device profiler, this module wraps nothing round
+it), (b) an ``obs.trace`` span while distributed tracing is on, (c) a
+per-name wall-time aggregate (the reference's CostProfiler,
+``distributed/common/cost_timer.h``) and (d) a bounded in-memory ring of
+completed spans with parent ids and counts (``host_spans``).
+
+One vocabulary, ``pt.*``: ``DEVICE_SCOPES`` are the ``jax.named_scope``
+names inside the jitted steps (HLO metadata, trace-time only; an
+operation belongs to the LAST ``pt.`` token of its ``op_name``);
+``pt.pass.*`` are the host spans of the pass lifecycle
+(``ps/embedding_cache.py``). docs/OPERATIONS.md §10 lists both.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import itertools
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Any, Dict, Iterator, List, NamedTuple
 
 import jax
 
@@ -24,16 +35,30 @@ __all__ = [
     "RecordEvent",
     "timed",
     "record_event",
-    "profiler_enabled",
-    "start_profiler",
-    "stop_profiler",
     "host_event_stats",
     "reset_host_events",
+    "host_spans",
+    "HostSpan",
+    "DEVICE_SCOPES",
     "export_chrome_tracing",
     "start_timeline",
     "stop_timeline",
-    "CostTimer",
 ]
+
+#: every ``jax.named_scope`` the program opens in a jitted step or round a
+#: kernel. Names are HLO metadata: jax leaves metadata out of the
+#: persistent compile-cache key, so an executable cached before a scope
+#: existed comes back without it.
+DEVICE_SCOPES = (
+    "pt.unpack", "pt.probe", "pt.pull", "pt.tower", "pt.dense_opt",
+    "pt.push.accumulate", "pt.push.update", "pt.route",
+    "pt.embed", "pt.attn", "pt.ffn", "pt.head_loss", "pt.loss",
+    "pt.flash_fwd", "pt.flash_bwd_dq", "pt.flash_bwd_dkv",
+)
+
+#: completed spans kept in memory (newest win): a pass is about a dozen
+#: spans, a step one, so this holds the last few thousand steps
+SPAN_RING = 4096
 
 
 class _HostEvents:
@@ -68,58 +93,59 @@ class _HostEvents:
             self._max.clear()
 
 
+class HostSpan(NamedTuple):
+    """One completed ``RecordEvent``. ``t0`` is ``time.perf_counter``
+    seconds (add ``obs.trace.EPOCH_ANCHOR_US`` for the wall clock);
+    ``parent_id`` is the enclosing open ``RecordEvent`` of the same
+    thread, 0 for a root."""
+
+    name: str
+    t0: float
+    dur: float
+    span_id: int
+    parent_id: int
+    tid: int
+    counts: Dict[str, Any]
+
+
 _EVENTS = _HostEvents()
-_TRACING = threading.Event()
-_TRACE_DIR: List[Optional[str]] = [None]
+_SPANS: collections.deque = collections.deque(maxlen=SPAN_RING)
+_SPANS_LOCK = threading.Lock()
+_IDS = itertools.count(1)          # next() is atomic under the GIL
+_OPEN = threading.local()          # .stack: ids of this thread's open spans
 
 
-class _Timeline:
-    """Complete-event recording for the ChromeTracingLogger export
-    (platform/profiler/dump/chrometracing_logger.cc): one "X" (complete)
-    event per RecordEvent scope with thread id, start, duration."""
-
-    def __init__(self) -> None:
-        self._lock = threading.Lock()
-        self.enabled = False
-        self.events: List[Dict] = []
-
-    def add(self, name: str, t0: float, dur: float) -> None:
-        if not self.enabled:
-            return
-        with self._lock:
-            self.events.append({
-                "name": name,
-                "ph": "X",
-                "ts": t0 * 1e6,          # chrome tracing wants microseconds
-                "dur": dur * 1e6,
-                "pid": 0,
-                "tid": threading.get_ident() % 1_000_000,
-            })
-
-
-_TIMELINE = _Timeline()
+def host_spans() -> List[HostSpan]:
+    """The completed spans still in the ring, oldest first."""
+    with _SPANS_LOCK:
+        return list(_SPANS)
 
 
 def start_timeline() -> None:
-    """Begin recording host RecordEvent scopes for chrome://tracing
-    export (the legacy profiler's EnableProfiler analogue)."""
-    _TIMELINE.events.clear()
-    _TIMELINE.enabled = True
+    """Forget the spans recorded so far, so that the next export holds
+    only what follows (the legacy profiler's EnableProfiler analogue).
+    Recording itself is always on."""
+    with _SPANS_LOCK:
+        _SPANS.clear()
 
 
 def stop_timeline() -> None:
-    _TIMELINE.enabled = False
+    """Kept for callers that bracket a region; the ring needs no stop."""
 
 
 def export_chrome_tracing(path: str) -> str:
-    """Dump recorded host events in the chrome://tracing JSON format
+    """Dump the span ring in the chrome://tracing JSON format
     (chrometracing_logger.cc / tools/timeline.py output). Load via
     chrome://tracing or perfetto ui. Device-side traces come from
-    start_profiler()'s XPlane dump instead."""
+    ``jax.profiler.start_trace``'s XPlane dump instead."""
     import json
 
-    with _TIMELINE._lock:
-        events = list(_TIMELINE.events)
+    events = [{
+        "name": s.name, "ph": "X",
+        "ts": s.t0 * 1e6,          # chrome tracing wants microseconds
+        "dur": s.dur * 1e6, "pid": 0, "tid": s.tid,
+        "args": dict(s.counts, span_id=s.span_id, parent_id=s.parent_id),
+    } for s in host_spans()]
     # clockSyncUs: this process's wall anchor for its perf_counter
     # timestamps — tools/timeline.py aligns multi-worker lanes by it
     # instead of interleaving raw per-host monotonic clocks
@@ -131,65 +157,50 @@ def export_chrome_tracing(path: str) -> str:
 
 
 @contextlib.contextmanager
-def RecordEvent(name: str):
-    """Annotate a host scope; shows up in the jax.profiler trace and in
-    ``host_event_stats()``. Ops in the reference are auto-wrapped this way
-    inside OperatorBase::Run (operator.cc); here users and the framework's
-    train loops wrap logical phases (forward, backward, pull_sparse...).
+def RecordEvent(name: str, **counts) -> Iterator[Dict[str, Any]]:
+    """Annotate a host scope; shows up in the jax.profiler trace, in
+    ``host_event_stats()`` and in ``host_spans()``. Ops in the reference
+    are auto-wrapped this way inside OperatorBase::Run (operator.cc);
+    here users and the framework's train loops wrap logical phases
+    (forward, backward, pull_sparse...).
+
+    ``counts`` (numbers: keys, rows, bytes) ride the ``TraceAnnotation``
+    and the span record. The scope yields that record's dict: a count
+    known only once the work is done (``ev["bytes"] = a.nbytes``) is
+    added there and reaches the record and the obs span, not the
+    annotation, which is written at entry.
 
     While distributed tracing is on (``obs.trace.start_tracing``) every
     RecordEvent scope ALSO opens an obs span — the existing annotations
     (``pserver_client_pull_sparse``, ``ctr_train_step``, …) become the
     client side of the cross-process timeline for free; tracing off
     costs one module-bool check."""
-    t0 = time.perf_counter()
+    stack = getattr(_OPEN, "stack", None)
+    if stack is None:
+        stack = _OPEN.stack = []
+    span_id = next(_IDS)
+    parent_id = stack[-1] if stack else 0
+    stack.append(span_id)
     obs = (_obs_trace.span(name) if _obs_trace.tracing_enabled()
            else contextlib.nullcontext())
-    with jax.profiler.TraceAnnotation(name), obs:
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(name, **counts), obs as obs_span:
         try:
-            yield
+            yield counts
         finally:
             dt = time.perf_counter() - t0
+            stack.pop()
+            if obs_span is not None:
+                for k, v in counts.items():
+                    obs_span.add_attr(k, v)
             _EVENTS.add(name, dt)
-            _TIMELINE.add(name, t0, dt)
+            with _SPANS_LOCK:
+                _SPANS.append(HostSpan(
+                    name, t0, dt, span_id, parent_id,
+                    threading.get_ident() % 1_000_000, counts))
 
 
 record_event = RecordEvent
-
-
-class CostTimer:
-    """Reference ``CostTimer`` (cost_timer.h:29): explicit start/stop timer
-    feeding the same aggregator, for non-scope-shaped measurement."""
-
-    def __init__(self, name: str) -> None:
-        self._name = name
-        self._t0 = time.perf_counter()
-
-    def stop(self) -> float:
-        dt = time.perf_counter() - self._t0
-        _EVENTS.add(self._name, dt)
-        return dt
-
-
-def start_profiler(log_dir: str = "/tmp/paddle_tpu_trace") -> None:
-    """Start a jax.profiler trace (XPlane; view with tensorboard/perfetto)."""
-    if _TRACING.is_set():
-        return
-    jax.profiler.start_trace(log_dir)
-    _TRACE_DIR[0] = log_dir
-    _TRACING.set()
-
-
-def stop_profiler() -> Optional[str]:
-    if not _TRACING.is_set():
-        return None
-    jax.profiler.stop_trace()
-    _TRACING.clear()
-    return _TRACE_DIR[0]
-
-
-def profiler_enabled() -> bool:
-    return _TRACING.is_set()
 
 
 def host_event_stats() -> Dict[str, Dict[str, float]]:

@@ -61,7 +61,8 @@ def make_train_step(
                     rng=rng,
                     training=True,
                 )
-                loss = loss_fn(out, *labels)
+                with jax.named_scope("pt.loss"):
+                    loss = loss_fn(out, *labels)
                 # AMP loss scaling: grads are taken of the scaled loss;
                 # the AMPOptimizer unscales them inside update
                 # (amp.GradScaler)
@@ -247,6 +248,16 @@ class Trainer:
         if self._dump_fh is not None:
             self._dump(inputs, labels, loss)
         return loss
+
+    def compiled_text(self, inputs, labels) -> str:
+        """Optimised HLO text of the train step on these arguments, with
+        each instruction's ``op_name`` (the ``pt.*`` scopes of
+        ``core/profiler.DEVICE_SCOPES``): what a profile's operation
+        names are grouped by. A step that already ran on such arguments
+        is in the compile cache, so this costs a load."""
+        return self._train_step.lower(
+            self.state, self.opt_state, self._rng, _as_tuple(inputs),
+            _as_tuple(labels)).compile().as_text()
 
     def predict(self, inputs):
         inputs = _as_tuple(inputs)

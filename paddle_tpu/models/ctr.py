@@ -289,8 +289,9 @@ def _ctr_step_body(model, optimizer, cache_cfg, params, opt_state,
         built = builder(model, dense_x, labels, weights, real)
     else:
         built = builder(model, dense_x, labels, weights)
-    (loss, _), (grads, emb_grad) = jax.value_and_grad(
-        built, argnums=(0, 1), has_aux=True)(params, emb)
+    with jax.named_scope("pt.tower"):  # forward and backward of the model
+        (loss, _), (grads, emb_grad) = jax.value_and_grad(
+            built, argnums=(0, 1), has_aux=True)(params, emb)
 
     new_params, new_opt = optimizer.update(grads, opt_state, params)
     # the click task is column 0 when labels carry multiple tasks
@@ -433,14 +434,15 @@ def _unpack_ctr(packed, B, S, D, o_dense, o_label, o_weight, with_weights):
     (lo32, dense, labels, weights) — static offsets."""
     from jax import lax
 
-    lo = lax.bitcast_convert_type(
-        packed[:o_dense].reshape(B * S, 4), jnp.uint32)
-    dense_x = lax.bitcast_convert_type(
-        packed[o_dense:o_label].reshape(B, D, 2), jnp.float16)
-    labels = lax.bitcast_convert_type(packed[o_label:o_weight], jnp.int8)
-    weights = (packed[o_weight:].astype(jnp.float32)
-               if with_weights else None)
-    return lo, dense_x, labels, weights
+    with jax.named_scope("pt.unpack"):
+        lo = lax.bitcast_convert_type(
+            packed[:o_dense].reshape(B * S, 4), jnp.uint32)
+        dense_x = lax.bitcast_convert_type(
+            packed[o_dense:o_label].reshape(B, D, 2), jnp.float16)
+        labels = lax.bitcast_convert_type(packed[o_label:o_weight], jnp.int8)
+        weights = (packed[o_weight:].astype(jnp.float32)
+                   if with_weights else None)
+        return lo, dense_x, labels, weights
 
 
 def make_ctr_train_step_packed(
@@ -542,7 +544,8 @@ def _lookup_rows(cache_state, map_state, hi, lo):
     dropped push) — ONE definition for the packed and from-keys steps."""
     rows = device_hash_lookup(map_state, hi, lo)
     C = cache_state["embed_w"].shape[0]
-    return jnp.where(rows >= 0, rows, C)
+    with jax.named_scope("pt.probe"):
+        return jnp.where(rows >= 0, rows, C)
 
 
 def make_ctr_train_step_from_keys(

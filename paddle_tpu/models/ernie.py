@@ -142,13 +142,14 @@ class ErnieEmbedding(Layer):
 
     def forward(self, ids: jax.Array) -> jax.Array:
         cfg = self.cfg
-        x = _take_rows(self.word_emb, ids, cfg.vocab_size, cfg.mp_axis)
-        L = ids.shape[-1]
-        pos = jnp.arange(L)
-        if _axis_active(cfg.cp_axis):
-            pos = pos + lax.axis_index(cfg.cp_axis) * L
-        x = x + jnp.take(self.pos_emb, pos, axis=0)
-        return self.drop(self.ln(x))
+        with jax.named_scope("pt.embed"):
+            x = _take_rows(self.word_emb, ids, cfg.vocab_size, cfg.mp_axis)
+            L = ids.shape[-1]
+            pos = jnp.arange(L)
+            if _axis_active(cfg.cp_axis):
+                pos = pos + lax.axis_index(cfg.cp_axis) * L
+            x = x + jnp.take(self.pos_emb, pos, axis=0)
+            return self.drop(self.ln(x))
 
 
 class _SelfAttention(Layer):
@@ -283,8 +284,12 @@ class ErnieBlock(Layer):
         self.drop = nn.Dropout(cfg.dropout)
 
     def forward(self, x: jax.Array) -> jax.Array:
-        x = x + self.drop(self.attn(self.ln1(x)))
-        return x + self.drop(self.ffn(self.ln2(x)))
+        # each sublayer's scope takes its pre-LN and its residual add, so
+        # that the two scopes cover the block
+        with jax.named_scope("pt.attn"):
+            x = x + self.drop(self.attn(self.ln1(x)))
+        with jax.named_scope("pt.ffn"):
+            return x + self.drop(self.ffn(self.ln2(x)))
 
 
 class ErnieStage(Layer):
@@ -314,7 +319,8 @@ class ErnieHead(Layer):
             initializer=lambda k, s, d: jax.random.normal(k, s, d) / np.sqrt(h))
 
     def forward(self, x: jax.Array) -> jax.Array:
-        return self.ln(x) @ self.w
+        with jax.named_scope("pt.head_loss"):
+            return self.ln(x) @ self.w
 
 
 class Ernie(Layer):
@@ -335,8 +341,10 @@ class Ernie(Layer):
 
     def loss(self, ids: jax.Array, labels: jax.Array) -> jax.Array:
         logits = self(ids)
-        ce = parallel_cross_entropy(logits, labels, self.cfg.vocab_size, self.cfg.mp_axis)
-        return jnp.mean(ce)
+        with jax.named_scope("pt.head_loss"):
+            ce = parallel_cross_entropy(logits, labels, self.cfg.vocab_size,
+                                        self.cfg.mp_axis)
+            return jnp.mean(ce)
 
 
 # ---------------------------------------------------------------------------

@@ -121,34 +121,36 @@ def _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, mxu):
     grid = (BH, nq, nk)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                bq=bq, bk=bk, mxu=mxu)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bq, D), lambda b, i, j, offs: (b, i, 0)),
-                pl.BlockSpec((1, bk, D), lambda b, i, j, offs: (b, j, 0)),
-                pl.BlockSpec((1, bk, D), lambda b, i, j, offs: (b, j, 0)),
+    with jax.named_scope("pt.flash_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=grid,
+                in_specs=[
+                    pl.BlockSpec((1, bq, D), lambda b, i, j, offs: (b, i, 0)),
+                    pl.BlockSpec((1, bk, D), lambda b, i, j, offs: (b, j, 0)),
+                    pl.BlockSpec((1, bk, D), lambda b, i, j, offs: (b, j, 0)),
+                ],
+                out_specs=[
+                    pl.BlockSpec((1, bq, D), lambda b, i, j, offs: (b, i, 0)),
+                    pl.BlockSpec((1, bq, 128), lambda b, i, j, offs: (b, i, 0)),
+                ],
+                scratch_shapes=[
+                    pltpu.VMEM((bq, D), jnp.float32),
+                    pltpu.VMEM((bq, 128), jnp.float32),
+                    pltpu.VMEM((bq, 128), jnp.float32),
+                ],
+            ),
+            out_shape=[
+                _out_struct((BH, Lq, D), q.dtype, q, k, v, offs),
+                _out_struct((BH, Lq, 128), jnp.float32, q, k, v, offs),
             ],
-            out_specs=[
-                pl.BlockSpec((1, bq, D), lambda b, i, j, offs: (b, i, 0)),
-                pl.BlockSpec((1, bq, 128), lambda b, i, j, offs: (b, i, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((bq, D), jnp.float32),
-                pltpu.VMEM((bq, 128), jnp.float32),
-                pltpu.VMEM((bq, 128), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            _out_struct((BH, Lq, D), q.dtype, q, k, v, offs),
-            _out_struct((BH, Lq, 128), jnp.float32, q, k, v, offs),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(offs, q, k, v)
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            name="flash_fwd",
+            interpret=interpret,
+        )(offs, q, k, v)
     return out, lse[:, :, 0]
 
 
@@ -275,21 +277,23 @@ def _bwd(scale, causal, bq, bk, interpret, mxu, res, grads):
         pl.BlockSpec((1, bq, 128), lambda b, i, j, offs: (b, i, 0)),    # lse
         pl.BlockSpec((1, bq, 128), lambda b, i, j, offs: (b, i, 0)),    # delta
     ]
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, mxu=mxu),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(BH, nq, nk),
-            in_specs=common_in,
-            out_specs=[pl.BlockSpec((1, bq, D), lambda b, i, j, offs: (b, i, 0))],
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        ),
-        out_shape=[_out_struct((BH, Lq, D), q.dtype, q, k, v, do, offs)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(offs, q, k, v, do, lse_pad, delta_pad)[0]
+    with jax.named_scope("pt.flash_bwd_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
+                              bq=bq, bk=bk, mxu=mxu),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(BH, nq, nk),
+                in_specs=common_in,
+                out_specs=[pl.BlockSpec((1, bq, D), lambda b, i, j, offs: (b, i, 0))],
+                scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+            ),
+            out_shape=[_out_struct((BH, Lq, D), q.dtype, q, k, v, do, offs)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            name="flash_bwd_dq",
+            interpret=interpret,
+        )(offs, q, k, v, do, lse_pad, delta_pad)[0]
 
     # swap block index roles: outer dim walks k blocks, inner walks q
     dkv_in = [
@@ -300,26 +304,28 @@ def _bwd(scale, causal, bq, bk, interpret, mxu, res, grads):
         pl.BlockSpec((1, bq, 128), lambda b, j, i, offs: (b, i, 0)),    # lse
         pl.BlockSpec((1, bq, 128), lambda b, j, i, offs: (b, i, 0)),    # delta
     ]
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, mxu=mxu),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(BH, nk, nq),
-            in_specs=dkv_in,
-            out_specs=[
-                pl.BlockSpec((1, bk, D), lambda b, j, i, offs: (b, j, 0)),
-                pl.BlockSpec((1, bk, D), lambda b, j, i, offs: (b, j, 0)),
-            ],
-            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                            pltpu.VMEM((bk, D), jnp.float32)],
-        ),
-        out_shape=[_out_struct((BH, Lk, D), k.dtype, q, k, v, do, offs),
-                   _out_struct((BH, Lk, D), v.dtype, q, k, v, do, offs)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(offs, q, k, v, do, lse_pad, delta_pad)
+    with jax.named_scope("pt.flash_bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
+                              bq=bq, bk=bk, mxu=mxu),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(BH, nk, nq),
+                in_specs=dkv_in,
+                out_specs=[
+                    pl.BlockSpec((1, bk, D), lambda b, j, i, offs: (b, j, 0)),
+                    pl.BlockSpec((1, bk, D), lambda b, j, i, offs: (b, j, 0)),
+                ],
+                scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                                pltpu.VMEM((bk, D), jnp.float32)],
+            ),
+            out_shape=[_out_struct((BH, Lk, D), k.dtype, q, k, v, do, offs),
+                       _out_struct((BH, Lk, D), v.dtype, q, k, v, do, offs)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            name="flash_bwd_dkv",
+            interpret=interpret,
+        )(offs, q, k, v, do, lse_pad, delta_pad)
     return dq, dk, dv
 
 

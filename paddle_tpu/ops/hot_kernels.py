@@ -180,6 +180,7 @@ def hot_probe_gather(
 
     out = pl.pallas_call(
         kern,
+        name="hot_probe_gather",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, b: (0, 0)),            # seed
@@ -252,6 +253,7 @@ def hot_probe(
 
     return pl.pallas_call(
         kern,
+        name="hot_probe",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, b: (0, 0)),
@@ -376,6 +378,7 @@ def hot_scatter_apply(
                   for k in _COLS]
     out = pl.pallas_call(
         kern,
+        name="hot_scatter_apply",
         grid=(),
         in_specs=state_specs + [
             pl.BlockSpec((n,), lambda: (0,)),        # uniq rows

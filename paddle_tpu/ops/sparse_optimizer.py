@@ -254,6 +254,7 @@ def ctr_sparse_rows(
                 spec2(wxs), spec1(), spec1(), spec1(), spec2(1), spec2(dim)]
     out = pl.pallas_call(
         kern,
+        name="ctr_sparse_rows",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,

@@ -240,12 +240,16 @@ class Optimizer:
     def update(
         self, grads: PyTree, opt_state: Dict[str, Any], params: PyTree
     ) -> Tuple[PyTree, Dict[str, Any]]:
-        if self.grad_clip is not None:
-            grads = self.grad_clip(grads)
-        step = opt_state["step"]
-        lr_t = self.schedule(step)
-        new_params, new_slots = self._apply(grads, opt_state["slots"], params, lr_t, step)
-        return new_params, {"step": step + 1, "slots": new_slots}
+        # the one scope every dense update runs under, whatever wraps
+        # this optimizer (core/profiler.DEVICE_SCOPES)
+        with jax.named_scope("pt.dense_opt"):
+            if self.grad_clip is not None:
+                grads = self.grad_clip(grads)
+            step = opt_state["step"]
+            lr_t = self.schedule(step)
+            new_params, new_slots = self._apply(
+                grads, opt_state["slots"], params, lr_t, step)
+            return new_params, {"step": step + 1, "slots": new_slots}
 
     def _init_slots(self, params: PyTree) -> PyTree:
         raise NotImplementedError

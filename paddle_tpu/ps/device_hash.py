@@ -64,21 +64,22 @@ def device_hash_lookup(table: Dict[str, jax.Array], keys_hi: jax.Array,
     efficient row-gather pattern as the embedding pull. (1-D scalar
     gathers lower to a pathological path on TPU; never probe slot-wise.)
     """
-    mask = jnp.uint32(table["row"].shape[0] - 1)  # nbuckets (power of 2)
-    seed = table["seed"]  # scalar uint32 (device array, donated with state)
-    hi = keys_hi.astype(jnp.uint32)
-    lo = keys_lo.astype(jnp.uint32)
-    found = jnp.full(hi.shape, -1, jnp.int32)
-    for which in (0, 1):
-        s = seed if which == 0 else seed ^ _SEED2_XOR
-        b = (_mix32(hi, lo, s) & mask).astype(jnp.int32)
-        bh = jnp.take(table["hi"], b, axis=0)    # [n, 4]
-        bl = jnp.take(table["lo"], b, axis=0)
-        br = jnp.take(table["row"], b, axis=0)
-        match = (bh == hi[:, None]) & (bl == lo[:, None]) & (br >= 0)
-        hit = jnp.max(jnp.where(match, br, -1), axis=1)
-        found = jnp.where(hit >= 0, hit, found)
-    return found
+    with jax.named_scope("pt.probe"):
+        mask = jnp.uint32(table["row"].shape[0] - 1)  # nbuckets (power of 2)
+        seed = table["seed"]  # scalar uint32 (device array, donated w/ state)
+        hi = keys_hi.astype(jnp.uint32)
+        lo = keys_lo.astype(jnp.uint32)
+        found = jnp.full(hi.shape, -1, jnp.int32)
+        for which in (0, 1):
+            s = seed if which == 0 else seed ^ _SEED2_XOR
+            b = (_mix32(hi, lo, s) & mask).astype(jnp.int32)
+            bh = jnp.take(table["hi"], b, axis=0)    # [n, 4]
+            bl = jnp.take(table["lo"], b, axis=0)
+            br = jnp.take(table["row"], b, axis=0)
+            match = (bh == hi[:, None]) & (bl == lo[:, None]) & (br >= 0)
+            hit = jnp.max(jnp.where(match, br, -1), axis=1)
+            found = jnp.where(hit >= 0, hit, found)
+        return found
 
 
 class DeviceKeyMap:

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.enforce import enforce, enforce_le
+from ..core.profiler import RecordEvent
 from ..ops.sparse_optimizer import ctr_sparse_rows, fused_row_update
 from .native import FeasignIndex
 from .sgd_rule import SGDRuleConfig
@@ -100,14 +101,15 @@ def cache_pull(state: Dict[str, jax.Array], rows: jax.Array) -> jax.Array:
     values under jit — another feature's embedding — and NaN-fill in
     eager mode; both are silent corruption."""
     C = state["embed_w"].shape[0]
-    safe = jnp.minimum(rows, C - 1)
-    # gather each column block THEN concat the [n, ·] results — never
-    # concat the [C, ·] table first (XLA may materialize the 72 MB temp
-    # every step at bench scale)
-    pulled = jnp.concatenate(
-        [jnp.take(state["embed_w"], safe, axis=0),
-         jnp.take(state["embedx_w"], safe, axis=0)], axis=1)
-    return jnp.where((rows < C)[:, None], pulled, 0.0)
+    with jax.named_scope("pt.pull"):
+        safe = jnp.minimum(rows, C - 1)
+        # gather each column block THEN concat the [n, ·] results — never
+        # concat the [C, ·] table first (XLA may materialize the 72 MB
+        # temp every step at bench scale)
+        pulled = jnp.concatenate(
+            [jnp.take(state["embed_w"], safe, axis=0),
+             jnp.take(state["embedx_w"], safe, axis=0)], axis=1)
+        return jnp.where((rows < C)[:, None], pulled, 0.0)
 
 
 def cache_push(
@@ -158,33 +160,35 @@ def cache_push_dense(
     C = state["embed_w"].shape[0]
     sgd = cfg.sgd
     dim = cfg.embedx_dim
-    ones = jnp.ones((rows.shape[0], 1), jnp.float32)
-    upd = jnp.concatenate(
-        [grads.astype(jnp.float32), shows[:, None], clicks[:, None], ones],
-        axis=1)  # [n, 4+dim]: grads | show | click | occurrence count
-    acc = jnp.zeros((C + 1, upd.shape[1]), jnp.float32)
-    acc = acc.at[rows].add(upd)[:C]
-    ge, gx = acc[:, :1], acc[:, 1:1 + dim]
-    dshow, dclick = acc[:, 1 + dim], acc[:, 2 + dim]
-    touched = acc[:, 3 + dim] > 0
-
-    outs = fused_row_update(
-        state["show"], state["click"], state["embed_w"],
-        state["embed_state"], state["embedx_w"], state["embedx_state"],
-        state["has_embedx"], dshow, dclick, ge, gx,
-        embed_rule=cfg.embed_rule, embedx_rule=cfg.embedx_rule,
-        dim=dim, lr=sgd.learning_rate, initial_g2sum=sgd.initial_g2sum,
-        wmin=sgd.weight_bounds[0], wmax=sgd.weight_bounds[1],
-        beta1=sgd.beta1, beta2=sgd.beta2, eps=sgd.ada_epsilon,
-        nonclk_coeff=cfg.nonclk_coeff, click_coeff=cfg.click_coeff,
-        embedx_threshold=cfg.embedx_threshold,
-        create_applies_grad=cfg.create_applies_grad)
+    with jax.named_scope("pt.push.accumulate"):
+        ones = jnp.ones((rows.shape[0], 1), jnp.float32)
+        upd = jnp.concatenate(
+            [grads.astype(jnp.float32), shows[:, None], clicks[:, None],
+             ones], axis=1)  # [n, 4+dim]: grads | show | click | occurrences
+        acc = jnp.zeros((C + 1, upd.shape[1]), jnp.float32)
+        acc = acc.at[rows].add(upd)[:C]
+        ge, gx = acc[:, :1], acc[:, 1:1 + dim]
+        dshow, dclick = acc[:, 1 + dim], acc[:, 2 + dim]
+        touched = acc[:, 3 + dim] > 0
 
     names = ("show", "click", "embed_w", "embed_state", "embedx_w",
              "embedx_state", "has_embedx")
-    tcol = touched[:, None]
-    return {k: jnp.where(touched if new.ndim == 1 else tcol, new, state[k])
-            for k, new in zip(names, outs)}
+    with jax.named_scope("pt.push.update"):
+        outs = fused_row_update(
+            state["show"], state["click"], state["embed_w"],
+            state["embed_state"], state["embedx_w"], state["embedx_state"],
+            state["has_embedx"], dshow, dclick, ge, gx,
+            embed_rule=cfg.embed_rule, embedx_rule=cfg.embedx_rule,
+            dim=dim, lr=sgd.learning_rate, initial_g2sum=sgd.initial_g2sum,
+            wmin=sgd.weight_bounds[0], wmax=sgd.weight_bounds[1],
+            beta1=sgd.beta1, beta2=sgd.beta2, eps=sgd.ada_epsilon,
+            nonclk_coeff=cfg.nonclk_coeff, click_coeff=cfg.click_coeff,
+            embedx_threshold=cfg.embedx_threshold,
+            create_applies_grad=cfg.create_applies_grad)
+        tcol = touched[:, None]
+        return {k: jnp.where(touched if new.ndim == 1 else tcol, new,
+                             state[k])
+                for k, new in zip(names, outs)}
 
 
 def merge_sparse_grads(rows: jax.Array, grads: jax.Array, shows: jax.Array,
@@ -197,12 +201,13 @@ def merge_sparse_grads(rows: jax.Array, grads: jax.Array, shows: jax.Array,
     kernel (ops/hot_kernels.py) — the f32 merge association is part of
     the bit-parity contract, so the two paths must not drift."""
     n = rows.shape[0]
-    uniq, inv = jnp.unique(rows, size=n, fill_value=capacity,
-                           return_inverse=True)
-    inv = inv.reshape(-1)
-    show_sum = jax.ops.segment_sum(shows, inv, num_segments=n)
-    click_sum = jax.ops.segment_sum(clicks, inv, num_segments=n)
-    g = jax.ops.segment_sum(grads, inv, num_segments=n)  # [n, 1+dim]
+    with jax.named_scope("pt.push.accumulate"):
+        uniq, inv = jnp.unique(rows, size=n, fill_value=capacity,
+                               return_inverse=True)
+        inv = inv.reshape(-1)
+        show_sum = jax.ops.segment_sum(shows, inv, num_segments=n)
+        click_sum = jax.ops.segment_sum(clicks, inv, num_segments=n)
+        g = jax.ops.segment_sum(grads, inv, num_segments=n)  # [n, 1+dim]
     return uniq, show_sum, click_sum, g
 
 
@@ -228,53 +233,56 @@ def cache_push_sparse(
 
     uniq, show_sum, click_sum, g = merge_sparse_grads(rows, grads, shows,
                                                       clicks, C)
-    srows = jnp.where(uniq < C, uniq, 0)  # safe gather index for padding
+    with jax.named_scope("pt.push.update"):
+        srows = jnp.where(uniq < C, uniq, 0)  # safe gather index for padding
 
-    gathered = (state["show"][srows], state["click"][srows],
-                state["embed_w"][srows], state["embed_state"][srows],
-                state["embedx_w"][srows], state["embedx_state"][srows],
-                state["has_embedx"][srows])
+        gathered = (state["show"][srows], state["click"][srows],
+                    state["embed_w"][srows], state["embed_state"][srows],
+                    state["embedx_w"][srows], state["embedx_state"][srows],
+                    state["has_embedx"][srows])
 
-    use_pallas = cfg.pallas_update
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if use_pallas:
-        # fused per-row optimizer kernel (optimizer.cuh.h analogue)
-        (show_rows, click_rows, embed_w_rows, embed_st_rows, ex_w_rows,
-         ex_st_rows, has_rows) = ctr_sparse_rows(
-            gathered, show_sum, click_sum, g[:, :1], g[:, 1:],
-            embed_rule=cfg.embed_rule, embedx_rule=cfg.embedx_rule,
-            lr=sgd.learning_rate, initial_g2sum=sgd.initial_g2sum,
-            weight_bounds=tuple(sgd.weight_bounds),
-            beta1=sgd.beta1, beta2=sgd.beta2, eps=sgd.ada_epsilon,
-            nonclk_coeff=cfg.nonclk_coeff, click_coeff=cfg.click_coeff,
-            embedx_threshold=cfg.embedx_threshold,
-            create_applies_grad=cfg.create_applies_grad)
-    else:
-        # same math, no kernel: fused_row_update is the single shared
-        # definition of the whole per-row update
-        (show_rows, click_rows, embed_w_rows, embed_st_rows, ex_w_rows,
-         ex_st_rows, has_rows) = fused_row_update(
-            *gathered, show_sum, click_sum, g[:, :1], g[:, 1:],
-            embed_rule=cfg.embed_rule, embedx_rule=cfg.embedx_rule,
-            dim=cfg.embedx_dim, lr=sgd.learning_rate,
-            initial_g2sum=sgd.initial_g2sum,
-            wmin=sgd.weight_bounds[0], wmax=sgd.weight_bounds[1],
-            beta1=sgd.beta1, beta2=sgd.beta2, eps=sgd.ada_epsilon,
-            nonclk_coeff=cfg.nonclk_coeff, click_coeff=cfg.click_coeff,
-            embedx_threshold=cfg.embedx_threshold,
-            create_applies_grad=cfg.create_applies_grad)
+        use_pallas = cfg.pallas_update
+        if use_pallas is None:
+            use_pallas = jax.default_backend() == "tpu"
+        if use_pallas:
+            # fused per-row optimizer kernel (optimizer.cuh.h analogue)
+            (show_rows, click_rows, embed_w_rows, embed_st_rows, ex_w_rows,
+             ex_st_rows, has_rows) = ctr_sparse_rows(
+                gathered, show_sum, click_sum, g[:, :1], g[:, 1:],
+                embed_rule=cfg.embed_rule, embedx_rule=cfg.embedx_rule,
+                lr=sgd.learning_rate, initial_g2sum=sgd.initial_g2sum,
+                weight_bounds=tuple(sgd.weight_bounds),
+                beta1=sgd.beta1, beta2=sgd.beta2, eps=sgd.ada_epsilon,
+                nonclk_coeff=cfg.nonclk_coeff, click_coeff=cfg.click_coeff,
+                embedx_threshold=cfg.embedx_threshold,
+                create_applies_grad=cfg.create_applies_grad)
+        else:
+            # same math, no kernel: fused_row_update is the single shared
+            # definition of the whole per-row update
+            (show_rows, click_rows, embed_w_rows, embed_st_rows, ex_w_rows,
+             ex_st_rows, has_rows) = fused_row_update(
+                *gathered, show_sum, click_sum, g[:, :1], g[:, 1:],
+                embed_rule=cfg.embed_rule, embedx_rule=cfg.embedx_rule,
+                dim=cfg.embedx_dim, lr=sgd.learning_rate,
+                initial_g2sum=sgd.initial_g2sum,
+                wmin=sgd.weight_bounds[0], wmax=sgd.weight_bounds[1],
+                beta1=sgd.beta1, beta2=sgd.beta2, eps=sgd.ada_epsilon,
+                nonclk_coeff=cfg.nonclk_coeff, click_coeff=cfg.click_coeff,
+                embedx_threshold=cfg.embedx_threshold,
+                create_applies_grad=cfg.create_applies_grad)
 
-    drop = dict(mode="drop")  # padding rows (sentinel C) fall away
-    return {
-        "show": state["show"].at[uniq].set(show_rows, **drop),
-        "click": state["click"].at[uniq].set(click_rows, **drop),
-        "embed_w": state["embed_w"].at[uniq].set(embed_w_rows, **drop),
-        "embed_state": state["embed_state"].at[uniq].set(embed_st_rows, **drop),
-        "embedx_w": state["embedx_w"].at[uniq].set(ex_w_rows, **drop),
-        "embedx_state": state["embedx_state"].at[uniq].set(ex_st_rows, **drop),
-        "has_embedx": state["has_embedx"].at[uniq].set(has_rows, **drop),
-    }
+        drop = dict(mode="drop")  # padding rows (sentinel C) fall away
+        return {
+            "show": state["show"].at[uniq].set(show_rows, **drop),
+            "click": state["click"].at[uniq].set(click_rows, **drop),
+            "embed_w": state["embed_w"].at[uniq].set(embed_w_rows, **drop),
+            "embed_state": state["embed_state"].at[uniq].set(
+                embed_st_rows, **drop),
+            "embedx_w": state["embedx_w"].at[uniq].set(ex_w_rows, **drop),
+            "embedx_state": state["embedx_state"].at[uniq].set(
+                ex_st_rows, **drop),
+            "has_embedx": state["has_embedx"].at[uniq].set(has_rows, **drop),
+        }
 
 
 class HbmEmbeddingCache:
@@ -372,32 +380,54 @@ class HbmEmbeddingCache:
         pass trains. Activate with :meth:`activate_pass` after the
         previous end_pass — table values are only read then, so the
         overlap changes nothing numerically."""
+        with RecordEvent("pt.pass.prepare", keys=len(keys)) as ev:
+            prepared = self._prepare(keys)
+            ev["unique_keys"] = len(prepared["uniq"])
+        return prepared
+
+    def _prepare(self, keys: np.ndarray) -> dict:
         cfg = self.config
         from .native import dedup_u64
 
-        uniq = dedup_u64(keys)  # parallel PreBuildTask-style dedup
+        with RecordEvent("pt.pass.dedup"):
+            uniq = dedup_u64(keys)  # parallel PreBuildTask-style dedup
         enforce_le(len(uniq), cfg.capacity,
                    "pass working set exceeds cache capacity")
-        index = FeasignIndex(len(uniq) * 2)
-        rows, _ = index.lookup_or_insert(uniq)
-        rows = self._spread(rows)
+        with RecordEvent("pt.pass.index"):
+            index = FeasignIndex(len(uniq) * 2)
+            rows, _ = index.lookup_or_insert(uniq)
+            rows = self._spread(rows)
         prepared = {"uniq": uniq, "index": index, "rows": rows,
                     "map_host": None}
         if self._device_map_enabled:
             from .device_hash import DeviceKeyMap
 
-            prepared["map_host"] = DeviceKeyMap.build_host(uniq, rows)
+            with RecordEvent("pt.pass.map_build"):
+                prepared["map_host"] = DeviceKeyMap.build_host(uniq, rows)
         return prepared
 
     def begin_pass(self, keys: np.ndarray) -> int:
         """PreBuildTask + BuildPull + BuildGPUTask: dedup the pass's keys,
-        pull current values from the host table, upload the working set."""
-        return self.activate_pass(self.prepare_pass(keys))
+        pull current values from the host table, upload the working set.
+        One ``pt.pass.begin`` span over the phases of both halves."""
+        with RecordEvent("pt.pass.begin", keys=len(keys),
+                         capacity=self.config.capacity,
+                         shards=self._n_shards) as ev:
+            prepared = self._prepare(keys)
+            ev["unique_keys"] = len(prepared["uniq"])
+            return self._activate(prepared)
 
     def activate_pass(self, prepared: dict) -> int:
         """The device half of begin_pass: export current table values
         for the prepared key set (insert-on-miss) and upload the working
-        set + key map."""
+        set + key map. Returns once the upload has landed."""
+        with RecordEvent("pt.pass.activate",
+                         unique_keys=len(prepared["uniq"]),
+                         capacity=self.config.capacity,
+                         shards=self._n_shards):
+            return self._activate(prepared)
+
+    def _activate(self, prepared: dict) -> int:
         cfg = self.config
         uniq, rows = prepared["uniq"], prepared["rows"]
         self._index = prepared["index"]
@@ -410,47 +440,61 @@ class HbmEmbeddingCache:
         es = acc.embed_rule.state_dim
         xs = acc.embedx_rule.state_dim
         xd = acc.config.embedx_dim
-        values, _ = self.table.export_full(uniq, create=True)
+        with RecordEvent("pt.pass.export") as ev:
+            values, _ = self.table.export_full(uniq, create=True)
+            ev["bytes"] = values.nbytes
         dim = cfg.embedx_dim
-        host = {
-            "show": np.zeros(cfg.capacity, np.float32),
-            "click": np.zeros(cfg.capacity, np.float32),
-            "embed_w": np.zeros((cfg.capacity, 1), np.float32),
-            "embed_state": np.zeros((cfg.capacity, es), np.float32),
-            "embedx_w": np.zeros((cfg.capacity, dim), np.float32),
-            "embedx_state": np.zeros((cfg.capacity, xs), np.float32),
-            "has_embedx": np.zeros(cfg.capacity, np.float32),
-        }
-        # full layout: slot, unseen_days, delta_score, show, click,
-        # embed_w, embed_state[es], has_embedx, embedx_w[xd], embedx_state
-        host["show"][rows] = values[:, 3]
-        host["click"][rows] = values[:, 4]
-        host["embed_w"][rows, 0] = values[:, 5]
-        host["embed_state"][rows] = values[:, 6 : 6 + es]
-        host["has_embedx"][rows] = values[:, 6 + es]
-        host["embedx_w"][rows] = values[:, 7 + es: 7 + es + xd]
-        host["embedx_state"][rows] = values[:, 7 + es + xd : 7 + es + xd + xs]
-
-        if self._device_map_enabled:
-            from .device_hash import DeviceKeyMap
-
-            map_sharding = None
-            if self._n_shards > 1:  # __init__ set _sharding with the mesh
-                # replicate the key→row map across the serving mesh (the
-                # probe runs per device on its local batch slice)
-                from jax.sharding import NamedSharding, PartitionSpec
-
-                map_sharding = NamedSharding(self._sharding.mesh,
-                                             PartitionSpec())
-            self.device_map = DeviceKeyMap(
-                sharding=map_sharding, host_built=prepared["map_host"])
-
-        if self._sharding is not None:
-            self.state = {
-                k: jax.device_put(jnp.asarray(v), self._sharding) for k, v in host.items()
+        with RecordEvent("pt.pass.layout") as ev:
+            host = {
+                "show": np.zeros(cfg.capacity, np.float32),
+                "click": np.zeros(cfg.capacity, np.float32),
+                "embed_w": np.zeros((cfg.capacity, 1), np.float32),
+                "embed_state": np.zeros((cfg.capacity, es), np.float32),
+                "embedx_w": np.zeros((cfg.capacity, dim), np.float32),
+                "embedx_state": np.zeros((cfg.capacity, xs), np.float32),
+                "has_embedx": np.zeros(cfg.capacity, np.float32),
             }
-        else:
-            self.state = {k: jnp.asarray(v) for k, v in host.items()}
+            # full layout: slot, unseen_days, delta_score, show, click,
+            # embed_w, embed_state[es], has_embedx, embedx_w[xd],
+            # embedx_state
+            host["show"][rows] = values[:, 3]
+            host["click"][rows] = values[:, 4]
+            host["embed_w"][rows, 0] = values[:, 5]
+            host["embed_state"][rows] = values[:, 6 : 6 + es]
+            host["has_embedx"][rows] = values[:, 6 + es]
+            host["embedx_w"][rows] = values[:, 7 + es: 7 + es + xd]
+            host["embedx_state"][rows] = \
+                values[:, 7 + es + xd : 7 + es + xd + xs]
+            ev["bytes"] = sum(v.nbytes for v in host.values())
+            del values  # the exported copy goes before the upload starts
+
+        with RecordEvent("pt.pass.upload") as ev:
+            if self._device_map_enabled:
+                from .device_hash import DeviceKeyMap
+
+                map_sharding = None
+                if self._n_shards > 1:  # __init__ set _sharding with the mesh
+                    # replicate the key→row map across the serving mesh
+                    # (the probe runs per device on its local batch slice)
+                    from jax.sharding import NamedSharding, PartitionSpec
+
+                    map_sharding = NamedSharding(self._sharding.mesh,
+                                                 PartitionSpec())
+                self.device_map = DeviceKeyMap(
+                    sharding=map_sharding, host_built=prepared["map_host"])
+
+            if self._sharding is not None:
+                self.state = {k: jax.device_put(jnp.asarray(v), self._sharding)
+                              for k, v in host.items()}
+            else:
+                self.state = {k: jnp.asarray(v) for k, v in host.items()}
+            # the span ends when the bytes have landed, not when the
+            # copies were enqueued; every caller needs the state next
+            uploaded = (self.state, self.device_map.state
+                        if self.device_map is not None else None)
+            jax.block_until_ready(uploaded)
+            ev["bytes"] = sum(a.nbytes for a in jax.tree.leaves(uploaded))
+            del host    # ... and the host columns once they have landed
         return len(uniq)
 
     def lookup(self, keys: np.ndarray) -> np.ndarray:
@@ -465,9 +509,21 @@ class HbmEmbeddingCache:
         table (values + optimizer state, direct overwrite)."""
         if self._index is None or self.state is None:
             return
-        host = {k: np.asarray(v) for k, v in jax.device_get(self.state).items()}
+        with RecordEvent("pt.pass.end", keys=len(self._pass_keys)):
+            self._flush()
+        self._index = None
+        self.state = None
+        self._pass_keys = None
+        self.device_map = None
+
+    def _flush(self) -> None:
+        with RecordEvent("pt.pass.fetch") as ev:
+            host = {k: np.asarray(v)
+                    for k, v in jax.device_get(self.state).items()}
+            ev["bytes"] = sum(v.nbytes for v in host.values())
         keys = self._pass_keys
-        rows = self._spread(self._index.lookup(keys))
+        with RecordEvent("pt.pass.flush_index"):
+            rows = self._spread(self._index.lookup(keys))
         acc = self.table.accessor
         es = acc.embed_rule.state_dim
         xs = acc.embedx_rule.state_dim
@@ -478,34 +534,37 @@ class HbmEmbeddingCache:
         # the same keys. All pass keys were created in begin_pass, so
         # every row must still exist (a mid-pass shrink would violate
         # the pass protocol; fail loudly rather than write stale rows).
-        old, found = self.table.export_full(keys)
+        with RecordEvent("pt.pass.flush_export"):
+            old, found = self.table.export_full(keys)
         enforce(bool(found.all()),
                 "end_pass: pass keys missing from host table (table was "
                 "shrunk or mutated mid-pass)")
-        new = old.copy()
-        # lifecycle stats: cache-trained features were seen this pass —
-        # zero unseen_days and fold the show/click growth into
-        # delta_score (else daily shrink would age out hot features and
-        # delta saves would drop them)
-        cfg = acc.config
-        d_show = host["show"][rows] - old[:, 3]
-        d_click = host["click"][rows] - old[:, 4]
-        new[:, 2] = old[:, 2] + (d_show - d_click) * cfg.nonclk_coeff + d_click * cfg.click_coeff
-        new[:, 1] = 0.0
-        new[:, 3] = host["show"][rows]
-        new[:, 4] = host["click"][rows]
-        new[:, 5] = host["embed_w"][rows, 0]
-        new[:, 6 : 6 + es] = host["embed_state"][rows]
-        has = host["has_embedx"][rows] > 0
-        keep_old = old[:, 6 + es] != 0.0
-        new[:, 6 + es] = (has | keep_old).astype(np.float32)
-        new[has, 7 + es : 7 + es + xd] = host["embedx_w"][rows[has]]
-        new[has, 7 + es + xd : 7 + es + xd + xs] = host["embedx_state"][rows[has]]
-        self.table.import_full(keys, new)
-        self._index = None
-        self.state = None
-        self._pass_keys = None
-        self.device_map = None
+        with RecordEvent("pt.pass.merge"):
+            new = old.copy()
+            # lifecycle stats: cache-trained features were seen this pass
+            # — zero unseen_days and fold the show/click growth into
+            # delta_score (else daily shrink would age out hot features
+            # and delta saves would drop them)
+            cfg = acc.config
+            d_show = host["show"][rows] - old[:, 3]
+            d_click = host["click"][rows] - old[:, 4]
+            new[:, 2] = (old[:, 2] + (d_show - d_click) * cfg.nonclk_coeff
+                         + d_click * cfg.click_coeff)
+            new[:, 1] = 0.0
+            new[:, 3] = host["show"][rows]
+            new[:, 4] = host["click"][rows]
+            new[:, 5] = host["embed_w"][rows, 0]
+            new[:, 6 : 6 + es] = host["embed_state"][rows]
+            has = host["has_embedx"][rows] > 0
+            keep_old = old[:, 6 + es] != 0.0
+            new[:, 6 + es] = (has | keep_old).astype(np.float32)
+            new[has, 7 + es : 7 + es + xd] = host["embedx_w"][rows[has]]
+            new[has, 7 + es + xd : 7 + es + xd + xs] = \
+                host["embedx_state"][rows[has]]
+            del host, old   # GBs each: released where they stop being used
+        with RecordEvent("pt.pass.import"):
+            self.table.import_full(keys, new)
+            del new
 
     def discard_pass(self) -> None:
         """Drop the working set WITHOUT flushing back (diverged/aborted

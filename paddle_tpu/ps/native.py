@@ -130,10 +130,12 @@ def table_native_params(shard_num: int, accessor: str, acc_cfg,
     (csrc/sparse_table.h) reads, shared by the in-process engines and
     the RPC create payload. ``acc_cfg`` is an AccessorConfig."""
     sgd = acc_cfg.sgd
+    # the ABI's seed is a non-negative i32: a larger one (a benchmark seed
+    # may pass 2**31) folds into 31 bits instead of overflowing
     ip = np.asarray(
         [shard_num, _ACCESSOR_IDS[accessor], acc_cfg.embedx_dim,
          _RULE_IDS[acc_cfg.embed_sgd_rule], _RULE_IDS[acc_cfg.embedx_sgd_rule],
-         seed], np.int32)
+         int(seed) & 0x7FFFFFFF], np.int32)
     fp = np.asarray(
         [acc_cfg.nonclk_coeff, acc_cfg.click_coeff, acc_cfg.base_threshold,
          acc_cfg.delta_threshold, acc_cfg.delta_keep_days,

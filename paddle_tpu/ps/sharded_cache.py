@@ -130,29 +130,31 @@ def _route_to_buckets(owner, K: int, cap: int, payloads, fills,
     jnp.unique — block ownership is monotone in row id), skipping the
     O(m log m) sort on the hot path."""
     m = owner.shape[0]
-    if presorted:
-        order, so = jnp.arange(m), owner
-    else:
-        order = jnp.argsort(owner, stable=True)
-        so = owner[order]
-    start = jnp.searchsorted(so, jnp.arange(K + 1))  # bucket group starts
-    pos = jnp.arange(m) - start[so]  # rank within the destination bucket
-    overflow = jnp.sum((so < K) & (pos >= cap)).astype(jnp.int32)
-    buckets = []
-    for p, fill in zip(payloads, fills):
-        b = jnp.full((K, cap) + p.shape[1:], fill, p.dtype)
-        # owner K / pos >= cap are out-of-bounds → mode="drop" discards
-        buckets.append(b.at[so, pos].set(p[order], mode="drop"))
-    src = jnp.full((K, cap), m, jnp.int32)
-    src = src.at[so, pos].set(order.astype(jnp.int32), mode="drop")
+    with jax.named_scope("pt.route"):
+        if presorted:
+            order, so = jnp.arange(m), owner
+        else:
+            order = jnp.argsort(owner, stable=True)
+            so = owner[order]
+        start = jnp.searchsorted(so, jnp.arange(K + 1))  # bucket starts
+        pos = jnp.arange(m) - start[so]  # rank within the destination bucket
+        overflow = jnp.sum((so < K) & (pos >= cap)).astype(jnp.int32)
+        buckets = []
+        for p, fill in zip(payloads, fills):
+            b = jnp.full((K, cap) + p.shape[1:], fill, p.dtype)
+            # owner K / pos >= cap are out-of-bounds → mode="drop" discards
+            buckets.append(b.at[so, pos].set(p[order], mode="drop"))
+        src = jnp.full((K, cap), m, jnp.int32)
+        src = src.at[so, pos].set(order.astype(jnp.int32), mode="drop")
     return buckets, src, overflow
 
 
 def _canonical_rows(rows: jax.Array, sentinel: int) -> jax.Array:
     """int32 rows with negative miss markers mapped to the canonical
     out-of-range sentinel (keeps sorted-unique output owner-ordered)."""
-    rows = rows.astype(jnp.int32)
-    return jnp.where(rows < 0, sentinel, rows)
+    with jax.named_scope("pt.route"):
+        rows = rows.astype(jnp.int32)
+        return jnp.where(rows < 0, sentinel, rows)
 
 
 def routed_dedup(rows: jax.Array, sentinel: int
@@ -165,15 +167,17 @@ def routed_dedup(rows: jax.Array, sentinel: int
     the sentinel so the sorted-unique output stays owner-ordered."""
     rows = _canonical_rows(rows, sentinel)
     m = rows.shape[0]
-    uniq, inv = jnp.unique(rows, size=m, fill_value=sentinel,
-                           return_inverse=True)
-    return uniq, inv.reshape(-1)
+    with jax.named_scope("pt.route"):
+        uniq, inv = jnp.unique(rows, size=m, fill_value=sentinel,
+                               return_inverse=True)
+        return uniq, inv.reshape(-1)
 
 
 def _owner_of(rows, shard_rows: int, K: int):
     """Owner shard of each global row id; K for sentinel/out-of-range."""
-    valid = (rows >= 0) & (rows < shard_rows * K)
-    return jnp.where(valid, rows // shard_rows, K).astype(jnp.int32)
+    with jax.named_scope("pt.route"):
+        valid = (rows >= 0) & (rows < shard_rows * K)
+        return jnp.where(valid, rows // shard_rows, K).astype(jnp.int32)
 
 
 def routed_cache_pull(
@@ -208,14 +212,17 @@ def routed_cache_pull(
     (breq,), src, overflow = _route_to_buckets(
         _owner_of(lookup, shard_rows, K), K, cap, [lookup], [0],
         presorted=pre_dedup)
+    # the collectives carry no pt.* scope: the trace finds them by name
     req = lax.all_to_all(breq, axis, 0, 0)  # [K, cap] rows I serve
-    loc = jnp.clip(req.reshape(-1) - my_start, 0, shard_rows - 1)
-    vals = cache_pull(state, loc).reshape(K, cap, -1)
+    with jax.named_scope("pt.pull"):        # the owner-side gather
+        loc = jnp.clip(req.reshape(-1) - my_start, 0, shard_rows - 1)
+        vals = cache_pull(state, loc).reshape(K, cap, -1)
     back = lax.all_to_all(vals, axis, 0, 0)  # [K, cap, D] my requests
-    D = back.shape[-1]
-    uvals = jnp.zeros((m + 1, D), back.dtype)
-    uvals = uvals.at[src.reshape(-1)].set(back.reshape(K * cap, D))[:m]
-    out = uvals[inv] if pre_dedup else uvals
+    with jax.named_scope("pt.route"):       # un-bucket to batch order
+        D = back.shape[-1]
+        uvals = jnp.zeros((m + 1, D), back.dtype)
+        uvals = uvals.at[src.reshape(-1)].set(back.reshape(K * cap, D))[:m]
+        out = uvals[inv] if pre_dedup else uvals
     return out, lax.psum(overflow, axis)
 
 
@@ -250,13 +257,15 @@ def routed_cache_push(
     rows = _canonical_rows(rows, C_total)
     enforce(dedup is None or pre_dedup,
             "dedup= requires pre_dedup=True (raw routing ignores it)")
-    payload = jnp.concatenate(
-        [grads, shows[:, None], clicks[:, None]], axis=1)
+    with jax.named_scope("pt.route"):
+        payload = jnp.concatenate(
+            [grads, shows[:, None], clicks[:, None]], axis=1)
     if pre_dedup:
         # merge_grad: per-device partial sums, one wire entry per row
         uniq, inv = dedup if dedup is not None else routed_dedup(
             rows, C_total)
-        payload = jax.ops.segment_sum(payload, inv, num_segments=m)
+        with jax.named_scope("pt.route"):
+            payload = jax.ops.segment_sum(payload, inv, num_segments=m)
         rows = uniq
     cap = route_bucket_capacity(m, K, cap_factor)
     (brow, bpay), _, overflow = _route_to_buckets(
@@ -264,11 +273,12 @@ def routed_cache_push(
         [rows, payload], [C_total, 0.0], presorted=pre_dedup)
     rrow = lax.all_to_all(brow, axis, 0, 0).reshape(-1)
     rpay = lax.all_to_all(bpay, axis, 0, 0).reshape(K * cap, -1)
-    loc = rrow - my_start
-    own = (loc >= 0) & (loc < shard_rows)
-    loc = jnp.where(own, loc, shard_rows)  # sentinel → dropped in cache_push
-    new_state = (push_fn or cache_push)(state, loc, rpay[:, :-2],
-                                        rpay[:, -2], rpay[:, -1], cfg)
+    with jax.named_scope("pt.route"):       # wire rows → my local rows
+        loc = rrow - my_start
+        own = (loc >= 0) & (loc < shard_rows)
+        loc = jnp.where(own, loc, shard_rows)  # sentinel → dropped in push
+        g, dshow, dclick = rpay[:, :-2], rpay[:, -2], rpay[:, -1]
+    new_state = (push_fn or cache_push)(state, loc, g, dshow, dclick, cfg)
     return new_state, lax.psum(overflow, axis)
 
 
@@ -283,10 +293,11 @@ def sharded_cache_pull(state: Dict[str, jax.Array], rows: jax.Array,
     shard_rows = state["embed_w"].shape[0]  # local block size
     my_start = lax.axis_index(axis) * shard_rows
     rows_all = lax.all_gather(rows, axis, tiled=True)  # [m*K], global order
-    loc = rows_all - my_start
-    own = (loc >= 0) & (loc < shard_rows)
-    vals = cache_pull(state, jnp.clip(loc, 0, shard_rows - 1))
-    vals = jnp.where(own[:, None], vals, 0.0)
+    with jax.named_scope("pt.pull"):
+        loc = rows_all - my_start
+        own = (loc >= 0) & (loc < shard_rows)
+        vals = cache_pull(state, jnp.clip(loc, 0, shard_rows - 1))
+        vals = jnp.where(own[:, None], vals, 0.0)
     # each row has exactly one owner → sum assembles, scatter returns my slice
     return lax.psum_scatter(vals, axis, scatter_dimension=0, tiled=True)
 
@@ -312,9 +323,10 @@ def sharded_cache_push(
     grads_all = lax.all_gather(grads, axis, tiled=True)
     shows_all = lax.all_gather(shows, axis, tiled=True)
     clicks_all = lax.all_gather(clicks, axis, tiled=True)
-    loc = rows_all - my_start
-    own = (loc >= 0) & (loc < shard_rows)
-    loc = jnp.where(own, loc, shard_rows)  # sentinel → dropped in cache_push
+    with jax.named_scope("pt.route"):
+        loc = rows_all - my_start
+        own = (loc >= 0) & (loc < shard_rows)
+        loc = jnp.where(own, loc, shard_rows)  # sentinel → dropped in push
     return (push_fn or cache_push)(state, loc, grads_all, shows_all,
                                    clicks_all, cfg)
 
@@ -510,12 +522,14 @@ def _sharded_step_body(model, optimizer, cache_cfg, axis, K, params,
             out, labels.astype(jnp.float32))
         return loss, out
 
-    (loss, _), (grads, emb_grad) = jax.value_and_grad(
-        loss_fn, argnums=(0, 1), has_aux=True)(params, emb)
-    # local-mean → global-mean: pmean dense grads; scale emb grads by
-    # 1/K (exact for power-of-two K) so push matches the unsharded step
+    with jax.named_scope("pt.tower"):  # forward and backward of the model
+        (loss, _), (grads, emb_grad) = jax.value_and_grad(
+            loss_fn, argnums=(0, 1), has_aux=True)(params, emb)
+        # local-mean → global-mean: scale emb grads by 1/K (exact for
+        # power-of-two K) so push matches the unsharded step
+        emb_grad = emb_grad / K
+    # ... and pmean the dense grads (collectives: no pt.* scope)
     grads = jax.tree.map(lambda g: lax.pmean(g, axis), grads)
-    emb_grad = emb_grad / K
     loss = lax.pmean(loss, axis)
 
     new_params, new_opt = optimizer.update(grads, opt_state, params)
@@ -569,7 +583,8 @@ def make_sharded_ctr_train_step_from_keys(
         hi = jnp.broadcast_to(slot_hi, (B, S)).reshape(-1)
         rows = device_hash_lookup(map_state, hi, keys_lo.reshape(-1))
         C_total = cache_state["embed_w"].shape[0] * K  # global capacity
-        rows = jnp.where(rows >= 0, rows, C_total)  # sentinel: no owner
+        with jax.named_scope("pt.probe"):
+            rows = jnp.where(rows >= 0, rows, C_total)  # sentinel: no owner
         return _sharded_step_body(model, optimizer, cache_cfg, axis, K,
                                   params, opt_state, cache_state, rows, B, S,
                                   dense_x, labels, routing, cap_factor,

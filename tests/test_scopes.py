@@ -1,0 +1,424 @@
+"""The program's own names: ``pt.*`` scopes in the jitted steps, phase
+spans of the pass lifecycle, and the benchmark reader over both
+(``benchmarks/harness/scopes.py``)."""
+
+import json
+import pathlib
+import re
+import sys
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu import nn, optimizer
+from paddle_tpu.core import profiler
+from paddle_tpu.core.profiler import DEVICE_SCOPES, RecordEvent, host_spans
+from paddle_tpu.ps.accessor import AccessorConfig
+from paddle_tpu.ps.embedding_cache import CacheConfig, HbmEmbeddingCache
+from paddle_tpu.ps.table import MemorySparseTable, TableConfig
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT / "benchmarks"))
+sys.path.insert(0, str(ROOT / "tools"))
+
+from harness import scopes, trace  # noqa: E402
+
+S, D, B, DIM = 4, 3, 16, 8
+
+
+def _table():
+    return MemorySparseTable(TableConfig(
+        shard_num=2, accessor_config=AccessorConfig(embedx_dim=DIM)))
+
+
+def _cache_cfg(capacity=256):
+    return CacheConfig(capacity=capacity, embedx_dim=DIM,
+                       embedx_threshold=0.0, push_mode="dense")
+
+
+def _keys(n=B * S, pool=50):
+    slot = np.arange(S, dtype=np.uint64)[None, :] << np.uint64(32)
+    return slot + (np.arange(n, dtype=np.uint64).reshape(-1, S)
+                   % np.uint64(pool))
+
+
+# -- (a) every step of the benchmark names its work ------------------------
+
+def _ctr_parts():
+    from paddle_tpu.models.ctr import CtrConfig, DeepFM
+
+    model = DeepFM(CtrConfig(num_sparse_slots=S, num_dense=D, embedx_dim=DIM,
+                             dnn_hidden=(16, 16)))
+    opt = optimizer.Adam(1e-3)
+    params = {"params": dict(model.named_parameters()), "buffers": {}}
+    return model, opt, params
+
+
+def _pass_step_text():
+    from paddle_tpu.models.ctr import (make_ctr_train_step_slab,
+                                       pack_ctr_batch)
+
+    model, opt, params = _ctr_parts()
+    keys = _keys()
+    cache = HbmEmbeddingCache(_table(), _cache_cfg(), device_map=True)
+    cache.begin_pass(keys.reshape(-1))
+    step = make_ctr_train_step_slab(
+        model, opt, _cache_cfg(), slot_ids=np.arange(S), batch_size=B,
+        num_dense=D, slab=2, with_weights=True, amp=True)
+    packed = pack_ctr_batch((keys & np.uint64(0xFFFFFFFF)).astype(np.uint32),
+                            np.zeros((B, D), np.float32),
+                            np.zeros(B, np.int8),
+                            weights=np.ones(B, np.uint8))
+    return step.lower(params, opt.init(params), cache.state,
+                      cache.device_map.state,
+                      jnp.asarray(np.stack([packed, packed]))
+                      ).compile().as_text()
+
+
+def _routed_step_text():
+    from paddle_tpu.core import mesh as mesh_mod
+    from paddle_tpu.ps.sharded_cache import \
+        make_sharded_ctr_train_step_from_keys
+
+    model, opt, params = _ctr_parts()
+    keys = _keys()
+    mesh = mesh_mod.make_mesh({"ps": 4}, devices=jax.devices()[:4])
+    cache = HbmEmbeddingCache(_table(), _cache_cfg(), device_map=True,
+                              mesh=mesh, axis="ps")
+    cache.begin_pass(keys.reshape(-1))
+    step = make_sharded_ctr_train_step_from_keys(
+        model, opt, _cache_cfg(), slot_ids=np.arange(S), mesh=mesh,
+        axis="ps")
+    return step.lower(
+        params, opt.init(params), cache.state, cache.device_map.state,
+        (keys & np.uint64(0xFFFFFFFF)).astype(np.uint32),
+        np.zeros((B, D), np.float32), np.zeros(B, np.int32)
+    ).compile().as_text()
+
+
+def _ernie_step_text():
+    from paddle_tpu.executor import Trainer
+    from paddle_tpu.models.ernie import Ernie, ErnieConfig
+
+    model = Ernie(ErnieConfig(vocab_size=128, hidden_size=64, num_heads=2,
+                              ffn_size=128, num_layers=2, max_seq_len=128,
+                              attn_impl="flash"))
+    trainer = Trainer(model, optimizer.Adam(1e-3),
+                      nn.functional.cross_entropy, amp=True)
+    ids = np.zeros((2, 128), np.int32)
+    return trainer.compiled_text(ids, ids)
+
+
+_PUSH = {"pt.push.accumulate", "pt.push.update"}
+STEPS = {
+    "pass_slab": (_pass_step_text, {"pt.unpack", "pt.probe", "pt.pull",
+                                    "pt.tower", "pt.dense_opt"} | _PUSH),
+    "routed_4dev": (_routed_step_text, {"pt.probe", "pt.pull", "pt.tower",
+                                        "pt.dense_opt", "pt.route"} | _PUSH),
+    "ernie": (_ernie_step_text, {"pt.embed", "pt.attn", "pt.ffn",
+                                 "pt.head_loss", "pt.loss", "pt.dense_opt",
+                                 "pt.flash_fwd", "pt.flash_bwd_dq",
+                                 "pt.flash_bwd_dkv"}),
+}
+# what computes nothing (and what XLA inserts without metadata), and the
+# collectives, which carry no scope by design
+_NO_WORK = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+            "copy", "broadcast", "iota"}
+_OPCODE_RE = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*.*?\s"
+                        r"([a-z][\w\-]*)\(")
+_COLLECTIVE_OP = re.compile(r"/(all_to_all|psum|all_gather|axis_index|"
+                            r"psum_scatter|pmean)$")
+
+
+@pytest.mark.parametrize("name", sorted(STEPS))
+def test_step_names_its_work(name):
+    build, want = STEPS[name]
+    assert want <= set(DEVICE_SCOPES)
+    text = build()
+    seen = {s for s in scopes.scope_of_ops(text).values() if s}
+    assert want <= seen, sorted(want - seen)
+    assert seen <= set(DEVICE_SCOPES), sorted(seen - set(DEVICE_SCOPES))
+    scoped = total = 0
+    for line in text.splitlines():
+        m = _OPCODE_RE.match(line)
+        if not m or m.group(1) in _NO_WORK:
+            continue
+        op = re.search(r'op_name="([^"]*)"', line)
+        if op and _COLLECTIVE_OP.search(op.group(1)):
+            continue
+        total += 1
+        scoped += bool(op and scopes.scope_of(op.group(1)))
+    assert total > 100 and scoped / total >= 0.90, (scoped, total)
+
+
+def test_every_pallas_call_is_named():
+    src = ROOT / "paddle_tpu"
+    calls = 0
+    for path in src.rglob("*.py"):
+        text = path.read_text()
+        for m in re.finditer(r"pl\.pallas_call\(", text):
+            depth, i = 0, m.end() - 1
+            while True:          # the call's own argument list
+                depth += {"(": 1, ")": -1}.get(text[i], 0)
+                if depth == 0:
+                    break
+                i += 1
+            assert re.search(r"\bname=\"\w+\"", text[m.end():i]), (
+                path, text[m.start():m.start() + 80])
+            calls += 1
+    assert calls == 7
+
+
+# -- (b) RecordEvent: ids, parents, counts, a bounded ring ----------------
+
+def test_record_event_parents_and_counts():
+    profiler.start_timeline()
+    with RecordEvent("outer", keys=3) as ev:
+        with RecordEvent("inner_a"):
+            pass
+        with RecordEvent("inner_b", rows=2.5):
+            with RecordEvent("leaf"):
+                pass
+        ev["bytes"] = 40            # a count known only after the work
+    by = {s.name: s for s in host_spans()}
+    assert [s.name for s in host_spans()] == ["inner_a", "leaf", "inner_b",
+                                              "outer"]
+    assert by["outer"].parent_id == 0
+    assert by["inner_a"].parent_id == by["outer"].span_id
+    assert by["inner_b"].parent_id == by["outer"].span_id
+    assert by["leaf"].parent_id == by["inner_b"].span_id
+    assert by["outer"].counts == {"keys": 3, "bytes": 40}
+    assert by["inner_b"].counts == {"rows": 2.5}
+    assert len({s.span_id for s in host_spans()}) == 4
+    assert by["inner_a"].dur + by["inner_b"].dur <= by["outer"].dur
+    assert by["outer"].t0 <= by["inner_a"].t0
+
+
+def test_record_event_parent_is_per_thread():
+    profiler.start_timeline()
+    inside = threading.Event()
+    release = threading.Event()
+
+    def worker():
+        with RecordEvent("worker_root"):
+            with RecordEvent("worker_child"):
+                inside.set()
+                release.wait(5)
+
+    t = threading.Thread(target=worker)
+    with RecordEvent("main_root"):
+        t.start()
+        assert inside.wait(5)
+        with RecordEvent("main_child"):   # opened while the worker's are
+            pass
+        release.set()
+        t.join()
+    by = {s.name: s for s in host_spans()}
+    assert by["worker_root"].parent_id == 0       # not main_root's child
+    assert by["worker_child"].parent_id == by["worker_root"].span_id
+    assert by["main_child"].parent_id == by["main_root"].span_id
+    assert by["worker_root"].tid != by["main_root"].tid
+
+
+def test_record_event_survives_an_exception():
+    profiler.start_timeline()
+    with pytest.raises(ValueError):
+        with RecordEvent("fails"):
+            raise ValueError("x")
+    with RecordEvent("after"):
+        pass
+    by = {s.name: s for s in host_spans()}
+    assert by["after"].parent_id == 0     # the failed scope was popped
+
+
+def test_span_ring_is_bounded_and_always_on():
+    for i in range(profiler.SPAN_RING + 10):
+        with RecordEvent("tick"):
+            pass
+    spans = host_spans()
+    assert len(spans) == profiler.SPAN_RING
+    assert spans[-1].span_id - spans[0].span_id >= profiler.SPAN_RING - 1
+    profiler.start_timeline()
+    assert host_spans() == []
+
+
+def test_chrome_export_carries_counts_and_merges(tmp_path):
+    from timeline import merge_traces
+
+    profiler.start_timeline()
+    with RecordEvent("pt.pass.upload", bytes=128):
+        pass
+    path = profiler.export_chrome_tracing(str(tmp_path / "w0.json"))
+    blob = json.load(open(path))
+    (ev,) = blob["traceEvents"]
+    assert ev["ph"] == "X" and ev["args"]["bytes"] == 128
+    assert ev["args"]["parent_id"] == 0 and "clockSyncUs" in blob
+    n = merge_traces([path], str(tmp_path / "merged.json"))
+    merged = json.load(open(tmp_path / "merged.json"))["traceEvents"]
+    assert n == len(merged)
+    assert "pt.pass.upload" in {e["name"] for e in merged}
+
+
+def test_removed_profiler_names_are_gone():
+    import paddle_tpu.core as core
+
+    for name in ("CostTimer", "start_profiler", "stop_profiler",
+                 "profiler_enabled"):
+        assert not hasattr(profiler, name) and not hasattr(core, name)
+
+
+# -- (c) the pass lifecycle's span tree ------------------------------------
+
+BEGIN = ["pt.pass.dedup", "pt.pass.index", "pt.pass.map_build",
+         "pt.pass.export", "pt.pass.layout", "pt.pass.upload"]
+END = ["pt.pass.fetch", "pt.pass.flush_index", "pt.pass.flush_export",
+       "pt.pass.merge", "pt.pass.import"]
+
+
+def _children(spans, root):
+    return [s for s in spans if s.parent_id == root.span_id]
+
+
+def test_pass_lifecycle_span_tree():
+    profiler.start_timeline()
+    table = _table()
+    cache = HbmEmbeddingCache(table, _cache_cfg(), device_map=True)
+    keys = _keys().reshape(-1)
+    n = cache.begin_pass(keys)
+    state_bytes = sum(a.nbytes for a in cache.state.values())
+    map_bytes = sum(a.nbytes for a in cache.device_map.state.values())
+    cache.end_pass()
+    spans = host_spans()
+    (begin,) = [s for s in spans if s.name == "pt.pass.begin"]
+    (end,) = [s for s in spans if s.name == "pt.pass.end"]
+    assert begin.parent_id == 0 and end.parent_id == 0
+    kids = _children(spans, begin)
+    assert [s.name for s in kids] == BEGIN
+    assert [s.name for s in _children(spans, end)] == END
+    for root in (begin, end):
+        assert sum(s.dur for s in _children(spans, root)) <= root.dur
+    uniq = len(np.unique(keys))
+    assert n == uniq
+    assert begin.counts == {"keys": len(keys), "unique_keys": uniq,
+                            "capacity": 256, "shards": 1}
+    assert end.counts == {"keys": uniq}
+    by = {s.name: s for s in spans}
+    full_dim = table.export_full(keys[:1])[0].shape[1]
+    assert by["pt.pass.export"].counts == {"bytes": uniq * full_dim * 4}
+    assert by["pt.pass.layout"].counts == {"bytes": state_bytes}
+    assert by["pt.pass.upload"].counts == {"bytes": state_bytes + map_bytes}
+    assert by["pt.pass.fetch"].counts == {"bytes": state_bytes}
+    # the table's own RecordEvent nests one level further down
+    assert by["pserver_sparse_export_full"].parent_id in {
+        by["pt.pass.export"].span_id, by["pt.pass.flush_export"].span_id}
+
+
+def test_prepare_and_activate_apart_make_two_roots():
+    profiler.start_timeline()
+    cache = HbmEmbeddingCache(_table(), _cache_cfg(), device_map=True)
+    prepared = cache.prepare_pass(_keys().reshape(-1))
+    with RecordEvent("ctr_pass_build"):       # as CtrPassTrainer wraps it
+        cache.activate_pass(prepared)
+    cache.discard_pass()
+    spans = host_spans()
+    by = {s.name: s for s in spans}
+    assert "pt.pass.begin" not in by
+    assert by["pt.pass.prepare"].parent_id == 0
+    assert by["pt.pass.prepare"].counts == {"keys": B * S,
+                                            "unique_keys": B * S}
+    assert [s.name for s in _children(spans, by["pt.pass.prepare"])] \
+        == BEGIN[:3]
+    assert by["pt.pass.activate"].parent_id == by["ctr_pass_build"].span_id
+    assert [s.name for s in _children(spans, by["pt.pass.activate"])] \
+        == BEGIN[3:]
+
+
+def test_pass_phases_picks_the_larger_pass():
+    profiler.start_timeline()
+    small = HbmEmbeddingCache(_table(), _cache_cfg(64), device_map=True)
+    big = HbmEmbeddingCache(_table(), _cache_cfg(), device_map=True)
+    small.begin_pass(_keys(16, pool=3).reshape(-1))      # the check's cache
+    big.begin_pass(_keys().reshape(-1))                  # the cell's pass
+    small.end_pass()
+    big.end_pass()
+    spans = host_spans()
+    roots = [s for s in spans if s.name == "pt.pass.begin"]
+    assert sorted(r.counts["unique_keys"] for r in roots) == [12, B * S]
+    want = max(roots, key=lambda r: r.counts["unique_keys"])
+    assert scopes.pick_root(spans, "begin") is want
+    got = scopes.pass_phases("begin")
+    kids = {s.name[len("pt.pass."):]: s.dur for s in _children(spans, want)}
+    assert {k: v for k, v in got.items() if k != "_root"} == kids
+    assert got["_root"] == want.dur
+    assert scopes.pick_root(spans, "end").counts == {"keys": B * S}
+    assert scopes.phase_seconds("begin", "dedup", "index", "map_build") \
+        == kids["dedup"] + kids["index"] + kids["map_build"]
+    assert scopes.phase_seconds("end", "no_such_phase") is None
+    profiler.start_timeline()
+    assert scopes.pass_phases("begin") is None           # nothing recorded
+
+
+# -- (d) the reader on a recorded pair --------------------------------------
+
+@pytest.fixture(scope="module")
+def recorded():
+    with open(ROOT / "benchmarks" / "testdata" / "scoped_trace.json") as f:
+        return json.load(f)
+
+
+def test_scope_of_ops_on_recorded_text(recorded):
+    got = scopes.scope_of_ops(recorded["hlo_text"])
+    for name, want in recorded["expect"]["scope_of_ops"].items():
+        assert got[name] == want, name
+    assert scopes.scope_of("jit(f)/transpose(jvp(pt.tower))/mul") \
+        == "pt.tower"
+    assert scopes.scope_of("jit(f)/pt.attn/pt.flash_fwd/x") == "pt.flash_fwd"
+    assert scopes.scope_of("jit(f)/pt.push.update/select_n") \
+        == "pt.push.update"
+    assert scopes.scope_of("jit(f)/script.py/opt.step/add") is None
+
+
+def test_scope_shares_on_recorded_trace(recorded):
+    red = trace.reduce_trace(recorded["events"])
+    got = scopes.shares_of(red["op_self_s"],
+                           scopes.scope_of_ops(recorded["hlo_text"]))
+    want = recorded["expect"]["shares"]
+    assert set(got["shares"]) == set(want)
+    for k, v in want.items():
+        assert got["shares"][k] == pytest.approx(v, rel=1e-9), k
+    assert sum(got["shares"].values()) == pytest.approx(1.0)
+    assert [[a, pytest.approx(b)] for a, b in
+            recorded["expect"]["unscoped_ops"]] == got["unscoped_ops"]
+
+
+def test_share_readers_on_recorded_trace(recorded, capsys):
+    class System:
+        def compiled_text(self):
+            return recorded["hlo_text"]
+
+    ctx = {"trace": trace.reduce_trace(recorded["events"]), "hlo_text": "",
+           "system": System()}
+    assert scopes.share(ctx, "pt.push.accumulate", "pt.push.update") \
+        == pytest.approx(8 / 25)
+    assert scopes.share(ctx, "pt.attn", prefix="pt.flash_") \
+        == pytest.approx(2 / 25)
+    assert scopes.share(ctx, "pt.embed") == 0.0       # read, and not there
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["unscoped_ops"][0][0] == "copy.5 f32[64,8]"
+    assert line["scoped_ops"][0] == ["fusion.1 f32[8,16]", "pt.tower",
+                                     pytest.approx(0.008)]
+    # no trace, or a step whose text names no scope: None, never 0
+    assert scopes.share({"trace": None}, "pt.tower") is None
+    bare = re.sub(r", metadata=\{[^}]*\}", "", recorded["hlo_text"])
+
+    class Unscoped:
+        def compiled_text(self):
+            return bare
+
+    none = {"trace": ctx["trace"], "hlo_text": bare, "system": Unscoped()}
+    assert scopes.share(none, "pt.tower") is None
+    assert "no share reported" in capsys.readouterr().err
