@@ -106,7 +106,9 @@ def main() -> None:
         "unit": "samples/s", "platform": dev.platform,
         "device_kind": dev.device_kind, "device_count": len(jax.devices()),
         "batch": BATCH, "slab": SLAB, "steps": STEPS, "amp": True,
-        "push_mode": resolve_push_mode(cache_cfg.push_mode)}))
+        "push_mode": resolve_push_mode(
+            cache_cfg.push_mode, cache_cfg.capacity,
+            BATCH * cfg.num_sparse_slots)}))
 
 
 if __name__ == "__main__":

@@ -165,6 +165,24 @@ def _deepfm(sz: Sizes):
                             embedx_dim=sz.embedx_dim, dnn_hidden=sz.tower))
 
 
+def _push_facts(since: float) -> Dict:
+    """The push formulations the program resolved since ``since`` (a
+    ``time.perf_counter``), with the shapes each was resolved for:
+    ``cache_push`` leaves one ``pt.push.select`` span a compile."""
+    from paddle_tpu.core import profiler
+
+    picked = []
+    for span in profiler.host_spans():
+        if span.name == "pt.push.select" and span.t0 >= since:
+            fact = {"capacity": span.counts["capacity"],
+                    "rows": span.counts["rows"],
+                    "mode": "dense" if span.counts["sweep"] else "sparse"}
+            if fact not in picked:
+                picked.append(fact)
+    return {"push_mode": "+".join(sorted({f["mode"] for f in picked})),
+            "push_select": picked}
+
+
 def _max_diff(a, b, relative: bool = False) -> float:
     """Largest |a - b| over two pytrees' leaves; ``relative`` divides each
     leaf's by that leaf's own max |b|."""
@@ -193,10 +211,10 @@ def leg_pass(sz: Sizes, log: CompileLog) -> Dict:
     from paddle_tpu import optimizer
     from paddle_tpu.models.ctr import (make_ctr_train_step_packed,
                                        pack_ctr_batch)
-    from paddle_tpu.ps.embedding_cache import (HbmEmbeddingCache,
-                                               resolve_push_mode)
+    from paddle_tpu.ps.embedding_cache import HbmEmbeddingCache
     from paddle_tpu.ps.ps_trainer import CtrPassTrainer
 
+    t_leg = time.perf_counter()
     ds, tagged, dense_x, labels = make_ctr_dataset(sz, sz.pass_batches, seed=1)
     sparse, dense = _slot_names(sz)
     pt.seed(0)
@@ -250,7 +268,7 @@ def leg_pass(sz: Sizes, log: CompileLog) -> Dict:
     pcache.discard_pass()
     return {"loss": [round(r1["loss"], 5), round(r2["loss"], 5)],
             "steps": int(r2["steps"]), "pass_keys": int(len(keys)),
-            "push_mode": resolve_push_mode(cache_cfg.push_mode),
+            **_push_facts(t_leg),
             "f32_step_vs_cpu": {"loss": [loss_dev, loss_cpu],
                                 "max_abs_params": d_params,
                                 "max_abs_cache": d_cache}}
@@ -267,11 +285,11 @@ def leg_stream(sz: Sizes, log: CompileLog) -> Dict:
     from paddle_tpu.ps import rpc
     from paddle_tpu.ps.accessor import AccessorConfig
     from paddle_tpu.ps.communicator import SyncCommunicator
-    from paddle_tpu.ps.embedding_cache import resolve_push_mode
     from paddle_tpu.ps.hot_tier import HotTierConfig
     from paddle_tpu.ps.ps_trainer import CtrStreamTrainer
     from paddle_tpu.ps.table import TableConfig
 
+    t_leg = time.perf_counter()
     ds = make_ctr_dataset(sz, sz.stream_batches, seed=2)[0]
     sparse, dense = _slot_names(sz)
     servers = [rpc.NativePsServer(n_trainers=1) for _ in range(2)]
@@ -329,7 +347,7 @@ def leg_stream(sz: Sizes, log: CompileLog) -> Dict:
     return {"loss": [round(cold["loss"], 5), round(warm["loss"], 5)],
             "steps": int(warm["steps"]), "resident_rows": int(len(keys)),
             "warm_rpcs": rpcs, "kernels": st["kernels"],
-            "push_mode": resolve_push_mode(tier.cache_config.push_mode)}
+            **_push_facts(t_leg)}
 
 
 # ---------------------------------------------------------------------------
@@ -434,6 +452,7 @@ def leg_four(sz: Sizes, devices) -> Dict:
     from paddle_tpu.ps.sharded_cache import (
         make_sharded_ctr_train_step_from_keys, select_routing)
 
+    t_leg = time.perf_counter()
     K = len(devices)
     mesh = mesh_mod.make_mesh({"ps": K}, devices=devices)
     cache_cfg = _cache_cfg(sz)
@@ -494,7 +513,7 @@ def leg_four(sz: Sizes, devices) -> Dict:
     return {"loss": [one["loss"], four["loss"]], "overflow": four["overflow"],
             "shard_devices": four["shard_devices"], "routing": list(routing),
             "max_abs_rows": d_rows, "max_abs_params": d_params,
-            "hybrid_loss": round(hybrid_loss, 5)}
+            "hybrid_loss": round(hybrid_loss, 5), **_push_facts(t_leg)}
 
 
 # ---------------------------------------------------------------------------

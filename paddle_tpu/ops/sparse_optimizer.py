@@ -12,9 +12,11 @@ OPTIMIZER MATH between gather and scatter is one fused Pallas kernel:
 every state column of a block of touched rows updates in a single VMEM
 pass. All four reference rules are supported for both the embed (1-d)
 and embedx (dim-d) blocks; the rule math lives in ``rule_update`` which
-is shared verbatim by the kernel body and the jnp fallback
-(``ps.embedding_cache.cache_push`` uses the kernel on TPU, jnp
-elsewhere; bit-parity is tested in tests/test_sparse_optimizer.py).
+is shared verbatim by the kernel body and the jnp form
+(``ps.embedding_cache.cache_push_sparse`` runs the jnp form unless
+``CacheConfig.pallas_update=True`` asks for the kernel: on the v5e the
+kernel measured slower, PR 25, its ``[n, 1]`` operands pad to 128 lanes;
+bit-parity is tested in tests/test_sparse_optimizer.py).
 """
 
 from __future__ import annotations

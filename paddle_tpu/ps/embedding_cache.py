@@ -32,6 +32,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..core.enforce import enforce, enforce_le
 from ..core.profiler import RecordEvent
@@ -45,14 +46,40 @@ __all__ = ["CacheConfig", "HbmEmbeddingCache", "cache_pull", "cache_push",
            "resolve_push_mode"]
 
 
-def resolve_push_mode(mode: str) -> str:
-    """Resolve CacheConfig.push_mode: "auto" → dense on TPU (the O(C/K)
-    streaming formulation the chip prefers), sparse elsewhere (bit
-    -identical to the reference's merge_grad shape). The single source
-    of truth — cache_push and sharded_cache.select_routing both use it."""
-    if mode == "auto":
-        return "dense" if jax.default_backend() == "tpu" else "sparse"
-    return mode
+#: table rows per pushed slot below which ``auto`` takes the full-table
+#: sweep (``cache_push_dense``) on a TPU. ONE number, from the crossover
+#: table of PERF.md section 5 (v5e, PR 25, ``tools/push_crossover.py``):
+#: with no row named twice, the touched rows' worst case, the sweep still
+#: wins at 79 rows a slot (19.8 against 23.8 ms) and loses at 157 (59.3
+#: against 32.7); with the benchmark's Zipf repeats the touched rows win
+#: at every shape measured, 10 to 630 rows a slot.
+SWEEP_MAX_ROWS_PER_SLOT = 128
+
+#: the state's columns, in the order ``fused_row_update`` takes and
+#: returns them
+_COLUMNS = ("show", "click", "embed_w", "embed_state", "embedx_w",
+            "embedx_state", "has_embedx")
+
+#: slots the touched-rows push walks at a time (see cache_push_sparse;
+#: v5e, PR 25, 2^26 rows x 106,496 slots, ms a push: 19.45 at 4096,
+#: 19.5 at 8192, 22.1 at 16384, 31.5 with the batch in one piece)
+PUSH_CHUNK = 8192
+
+
+def resolve_push_mode(mode: str, capacity: int, rows: int) -> str:
+    """Resolve ``CacheConfig.push_mode`` for a push of ``rows`` slots
+    into a table of ``capacity`` rows (both static at trace time, so the
+    answer specializes per compiled shape). "dense" and "sparse" mean
+    themselves. "auto" off TPU is "sparse" (the formulation the host
+    tables are bit-identical to); on TPU it is the sweep ("dense") iff
+    ``capacity < SWEEP_MAX_ROWS_PER_SLOT * rows``, else the touched rows
+    ("sparse"). The single source of truth — ``cache_push`` and
+    ``sharded_cache.select_routing`` both use it."""
+    if mode != "auto":
+        return mode
+    if jax.default_backend() != "tpu":
+        return "sparse"
+    return "dense" if capacity < SWEEP_MAX_ROWS_PER_SLOT * rows else "sparse"
 
 
 @dataclasses.dataclass
@@ -73,22 +100,29 @@ class CacheConfig:
     #: applying (optimizer.cuh.h:81-94 inits and returns). True = CPU
     #: order (default — bit-parity with the host tables); False = GPU.
     create_applies_grad: bool = True
-    #: run the per-row optimizer math as the fused Pallas kernel
-    #: (ops/sparse_optimizer.py, the optimizer.cuh.h analogue); only
-    #: meaningful for the "sparse" push mode. None = auto (on for TPU
-    #: backends, jnp elsewhere)
+    #: run the per-row optimizer math of the touched-rows push as the
+    #: fused Pallas kernel (ops/sparse_optimizer.py, the optimizer.cuh.h
+    #: analogue) instead of the same math as jnp. None = jnp on every
+    #: backend: on the v5e the kernel measured slower (PR 25, 2^26 rows
+    #: x 106,496 slots: 20.7 against 19.5 ms a push; its [n, 1]
+    #: operands are padded to 128 lanes). True asks for it
+    #: (Mosaic compiles all four rules; interpret mode off TPU).
     pallas_update: Optional[bool] = None
-    #: push formulation. "sparse": the reference's merge_grad shape —
-    #: sorted-unique dedup, gather touched rows, rule kernel, scatter
-    #: back (O(batch) HBM traffic, sort/gather/scatter-bound). "dense": one
-    #: duplicate-safe 2-D scatter-add of [grads|show|click] into a
-    #: [C+1, 3+dim] accumulator, then the SAME fused_row_update math
-    #: streamed over the whole table with a touched-row mask — no sort,
-    #: no unique, no row gather/scatter; pure sequential HBM traffic
-    #: O(capacity·width). "auto": dense on TPU, sparse elsewhere (keeps
-    #: CPU-path tests bit-identical to the reference formulation).
-    #: Which is faster on the chip, at which capacity: not measured
-    #: (ROADMAP S2).
+    #: push formulation. "sparse": the touched rows, the reference's
+    #: merge_grad shape — one sort, one segment-sum, gather the rows the
+    #: batch named, rule, scatter back; cost follows the batch. "dense":
+    #: the sweep — one duplicate-safe 2-D scatter-add of
+    #: [grads|show|click] into a [C+1, 4+dim] accumulator, then the SAME
+    #: fused_row_update math over the whole table under a touched-row
+    #: mask; no sort, no row gather/scatter, cost O(capacity·width).
+    #: "auto": sparse off TPU (keeps CPU-path tests bit-identical to the
+    #: reference formulation); on TPU whichever the shapes favour
+    #: (:func:`resolve_push_mode`). Measured on the v5e in PR 25
+    #: (PERF.md section 5, tools/push_crossover.py): at 2^26 rows and a
+    #: 4096 x 26 batch the sweep takes 85.4 ms a push, the touched rows
+    #: 19.5 (28.0 when no row repeats); the sweep costs about 1.07 ns a
+    #: table row plus 0.113 us a slot whatever the rows, the touched
+    #: rows cost by the distinct rows named.
     push_mode: str = "auto"
 
 
@@ -121,14 +155,20 @@ def cache_push(
     cfg: CacheConfig,
 ) -> Dict[str, jax.Array]:
     """In-graph push (PushSparseGrad / merge_grad analogue). Dispatches
-    on ``cfg.push_mode`` — see CacheConfig; both modes apply the same
-    ``fused_row_update`` math to the same per-row summed deltas, so they
-    agree up to f32 re-association of duplicate-row sums."""
-    mode = resolve_push_mode(cfg.push_mode)
-    if mode == "dense":
-        return cache_push_dense(state, rows, grads, shows, clicks, cfg)
-    enforce(mode == "sparse", f"unknown push_mode {cfg.push_mode!r}")
-    return cache_push_sparse(state, rows, grads, shows, clicks, cfg)
+    on ``cfg.push_mode`` and the two shapes (:func:`resolve_push_mode`);
+    both formulations apply the same ``fused_row_update`` math to the
+    same per-row summed deltas, so they agree up to f32 re-association
+    of duplicate-row sums. The choice is static per compiled shape and
+    is recorded where it is made: one ``pt.push.select`` host span a
+    trace (``profiler.host_spans()``), none on the step path."""
+    C, n = state["embed_w"].shape[0], rows.shape[0]
+    mode = resolve_push_mode(cfg.push_mode, C, n)
+    enforce(mode in ("dense", "sparse"),
+            f"unknown push_mode {cfg.push_mode!r}")
+    with RecordEvent("pt.push.select", capacity=C, rows=n,
+                     sweep=1 if mode == "dense" else 0):
+        push = cache_push_dense if mode == "dense" else cache_push_sparse
+        return push(state, rows, grads, shows, clicks, cfg)
 
 
 def cache_push_dense(
@@ -149,8 +189,13 @@ def cache_push_dense(
     heter_comm_inl.h:388) exists because GPUs update rows one-thread-
     per-row; the TPU shape of "merge then update touched rows" is
     "scatter-add then masked dense update" — cost O(capacity), against
-    the sparse shape's sort + row gather/scatter at O(batch) (their
-    crossover on the chip: not measured, ROADMAP S2). "Touched"
+    the touched-rows shape's sort + row gather/scatter at O(batch).
+    Measured on the v5e (PR 25, PERF.md section 5): about 1.07 ns a
+    table row (five sweeps of the rule and the mask, zeroing and
+    reading the accumulator) plus 0.113 us a slot (the scatter-add) —
+    85.4 ms a push at 2^26 rows, 12.2 at 2^21 — so it is what ``auto``
+    takes only where the table is small against the batch
+    (:func:`resolve_push_mode`). "Touched"
     means PRESENT IN THE BATCH (an occurrence count rides the
     accumulator), exactly the sparse path's `uniq` membership — so a
     row whose occurrences all carry show=0 still gets the rule applied
@@ -171,8 +216,6 @@ def cache_push_dense(
         dshow, dclick = acc[:, 1 + dim], acc[:, 2 + dim]
         touched = acc[:, 3 + dim] > 0
 
-    names = ("show", "click", "embed_w", "embed_state", "embedx_w",
-             "embedx_state", "has_embedx")
     with jax.named_scope("pt.push.update"):
         outs = fused_row_update(
             state["show"], state["click"], state["embed_w"],
@@ -188,27 +231,40 @@ def cache_push_dense(
         tcol = touched[:, None]
         return {k: jnp.where(touched if new.ndim == 1 else tcol, new,
                              state[k])
-                for k, new in zip(names, outs)}
+                for k, new in zip(_COLUMNS, outs)}
 
 
 def merge_sparse_grads(rows: jax.Array, grads: jax.Array, shows: jax.Array,
                        clicks: jax.Array, capacity: int):
     """merge_grad: in-batch dedup (the cub sort+reduce step,
-    heter_comm_inl.h:388, as sorted-unique + segment-sum). ``uniq`` is
-    the (padded) set of distinct rows; padding slots get the sentinel
-    ``capacity`` and are dropped at scatter time. ONE definition shared
-    by :func:`cache_push_sparse` and the fused Pallas scatter+apply
-    kernel (ops/hot_kernels.py) — the f32 merge association is part of
-    the bit-parity contract, so the two paths must not drift."""
+    heter_comm_inl.h:388): ONE stable sort of the rows, run starts by
+    comparing neighbours, ONE segment-sum of the packed
+    [grads | show | click] payload over the sorted run ids. ``uniq`` is
+    the (padded) ascending set of distinct rows; padding slots get the
+    sentinel ``capacity`` and are dropped at scatter time. The sort is
+    stable and a run's occurrences are summed in batch order, the f32
+    association of ``segment_sum`` over ``jnp.unique``'s inverse. ONE
+    definition shared by :func:`cache_push_sparse` and the fused Pallas
+    scatter+apply kernel (ops/hot_kernels.py) — the f32 merge
+    association is part of the bit-parity contract, so the two paths
+    must not drift."""
     n = rows.shape[0]
     with jax.named_scope("pt.push.accumulate"):
-        uniq, inv = jnp.unique(rows, size=n, fill_value=capacity,
-                               return_inverse=True)
-        inv = inv.reshape(-1)
-        show_sum = jax.ops.segment_sum(shows, inv, num_segments=n)
-        click_sum = jax.ops.segment_sum(clicks, inv, num_segments=n)
-        g = jax.ops.segment_sum(grads, inv, num_segments=n)  # [n, 1+dim]
-    return uniq, show_sum, click_sum, g
+        rows = jnp.where(rows < 0, capacity, rows)  # a miss marker drops too
+        srows, order = lax.sort((rows, jnp.arange(n, dtype=jnp.int32)),
+                                num_keys=1, is_stable=True)
+        first = jnp.concatenate(
+            [jnp.ones((1,), jnp.bool_), srows[1:] != srows[:-1]])
+        run = jnp.cumsum(first.astype(jnp.int32)) - 1  # sorted, in [0, n)
+        packed = jnp.concatenate(
+            [grads.astype(jnp.float32), shows[:, None], clicks[:, None]],
+            axis=1)  # [n, 1+dim+2]
+        summed = jax.ops.segment_sum(packed[order], run, num_segments=n,
+                                     indices_are_sorted=True)
+        # every occurrence of a run writes the same row id
+        uniq = jnp.full((n,), capacity, rows.dtype).at[run].set(
+            srows, indices_are_sorted=True)
+    return uniq, summed[:, -2], summed[:, -1], summed[:, :-2]
 
 
 def cache_push_sparse(
@@ -219,70 +275,102 @@ def cache_push_sparse(
     clicks: jax.Array,  # [n]
     cfg: CacheConfig,
 ) -> Dict[str, jax.Array]:
-    """The merge_grad-shaped push: dedup duplicate rows inside the batch
-    (the cub sort+reduce merge_grad step, heter_comm_inl.h:388, becomes
-    sorted-unique + segment-sum), then gather the touched rows, apply the
-    per-feature CTR rule (optimizer.cuh.h:35-70 / sparse_sgd_rule) and
-    scatter only those rows back. Per-step HBM traffic is O(batch·dim),
-    independent of cache capacity — the right shape for hosts/CPU; on
-    TPU prefer push_mode="dense" (sort and row scatter dominate there).
-    """
+    """The touched-rows push, the reference's merge_grad shape: dedup
+    duplicate rows inside the batch (the cub sort+reduce merge_grad
+    step, heter_comm_inl.h:388, is :func:`merge_sparse_grads`), gather
+    the rows the batch named, apply the per-feature CTR rule
+    (optimizer.cuh.h:35-70 / sparse_sgd_rule) and scatter only those
+    rows back. Per-step HBM traffic is O(batch·dim), independent of
+    cache capacity: what hosts and CPUs run, and what ``auto`` takes on
+    a TPU where the table dwarfs the batch (v5e, PR 25: 19.5 ms a push
+    at 2^26 rows x 106,496 slots half of them repeats, against the
+    sweep's 85.4; PERF.md section 5 has the split by operation)."""
     n = rows.shape[0]
     C = state["embed_w"].shape[0]
     sgd = cfg.sgd
+    enforce_le(C + n, np.iinfo(np.int32).max,
+               "capacity + batch rows must fit an int32 row id")
 
     uniq, show_sum, click_sum, g = merge_sparse_grads(rows, grads, shows,
                                                       clicks, C)
-    with jax.named_scope("pt.push.update"):
-        srows = jnp.where(uniq < C, uniq, 0)  # safe gather index for padding
+    rule_kw = dict(
+        embed_rule=cfg.embed_rule, embedx_rule=cfg.embedx_rule,
+        lr=sgd.learning_rate, initial_g2sum=sgd.initial_g2sum,
+        beta1=sgd.beta1, beta2=sgd.beta2, eps=sgd.ada_epsilon,
+        nonclk_coeff=cfg.nonclk_coeff, click_coeff=cfg.click_coeff,
+        embedx_threshold=cfg.embedx_threshold,
+        create_applies_grad=cfg.create_applies_grad)
 
-        gathered = (state["show"][srows], state["click"][srows],
-                    state["embed_w"][srows], state["embed_state"][srows],
-                    state["embedx_w"][srows], state["embedx_state"][srows],
-                    state["has_embedx"][srows])
-
-        use_pallas = cfg.pallas_update
-        if use_pallas is None:
-            use_pallas = jax.default_backend() == "tpu"
-        if use_pallas:
+    def rule(gathered, dshow, dclick, g):
+        if cfg.pallas_update:
             # fused per-row optimizer kernel (optimizer.cuh.h analogue)
-            (show_rows, click_rows, embed_w_rows, embed_st_rows, ex_w_rows,
-             ex_st_rows, has_rows) = ctr_sparse_rows(
-                gathered, show_sum, click_sum, g[:, :1], g[:, 1:],
-                embed_rule=cfg.embed_rule, embedx_rule=cfg.embedx_rule,
-                lr=sgd.learning_rate, initial_g2sum=sgd.initial_g2sum,
-                weight_bounds=tuple(sgd.weight_bounds),
-                beta1=sgd.beta1, beta2=sgd.beta2, eps=sgd.ada_epsilon,
-                nonclk_coeff=cfg.nonclk_coeff, click_coeff=cfg.click_coeff,
-                embedx_threshold=cfg.embedx_threshold,
-                create_applies_grad=cfg.create_applies_grad)
-        else:
-            # same math, no kernel: fused_row_update is the single shared
-            # definition of the whole per-row update
-            (show_rows, click_rows, embed_w_rows, embed_st_rows, ex_w_rows,
-             ex_st_rows, has_rows) = fused_row_update(
-                *gathered, show_sum, click_sum, g[:, :1], g[:, 1:],
-                embed_rule=cfg.embed_rule, embedx_rule=cfg.embedx_rule,
-                dim=cfg.embedx_dim, lr=sgd.learning_rate,
-                initial_g2sum=sgd.initial_g2sum,
-                wmin=sgd.weight_bounds[0], wmax=sgd.weight_bounds[1],
-                beta1=sgd.beta1, beta2=sgd.beta2, eps=sgd.ada_epsilon,
-                nonclk_coeff=cfg.nonclk_coeff, click_coeff=cfg.click_coeff,
-                embedx_threshold=cfg.embedx_threshold,
-                create_applies_grad=cfg.create_applies_grad)
+            return ctr_sparse_rows(
+                gathered, dshow, dclick, g[:, :1], g[:, 1:],
+                weight_bounds=tuple(sgd.weight_bounds), **rule_kw)
+        # same math, no kernel: fused_row_update is the single shared
+        # definition of the whole per-row update
+        return fused_row_update(
+            *gathered, dshow, dclick, g[:, :1], g[:, 1:],
+            dim=cfg.embedx_dim, wmin=sgd.weight_bounds[0],
+            wmax=sgd.weight_bounds[1], **rule_kw)
 
-        drop = dict(mode="drop")  # padding rows (sentinel C) fall away
-        return {
-            "show": state["show"].at[uniq].set(show_rows, **drop),
-            "click": state["click"].at[uniq].set(click_rows, **drop),
-            "embed_w": state["embed_w"].at[uniq].set(embed_w_rows, **drop),
-            "embed_state": state["embed_state"].at[uniq].set(
-                embed_st_rows, **drop),
-            "embedx_w": state["embedx_w"].at[uniq].set(ex_w_rows, **drop),
-            "embedx_state": state["embedx_state"].at[uniq].set(
-                ex_st_rows, **drop),
-            "has_embedx": state["has_embedx"].at[uniq].set(has_rows, **drop),
-        }
+    def gather(col, uniq):
+        # ascending like uniq; padding reads the last row, and its
+        # update is dropped by ``scatter``
+        return col.at[jnp.minimum(uniq, C - 1)].get(indices_are_sorted=True)
+
+    def scatter(col, uniq, lo, new):
+        # uniq is ascending with its padding (everything >= C) last:
+        # giving each padding slot its own out-of-range id makes the
+        # indices sorted AND unique, which spares XLA:TPU a sort of the
+        # indices per scatter; mode="drop" discards them
+        own = (C + lo + jnp.arange(uniq.shape[0])).astype(uniq.dtype)
+        srows = jnp.where(uniq < C, uniq, own)
+        return col.at[srows].set(new, mode="drop", indices_are_sorted=True,
+                                 unique_indices=True)
+
+    with jax.named_scope("pt.push.update"):
+        # The distinct rows sit first in ``uniq``, so only the chunks of
+        # ``step`` slots that hold one are walked: gathers and rule
+        # follow the rows the batch NAMED, not the slots it was padded
+        # to. What XLA:TPU makes of the scatters decides where each goes
+        # (v5e, PERF.md section 5, PR 25): into a [C, w > 1] column it
+        # writes row by row, so that scatter is walked with the chunks;
+        # into a [C] or [C, 1] column it makes one pass over the whole
+        # column whatever the number of updates, so those are scattered
+        # once, after the walk, from the [n, .] buffers the walk fills.
+        step = min(PUSH_CHUNK, n)
+        pad = -n % step
+        uniq = jnp.pad(uniq, (0, pad), constant_values=C)
+        show_sum, click_sum = (jnp.pad(a, (0, pad))
+                               for a in (show_sum, click_sum))
+        g = jnp.pad(g, ((0, pad), (0, 0)))
+        chunks = (jnp.sum(uniq < C) + step - 1) // step
+        walked = tuple(k for k in _COLUMNS
+                       if state[k].ndim == 2 and state[k].shape[1] > 1)
+        after = tuple(k for k in _COLUMNS if k not in walked)
+
+        def chunk(i, carry):
+            tables, rows_out = carry
+            lo = i * step
+            cut = lambda a: lax.dynamic_slice_in_dim(a, lo, step)
+            u = cut(uniq)
+            new_rows = dict(zip(_COLUMNS, rule(
+                tuple(gather(tables.get(k, state[k]), u) for k in _COLUMNS),
+                cut(show_sum), cut(click_sum), cut(g))))
+            tables = {k: scatter(tables[k], u, lo, new_rows[k])
+                      for k in walked}
+            rows_out = {k: lax.dynamic_update_slice_in_dim(
+                rows_out[k], new_rows[k], lo, 0) for k in after}
+            return tables, rows_out
+
+        tables, rows_out = lax.fori_loop(
+            0, chunks, chunk,
+            ({k: state[k] for k in walked},
+             {k: jnp.zeros(uniq.shape + state[k].shape[1:], state[k].dtype)
+              for k in after}))
+        return dict(tables, **{k: scatter(state[k], uniq, 0, rows_out[k])
+                               for k in after})
 
 
 class HbmEmbeddingCache:

@@ -98,11 +98,14 @@ class HotTierConfig:
     #: and a read-only cold store (serving replica) accepts the fetch
     create_on_miss: bool = True
     #: in-graph push formulation (embedding_cache.resolve_push_mode):
-    #: "dense" streams the whole capacity through the rule (the TPU
-    #: shape — cost ∝ capacity), "sparse" sorts/dedups the batch (cost
-    #: ∝ batch keys); "auto" picks by backend. A persistent tier sized
-    #: tight can prefer "dense" even off-TPU: its capacity-stream can
-    #: undercut the sparse mode's per-key sort at large batches.
+    #: "dense" sweeps the whole capacity through the rule (cost ∝
+    #: capacity), "sparse" sorts/dedups the batch and touches its rows
+    #: (cost ∝ distinct keys); "auto" is sparse off TPU and picks from
+    #: capacity against batch slots on TPU (v5e crossover, PR 25:
+    #: PERF.md section 5 — a 2^21-row tier under a 4096 x 26 batch is on
+    #: the sweep's side). A persistent tier sized tight can prefer
+    #: "dense" even off-TPU: its capacity-stream can undercut the
+    #: sparse mode's per-key sort at large batches.
     push_mode: str = "auto"
     #: sparse-kernel implementation (ops/hot_kernels.py): "jnp" is the
     #: XLA formulation (two bucket gathers + separate gather + the

@@ -378,15 +378,21 @@ def select_routing(m_local: int, shard_rows: int, K: int,
       the O(C/K) full-table update dominates BOTH routings there
       (all four combos within ~6%), so the choice is immaterial.
 
-    ``m_local`` and ``shard_rows`` are accepted (and currently unused)
-    so a hardware recalibration can key on the batch/table regime
-    without an API change. Inputs are static at trace time, so the
-    selection specializes per compiled shape, like every other XLA
-    shape decision.
+    ``m_local`` and ``shard_rows`` key the push formulation:
+    ``push_mode="auto"`` is resolved by
+    :func:`embedding_cache.resolve_push_mode` for a shard of
+    ``shard_rows`` rows receiving the routed push's ``K`` buckets of
+    ``route_bucket_capacity(m_local, K)`` slots — the shapes the
+    owner-side ``cache_push`` sees at the default ``cap_factor`` (on
+    the v5e, PR 25: PERF.md §5's crossover table). Inputs are static at
+    trace time, so the selection specializes per compiled shape, like
+    every other XLA shape decision.
 
     **KNOWN RISK — CPU provenance.** Every number behind this rule was
     measured on the 8-device virtual CPU mesh (ROUTED_GRID.json records
-    ``"platform": "cpu"``); no on-chip timing exists (ROADMAP S6/D5).
+    ``"platform": "cpu"``); no on-chip timing of the ROUTINGS exists
+    (ROADMAP S6/D5; the push formulation they carry was measured on the
+    v5e in PR 25).
     CPU relative costs do NOT transfer to the chip, so the K≥4
     threshold and especially the "never mix sides" conclusion may
     invert on ICI, where all_gather bandwidth and the dedup sort have
@@ -395,10 +401,10 @@ def select_routing(m_local: int, shard_rows: int, K: int,
     performance work; correctness is unaffected (all combos are exact,
     and chip_smoke.py's four-chip leg runs the K=4 choice on the chip).
     """
-    push_mode = resolve_push_mode(push_mode)
+    push_mode = resolve_push_mode(
+        push_mode, shard_rows, K * route_bucket_capacity(m_local, K))
     enforce(push_mode in ("dense", "sparse"),
             f"push_mode must be 'dense' or 'sparse', got {push_mode!r}")
-    del m_local, shard_rows  # regime keys reserved for hw recalibration
     # multi-PROCESS meshes in DENSE mode route at every K: the
     # cross-process sweep (ROUTED_MULTIHOST_DENSE.json) measured
     # routed/gathered 0.92x at K=2, 0.82x at K=4, 0.60x at K=8 — the

@@ -43,11 +43,16 @@ def test_leg_pass_tiny(log):
     facts = chip_smoke.leg_pass(TINY, log)
     assert facts["loss"][1] < facts["loss"][0]
     assert facts["push_mode"] == "sparse"   # what auto resolves to off-TPU
+    # ... with the shapes it was resolved for, one entry a compiled shape
+    assert {"capacity": TINY.capacity, "rows": TINY.batch * TINY.slots,
+            "mode": "sparse"} in facts["push_select"]
 
 
 def test_leg_stream_tiny(log):
     facts = chip_smoke.leg_stream(TINY, log)
     assert facts["warm_rpcs"] == {} and facts["kernels"] == "jnp"
+    assert facts["push_mode"] == "sparse" and all(
+        f["capacity"] == TINY.capacity for f in facts["push_select"])
 
 
 def test_leg_dense_tiny():
@@ -58,6 +63,8 @@ def test_leg_dense_tiny():
 def test_leg_four_tiny():
     facts = chip_smoke.leg_four(TINY, jax.devices()[:4])
     assert facts["overflow"] == 0 and facts["shard_devices"] == 4
+    shard = {f["capacity"] for f in facts["push_select"]}
+    assert shard == {TINY.capacity, TINY.capacity // 4}, facts["push_select"]
 
 
 def test_compile_cache_path_is_fixed_or_the_environments(monkeypatch, tmp_path):

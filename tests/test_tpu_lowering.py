@@ -280,3 +280,54 @@ def test_hybrid_step_compiles(v5e, as_tpu):
     hlo = tr._step.lower(_shapes(tr.params), _shapes(tr.opt_state), ids, ids,
                          _rng_key()).compile().as_text()
     assert hlo.count("tpu_custom_call") == 3 * cfg.num_layers // pp
+
+
+# ---------------------------------------------------------------------------
+# the touched-rows push, as the chip compiles it (tier-1: a small tower)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("pallas", [None, True])
+def test_slab_step_on_the_touched_side_compiles(v5e, as_tpu, pallas):
+    """A slab step whose table dwarfs its batch (``auto`` → touched rows):
+    no operand has the sweep's accumulator in it (leading dimension C+1),
+    the scatters were told their indices are sorted and unique (XLA:TPU
+    sorts them itself otherwise: the only sort left is the dedup's), the
+    rule is jnp unless the kernel is asked for, and then Mosaic accepts
+    it at the chunk's shape."""
+    import re
+
+    from paddle_tpu import optimizer
+    from paddle_tpu.models.ctr import _packed_layout, make_ctr_train_step_slab
+    from paddle_tpu.ps.embedding_cache import resolve_push_mode
+
+    from paddle_tpu.ps.embedding_cache import PUSH_CHUNK
+
+    sz = chip_smoke.Sizes(tower=(32, 32), batch=512, slab=2,
+                          capacity=1 << 21)
+    C, n = sz.capacity, sz.batch * sz.slots
+    assert resolve_push_mode("auto", C, n) == "sparse"
+    assert n > PUSH_CHUNK     # the rows are walked in chunks
+    model = chip_smoke._deepfm(sz)
+    opt = optimizer.Adam(learning_rate=1e-3)
+    params = {"params": dict(model.named_parameters()), "buffers": {}}
+    cfg = chip_smoke._cache_cfg(sz)
+    cfg.pallas_update = pallas
+    step = make_ctr_train_step_slab(
+        model, opt, cfg, slot_ids=np.arange(sz.slots), batch_size=sz.batch,
+        num_dense=sz.dense, slab=sz.slab, with_weights=True, amp=True)
+    xd = sz.embedx_dim
+    cache = {"show": _z(C), "click": _z(C), "embed_w": _z(C, 1),
+             "embed_state": _z(C, 1), "embedx_w": _z(C, xd),
+             "embedx_state": _z(C, 1), "has_embedx": _z(C)}
+    nb = 1 << 18
+    cmap = {"hi": _z(nb, 4, dtype=jnp.uint32), "lo": _z(nb, 4, dtype=jnp.uint32),
+            "row": _z(nb, 4, dtype=jnp.int32), "seed": np.uint32(0)}
+    total = _packed_layout(sz.batch, sz.slots, sz.dense, True)[3]
+    hlo = _compile(step, SingleDeviceSharding(v5e[0]), params,
+                   opt.init(params), cache, cmap,
+                   _z(sz.slab, total, dtype=jnp.uint8)).as_text()
+    assert f"[{C + 1}," not in hlo and f"[{C + 1}]" not in hlo
+    sorts = re.findall(r"= \([^=]*\) sort\(.*?op_name=\"([^\"]*)\"", hlo)
+    assert sorts and all("pt.push.accumulate" in s for s in sorts), sorts
+    assert ("tpu_custom_call" in hlo) == bool(pallas)
