@@ -24,7 +24,17 @@ from .core.nan_inf import check_numerics
 from .core.profiler import RecordEvent
 from .optimizer import Optimizer
 
-__all__ = ["Trainer", "make_train_step", "make_eval_step"]
+__all__ = ["Trainer", "make_train_step", "make_eval_step", "auxiliary_loss"]
+
+
+def auxiliary_loss(buffers: Dict[str, Any]) -> Optional[jax.Array]:
+    """What a model reports as auxiliary loss: the sum of every buffer
+    whose own name is ``aux_loss`` (``blocks.3.ffn.aux_loss``), as its
+    forward left it — each layer applies its own coefficient. None for a
+    model that reports none."""
+    terms = [v for name, v in buffers.items()
+             if name.rsplit(".", 1)[-1] == "aux_loss"]
+    return sum(terms[1:], terms[0]) if terms else None
 
 
 def make_train_step(
@@ -48,6 +58,11 @@ def make_train_step(
     INSIDE the traced body (rather than around the first call) makes
     the mode a property of the step, immune to auto_cast's trace-time
     call-site pitfall.
+
+    The loss differentiated is ``loss_fn``'s plus the model's
+    ``auxiliary_loss`` (router losses of expert layers); the loss returned
+    is ``loss_fn``'s alone. A model that reports none compiles to the step
+    it always did.
     """
     from .amp import step_ctx
 
@@ -66,8 +81,10 @@ def make_train_step(
                 # AMP loss scaling: grads are taken of the scaled loss;
                 # the AMPOptimizer unscales them inside update
                 # (amp.GradScaler)
-                scaled = (optimizer.scale_loss(loss, opt_state)
-                          if hasattr(optimizer, "scale_loss") else loss)
+                aux = auxiliary_loss(new_state["buffers"])
+                total = loss if aux is None else loss + aux
+                scaled = (optimizer.scale_loss(total, opt_state)
+                          if hasattr(optimizer, "scale_loss") else total)
                 return scaled, (loss, new_state["buffers"])
 
             (_, (loss, new_buffers)), grads = jax.value_and_grad(

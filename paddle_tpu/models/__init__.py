@@ -1,5 +1,6 @@
 from .lenet import LeNet
 from .ernie import Ernie, ErnieConfig
+from .olmoe import Olmoe, OlmoeConfig
 from .ctr import (CtrConfig, DCN, DeepFM, WideDeep, XDeepFM,
                   make_ctr_train_step)
 from .din import DIN, make_ctr_attention_train_step
@@ -21,7 +22,7 @@ from .shufflenetv2 import (ShuffleNetV2, shufflenet_v2_x0_25,
                            shufflenet_v2_x0_5, shufflenet_v2_x1_0,
                            shufflenet_v2_x1_5, shufflenet_v2_x2_0)
 
-__all__ = ["LeNet", "Ernie", "ErnieConfig",
+__all__ = ["LeNet", "Ernie", "ErnieConfig", "Olmoe", "OlmoeConfig",
            "CtrConfig", "DeepFM", "WideDeep", "make_ctr_train_step",
            "DCN", "XDeepFM", "DIN", "DSSM", "ESMM", "MMoE",
            "DeepWalkConfig", "make_deepwalk_train_step",

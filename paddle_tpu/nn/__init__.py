@@ -30,6 +30,7 @@ from .layers import (
     MaxPool2D,
     MSELoss,
     ReLU,
+    RMSNorm,
     Sigmoid,
     Softmax,
     Tanh,

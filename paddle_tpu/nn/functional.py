@@ -31,6 +31,7 @@ __all__ = [
     "adaptive_avg_pool2d",
     "batch_norm",
     "layer_norm",
+    "rms_norm",
     "embedding",
     "one_hot",
     "cross_entropy",
@@ -243,6 +244,13 @@ def batch_norm(
     inv = lax.rsqrt(var + eps)
     y = (x - mean.reshape(shape)) * (inv * weight).reshape(shape) + bias.reshape(shape)
     return y.astype(x.dtype), new_rm, new_rv
+
+
+def rms_norm(x: jax.Array, weight: Optional[jax.Array] = None,
+             eps: float = 1e-5) -> jax.Array:
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    return y if weight is None else y * weight
 
 
 def layer_norm(

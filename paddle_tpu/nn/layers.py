@@ -152,6 +152,20 @@ class LayerNorm(Layer):
         return F.layer_norm(x, self.weight, self.bias, self.epsilon)
 
 
+class RMSNorm(Layer):
+    """``x * rsqrt(mean(x^2) + epsilon) * weight`` over the last axis, the
+    statistics in float32 whatever ``x`` is."""
+
+    def __init__(self, size: int, epsilon: float = 1e-5) -> None:
+        super().__init__()
+        self.epsilon = epsilon
+        self.create_parameter("weight", (size,),
+                              init_value=np.ones((size,), np.float32))
+
+    def forward(self, x: jax.Array) -> jax.Array:
+        return F.rms_norm(x, self.weight, self.epsilon)
+
+
 class Embedding(Layer):
     def __init__(
         self,

@@ -12,24 +12,40 @@ tokens are combined into dense ``[experts, capacity, d]`` buffers
 (dropping overflow, like the reference's capacity in gshard_gate) and
 exchanged with a single tiled ``all_to_all`` over the ``ep`` axis. Each
 rank hosts ``num_experts / ep_size`` experts.
+
+Beside it, the **dropless** formulation (OLMoE, ``models/olmoe.py``):
+``dropless_moe`` routes every token to its top-k experts for any k, sorts
+the T*k assignments by expert, runs the expert banks as grouped matmuls
+over the run-time group sizes and brings the rows back — no capacity, no
+``[T, E, C]`` tensor, nothing dropped. Its stages are separate functions
+(``topk_route`` -> ``sort_by_expert`` -> ``dispatch_rows`` ->
+``expert_ffn`` -> ``combine_rows``): an expert-parallel exchange belongs
+between ``dispatch_rows`` and ``expert_ffn``.
 """
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Callable, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+# from the module by its path: the package's own ``gmm`` attribute is its
+# custom-VJP wrapper, not the module
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as _gmm_kernel
+from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm as _tgmm_kernel
 
 from .. import nn
 from ..core.enforce import enforce, enforce_eq
 from ..nn.layer import Layer
 from ..ops import collectives as coll
 
-__all__ = ["top1_gate", "top2_gate", "MoELayer", "ExpertFFN"]
+__all__ = ["top1_gate", "top2_gate", "MoELayer", "ExpertFFN",
+           "topk_route", "sort_by_expert", "dispatch_rows", "combine_rows",
+           "grouped_matmul", "expert_ffn", "dropless_moe"]
 
 
 def _one_hot(x, n):
@@ -163,8 +179,8 @@ class MoELayer(Layer):
         self.experts = ExpertFFN(self.num_local, d_model, d_hidden)
         # aux (load-balance) loss travels through the buffers path so
         # functional_call captures it under jit (a plain attribute would
-        # leak a tracer); read new_state["buffers"]["aux_loss"] in the
-        # train step and add it to the loss
+        # leak a tracer); ``executor.make_train_step`` adds every buffer
+        # named ``aux_loss`` to the loss it differentiates
         self.register_buffer("aux_loss", jnp.zeros(()))
 
     def _capacity(self, tokens: int) -> int:
@@ -190,3 +206,198 @@ class MoELayer(Layer):
             expert_out = coll.all_to_all(expert_out, self.mesh_axis, split_axis_=1, concat_axis=0)
         # combine back: [T, D]
         return jnp.einsum("tec,ecd->td", combine, expert_out)
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k routing: sort by expert, grouped matmul, gather back.
+# ---------------------------------------------------------------------------
+
+
+def topk_route(logits: jax.Array, k: int) -> Dict[str, jax.Array]:
+    """Softmax router with the k largest probabilities a token, for any k,
+    NOT renormalised (OLMoE's ``norm_topk_prob`` false). ``logits`` [T, E]
+    float32. Returns ``index`` [T, k] int32 (ties: the lower expert),
+    ``weight`` [T, k] (those probabilities as they are), ``counts`` [E]
+    int32 (assignments an expert), and the two router losses:
+    ``lb = E * sum_e f_e * P_e`` with ``f_e`` = assignments to e over T (so
+    ``sum_e f_e = k``) and ``P_e`` the mean probability of e, and
+    ``z = mean_t logsumexp(logits_t)^2``.
+
+    The choice is not differentiated; ``weight`` is read out of the
+    probabilities through the one-hot of the choice, so its backward is a
+    product, not a scatter."""
+    T, E = logits.shape
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    probs = jnp.exp(logits - lse[:, None])
+    _, index = lax.top_k(lax.stop_gradient(probs), k)
+    hot = _one_hot(index, E)                                  # [T, k, E]
+    weight = jnp.einsum("tke,te->tk", hot, probs)
+    per_expert = jnp.sum(hot, axis=(0, 1))                    # exact < 2^24
+    lb = E * jnp.sum(per_expert / T * jnp.mean(probs, axis=0))
+    return {"index": index, "weight": weight,
+            "counts": per_expert.astype(jnp.int32), "lb": lb,
+            "z": jnp.mean(jnp.square(lse))}
+
+
+def sort_by_expert(index: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """One stable sort of the T*k expert ids. Assignment ``a = t*k + j`` is
+    token t's j-th choice. Returns ``order`` [T*k] (sorted position ->
+    assignment: rows of one expert contiguous, in token order) and
+    ``inverse`` (assignment -> sorted position), itself a sort: a scatter
+    of T*k scalars goes row by row on the TPU."""
+    flat = index.reshape(-1)
+    slots = jnp.arange(flat.shape[0], dtype=jnp.int32)
+    _, order = lax.sort((flat, slots), num_keys=1, is_stable=True)
+    _, inverse = lax.sort((order, slots), num_keys=1)
+    return order, inverse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def dispatch_rows(x: jax.Array, order: jax.Array, inverse: jax.Array,
+                  k: int) -> jax.Array:
+    """Token rows ``x`` [T, d] gathered into expert order: row i of the
+    result is the token of assignment ``order[i]``. Backward: the inverse
+    gather and a sum over a token's k rows — never a scatter-add."""
+    return jnp.take(x, order // k, axis=0)
+
+
+def _dispatch_fwd(x, order, inverse, k):
+    return dispatch_rows(x, order, inverse, k), inverse
+
+
+def _dispatch_bwd(k, inverse, g):
+    per_choice = jnp.take(g, inverse, axis=0)
+    return (per_choice.reshape(-1, k, g.shape[-1]).sum(axis=1), None, None)
+
+
+dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _unsort_rows(y: jax.Array, order: jax.Array,
+                 inverse: jax.Array) -> jax.Array:
+    return jnp.take(y, inverse, axis=0)
+
+
+_unsort_rows.defvjp(
+    lambda y, order, inverse: (_unsort_rows(y, order, inverse), order),
+    lambda order, g: (jnp.take(g, order, axis=0), None, None))
+
+
+def combine_rows(y: jax.Array, weight: jax.Array, order: jax.Array,
+                 inverse: jax.Array) -> jax.Array:
+    """Expert outputs ``y`` [T*k, d] (expert order) back to tokens: a
+    gather through the inverse permutation (backward: the gather through
+    ``order``), then the sum over a token's k rows weighted by ``weight``
+    [T, k]. Float32 out."""
+    T, k = weight.shape
+    per_choice = _unsort_rows(y, order, inverse).reshape(T, k, y.shape[-1])
+    return jnp.einsum("tk,tkd->td", weight, per_choice)
+
+
+#: (rows, contraction, columns) tile of the grouped-matmul kernel by operand
+#: width in bytes. bf16: the fastest of nine tried on the v5e at
+#: [65536, 2048] x [64, 2048, 1024] (7.2 ms for the three passes; the
+#: kernel's default 128^3: 92 ms; 512 x 1024 x 1024 and larger: over the
+#: kernel's VMEM). float32: half the columns, for the same VMEM.
+#: ``tools/grouped_matmul_bench.py``, PERF.md section 6. A tile is cut to
+#: the dimension where that is smaller.
+_GMM_TILE = {2: (256, 1024, 1024), 4: (256, 512, 512)}
+
+
+def _gmm_call(kernel, lhs, rhs, group_sizes, out_dtype, k, n, **kw):
+    """``kernel`` on a [., k] x [k, n] product, tiles cut to k and n."""
+    tm, tk, tn = _GMM_TILE[lhs.dtype.itemsize]
+    return kernel(lhs, rhs, group_sizes, out_dtype,
+                  (tm, min(tk, k), min(tn, n)),
+                  interpret=jax.default_backend() != "tpu", **kw)
+
+
+@jax.custom_vjp
+def _gmm(x: jax.Array, bank: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    _, k, n = bank.shape
+    return _gmm_call(_gmm_kernel, x, bank, group_sizes, jnp.float32, k, n)
+
+
+def _gmm_bwd(res, g):
+    # both backward products with the cotangent in the operands' dtype:
+    # dx = g @ bank^T row by row's expert, dbank[e] = x_e^T @ g_e
+    x, bank, group_sizes = res
+    E, k, n = bank.shape
+    g = g.astype(x.dtype)
+    dx = _gmm_call(_gmm_kernel, g, bank, group_sizes, x.dtype, n, k,
+                   transpose_rhs=True)
+    dbank = _gmm_call(_tgmm_kernel, x.swapaxes(0, 1), g, group_sizes,
+                      bank.dtype, k, n, num_actual_groups=E)
+    return dx, dbank, None
+
+
+_gmm.defvjp(lambda x, bank, gs: (_gmm(x, bank, gs), (x, bank, gs)), _gmm_bwd)
+
+
+def grouped_matmul(x: jax.Array, bank: jax.Array,
+                   group_sizes: jax.Array) -> jax.Array:
+    """``x`` [M, d] with rows grouped by expert (``group_sizes`` [E], known
+    at run time; rows past their sum are no expert's) times ``bank``
+    [E, d, f]: row i meets its own expert's matrix; float32 out. The
+    Pallas grouped-matmul kernel that ships with jax
+    (``jax.experimental.pallas.ops.tpu.megablox``: ``gmm`` forward and for
+    dx, ``tgmm`` for the bank's gradient), interpreted off TPU — chosen on
+    the chip against ``jax.lax.ragged_dot``: 7.7 ms against 14.8 for the
+    three passes at [65536, 2048] x [64, 2048, 1024], and XLA:TPU renames
+    its own ragged-dot kernels ``ragged-dot-none``, outside every ``pt.*``
+    scope (``tools/grouped_matmul_bench.py``; PERF.md section 6).
+    Under ``amp.auto_cast`` the operands go in the amp dtype with float32
+    accumulation, as ``nn.functional.linear``'s do."""
+    from .. import amp
+
+    if amp.amp_enabled() and bank.dtype == jnp.float32:
+        dt = amp.amp_dtype()
+        x, bank = x.astype(dt), bank.astype(dt)
+    m = x.shape[0]
+    pad = -m % _GMM_TILE[x.dtype.itemsize][0]      # whole row tiles
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+    return _gmm(x, bank, group_sizes)[:m]
+
+
+def expert_ffn(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+               w_down: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """Gated SiLU feed-forward of every row through its own expert:
+    ``down(silu(gate(x)) * up(x))``, three grouped matmuls, no bias."""
+    gate = grouped_matmul(x, w_gate, group_sizes)
+    up = grouped_matmul(x, w_up, group_sizes)
+    return grouped_matmul(jax.nn.silu(gate) * up, w_down, group_sizes)
+
+
+def dropless_moe(x: jax.Array, router_w: jax.Array, w_gate: jax.Array,
+                 w_up: jax.Array, w_down: jax.Array,
+                 k: int) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Top-k-of-E expert layer with every assignment computed. ``x``
+    [T, d]; ``router_w`` [d, E]; banks [E, d, f], [E, d, f], [E, f, d].
+    Returns (out [T, d] float32, the router's ``topk_route`` dict with its
+    float32 ``logits`` [T, E] and ``dropped``: T*k less the assignments
+    that reached an expert, 0 by construction).
+
+    The router runs in float32 at the highest matmul precision whatever
+    ``amp`` says: the choice of experts is a comparison of near-equal
+    numbers."""
+    from .. import amp
+
+    T = x.shape[0]
+    with jax.named_scope("pt.moe.route"):
+        logits = jnp.matmul(x.astype(jnp.float32), router_w,
+                            precision=lax.Precision.HIGHEST)
+        route = topk_route(logits, k)
+        route["logits"] = logits
+        route["dropped"] = T * k - jnp.sum(route["counts"])
+    with jax.named_scope("pt.moe.dispatch"):
+        order, inverse = sort_by_expert(route["index"])
+        if amp.amp_enabled() and x.dtype == jnp.float32:
+            x = x.astype(amp.amp_dtype())      # half the bytes to permute
+        rows = dispatch_rows(x, order, inverse, k)
+    with jax.named_scope("pt.moe.experts"):
+        y = expert_ffn(rows, w_gate, w_up, w_down, route["counts"])
+    with jax.named_scope("pt.moe.combine"):
+        out = combine_rows(y, route["weight"], order, inverse)
+    return out, route

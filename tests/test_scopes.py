@@ -113,6 +113,20 @@ def _ernie_step_text():
     return trainer.compiled_text(ids, ids)
 
 
+def _olmoe_step_text():
+    from paddle_tpu.executor import Trainer
+    from paddle_tpu.models.olmoe import Olmoe, OlmoeConfig
+
+    model = Olmoe(OlmoeConfig(vocab_size=128, hidden_size=64, num_heads=2,
+                              num_layers=2, num_experts=8,
+                              experts_per_token=2, expert_size=32,
+                              max_seq_len=128, attn_impl="flash"))
+    trainer = Trainer(model, optimizer.AdamW(1e-3, weight_decay=0.1),
+                      nn.functional.cross_entropy, amp=True)
+    ids = np.zeros((2, 128), np.int32)
+    return trainer.compiled_text(ids, ids)
+
+
 _PUSH = {"pt.push.accumulate", "pt.push.update"}
 STEPS = {
     "pass_slab": (_pass_step_text, {"pt.unpack", "pt.probe", "pt.pull",
@@ -120,6 +134,12 @@ STEPS = {
     "routed_4dev": (_routed_step_text, {"pt.probe", "pt.pull", "pt.tower",
                                         "pt.dense_opt", "pt.route"} | _PUSH),
     "ernie": (_ernie_step_text, {"pt.embed", "pt.attn", "pt.ffn",
+                                 "pt.head_loss", "pt.loss", "pt.dense_opt",
+                                 "pt.flash_fwd", "pt.flash_bwd_dq",
+                                 "pt.flash_bwd_dkv"}),
+    "olmoe": (_olmoe_step_text, {"pt.embed", "pt.attn", "pt.rope", "pt.ffn",
+                                 "pt.moe.route", "pt.moe.dispatch",
+                                 "pt.moe.experts", "pt.moe.combine",
                                  "pt.head_loss", "pt.loss", "pt.dense_opt",
                                  "pt.flash_fwd", "pt.flash_bwd_dq",
                                  "pt.flash_bwd_dkv"}),

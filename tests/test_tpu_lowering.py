@@ -81,6 +81,66 @@ def test_flash_attention_fwd_bwd_compiles(v5e, dtype):
     assert hlo.count("tpu_custom_call") == 3   # fwd, dq, dk/dv
 
 
+def test_flash_attention_causal_head128_seq4096_compiles(v5e):
+    """OLMoE's attention shape: causal, head dim 128 (no pad to the lane
+    width), 4096 positions, bf16-operand kernels on float32 arrays."""
+    q = _z(2, 4096, 16, 128)
+
+    def fwd_bwd(q, k, v):
+        loss = lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=False).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    hlo = _compile(fwd_bwd, SingleDeviceSharding(v5e[0]), q, q, q).as_text()
+    assert hlo.count("tpu_custom_call") == 3
+
+
+def _olmoe_step(v5e, cfg, batch, seq):
+    from paddle_tpu import nn, optimizer
+    from paddle_tpu.executor import make_train_step
+    from paddle_tpu.models.olmoe import Olmoe
+
+    model = Olmoe(cfg)
+    opt = optimizer.AdamW(learning_rate=4e-4, weight_decay=0.1, beta2=0.95)
+    step = make_train_step(model, opt, nn.functional.cross_entropy, amp=True)
+    state = nn.get_state(model)
+    ids = (_z(batch, seq, dtype=jnp.int32),)
+    s = SingleDeviceSharding(v5e[0])
+    return step.lower(
+        _shapes(state, s),
+        _shapes(jax.eval_shape(opt.init, state["params"]), s), _rng_key(s),
+        _shapes(ids, s), _shapes(ids, s)).compile()
+
+
+def test_olmoe_step_compiles_small(v5e, as_tpu):
+    """The OLMoE train step for the chip at small widths with the
+    published head dim: three flash kernels a layer, and XLA:TPU takes the
+    grouped matmuls (``ragged_dot`` forward and both transposes), the sort
+    by expert and the row gathers as written."""
+    from paddle_tpu.models.olmoe import OlmoeConfig
+
+    compiled = _olmoe_step(v5e, OlmoeConfig(
+        vocab_size=1024, hidden_size=256, num_heads=2, num_layers=2,
+        num_experts=8, experts_per_token=2, expert_size=128,
+        max_seq_len=512), batch=2, seq=512)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3 * 2
+    assert "pt.moe.experts" in text and "pt.rope" in text
+
+
+@pytest.mark.slow
+def test_olmoe_cell_step_compiles(v5e, as_tpu):
+    """The benchmark cell's step at full widths (one layer, 2 x 4096
+    tokens): fits a v5e beside its 7 GiB of parameters and moments."""
+    from paddle_tpu.models.olmoe import OlmoeConfig
+
+    m = _olmoe_step(v5e, OlmoeConfig(num_layers=1), batch=2,
+                    seq=4096).memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert live < 14.5 * 2**30
+
+
 @pytest.mark.parametrize("rule", ["naive", "adagrad", "std_adagrad", "adam"])
 def test_ctr_sparse_rows_compiles(v5e, rule):
     n, dim = 2048, 8
