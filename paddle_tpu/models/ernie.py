@@ -40,7 +40,7 @@ from .. import nn
 from ..core.enforce import enforce, enforce_eq
 from ..nn.layer import Layer
 from ..ops import collectives as coll
-from ..ops.flash_attention import flash_attention
+from ..ops.flash_attention import flash_attention, mxu_rounded
 from ..parallel.mp_layers import _axis_active
 from ..parallel.moe import top1_gate, top2_gate
 from ..parallel.ring_attention import (local_attention, ring_attention,
@@ -179,13 +179,18 @@ class _SelfAttention(Layer):
         lead = x.shape[:-2]            # arbitrary leading dims
         L = x.shape[-2]
         x2 = x.reshape((-1, L, cfg.hidden_size))
-        y = x2 @ self.qkv_w + self.qkv_b            # [B, L, H_local*3*D]
-        H_local = y.shape[-1] // (3 * D)
-        y = y.reshape(y.shape[0], L, H_local, 3, D)
-        q, k, v = y[..., 0, :], y[..., 1, :], y[..., 2, :]
         impl = cfg.attn_impl
         if impl == "auto":
             impl = "flash" if jax.default_backend() == "tpu" else "einsum"
+        y = x2 @ self.qkv_w + self.qkv_b            # [B, L, H_local*3*D]
+        if impl == "flash":
+            # the flash kernels read q, k, v in their MXU dtype: round here,
+            # where QKV is written, so the split and the transposes to the
+            # kernels' layout move the narrow bytes under this scope's name
+            y = mxu_rounded(y)
+        H_local = y.shape[-1] // (3 * D)
+        y = y.reshape(y.shape[0], L, H_local, 3, D)
+        q, k, v = y[..., 0, :], y[..., 1, :], y[..., 2, :]
         if _axis_active(cfg.cp_axis):
             ring = ring_flash_attention if impl == "flash" else ring_attention
             out = ring(q, k, v, axis=cfg.cp_axis, causal=cfg.causal)

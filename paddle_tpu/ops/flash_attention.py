@@ -9,8 +9,24 @@ without renormalizing through HBM.
 
 Layout: [B, L, H, D] (framework-wide attention layout); internally
 reshaped to [B*H, L, D] and padded to MXU tiles (D→128 multiples,
-L→block multiples). ``q_offset``/``k_offset`` shift the causal mask for
-sequence-sharded (cp) blocks; they may be traced values (axis_index).
+L→block multiples). On the chip the minor dimension of a kernel operand
+occupies whole 128-lane tiles in HBM whatever its logical size (an
+``[…, 64]`` operand gets ``T(8,128)`` tiles too), so the pad costs no
+bytes beyond what the layout already does; what halves them is the dtype.
+The kernels multiply in ``mxu`` = bf16 (float32 under
+``precision="highest"``), so q, k, v and dO are HANDED to them in ``mxu``:
+the convert is done inside the custom VJP — primals, cotangents and every
+result keep the caller's dtype — fuses into the producer that writes the
+padded operand, and the saved residuals are the narrow ones. This is
+exact: the rounding only moves from the kernel's first line to its
+producer's last (``pt.flash.operands`` records the width, one host span a
+trace). A producer that does that rounding itself (``mxu_rounded`` on the
+QKV matmul's output) keeps the moved bytes under its own scope's name.
+Row statistics travel lane-padded, ``f32[B*H, L, 128]``: the
+forward writes lse broadcast over the lanes, the backward reads ONE such
+array with lse in lane 0 and Δ in lane 1. ``q_offset``/``k_offset`` shift
+the causal mask for sequence-sharded (cp) blocks; they may be traced
+values (axis_index).
 
 Backward: standard flash backward — recompute P = exp(S - lse) blockwise;
 dV = P^T dO, dS = P ∘ (dO V^T - Δ), dQ = dS K, dK = dS^T Q with
@@ -29,7 +45,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "flash_attention_with_lse"]
+from ..core.profiler import RecordEvent
+
+__all__ = ["flash_attention", "flash_attention_with_lse", "mxu_rounded"]
 
 NEG = -1e30
 
@@ -108,7 +126,8 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
 
 
-def _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, mxu):
+def _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, mxu,
+         dtype):
     BH, Lq, D = q.shape
     Lk = k.shape[1]
     nq, nk = Lq // bq, Lk // bk
@@ -143,7 +162,7 @@ def _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, mxu):
                 ],
             ),
             out_shape=[
-                _out_struct((BH, Lq, D), q.dtype, q, k, v, offs),
+                _out_struct((BH, Lq, D), dtype, q, k, v, offs),
                 _out_struct((BH, Lq, 128), jnp.float32, q, k, v, offs),
             ],
             compiler_params=pltpu.CompilerParams(
@@ -159,7 +178,7 @@ def _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, mxu):
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
                    dq_ref, dq_acc, *, scale, causal, bq, bk, mxu):
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -183,13 +202,14 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if causal:
             rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             mask = mask & (cols <= rows)
-        lse = lse_ref[0][:, :1]
+        stats = stats_ref[0]
+        lse, delta = stats[:, :1], stats[:, 1:2]
         p = jnp.where(mask & (lse > NEG / 2), jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(do_ref[0].astype(mxu),
                                  v_ref[0].astype(mxu),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1])
+        ds = p * (dp - delta)
         dq_acc[:] += jax.lax.dot_general(ds.astype(mxu), k,
                                          (((1,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32) * scale
@@ -204,7 +224,7 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, bq, bk, mxu):
     i = pl.program_id(2)           # q-block index (inner loop)
     nq = pl.num_programs(2)
@@ -229,7 +249,8 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if causal:
             rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             mask = mask & (cols <= rows)
-        lse = lse_ref[0][:, :1]
+        stats = stats_ref[0]
+        lse, delta = stats[:, :1], stats[:, 1:2]
         p = jnp.where(mask & (lse > NEG / 2), jnp.exp(s - lse), 0.0)
         do = do_ref[0].astype(mxu)
         dv_acc[:] += jax.lax.dot_general(p.astype(mxu), do,
@@ -238,7 +259,7 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dp = jax.lax.dot_general(do, v_ref[0].astype(mxu),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1])
+        ds = p * (dp - delta)
         dk_acc[:] += jax.lax.dot_general(ds.astype(mxu), q,
                                          (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32) * scale
@@ -255,27 +276,33 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd(scale, causal, bq, bk, interpret, mxu, res, grads):
-    q, k, v, out, lse, offs = res
+    q, k, v, out, lse, offs = res          # q, k, v as the kernels read them
     do, dlse = grads
     BH, Lq, D = q.shape
     Lk = k.shape[1]
     nq, nk = Lq // bq, Lk // bk
+    dtype = out.dtype                      # the caller's: dq, dk, dv leave in it
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)                                  # [BH, Lq]
     if dlse is not None:
         # d(lse)/dS = P, so an lse cotangent enters dS = P∘(dP - Δ + dlse)
         # — fold it into Δ rather than touching the kernels
         delta = delta - dlse.astype(jnp.float32)
-    lse_pad = jnp.broadcast_to(lse[..., None], (BH, Lq, 128))
-    delta_pad = jnp.broadcast_to(delta[..., None], (BH, Lq, 128))
+    do = do.astype(mxu)                    # after Δ, which reads the f32
+    # Δ stays a [BH, Lq] array of its own: left to fuse with what follows,
+    # XLA:TPU turns the row sum and its broadcast into a reduce-window
+    # 255 lanes wide over the whole [BH, Lq, 128] (PERF.md §6, PR 27)
+    delta = jax.lax.optimization_barrier(delta)
+    # the row statistics as ONE lane-padded array: lse in lane 0, Δ in lane 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, (BH, Lq, 128), 2)
+    stats = jnp.where(lane == 0, lse[..., None], delta[..., None])
 
     common_in = [
         pl.BlockSpec((1, bq, D), lambda b, i, j, offs: (b, i, 0)),      # q
         pl.BlockSpec((1, bk, D), lambda b, i, j, offs: (b, j, 0)),      # k
         pl.BlockSpec((1, bk, D), lambda b, i, j, offs: (b, j, 0)),      # v
         pl.BlockSpec((1, bq, D), lambda b, i, j, offs: (b, i, 0)),      # do
-        pl.BlockSpec((1, bq, 128), lambda b, i, j, offs: (b, i, 0)),    # lse
-        pl.BlockSpec((1, bq, 128), lambda b, i, j, offs: (b, i, 0)),    # delta
+        pl.BlockSpec((1, bq, 128), lambda b, i, j, offs: (b, i, 0)),    # stats
     ]
     with jax.named_scope("pt.flash_bwd_dq"):
         dq = pl.pallas_call(
@@ -288,12 +315,12 @@ def _bwd(scale, causal, bq, bk, interpret, mxu, res, grads):
                 out_specs=[pl.BlockSpec((1, bq, D), lambda b, i, j, offs: (b, i, 0))],
                 scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
             ),
-            out_shape=[_out_struct((BH, Lq, D), q.dtype, q, k, v, do, offs)],
+            out_shape=[_out_struct((BH, Lq, D), dtype, q, k, v, do, offs)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             name="flash_bwd_dq",
             interpret=interpret,
-        )(offs, q, k, v, do, lse_pad, delta_pad)[0]
+        )(offs, q, k, v, do, stats)[0]
 
     # swap block index roles: outer dim walks k blocks, inner walks q
     dkv_in = [
@@ -301,8 +328,7 @@ def _bwd(scale, causal, bq, bk, interpret, mxu, res, grads):
         pl.BlockSpec((1, bk, D), lambda b, j, i, offs: (b, j, 0)),      # k
         pl.BlockSpec((1, bk, D), lambda b, j, i, offs: (b, j, 0)),      # v
         pl.BlockSpec((1, bq, D), lambda b, j, i, offs: (b, i, 0)),      # do
-        pl.BlockSpec((1, bq, 128), lambda b, j, i, offs: (b, i, 0)),    # lse
-        pl.BlockSpec((1, bq, 128), lambda b, j, i, offs: (b, i, 0)),    # delta
+        pl.BlockSpec((1, bq, 128), lambda b, j, i, offs: (b, i, 0)),    # stats
     ]
     with jax.named_scope("pt.flash_bwd_dkv"):
         dk, dv = pl.pallas_call(
@@ -319,13 +345,13 @@ def _bwd(scale, causal, bq, bk, interpret, mxu, res, grads):
                 scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                                 pltpu.VMEM((bk, D), jnp.float32)],
             ),
-            out_shape=[_out_struct((BH, Lk, D), k.dtype, q, k, v, do, offs),
-                       _out_struct((BH, Lk, D), v.dtype, q, k, v, do, offs)],
+            out_shape=[_out_struct((BH, Lk, D), dtype, q, k, v, do, offs),
+                       _out_struct((BH, Lk, D), dtype, q, k, v, do, offs)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             name="flash_bwd_dkv",
             interpret=interpret,
-        )(offs, q, k, v, do, lse_pad, delta_pad)
+        )(offs, q, k, v, do, stats)
     return dq, dk, dv
 
 
@@ -341,14 +367,50 @@ def _flash(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, precis
     return out
 
 
+def _mxu_dtype(precision):
+    return jnp.float32 if precision == "highest" else jnp.bfloat16
+
+
+@jax.custom_vjp
+def mxu_rounded(x: jax.Array) -> jax.Array:
+    """``x`` rounded to the dtype the kernels multiply in at the default
+    precision, still in its own dtype; the cotangent passes through whole —
+    what the kernels' custom VJP does to q, k and v anyway, so a call
+    changes no value and no gradient. It is for the PRODUCER of q, k, v
+    (``models/ernie.py``: the QKV matmul's output, under ``pt.attn``): the
+    narrowing is then an op of the producer's scope that XLA fuses into
+    the matmul, the kernels' operand convert cancels against the widening,
+    and every layout op in between moves the narrow bytes under the
+    producer's name. Left to itself XLA:TPU hoists the kernels' convert to
+    the same place, but as an instruction of its own making, and the
+    layout copy made from that carries no ``op_name`` (PERF.md §6, PR 27)."""
+    # the round trip IS the op: the value is rounded where it is written,
+    # and the widening cancels against the kernels' own narrowing
+    return x.astype(_mxu_dtype("default")).astype(x.dtype)  # graftlint: ignore[cast-roundtrip]
+
+
+mxu_rounded.defvjp(lambda x: (mxu_rounded(x), None), lambda _, g: (g,))
+
+
 def _flash_fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, precision):
-    mxu = jnp.float32 if precision == "highest" else jnp.bfloat16
+    mxu = _mxu_dtype(precision)
     offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(k_offset, jnp.int32),
                       jnp.asarray(q.shape[1], jnp.int32),
                       jnp.asarray(k.shape[1], jnp.int32)])
-    out, lse = _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk,
-                    interpret, mxu)
+    # The kernels multiply in `mxu`, so hand them `mxu`: the convert fuses
+    # into the producer that writes each padded operand, and the residuals
+    # are these. Inside the custom_vjp, so primals and cotangents keep the
+    # caller's dtype (`out.dtype` carries it to the backward).
+    dtype = q.dtype
+    q, k, v = q.astype(mxu), k.astype(mxu), v.astype(mxu)
+    # what the kernels are handed, read off the arrays themselves: one host
+    # span a trace (``profiler.host_spans()``), none on the step path.
+    # `scale` is 1/sqrt(D), all that is left here of the unpadded D
+    with RecordEvent("pt.flash.operands", bits=8 * q.dtype.itemsize,
+                     head_dim=round(scale ** -2), lanes=q.shape[-1]):
+        out, lse = _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk,
+                        interpret, mxu, dtype)
     return (out, lse), (q, k, v, out, lse, offs)
 
 
@@ -361,7 +423,7 @@ def _flash_fwd_rule(q, k, v, scale, causal, q_offset, k_offset, bq, bk,
 
 def _flash_bwd_rule(scale, causal, bq, bk, interpret, precision, saved, g):
     res, (q_offset, k_offset) = saved
-    mxu = jnp.float32 if precision == "highest" else jnp.bfloat16
+    mxu = _mxu_dtype(precision)
     dq, dk, dv = _bwd(scale, causal, bq, bk, interpret, mxu, res, (g, None))
     return dq, dk, dv, None, None
 
@@ -386,7 +448,7 @@ def _flash_pair_fwd_rule(q, k, v, scale, causal, q_offset, k_offset, bq, bk,
 
 def _flash_pair_bwd_rule(scale, causal, bq, bk, interpret, precision, res, g):
     do, dlse = g
-    mxu = jnp.float32 if precision == "highest" else jnp.bfloat16
+    mxu = _mxu_dtype(precision)
     dq, dk, dv = _bwd(scale, causal, bq, bk, interpret, mxu, res, (do, dlse))
     return dq, dk, dv, None, None
 
@@ -438,8 +500,14 @@ def _run_padded(q, k, v, causal, q_offset, k_offset, block_q, block_k,
     Lq_p, Lk_p = _round_up(Lq, bq), _round_up(Lk, bk)
     D_p = _round_up(D, 128)
 
+    # one dtype serves the three operands and every result: the widest, so
+    # that nothing is rounded which the kernels would have read whole
+    # (float32 k under ``precision="highest"`` beside a bf16 q); each
+    # cotangent returns to its operand's own dtype through this convert
+    wide = jnp.result_type(q, k, v)
+
     def to_bh(x, L, L_p):
-        x = jnp.moveaxis(x, 2, 1).reshape(B * H, L, D)
+        x = jnp.moveaxis(x, 2, 1).reshape(B * H, L, D).astype(wide)
         return jnp.pad(x, ((0, 0), (0, L_p - L), (0, D_p - D)))
 
     qp, kp, vp = to_bh(q, Lq, Lq_p), to_bh(k, Lk, Lk_p), to_bh(v, Lk, Lk_p)
@@ -451,7 +519,7 @@ def _run_padded(q, k, v, causal, q_offset, k_offset, block_q, block_k,
         out = _flash(qp, kp, vp, scale, causal, q_offset, k_offset, bq, bk,
                      interpret, precision)
         lse = None
-    out = out[:, :Lq, :D].reshape(B, H, Lq, D)
+    out = out[:, :Lq, :D].reshape(B, H, Lq, D).astype(q.dtype)
     out = jnp.moveaxis(out, 1, 2)
     if lse is not None:
         lse = lse[:, :Lq].reshape(B, H, Lq)

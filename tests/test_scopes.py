@@ -150,6 +150,8 @@ _NO_WORK = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
             "copy", "broadcast", "iota"}
 _OPCODE_RE = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*.*?\s"
                         r"([a-z][\w\-]*)\(")
+_NAME_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+_CONVERT_OF = re.compile(r"\sconvert\(%?([\w.\-]+)\)")
 _COLLECTIVE_OP = re.compile(r"/(all_to_all|psum|all_gather|axis_index|"
                             r"psum_scatter|pmean)$")
 
@@ -162,16 +164,35 @@ def test_step_names_its_work(name):
     seen = {s for s in scopes.scope_of_ops(text).values() if s}
     assert want <= seen, sorted(want - seen)
     assert seen <= set(DEVICE_SCOPES), sorted(seen - set(DEVICE_SCOPES))
+    # A convert XLA made itself (no metadata) is counted — it is work — in
+    # the scope of what it converts. XLA:CPU has no bf16 storage ops: the
+    # Pallas interpreter slices every block of a bf16 operand out of the
+    # loop's carry and writes it back each grid step, and XLA:CPU widens
+    # and narrows round each of those slices. No other convert comes
+    # without metadata: where no kernel operand is bf16 the rule changes
+    # nothing (ERNIE step before PR 27: 3179 / 3344 with it and without).
+    op_name, converts = {}, {}
+    for line in text.splitlines():
+        m = _NAME_RE.match(line)
+        if m:
+            op = re.search(r'op_name="([^"]*)"', line)
+            op_name[m.group(1)] = op.group(1) if op else None
+            src = _CONVERT_OF.search(line)
+            if src and not op:
+                converts[m.group(1)] = src.group(1)
     scoped = total = 0
     for line in text.splitlines():
         m = _OPCODE_RE.match(line)
         if not m or m.group(1) in _NO_WORK:
             continue
-        op = re.search(r'op_name="([^"]*)"', line)
-        if op and _COLLECTIVE_OP.search(op.group(1)):
+        name = _NAME_RE.match(line).group(1)
+        while op_name.get(name) is None and name in converts:
+            name = converts[name]
+        op = op_name.get(name)
+        if op and _COLLECTIVE_OP.search(op):
             continue
         total += 1
-        scoped += bool(op and scopes.scope_of(op.group(1)))
+        scoped += bool(op and scopes.scope_of(op))
     assert total > 100 and scoped / total >= 0.90, (scoped, total)
 
 

@@ -95,6 +95,73 @@ def test_flash_attention_causal_head128_seq4096_compiles(v5e):
     assert hlo.count("tpu_custom_call") == 3
 
 
+@pytest.mark.parametrize("shape,causal", [((32, 512, 12, 64), False),
+                                          ((2, 4096, 16, 128), True)])
+def test_flash_kernels_are_handed_bf16_and_one_statistics_array(
+        v5e, shape, causal):
+    """The two dense cells' attention shapes, float32 in and out: as the
+    chip compiles them, every ``flash_*`` custom call takes q, k, v (and
+    do) as ``bf16[BH, L, 128]`` — the convert rides in the fusion that
+    writes the operand — and each backward call exactly one float32
+    ``[BH, L, 128]`` array, lse and delta in its lanes 0 and 1."""
+    import re
+
+    q = _z(*shape)
+
+    def fwd_bwd(q, k, v):
+        # a cotangent that is not a constant, as a model's is
+        loss = lambda q, k, v: (flash_attention(
+            q, k, v, causal=causal, interpret=False) ** 2).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    hlo = _compile(fwd_bwd, SingleDeviceSharding(v5e[0]), q, q, q).as_text()
+    B, L, H, _ = shape
+    wide = f"[{B * H},{L},128]"
+    calls = dict(re.findall(
+        r"%(flash_[a-z_]+)[.\d]* = .*?operand_layout_constraints=\{(.*?)\}, \w+=",
+        hlo))
+    assert set(calls) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    for name, operands in calls.items():
+        got = re.findall(r"(\w+\[[\d,]*\])", operands)
+        n = 3 if name == "flash_fwd" else 4
+        stats = [] if name == "flash_fwd" else ["f32" + wide]
+        assert got == ["s32[4]"] + ["bf16" + wide] * n + stats, (name, got)
+
+
+def test_ernie_layer_moves_its_bf16_under_a_name(v5e, as_tpu):
+    """One ERNIE layer of the benchmark cell's widths, as the chip compiles
+    its train step: every copy, convert and fusion of the entry computation
+    that writes bf16 carries a ``pt.*`` scope. The narrowing of QKV is the
+    model's own op (``mxu_rounded`` under ``pt.attn``); a convert XLA hoists
+    there by itself has no metadata, and the layout copy made from it then
+    reads as unscoped device time (PERF.md §6, PR 27)."""
+    import re
+
+    from paddle_tpu import nn, optimizer
+    from paddle_tpu.executor import make_train_step
+    from paddle_tpu.models.ernie import Ernie, ErnieConfig
+
+    model = Ernie(ErnieConfig(vocab_size=1024, hidden_size=768, num_heads=12,
+                              ffn_size=3072, num_layers=1, max_seq_len=512))
+    opt = optimizer.Adam(learning_rate=1e-4)
+    step = make_train_step(model, opt, nn.functional.cross_entropy, amp=True)
+    state = nn.get_state(model)
+    ids = (_z(32, 512, dtype=jnp.int32),)
+    s = SingleDeviceSharding(v5e[0])
+    text = step.lower(
+        _shapes(state, s), _shapes(opt.init(state["params"]), s), _rng_key(s),
+        _shapes(ids, s), _shapes(ids, s)).compile().as_text()
+    entry = text[text.index("\nENTRY"):]
+    wrote = re.findall(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (bf16\[[\d,]+\])\S* "
+        r"(copy|convert|fusion)\((.*)$", entry, re.M)
+    assert any(shape == "bf16[32,512,2304]" and op == "copy"
+               for _, shape, op, _ in wrote)      # the QKV layout copy
+    unnamed = [(name, shape) for name, shape, _, rest in wrote
+               if not re.search(r'op_name="[^"]*pt\.[a-z_]+', rest)]
+    assert not unnamed, unnamed
+
+
 def _olmoe_step(v5e, cfg, batch, seq):
     from paddle_tpu import nn, optimizer
     from paddle_tpu.executor import make_train_step
