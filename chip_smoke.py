@@ -346,8 +346,7 @@ def leg_stream(sz: Sizes, log: CompileLog) -> Dict:
             s.stop()
     return {"loss": [round(cold["loss"], 5), round(warm["loss"], 5)],
             "steps": int(warm["steps"]), "resident_rows": int(len(keys)),
-            "warm_rpcs": rpcs, "kernels": st["kernels"],
-            **_push_facts(t_leg)}
+            "warm_rpcs": rpcs, **_push_facts(t_leg)}
 
 
 # ---------------------------------------------------------------------------

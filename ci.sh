@@ -231,13 +231,10 @@ fi
 
 if [[ "${1:-fast}" == "hot_tier" ]]; then
   echo "== hot_tier gate: HBM tier ≡ RPC-only parity + 0-RPC warm steps =="
-  # test_hot_kernels.py is the Pallas(interpret) ≡ jnp kernel parity
-  # matrix (probe+gather / scatter+apply, all rules, unaligned n);
   # test_hot_tier.py carries the tier-level matrix (eviction churn,
-  # adam, checkpoint/restore, banked sharded mesh) incl. the pallas
-  # variants — both run before the bench so a rule/kernels regression
-  # fails in seconds
-  python -m pytest tests/test_hot_tier.py tests/test_hot_kernels.py -q -m ""
+  # every rule, checkpoint/restore, banked sharded mesh) — run before
+  # the bench so a rule regression fails in seconds
+  python -m pytest tests/test_hot_tier.py -q -m ""
   echo "== sparse_hot bench (single-chip + multi-host rung) =="
   PYTHONPATH="$PWD:${PYTHONPATH:-}" SHB_SAMPLES=2048 \
     python tools/sparse_hot_bench.py | python -c "
@@ -639,8 +636,7 @@ fi
 echo "== hot-tier fast checks (parity / eviction churn / 0-RPC warm) =="
 # the hot tier's bit-parity contract is the cheapest place to catch a
 # sparse-rule or flush-back regression — fail it before the full matrix
-# (test_hot_kernels.py = the fused Pallas-kernel half of the contract)
-python -m pytest tests/test_hot_tier.py tests/test_hot_kernels.py -q
+python -m pytest tests/test_hot_tier.py -q
 
 echo "== comm-fusion fast checks (fused dense-DP collectives + hlo_bytes) =="
 # fail the fused-bucket/quantized-collective layer in seconds, before the
@@ -659,7 +655,7 @@ echo "== fast gate (default: -m 'not slow') =="
 # pay them twice
 python -m pytest tests/ -q -x \
   --ignore=tests/test_comm_fusion.py --ignore=tests/test_hlo_bytes.py \
-  --ignore=tests/test_hot_tier.py --ignore=tests/test_hot_kernels.py \
+  --ignore=tests/test_hot_tier.py \
   --ignore=tests/test_sparse_wire.py --ignore=tests/test_placement.py
 
 if [[ "${1:-fast}" == "full" ]]; then

@@ -142,13 +142,6 @@ def set_flags(kv: Dict[str, Any]) -> None:
 # Core flags (subsystem-specific flags are defined by their owning modules).
 # ---------------------------------------------------------------------------
 define_flag("check_nan_inf", False, "Scan op outputs for NaN/Inf after each step.")
-define_flag("benchmark", False, "Block-on-ready after each step for timing.")
-define_flag(
-    "tpu_allocator_strategy",
-    "auto_growth",
-    "Informational: XLA owns device memory; kept for API parity.",
-)
-define_flag("eager_delete_tensor_gb", 0.0, "Kept for API parity (XLA GC owns memory).")
 # (the RNG seed flag is defined by paddle_tpu.nn.layer, which owns the
 # ambient RNG stream, so its on_change callback can reseed it directly)
 # Cross-cutting chaos switch: read by BOTH the transport faultpoint sites

@@ -261,12 +261,6 @@ def _push_stats(labels, weights, n_cols, real=None):
     return shows, clicks
 
 
-def _masked_pull(cache_state, flat_rows):
-    """Kept as the family-internal name; ``cache_pull`` itself is
-    sentinel-safe now (rows ≥ capacity pull zeros)."""
-    return cache_pull(cache_state, flat_rows)
-
-
 def _ctr_step_body(model, optimizer, cache_cfg, params, opt_state,
                    cache_state, flat_rows, B, S, dense_x, labels,
                    weights=None, loss_builder=None, with_real=False):
@@ -280,7 +274,7 @@ def _ctr_step_body(model, optimizer, cache_cfg, params, opt_state,
     # consume it; push stats mask padding positions with it).
     dense_x = dense_x.astype(jnp.float32)
     labels = labels.astype(jnp.int32)
-    emb = _masked_pull(cache_state, flat_rows).reshape(B, S, -1)
+    emb = cache_pull(cache_state, flat_rows).reshape(B, S, -1)
     builder = loss_builder or _make_loss_fn
     real = None
     if with_real:
@@ -343,7 +337,7 @@ def make_ctr_pooled_train_step(
         B, T = rows.shape
         C = cache_state["embed_w"].shape[0]
         flat = rows.reshape(-1)
-        emb_pos = _masked_pull(cache_state, flat).reshape(B, T, -1)
+        emb_pos = cache_pull(cache_state, flat).reshape(B, T, -1)
         # sum-pool columns into slots: [B, T, 1+dim] → [B, S, 1+dim]
         pooled = jax.ops.segment_sum(
             jnp.swapaxes(emb_pos, 0, 1), seg, num_segments=S)

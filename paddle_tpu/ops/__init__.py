@@ -1,16 +1,4 @@
 from . import collectives
 from .device_graph import DeviceGraph
 
-
-def __getattr__(name):
-    # PEP-562 lazy: hot_kernels pulls in pallas + ps.device_hash — keep
-    # it off the bare `paddle_tpu.ops` import path (the obs/__init__
-    # exporter precedent)
-    if name == "hot_kernels":
-        import importlib
-
-        return importlib.import_module(".hot_kernels", __name__)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
-
-
-__all__ = ["collectives", "DeviceGraph", "hot_kernels"]
+__all__ = ["collectives", "DeviceGraph"]

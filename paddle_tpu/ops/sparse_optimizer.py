@@ -1,36 +1,31 @@
-"""Pallas fused sparse-optimizer kernel (per-row CTR update, all rules).
+"""The per-row CTR update (all rules): the ONE definition of the rule.
 
 The reference applies its sparse optimizer on-device inside the
 hashtable update kernels (`/root/reference/paddle/fluid/framework/fleet/
 heter_ps/optimizer.cuh.h:27-100` — update_lr/update_mf/update_value with
 show/click coeffs, bounds, lazy mf creation), one GPU thread per row;
 the CPU server supports the full rule family (sparse_sgd_rule.h:27-135:
-naive / AdaGrad shared-g2sum / StdAdaGrad per-dim / Adam). The TPU
-decomposition: random-access gather/scatter stays on XLA (the hardware's
-bulk path — per-row DMA loops in Pallas serialize), and the PER-ROW
-OPTIMIZER MATH between gather and scatter is one fused Pallas kernel:
-every state column of a block of touched rows updates in a single VMEM
-pass. All four reference rules are supported for both the embed (1-d)
-and embedx (dim-d) blocks; the rule math lives in ``rule_update`` which
-is shared verbatim by the kernel body and the jnp form
-(``ps.embedding_cache.cache_push_sparse`` runs the jnp form unless
-``CacheConfig.pallas_update=True`` asks for the kernel: on the v5e the
-kernel measured slower, PR 25, its ``[n, 1]`` operands pad to 128 lanes;
-bit-parity is tested in tests/test_sparse_optimizer.py).
+naive / AdaGrad shared-g2sum / StdAdaGrad per-dim / Adam). Here the
+random-access gather/scatter and the per-row math between them are all
+XLA: ``fused_row_update`` is plain jnp on gathered (or whole-table)
+columns, and both push formulations of ``ps.embedding_cache`` call it.
+All four reference rules are supported for both the embed (1-d) and
+embedx (dim-d) blocks; the host oracle is ``ps/sgd_rule.py`` through
+``MemorySparseTable`` (tests/test_sparse_optimizer.py).
+
+There is no kernel. A Pallas launcher of this rule compiled for the v5e
+and lost: 20.7 against 19.5 ms a push at 2^26 rows x 106,496 slots
+(PR 25, PERF.md section 6) — its ``[n, 1]`` operands pad to 128 lanes,
+and XLA already fuses the rule into the gathers' consumers.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from ..core.enforce import enforce
-
-__all__ = ["ctr_sparse_rows", "rule_update", "rule_state_dim",
+__all__ = ["fused_row_update", "rule_update", "rule_state_dim",
            "rule_init_state"]
 
 
@@ -45,8 +40,6 @@ def rule_init_state(rule: str, n: int, dim: int, *, beta1: float,
     """Fresh-feature optimizer state (zeros; Adam's beta powers start at
     beta1/beta2 — sparse_sgd_rule.cc InitValueWork)."""
     if rule == "adam":
-        # built by concatenation, not .at[].set: Mosaic has no scatter
-        # lowering and this runs inside the Pallas rule kernel
         return jnp.concatenate(
             [jnp.zeros((n, 2 * dim), jnp.float32),
              jnp.full((n, 1), beta1, jnp.float32),
@@ -135,27 +128,24 @@ def fused_row_update(show, click, ew, estate, xw, xstate, has,
                      click_coeff, embedx_threshold, create_applies_grad):
     """The complete per-row CTR update on plain arrays (touched rows,
     pre-merged): show/click accumulation, embed rule step, lazy embedx
-    creation, embedx rule step. ONE definition shared by the Pallas
-    kernel body and the jnp fallback — divergence between the two paths
-    is structurally impossible. Returns the seven updated columns.
-
-    State arrays may carry one extra dummy column when the rule is
-    stateless (the kernel's block specs need width >= 1); the rule
-    ignores it and it round-trips unchanged."""
+    creation, embedx rule step. ONE definition shared by the touched
+    -rows push and the sweep (``ps.embedding_cache``) — divergence
+    between the two formulations is structurally impossible. Returns the
+    seven updated columns; a stateless rule's state column is zero wide
+    and round-trips unchanged."""
     upd = functools.partial(rule_update, lr=lr, initial_g2sum=initial_g2sum,
                             wmin=wmin, wmax=wmax, beta1=beta1, beta2=beta2,
                             eps=eps)
-    # Mosaic lowers [n] -> [n,1] reshapes only for 32-bit types, so bool
-    # masks broadcast to columns via f32 + compare, never via i1 reshape
+    # [n] bool -> [n, 1] through f32 + compare: the form the cells'
+    # compiled steps hold (PR 28 pinned them byte for byte; a plain
+    # ``m[:, None]`` compiles to a different program)
     col = lambda m: m.astype(jnp.float32)[:, None] > 0.5
 
     show_new = show + dshow
     click_new = click + dclick
     scale = jnp.maximum(dshow, 1e-10)[:, None]
 
-    es = rule_state_dim(embed_rule, 1)
-    xs = rule_state_dim(embedx_rule, dim)
-    ew_new, es_new = upd(embed_rule, ew, estate[:, :max(es, 1)], ge, scale)
+    ew_new, es_new = upd(embed_rule, ew, estate, ge, scale)
 
     # lazy embedx creation on the show/click score: created rows start
     # from INIT state; create_applies_grad selects CPU (create + apply,
@@ -169,104 +159,12 @@ def fused_row_update(show, click, ew, estate, xw, xstate, has,
     create = jnp.logical_and(jnp.logical_not(had),
                              score >= embedx_threshold)
     apply_mask = jnp.logical_or(had, create) if create_applies_grad else had
-    n = show.shape[0]
-    if xs > 0:
-        init = rule_init_state(embedx_rule, n, dim, beta1=beta1, beta2=beta2)
-        st_base = jnp.where(col(create), init, xstate)
-    else:
-        st_base = xstate[:, :max(xs, 1)]
+    init = rule_init_state(embedx_rule, show.shape[0], dim, beta1=beta1,
+                           beta2=beta2)
+    st_base = jnp.where(col(create), init, xstate)
     xw_new, xs_new = upd(embedx_rule, xw, st_base, gx, scale)
 
-    return (show_new, click_new, ew_new,
-            es_new if es > 0 else estate,
+    return (show_new, click_new, ew_new, es_new,
             jnp.where(col(apply_mask), xw_new, xw),
-            jnp.where(col(apply_mask), xs_new, st_base) if xs > 0 else xstate,
+            jnp.where(col(apply_mask), xs_new, st_base),
             jnp.where(create, 1.0, has))
-
-
-def _kernel(show_ref, click_ref, ew_ref, es_ref, xw_ref, xs_ref, has_ref,
-            dshow_ref, dclick_ref, ge_ref, gx_ref,
-            o_show, o_click, o_ew, o_es, o_xw, o_xs, o_has,
-            **fused_kwargs):
-    outs = fused_row_update(
-        show_ref[...], click_ref[...], ew_ref[...], es_ref[...],
-        xw_ref[...], xs_ref[...], has_ref[...],
-        dshow_ref[...], dclick_ref[...], ge_ref[...], gx_ref[...],
-        **fused_kwargs)
-    for ref, val in zip((o_show, o_click, o_ew, o_es, o_xw, o_xs, o_has),
-                        outs):
-        ref[...] = val
-
-
-def ctr_sparse_rows(
-    rows_state: Tuple[jax.Array, ...],  # show, click, ew, estate, xw, xstate, has
-    dshow: jax.Array,   # [n] merged show deltas
-    dclick: jax.Array,  # [n]
-    g_embed: jax.Array,   # [n, 1] merged embed grads
-    g_embedx: jax.Array,  # [n, dim]
-    *,
-    embed_rule: str, embedx_rule: str,
-    lr: float, initial_g2sum: float, weight_bounds: Tuple[float, float],
-    beta1: float, beta2: float, eps: float,
-    nonclk_coeff: float, click_coeff: float, embedx_threshold: float,
-    create_applies_grad: bool = True,
-    block: int = 1024,
-    interpret: Optional[bool] = None,
-) -> Tuple[jax.Array, ...]:
-    """Fused per-row CTR update over gathered rows; returns the updated
-    seven state columns in the same order. Rows are pre-merged uniques
-    (the caller's segment-sum); padding rows are fine — the caller's
-    scatter drops them. State columns may be zero-width (naive rule): a
-    one-column dummy is threaded through the kernel and sliced away."""
-    show, click, ew, estate, xw, xstate, has = rows_state
-    n = show.shape[0]
-    dim = xw.shape[1]
-    es = rule_state_dim(embed_rule, 1)
-    xs = rule_state_dim(embedx_rule, dim)
-    # enforce (not assert): a mismatched cache/table state layout must
-    # fail loudly even under python -O, not corrupt rows silently
-    enforce(estate.shape[1] == es and xstate.shape[1] == xs,
-            f"optimizer-state width mismatch: estate {estate.shape} vs "
-            f"{es}, xstate {xstate.shape} vs {xs}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    # zero-width state -> one dummy column through the kernel
-    estate_k = estate if es > 0 else jnp.zeros((n, 1), jnp.float32)
-    xstate_k = xstate if xs > 0 else jnp.zeros((n, 1), jnp.float32)
-    wes, wxs = estate_k.shape[1], xstate_k.shape[1]
-    bn = min(block, n)
-    grid = (pl.cdiv(n, bn),)
-
-    def spec1(): return pl.BlockSpec((bn,), lambda i: (i,))
-    def spec2(d): return pl.BlockSpec((bn, d), lambda i: (i, 0))
-
-    kern = functools.partial(
-        _kernel, embed_rule=embed_rule, embedx_rule=embedx_rule, dim=dim,
-        lr=lr, initial_g2sum=initial_g2sum,
-        wmin=weight_bounds[0], wmax=weight_bounds[1],
-        beta1=beta1, beta2=beta2, eps=eps,
-        nonclk_coeff=nonclk_coeff, click_coeff=click_coeff,
-        embedx_threshold=embedx_threshold,
-        create_applies_grad=create_applies_grad)
-    out_shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype)
-                  for a in (show, click, ew, estate_k, xw, xstate_k, has)]
-    out_specs = [spec1(), spec1(), spec2(1), spec2(wes), spec2(dim),
-                 spec2(wxs), spec1()]
-    in_specs = [spec1(), spec1(), spec2(1), spec2(wes), spec2(dim),
-                spec2(wxs), spec1(), spec1(), spec1(), spec2(1), spec2(dim)]
-    out = pl.pallas_call(
-        kern,
-        name="ctr_sparse_rows",
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(show, click, ew, estate_k, xw, xstate_k, has, dshow, dclick,
-      g_embed, g_embedx)
-    o_show, o_click, o_ew, o_es, o_xw, o_xs, o_has = out
-    if es == 0:
-        o_es = estate
-    if xs == 0:
-        o_xs = xstate
-    return o_show, o_click, o_ew, o_es, o_xw, o_xs, o_has

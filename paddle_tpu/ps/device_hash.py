@@ -186,10 +186,8 @@ def dynamic_probe_buckets(nbuckets: int, keys_hi: jax.Array,
                           keys_lo: jax.Array, seed, probe_buckets: int,
                           banks: int = 1):
     """The probe-window bucket ids ([n] int32 per window step) of a
-    :class:`DynamicDeviceKeyMap` — ONE definition of the bank+bucket
-    hash shared by the jnp probe below and the fused Pallas kernels
-    (ops/hot_kernels.py), so the two paths cannot drift. With banks,
-    the window wraps WITHIN the key's bank region."""
+    :class:`DynamicDeviceKeyMap` — the bank+bucket hash of the probe
+    below. With banks, the window wraps WITHIN the key's bank region."""
     hi = keys_hi.astype(jnp.uint32)
     lo = keys_lo.astype(jnp.uint32)
     nbpb = nbuckets // banks          # buckets per bank (both pow2)
@@ -209,9 +207,7 @@ def dynamic_map_lookup(table: Dict[str, jax.Array], keys_hi: jax.Array,
     """In-graph probe of a :class:`DynamicDeviceKeyMap`: [n] int32 rows
     (−1 = missing). ``probe_buckets`` consecutive bucket-ROW gathers;
     inserts guarantee placement inside that window (else the host
-    rebuilt), so no early-exit-on-empty logic is needed. This is the
-    REFERENCE formulation (two separate bucket-row gathers); the fused
-    Pallas probe (ops/hot_kernels.py) must stay bit-identical to it."""
+    rebuilt), so no early-exit-on-empty logic is needed."""
     hi = keys_hi.astype(jnp.uint32)
     lo = keys_lo.astype(jnp.uint32)
     found = jnp.full(hi.shape, -1, jnp.int32)

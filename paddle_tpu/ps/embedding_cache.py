@@ -36,7 +36,7 @@ from jax import lax
 
 from ..core.enforce import enforce, enforce_le
 from ..core.profiler import RecordEvent
-from ..ops.sparse_optimizer import ctr_sparse_rows, fused_row_update
+from ..ops.sparse_optimizer import fused_row_update
 from .native import FeasignIndex
 from .sgd_rule import SGDRuleConfig
 from .table import MemorySparseTable
@@ -100,14 +100,6 @@ class CacheConfig:
     #: applying (optimizer.cuh.h:81-94 inits and returns). True = CPU
     #: order (default — bit-parity with the host tables); False = GPU.
     create_applies_grad: bool = True
-    #: run the per-row optimizer math of the touched-rows push as the
-    #: fused Pallas kernel (ops/sparse_optimizer.py, the optimizer.cuh.h
-    #: analogue) instead of the same math as jnp. None = jnp on every
-    #: backend: on the v5e the kernel measured slower (PR 25, 2^26 rows
-    #: x 106,496 slots: 20.7 against 19.5 ms a push; its [n, 1]
-    #: operands are padded to 128 lanes). True asks for it
-    #: (Mosaic compiles all four rules; interpret mode off TPU).
-    pallas_update: Optional[bool] = None
     #: push formulation. "sparse": the touched rows, the reference's
     #: merge_grad shape — one sort, one segment-sum, gather the rows the
     #: batch named, rule, scatter back; cost follows the batch. "dense":
@@ -243,11 +235,9 @@ def merge_sparse_grads(rows: jax.Array, grads: jax.Array, shows: jax.Array,
     the (padded) ascending set of distinct rows; padding slots get the
     sentinel ``capacity`` and are dropped at scatter time. The sort is
     stable and a run's occurrences are summed in batch order, the f32
-    association of ``segment_sum`` over ``jnp.unique``'s inverse. ONE
-    definition shared by :func:`cache_push_sparse` and the fused Pallas
-    scatter+apply kernel (ops/hot_kernels.py) — the f32 merge
-    association is part of the bit-parity contract, so the two paths
-    must not drift."""
+    association of ``segment_sum`` over ``jnp.unique``'s inverse: the
+    f32 merge association is part of the bit-parity contract with the
+    host tables."""
     n = rows.shape[0]
     with jax.named_scope("pt.push.accumulate"):
         rows = jnp.where(rows < 0, capacity, rows)  # a miss marker drops too
@@ -293,26 +283,18 @@ def cache_push_sparse(
 
     uniq, show_sum, click_sum, g = merge_sparse_grads(rows, grads, shows,
                                                       clicks, C)
-    rule_kw = dict(
-        embed_rule=cfg.embed_rule, embedx_rule=cfg.embedx_rule,
-        lr=sgd.learning_rate, initial_g2sum=sgd.initial_g2sum,
-        beta1=sgd.beta1, beta2=sgd.beta2, eps=sgd.ada_epsilon,
-        nonclk_coeff=cfg.nonclk_coeff, click_coeff=cfg.click_coeff,
-        embedx_threshold=cfg.embedx_threshold,
-        create_applies_grad=cfg.create_applies_grad)
 
     def rule(gathered, dshow, dclick, g):
-        if cfg.pallas_update:
-            # fused per-row optimizer kernel (optimizer.cuh.h analogue)
-            return ctr_sparse_rows(
-                gathered, dshow, dclick, g[:, :1], g[:, 1:],
-                weight_bounds=tuple(sgd.weight_bounds), **rule_kw)
-        # same math, no kernel: fused_row_update is the single shared
-        # definition of the whole per-row update
         return fused_row_update(
             *gathered, dshow, dclick, g[:, :1], g[:, 1:],
-            dim=cfg.embedx_dim, wmin=sgd.weight_bounds[0],
-            wmax=sgd.weight_bounds[1], **rule_kw)
+            embed_rule=cfg.embed_rule, embedx_rule=cfg.embedx_rule,
+            dim=cfg.embedx_dim, lr=sgd.learning_rate,
+            initial_g2sum=sgd.initial_g2sum, wmin=sgd.weight_bounds[0],
+            wmax=sgd.weight_bounds[1], beta1=sgd.beta1, beta2=sgd.beta2,
+            eps=sgd.ada_epsilon, nonclk_coeff=cfg.nonclk_coeff,
+            click_coeff=cfg.click_coeff,
+            embedx_threshold=cfg.embedx_threshold,
+            create_applies_grad=cfg.create_applies_grad)
 
     def gather(col, uniq):
         # ascending like uniq; padding reads the last row, and its

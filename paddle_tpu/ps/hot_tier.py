@@ -58,8 +58,6 @@ import numpy as np
 from .. import nn
 from ..core.enforce import enforce
 from ..obs.registry import CounterGroup
-from ..ops.hot_kernels import (hot_probe, hot_probe_gather,
-                               hot_scatter_apply, resolve_hot_kernels)
 from .device_hash import DynamicDeviceKeyMap, dynamic_map_lookup
 from .embedding_cache import CacheConfig, cache_pull, cache_push
 
@@ -107,15 +105,6 @@ class HotTierConfig:
     #: "dense" even off-TPU: its capacity-stream can undercut the
     #: sparse mode's per-key sort at large batches.
     push_mode: str = "auto"
-    #: sparse-kernel implementation (ops/hot_kernels.py): "jnp" is the
-    #: XLA formulation (two bucket gathers + separate gather + the
-    #: push_mode push), "pallas" the fused probe+gather and
-    #: scatter+apply kernels — interpret mode off-TPU (the parity
-    #: configuration); on the chip Mosaic refuses them today, loudly.
-    #: "auto" = jnp on every backend. The pallas push is the SPARSE
-    #: (merge_grad) formulation — pair it with push_mode="sparse" when
-    #: pinning parity against the jnp oracle.
-    kernels: str = "auto"
     #: NUMA-style bucket/row banks (ps/device_hash.py): keys hash to a
     #: bank with a FIXED seed; a bank's rows live in one contiguous HBM
     #: block that never crosses a mesh-shard boundary, so the sharded
@@ -708,8 +697,6 @@ class HotEmbeddingTier:
             "map_rebuilds": self.device_map.rebuilds,
             "shards": self._n_shards,
             "banks": self._banks,
-            "kernels": "pallas" if resolve_hot_kernels(self.config.kernels)
-                       else "jnp",
         }
 
 
@@ -735,53 +722,40 @@ def _stream_loss_fn(model, dense_x, labels):
 
 def make_hot_ctr_train_step(model, optimizer, cache_cfg: CacheConfig,
                             slot_ids: Sequence[int], donate: bool = True,
-                            probe_buckets: int = 2, banks: int = 1,
-                            kernels: str = "auto"):
+                            probe_buckets: int = 2, banks: int = 1):
     """Single-chip hot-tier step: in-graph map probe → in-graph pull →
     fwd/bwd → dense update → in-graph CTR push. A warm batch never
     touches the host beyond shipping the lo32 key halves.
     ``probe_buckets`` and ``banks`` MUST be the map's own layout (the
     trainer passes ``tier.device_map.probe_buckets``/``.banks``): a
     narrower in-graph probe than the host mirror's would silently miss
-    host-resident keys. ``kernels`` selects the fused Pallas
-    probe+gather / scatter+apply kernels (ops/hot_kernels.py) vs the
-    jnp reference formulation — bit-identical by contract.
+    host-resident keys.
 
     step(params, opt_state, tier_state, map_state, keys_lo [B,S] u32,
          dense_x, labels) → (params, opt_state, tier_state, loss)
     """
     slot_hi = jnp.asarray(np.asarray(slot_ids, np.uint32))[None, :]
-    use_pallas = resolve_hot_kernels(kernels)
 
     def step(params, opt_state, tier_state, map_state, keys_lo, dense_x,
              labels):
         B, S = keys_lo.shape
         hi = jnp.broadcast_to(slot_hi, (B, S)).reshape(-1)
         C = tier_state["embed_w"].shape[0]
-        if use_pallas:
-            # ONE kernel pass: probe buckets + matched value row
-            rows, emb = hot_probe_gather(
-                map_state, hi, keys_lo.reshape(-1), tier_state,
-                probe_buckets=probe_buckets, banks=banks)
-            rows = jnp.where(rows >= 0, rows, C)
-            emb = emb.reshape(B, S, -1)
-        else:
-            rows = dynamic_map_lookup(map_state, hi, keys_lo.reshape(-1),
-                                      probe_buckets, banks)
-            # ensure() guarantees residency; sentinel-map anyway (a miss
-            # pulls zeros and drops its push instead of corrupting C-1)
-            rows = jnp.where(rows >= 0, rows, C)
-            emb = cache_pull(tier_state, rows).reshape(B, S, -1)
+        rows = dynamic_map_lookup(map_state, hi, keys_lo.reshape(-1),
+                                  probe_buckets, banks)
+        # ensure() guarantees residency; sentinel-map anyway (a miss
+        # pulls zeros and drops its push instead of corrupting C-1)
+        rows = jnp.where(rows >= 0, rows, C)
+        emb = cache_pull(tier_state, rows).reshape(B, S, -1)
         loss_fn = _stream_loss_fn(model, dense_x, labels)
         (loss, _), (grads, emb_grad) = jax.value_and_grad(
             loss_fn, argnums=(0, 1), has_aux=True)(params, emb)
         new_params, new_opt = optimizer.update(grads, opt_state, params)
         shows = jnp.ones((B * S,), jnp.float32)
         clicks = jnp.repeat(labels.astype(jnp.float32), S)
-        push = hot_scatter_apply if use_pallas else cache_push
-        new_tier = push(tier_state, rows,
-                        emb_grad.reshape(B * S, -1), shows, clicks,
-                        cache_cfg)
+        new_tier = cache_push(tier_state, rows,
+                              emb_grad.reshape(B * S, -1), shows, clicks,
+                              cache_cfg)
         return new_params, new_opt, new_tier, loss
 
     # donate ONLY the tier state (the HBM-scale buffer): params/opt are
@@ -796,14 +770,12 @@ def make_sharded_hot_train_step(model, optimizer, cache_cfg: CacheConfig,
                                 axis: str = "ps", donate: bool = True,
                                 routing="auto", cap_factor: float = 2.0,
                                 pre_dedup: bool = True,
-                                probe_buckets: int = 2, banks: int = 1,
-                                kernels: str = "auto"):
+                                probe_buckets: int = 2, banks: int = 1):
     """Multi-host hot-tier step: each device probes its LOCAL batch
-    slice against the replicated dynamic map (the fused Pallas probe
-    when ``kernels`` selects it), then the id/vector exchange rides the
-    keyed tier's ``all_to_all`` routing (ps/sharded_cache.py routed
-    pull/push) and the OWNER shard applies the fused scatter+optimizer
-    kernel on its local bank block. With the banked map (``banks`` a
+    slice against the replicated dynamic map, then the id/vector
+    exchange rides the keyed tier's ``all_to_all`` routing
+    (ps/sharded_cache.py routed pull/push) and the OWNER shard applies
+    ``cache_push`` on its local bank block. With the banked map (``banks`` a
     multiple of the shard count) a key's row lives in its hash-bank's
     block, which never crosses a shard boundary — the exchange ships
     each id straight to the HBM bank that holds it, and each host's
@@ -820,27 +792,19 @@ def make_sharded_hot_train_step(model, optimizer, cache_cfg: CacheConfig,
     _check_routing_arg(routing)
     K = mesh.shape[axis]
     slot_hi = jnp.asarray(np.asarray(slot_ids, np.uint32))[None, :]
-    use_pallas = resolve_hot_kernels(kernels)
-    # the owner-side push: the fused kernel is a drop-in cache_push with
-    # sparse-formulation semantics (hot_kernels.hot_scatter_apply)
-    push_fn = hot_scatter_apply if use_pallas else None
 
     def inner(params, opt_state, tier_state, map_state, keys_lo, dense_x,
               labels):
         B, S = keys_lo.shape  # local slice
         hi = jnp.broadcast_to(slot_hi, (B, S)).reshape(-1)
-        if use_pallas:
-            rows = hot_probe(map_state, hi, keys_lo.reshape(-1),
-                             probe_buckets=probe_buckets, banks=banks)
-        else:
-            rows = dynamic_map_lookup(map_state, hi, keys_lo.reshape(-1),
-                                      probe_buckets, banks)
+        rows = dynamic_map_lookup(map_state, hi, keys_lo.reshape(-1),
+                                  probe_buckets, banks)
         C_total = tier_state["embed_w"].shape[0] * K  # global capacity
         rows = jnp.where(rows >= 0, rows, C_total)  # sentinel: no owner
         return _sharded_step_body(model, optimizer, cache_cfg, axis, K,
                                   params, opt_state, tier_state, rows, B, S,
                                   dense_x, labels, routing, cap_factor,
-                                  pre_dedup, push_fn=push_fn)
+                                  pre_dedup)
 
     shmapped = shard_map(
         inner, mesh=mesh,

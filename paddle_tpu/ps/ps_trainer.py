@@ -616,13 +616,11 @@ class CtrStreamTrainer:
                 self._hot_step = make_sharded_hot_train_step(
                     model, optimizer, self.hot_tier.cache_config, tc.mesh,
                     slot_ids=slot_ids, axis=tc.axis, routing=tc.routing,
-                    cap_factor=tc.cap_factor, probe_buckets=pb, banks=bks,
-                    kernels=tc.kernels)
+                    cap_factor=tc.cap_factor, probe_buckets=pb, banks=bks)
             else:
                 self._hot_step = make_hot_ctr_train_step(
                     model, optimizer, self.hot_tier.cache_config,
-                    slot_ids=slot_ids, probe_buckets=pb, banks=bks,
-                    kernels=tc.kernels)
+                    slot_ids=slot_ids, probe_buckets=pb, banks=bks)
 
     # -- job checkpoint surface (io/job_checkpoint.py) --------------------
 

@@ -237,18 +237,13 @@ def routed_cache_push(
     cap_factor: float = 2.0,
     pre_dedup: bool = True,
     dedup: Optional[Tuple[jax.Array, jax.Array]] = None,
-    push_fn=None,
 ) -> Tuple[Dict[str, jax.Array], jax.Array]:
     """Inside shard_map: key-routed push (heter_comm_inl.h:575): local
     merge_grad (segment-sum duplicates), split to shard, ONE all_to_all
     pair ships each owner only its rows+grads, owner runs the batch
     -scaled `cache_push` over O(m·cap_factor) rows — per-chip update work
     independent of the shard count. Returns (new_state, overflow).
-    ``dedup``: precomputed ``(uniq, inv)`` (see :func:`routed_dedup`).
-    ``push_fn``: the owner-side row-update implementation (defaults to
-    :func:`cache_push`; the hot tier passes its fused Pallas
-    scatter+apply kernel — same signature, same sparse-merge
-    semantics)."""
+    ``dedup``: precomputed ``(uniq, inv)`` (see :func:`routed_dedup`)."""
     K = int(_axis_size(axis))
     shard_rows = state["embed_w"].shape[0]
     C_total = shard_rows * K
@@ -278,7 +273,7 @@ def routed_cache_push(
         own = (loc >= 0) & (loc < shard_rows)
         loc = jnp.where(own, loc, shard_rows)  # sentinel → dropped in push
         g, dshow, dclick = rpay[:, :-2], rpay[:, -2], rpay[:, -1]
-    new_state = (push_fn or cache_push)(state, loc, g, dshow, dclick, cfg)
+    new_state = cache_push(state, loc, g, dshow, dclick, cfg)
     return new_state, lax.psum(overflow, axis)
 
 
@@ -310,13 +305,11 @@ def sharded_cache_push(
     clicks: jax.Array,  # [m]
     cfg: CacheConfig,
     axis: Axis,
-    push_fn=None,
 ) -> Dict[str, jax.Array]:
     """Inside shard_map: push the batch's gradients into the row-sharded
     cache (HeterComm push_sparse, heter_comm_inl.h:575). Each shard runs
     the batch-scaled merge+AdaGrad (`cache_push`) on the full gathered
-    batch with non-owned rows mapped to the dropped sentinel.
-    ``push_fn``: see :func:`routed_cache_push`."""
+    batch with non-owned rows mapped to the dropped sentinel."""
     shard_rows = state["embed_w"].shape[0]
     my_start = lax.axis_index(axis) * shard_rows
     rows_all = lax.all_gather(rows, axis, tiled=True)
@@ -327,8 +320,7 @@ def sharded_cache_push(
         loc = rows_all - my_start
         own = (loc >= 0) & (loc < shard_rows)
         loc = jnp.where(own, loc, shard_rows)  # sentinel → dropped in push
-    return (push_fn or cache_push)(state, loc, grads_all, shows_all,
-                                   clicks_all, cfg)
+    return cache_push(state, loc, grads_all, shows_all, clicks_all, cfg)
 
 
 def shard_spread_rows(rows: np.ndarray, capacity: int, n_shards: int) -> np.ndarray:
@@ -496,14 +488,12 @@ def make_sharded_ctr_train_step(
 def _sharded_step_body(model, optimizer, cache_cfg, axis, K, params,
                        opt_state, cache_state, flat_rows, B, S, dense_x,
                        labels, routing="auto", cap_factor=2.0,
-                       pre_dedup=True, push_fn=None):
+                       pre_dedup=True):
     """Per-rank body of the multi-chip CTR step: sharded pull, local
     fwd/bwd, grad pmean (Reducer role), sharded push. ``flat_rows`` are
     GLOBAL spread row ids for this rank's batch slice; sentinel rows
     (≥ global capacity) pull zeros and drop their pushes. ``routing``
-    resolves per side (pull, push) — see :func:`select_routing`.
-    ``push_fn``: owner-side row update override (the hot tier's fused
-    Pallas scatter+apply kernel) — see :func:`routed_cache_push`."""
+    resolves per side (pull, push) — see :func:`select_routing`."""
     shard_rows = cache_state["embed_w"].shape[0]
     pull_r, push_r = _resolve_routing(routing, flat_rows.shape[0],
                                       shard_rows, K, cache_cfg.push_mode)
@@ -544,13 +534,11 @@ def _sharded_step_body(model, optimizer, cache_cfg, axis, K, params,
     if push_r == "alltoall":
         new_cache, ov_push = routed_cache_push(
             cache_state, flat_rows, emb_grad.reshape(B * S, -1), shows,
-            clicks, cache_cfg, axis, cap_factor, pre_dedup, dedup=dedup,
-            push_fn=push_fn)
+            clicks, cache_cfg, axis, cap_factor, pre_dedup, dedup=dedup)
     else:
         new_cache = sharded_cache_push(cache_state, flat_rows,
                                        emb_grad.reshape(B * S, -1), shows,
-                                       clicks, cache_cfg, axis,
-                                       push_fn=push_fn)
+                                       clicks, cache_cfg, axis)
         ov_push = jnp.int32(0)
     return new_params, new_opt, new_cache, loss, ov_pull + ov_push
 

@@ -102,3 +102,52 @@ def test_profiler_timed_waits_for_the_device(monkeypatch):
     assert t > 0 and len(calls) == 1 + 3
     assert float(out[0]) == 4.0            # the last dispatch's output
     assert len(synced) == 2 and synced[-1] is out   # warm-up, then the close
+
+
+def test_every_flag_is_read():
+    """A flag is an option: one that nothing reads configures nothing.
+    Every ``define_flag`` name under ``paddle_tpu/``, ``tools/`` and
+    ``chip_smoke.py`` is read or set somewhere outside its definition —
+    as an argument of ``flag(...)``, ``get_flags(...)`` or
+    ``set_flags(...)``, or spelled ``FLAGS_<name>`` (the environment
+    bootstrap and the docs' spelling), or handed on by name as a
+    ``*_flag="<name>"`` argument (``ps/rpc.py`` picks its deadline and
+    retry flags by QoS class that way)."""
+    import ast
+    import os
+    import re
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    files = [os.path.join(root, "chip_smoke.py")]
+    for top in ("paddle_tpu", "tools"):
+        for d, _, names in os.walk(os.path.join(root, top)):
+            files += [os.path.join(d, n) for n in names if n.endswith(".py")]
+
+    def called(node):
+        f = node.func
+        return f.id if isinstance(f, ast.Name) else getattr(f, "attr", None)
+
+    defined, used = {}, set()
+    for path in files:
+        with open(path) as f:
+            src = f.read()
+        lines = src.splitlines()
+        for node in ast.walk(ast.parse(src)):
+            if not isinstance(node, ast.Call):
+                continue
+            if called(node) == "define_flag" and node.args and isinstance(
+                    node.args[0], ast.Constant):
+                defined[node.args[0].value] = os.path.relpath(path, root)
+                # a definition's own text (its help string) is not a use
+                for i in range(node.lineno - 1, node.end_lineno):
+                    lines[i] = ""
+            elif called(node) in ("flag", "get_flags", "set_flags"):
+                used |= {c.value for a in node.args + node.keywords
+                         for c in ast.walk(a) if isinstance(c, ast.Constant)
+                         and isinstance(c.value, str)}
+        rest = "\n".join(lines)
+        used |= set(re.findall(r"FLAGS_(\w+)", rest))
+        used |= set(re.findall(r'\w+_flag(?:: str)? ?= ?"(\w+)"', rest))
+    assert len(defined) > 20, defined
+    unread = {n: p for n, p in defined.items() if n not in used}
+    assert not unread, f"defined and read by nothing: {unread}"

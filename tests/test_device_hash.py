@@ -5,13 +5,15 @@ in-graph; and the key-fed CTR step that fuses the probe into the program.
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import optimizer
 from paddle_tpu.models.ctr import (CtrConfig, DeepFM, make_ctr_train_step,
                                    make_ctr_train_step_from_keys)
 from paddle_tpu.ps.accessor import AccessorConfig
-from paddle_tpu.ps.device_hash import DeviceKeyMap, split_keys
+from paddle_tpu.ps.device_hash import (DeviceKeyMap, DynamicDeviceKeyMap,
+                                       dynamic_map_lookup, split_keys)
 from paddle_tpu.ps.embedding_cache import CacheConfig, HbmEmbeddingCache
 from paddle_tpu.ps.table import MemorySparseTable, TableConfig
 
@@ -140,3 +142,91 @@ def test_wide_key_step_matches_slot_tagged(rng):
     np.testing.assert_array_equal(np.asarray(loss1), np.asarray(loss2))
     for k in st1:
         np.testing.assert_array_equal(np.asarray(st1[k]), np.asarray(st2[k]))
+
+
+# ---------------------------------------------------------------------------
+# the hot tier's dynamic map, banked: the in-graph probe against the host
+# mirror (the tier's placement contract: a key's row lives inside its
+# bank's contiguous row block; banks=8 is one bank a shard of the 8-shard
+# tier)
+# ---------------------------------------------------------------------------
+
+
+def _banked_map(C, banks, keys):
+    m = DynamicDeviceKeyMap(C, banks=banks)
+    Cb = C // banks
+    rows = np.zeros(len(keys), np.int32)
+    nxt = [0] * banks
+    for i, b in enumerate(m.bank_of(keys)):
+        rows[i] = b * Cb + nxt[b]
+        nxt[b] += 1
+    assert max(nxt) <= Cb
+    m.insert(keys, rows)
+    return m, rows
+
+
+def _dyn_lookup(m, keys):
+    hi, lo = split_keys(keys)
+    return np.asarray(dynamic_map_lookup(
+        m.device_state(), jnp.asarray(hi), jnp.asarray(lo), m.probe_buckets,
+        m.banks))
+
+
+@pytest.mark.parametrize("banks", [1, 4, 8])
+def test_probe_gather_matches_jnp_reference(banks):
+    """The banked ``dynamic_map_lookup`` resolves every resident key to
+    the row the host gave it and every absent key to -1, at an unaligned
+    n (157 probes)."""
+    rng = np.random.default_rng(0)
+    keys = np.unique(rng.integers(1, 2**63, 300).astype(np.uint64))[:120]
+    m, rows = _banked_map(256, banks, keys)
+    absent = rng.integers(1, 2**63, 37).astype(np.uint64)
+    got = _dyn_lookup(m, np.concatenate([keys, absent]))
+    np.testing.assert_array_equal(got[:len(keys)], rows)
+    assert (got[len(keys):] == -1).all()
+    # a bank's rows never leave its block
+    np.testing.assert_array_equal(rows // (256 // banks), m.bank_of(keys))
+
+
+@pytest.mark.parametrize("banks", [1, 4, 8])
+def test_probe_gather_after_mutation_and_rebuild(banks):
+    """Evict/insert churn (incremental device patches) and a grow
+    rebuild (full re-upload, new probe seed): the in-graph probe reads
+    the state the host mirror describes through both."""
+    rng = np.random.default_rng(1)
+    keys = np.unique(rng.integers(1, 2**63, 300).astype(np.uint64))[:96]
+    m, rows = _banked_map(256, banks, keys)
+
+    def check():
+        np.testing.assert_array_equal(_dyn_lookup(m, keys),
+                                      m.lookup_host(keys))
+
+    check()
+    np.testing.assert_array_equal(m.lookup_host(keys), rows)
+    m.remove(keys[::3])          # tombstones → incremental patches
+    check()
+    assert (m.lookup_host(keys[::3]) == -1).all()
+    m._rebuild(grow=False)       # reseed
+    check()
+    m._rebuild(grow=True)        # grow → full re-upload
+    check()
+    np.testing.assert_array_equal(m.lookup_host(keys[1::3]), rows[1::3])
+
+
+def test_bank_membership_stable_across_rebuilds():
+    """bank_of is a FIXED hash: reseed and grow rebuilds relocate
+    buckets but never move a key between banks (the tier's row blocks
+    depend on it)."""
+    rng = np.random.default_rng(4)
+    m = DynamicDeviceKeyMap(256, banks=8)
+    keys = np.unique(rng.integers(1, 2**63, 300).astype(np.uint64))[:128]
+    before = m.bank_of(keys)
+    m.insert(keys, np.arange(len(keys), dtype=np.int32))
+    m._rebuild(grow=False)   # reseed
+    m._rebuild(grow=True)    # grow
+    np.testing.assert_array_equal(m.bank_of(keys), before)
+    np.testing.assert_array_equal(m.lookup_host(keys),
+                                  np.arange(len(keys), dtype=np.int32))
+    # banked probe never resolves a key through another bank's region:
+    # the in-graph lookup agrees with the host mirror on every key
+    np.testing.assert_array_equal(_dyn_lookup(m, keys), m.lookup_host(keys))

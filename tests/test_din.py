@@ -10,7 +10,7 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu import nn, optimizer
 from paddle_tpu.metrics.auc import AUC
-from paddle_tpu.models.ctr import _masked_pull
+from paddle_tpu.ps.embedding_cache import cache_pull
 from paddle_tpu.models.din import DIN, make_ctr_attention_train_step
 from paddle_tpu.ps.accessor import AccessorConfig
 from paddle_tpu.ps.embedding_cache import CacheConfig, HbmEmbeddingCache
@@ -80,9 +80,8 @@ def test_din_learns_match_signal_and_ignores_padding():
     m = AUC()
     for i in range(0, len(keys), B):
         rows = jnp.asarray(rows_of(keys[i:i + B], pad_mask[i:i + B]))
-        # sentinel-safe pull (raw eager cache_pull would FILL NaN for
-        # out-of-bounds sentinel rows — the step uses the masked pull)
-        emb = _masked_pull(cache.state, rows.reshape(-1)).reshape(
+        # cache_pull is sentinel-safe: rows >= capacity pull zeros
+        emb = cache_pull(cache.state, rows.reshape(-1)).reshape(
             rows.shape[0], G + TB, -1)
         real = (rows < C).astype(jnp.float32)
         out, _ = nn.functional_call(model, params, emb, real,
@@ -97,7 +96,7 @@ def test_din_learns_match_signal_and_ignores_padding():
     # is what excludes padding)
     i = 0
     rows = jnp.asarray(rows_of(keys[i:i + B], pad_mask[i:i + B]))
-    emb = np.array(_masked_pull(cache.state, rows.reshape(-1)).reshape(
+    emb = np.array(cache_pull(cache.state, rows.reshape(-1)).reshape(
         B, G + TB, -1))
     real = np.asarray(rows) < C
     out1, _ = nn.functional_call(model, params, jnp.asarray(emb),

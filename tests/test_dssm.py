@@ -7,7 +7,7 @@ import numpy as np
 
 import paddle_tpu as pt
 from paddle_tpu import nn, optimizer
-from paddle_tpu.models.ctr import _masked_pull
+from paddle_tpu.ps.embedding_cache import cache_pull
 from paddle_tpu.models.dssm import DSSM, make_dssm_train_step
 from paddle_tpu.ps.accessor import AccessorConfig
 from paddle_tpu.ps.embedding_cache import CacheConfig, HbmEmbeddingCache
@@ -74,7 +74,7 @@ def test_dssm_learns_pairing_and_ranks_true_doc():
     for i in range(0, len(keys2), B):
         k = keys2[i:i + B]
         rows = jnp.asarray(cache.lookup(k.reshape(-1)).reshape(B, SQ + SD))
-        emb = _masked_pull(cache.state, rows.reshape(-1)).reshape(
+        emb = cache_pull(cache.state, rows.reshape(-1)).reshape(
             B, SQ + SD, -1)
         (q, d), _ = nn.functional_call(model, params, emb,
                                        jnp.asarray(dense2[i:i + B]),
@@ -157,7 +157,6 @@ def test_dssm_tower_export(tmp_path):
     # in-process reference through the full model
     rows = jnp.asarray(cache.lookup(keys[:B].reshape(-1)).reshape(
         B, SQ + SD))
-    from paddle_tpu.ps.embedding_cache import cache_pull
     emb = cache_pull(cache.state, rows.reshape(-1)).reshape(B, SQ + SD, -1)
     (q_ref, d_ref), _ = functional_call(
         model, {"params": dict(model.named_parameters()), "buffers": {}},

@@ -22,6 +22,7 @@ from paddle_tpu import optimizer
 from paddle_tpu.core import mesh as mesh_mod
 from paddle_tpu.data.dataset import InMemoryDataset, SlotDesc
 from paddle_tpu.models.ctr import CtrConfig, DeepFM
+from paddle_tpu.ops.sparse_optimizer import rule_state_dim
 from paddle_tpu.ps import rpc
 from paddle_tpu.ps.communicator import (HalfAsyncCommunicator,
                                          SyncCommunicator)
@@ -291,23 +292,35 @@ def test_hot_tier_warm_steady_state_zero_rpcs():
             s.stop()
 
 
-def test_hot_tier_sharded_mesh_step_matches_single_chip():
-    """8-shard GSPMD mesh tier (replicated dynamic map + all_to_all
-    routed rows) trains to the single-chip tier's results. Dense grads
-    psum over the mesh (association differs from the serial sum), so
-    this pins a tight tolerance, not bits — within-mesh routed≡gathered
-    bitwise parity is pinned by test_sharded_cache.py."""
+def _sharded_against_single_chip(acc=None, single_chip_banks=None):
+    """8-shard GSPMD mesh tier (replicated dynamic map, one bank a shard,
+    all_to_all routed rows, the owner's ``cache_push`` on its bank block)
+    trains to the single-chip tier's results. Dense grads psum over the
+    mesh (association differs from the serial sum), so this pins a tight
+    tolerance, not bits — within-mesh routed≡gathered bitwise parity is
+    pinned by test_sharded_cache.py. With the SAME eight banks on the
+    single chip a bank is a placement unit: every key gets the same row
+    in both tiers."""
     ds = make_data(n=512, nid=60)
     mesh = mesh_mod.make_mesh({"ps": 8})
-    ta = MemorySparseTable(TableConfig(shard_num=4, accessor="ctr"))
-    a = make_trainer(ta, HotTierConfig(capacity=512))
+    table = lambda: MemorySparseTable(TableConfig(  # noqa: E731
+        shard_num=4, accessor="ctr", accessor_config=acc))
+    ta = table()
+    a = make_trainer(ta, HotTierConfig(capacity=512, banks=single_chip_banks))
     ra = a.train_from_dataset(ds, batch_size=128)
-    a.hot_tier.flush()
-    tb = MemorySparseTable(TableConfig(shard_num=4, accessor="ctr"))
+    tb = table()
     b = make_trainer(tb, HotTierConfig(capacity=512, mesh=mesh, axis="ps"))
     rb = b.train_from_dataset(ds, batch_size=128)
+    assert rb["hot_tier"]["shards"] == 8 and rb["hot_tier"]["banks"] == 8
+    if single_chip_banks == 8:
+        keys = a.hot_tier.resident_keys()
+        np.testing.assert_array_equal(
+            np.sort(keys), np.sort(b.hot_tier.resident_keys()))
+        np.testing.assert_array_equal(
+            a.hot_tier.device_map.lookup_host(keys),
+            b.hot_tier.device_map.lookup_host(keys))
+    a.hot_tier.flush()
     b.hot_tier.flush()
-    assert rb["hot_tier"]["shards"] == 8
     assert abs(ra["loss"] - rb["loss"]) < 1e-6
     for x, y in zip(_leaves(a.params), _leaves(b.params)):
         np.testing.assert_allclose(x, y, rtol=0, atol=1e-6)
@@ -315,6 +328,10 @@ def test_hot_tier_sharded_mesh_step_matches_single_chip():
     kb, vb = _sorted_items(tb)
     np.testing.assert_array_equal(ka, kb)
     np.testing.assert_allclose(va, vb, rtol=0, atol=1e-6)
+
+
+def test_hot_tier_sharded_mesh_step_matches_single_chip():
+    _sharded_against_single_chip()
 
 
 def test_hot_tier_stats_and_drop():
@@ -342,51 +359,24 @@ def test_hot_tier_stats_and_drop():
 
 
 # ---------------------------------------------------------------------------
-# fused Pallas kernels (ops/hot_kernels.py) — tier-level parity matrix.
-# Kernel-level parity (vs the jnp formulations, every rule, unaligned n)
-# is pinned in tests/test_hot_kernels.py; here the kernels run inside
-# the REAL compiled steps (interpret mode on CPU) and must reproduce
-# the jnp tier AND the RPC-only oracle bit-for-bit through eviction
-# churn, adam rules, checkpoint/restore and the sharded banked mesh.
+# the rule family and the banked layouts at tier level: the REAL compiled
+# steps against the RPC-only oracle and against each other
 # ---------------------------------------------------------------------------
 
 
-def test_hot_tier_pallas_parity_through_eviction_churn():
-    """kernels="pallas" (interpret) ≡ kernels="jnp" ≡ RPC-only oracle
-    under heavy eviction/readmission churn: dense params/opt bitwise,
-    table rows bitwise between the two tiers (same flush points ⇒ full
-    equality incl. delta_score), rows-mod-delta vs the oracle."""
-    ds = make_data(nid=400)
-    ta = MemorySparseTable(TableConfig(shard_num=4, accessor="ctr"))
-    a = make_trainer(ta)
-    a.train_from_dataset(ds, batch_size=64)
-    tb = MemorySparseTable(TableConfig(shard_num=4, accessor="ctr"))
-    b = make_trainer(tb, hot=HotTierConfig(capacity=224, kernels="jnp"))
-    b.train_from_dataset(ds, batch_size=64)
-    b.hot_tier.flush()
-    tc = MemorySparseTable(TableConfig(shard_num=4, accessor="ctr"))
-    c = make_trainer(tc, hot=HotTierConfig(capacity=224, kernels="pallas"))
-    rc = c.train_from_dataset(ds, batch_size=64)
-    c.hot_tier.flush()
-    st = rc["hot_tier"]
-    assert st["evictions"] > 0 and st["kernels"] == "pallas"
-    _assert_bitwise_equal(_leaves(a.params), _leaves(c.params))
-    _assert_bitwise_equal(_leaves(b.params), _leaves(c.params))
-    _assert_bitwise_equal(_leaves(b.opt_state), _leaves(c.opt_state))
-    kb, vb = _sorted_items(tb)
-    kc, vc = _sorted_items(tc)
-    np.testing.assert_array_equal(kb, kc)
-    np.testing.assert_array_equal(vb, vc)  # incl. delta_score
-    _assert_rows_equal_mod_delta(ta, tc)
-
-
-def test_hot_tier_pallas_adam_rule_parity():
-    """The adam half of the kernel parity matrix at tier level: an
-    adam/adam accessor trains bit-identically through the fused
-    kernels (m/v moments and beta powers round-trip the writeback)."""
+def _rule_parity(embed_rule, embedx_rule):
+    """A tier whose accessor runs ``embed_rule`` / ``embedx_rule`` trains
+    to the RPC-only oracle's bits: dense params, and after ``flush()``
+    every save column but the per-flush delta_score. The embedx block is
+    created on a row's first push (threshold 0, initial_range 0: the
+    bit-parity preconditions of OPERATIONS.md section 5d), so the embedx
+    rule's state round-trips the write-back too."""
     from paddle_tpu.ps.accessor import AccessorConfig
+    from paddle_tpu.ps.sgd_rule import SGDRuleConfig
 
-    acc = AccessorConfig(embed_sgd_rule="adam", embedx_sgd_rule="adam")
+    acc = AccessorConfig(embed_sgd_rule=embed_rule,
+                         embedx_sgd_rule=embedx_rule, embedx_threshold=0.0,
+                         sgd=SGDRuleConfig(initial_range=0.0))
     ds = make_data(nid=120)
     ta = MemorySparseTable(TableConfig(shard_num=4, accessor="ctr",
                                        accessor_config=acc))
@@ -394,52 +384,32 @@ def test_hot_tier_pallas_adam_rule_parity():
     a.train_from_dataset(ds, batch_size=64)
     tb = MemorySparseTable(TableConfig(shard_num=4, accessor="ctr",
                                        accessor_config=acc))
-    b = make_trainer(tb, hot=HotTierConfig(capacity=256, kernels="pallas"))
-    b.train_from_dataset(ds, batch_size=64)
+    # 224 rows under ~330 distinct keys: evictions write state back
+    b = make_trainer(tb, hot=HotTierConfig(capacity=224))
+    rb = b.train_from_dataset(ds, batch_size=64)
     b.hot_tier.flush()
+    assert rb["hot_tier"]["evictions"] > 0
     _assert_bitwise_equal(_leaves(a.params), _leaves(b.params))
     _assert_rows_equal_mod_delta(ta, tb)
+    _, rows = _sorted_items(tb)
+    has_embedx = rows[:, 6 + rule_state_dim(embed_rule, 1)]  # save layout
+    assert (has_embedx == 1.0).all()
 
 
-def test_hot_tier_pallas_checkpoint_restore_parity():
-    """Mid-stream checkpoint → restore → resume with kernels="pallas":
-    final digests AND dense state bitwise equal to an uninterrupted
-    pallas oracle (the kernels change nothing about the flush-dirty-
-    then-snapshot contract)."""
-    from paddle_tpu.io.job_checkpoint import JobCheckpointManager
+def test_hot_tier_pallas_adam_rule_parity():
+    """An adam/adam accessor trains bit-identically through the tier
+    (m/v moments and beta powers round-trip the writeback)."""
+    _rule_parity("adam", "adam")
 
-    tmp = tempfile.mkdtemp()
-    ds = make_data(n=384, nid=120)
-    cfg = lambda: HotTierConfig(capacity=256, kernels="pallas")  # noqa: E731
-    ta = MemorySparseTable(TableConfig(shard_num=4, accessor="ctr"))
-    a = make_trainer(ta, hot=cfg())
-    mga = JobCheckpointManager(os.path.join(tmp, "a"), max_keep=8)
-    mga.register_sparse("ctr", ta)
-    a.train_from_dataset(ds, batch_size=128, checkpoint=mga,
-                         checkpoint_every=2)
-    mga.stop()
-    a.hot_tier.flush()
 
-    tb = MemorySparseTable(TableConfig(shard_num=4, accessor="ctr"))
-    b = make_trainer(tb, hot=cfg())
-    mgr = JobCheckpointManager(os.path.join(tmp, "b"), max_keep=8)
-    mgr.register_sparse("ctr", tb)
-    b.train_from_dataset(ds, batch_size=128, checkpoint=mgr,
-                         checkpoint_every=2)
-    mgr.wait()
-    restored = mgr.load_latest()
-
-    tc = MemorySparseTable(TableConfig(shard_num=4, accessor="ctr"))
-    c = make_trainer(tc, hot=cfg())
-    restored.restore_sparse("ctr", tc)
-    c.restore_train_state(restored.dense)
-    assert c.hot_tier.stats()["occupancy"] == 0
-    c.train_from_dataset(ds, batch_size=128, start_batch=restored.cursor)
-    c.hot_tier.flush()
-    mgr.stop()
-    assert tc.digest() == ta.digest()
-    _assert_bitwise_equal(_leaves(a.params), _leaves(c.params))
-    _assert_bitwise_equal(_leaves(a.opt_state), _leaves(c.opt_state))
+@pytest.mark.parametrize("embed_rule,embedx_rule",
+                         [("naive", "naive"), ("std_adagrad", "std_adagrad"),
+                          ("adagrad", "adam")],
+                         ids=["naive", "std_adagrad", "adagrad+adam"])
+def test_hot_tier_rule_parity(embed_rule, embedx_rule):
+    """The rest of the rule family through the tier: zero-width state
+    columns (naive), per-dimension state, and a mixed pair."""
+    _rule_parity(embed_rule, embedx_rule)
 
 
 def test_hot_tier_banked_single_chip_parity():
@@ -452,8 +422,7 @@ def test_hot_tier_banked_single_chip_parity():
     a.train_from_dataset(ds, batch_size=64)
     a.hot_tier.flush()
     tb = MemorySparseTable(TableConfig(shard_num=4, accessor="ctr"))
-    b = make_trainer(tb, hot=HotTierConfig(capacity=512, banks=4,
-                                           kernels="pallas"))
+    b = make_trainer(tb, hot=HotTierConfig(capacity=512, banks=4))
     rb = b.train_from_dataset(ds, batch_size=64)
     b.hot_tier.flush()
     assert rb["hot_tier"]["banks"] == 4
@@ -465,31 +434,19 @@ def test_hot_tier_banked_single_chip_parity():
 
 
 def test_hot_tier_sharded_banked_pallas_matches_jnp_bitwise():
-    """8-shard mesh, banked map (one bank per shard — a key's row block
-    IS its owner's HBM): the pallas sharded step (fused local probe +
-    owner-side scatter+apply behind the all_to_all exchange) is
-    BIT-identical to the jnp sharded step — same routing, same merge
-    association, same sealed rule bits."""
-    ds = make_data(n=512, nid=60)
-    mesh = mesh_mod.make_mesh({"ps": 8})
-    tb = MemorySparseTable(TableConfig(shard_num=4, accessor="ctr"))
-    b = make_trainer(tb, HotTierConfig(capacity=512, mesh=mesh, axis="ps",
-                                       kernels="jnp"))
-    rb = b.train_from_dataset(ds, batch_size=128)
-    b.hot_tier.flush()
-    assert rb["hot_tier"]["shards"] == 8 and rb["hot_tier"]["banks"] == 8
-    tc = MemorySparseTable(TableConfig(shard_num=4, accessor="ctr"))
-    c = make_trainer(tc, HotTierConfig(capacity=512, mesh=mesh, axis="ps",
-                                       kernels="pallas"))
-    rc = c.train_from_dataset(ds, batch_size=128)
-    c.hot_tier.flush()
-    assert rc["loss"] == rb["loss"]
-    _assert_bitwise_equal(_leaves(b.params), _leaves(c.params))
-    _assert_bitwise_equal(_leaves(b.opt_state), _leaves(c.opt_state))
-    kb, vb = _sorted_items(tb)
-    kc, vc = _sorted_items(tc)
-    np.testing.assert_array_equal(kb, kc)
-    np.testing.assert_array_equal(vb, vc)
+    """The banked 8-shard tier against the single-chip tier with the same
+    eight banks: same rows for the same keys, same results."""
+    _sharded_against_single_chip(single_chip_banks=8)
+
+
+def test_hot_tier_sharded_adam_matches_single_chip():
+    """The routed owner-side push carrying adam's state: 4 columns on
+    the embed weight, 18 on the embedx block."""
+    from paddle_tpu.ps.accessor import AccessorConfig
+
+    _sharded_against_single_chip(
+        AccessorConfig(embed_sgd_rule="adam", embedx_sgd_rule="adam"),
+        single_chip_banks=8)
 
 
 def test_hot_tier_rejects_mismatched_embedx_dim():

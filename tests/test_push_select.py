@@ -115,7 +115,7 @@ def test_touched_rows_match_the_sweep(rng, rule, kind, monkeypatch):
     state = _state(rng, C, dim, rule)
     batch = _batch(rng, C, n, kind)
     kw = dict(capacity=C, embedx_dim=dim, embedx_threshold=2.0,
-              embed_rule=rule, embedx_rule=rule, pallas_update=False)
+              embed_rule=rule, embedx_rule=rule)
     out = {}
     for mode in ("dense", "sparse"):
         cfg = CacheConfig(push_mode=mode, **kw)
@@ -174,8 +174,7 @@ def test_push_select_is_recorded_once_a_compile(as_tpu):
     """The choice is static per compiled shape: one ``pt.push.select``
     span with the shapes and the choice per trace, none per step."""
     C, dim = 1 << 12, 4
-    cfg = CacheConfig(capacity=C, embedx_dim=dim, embedx_threshold=0.0,
-                      pallas_update=False)
+    cfg = CacheConfig(capacity=C, embedx_dim=dim, embedx_threshold=0.0)
     step = jax.jit(lambda st, *b: cache_push(st, *b, cfg))
     rng = np.random.default_rng(0)
     selects = lambda: [s.counts for s in profiler.host_spans()

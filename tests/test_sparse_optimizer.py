@@ -1,19 +1,26 @@
-"""Pallas fused CTR row kernel (ops/sparse_optimizer.py) vs the jnp
-path — parity of the optimizer.cuh.h / sparse_sgd_rule.cc math across
-the whole rule family (interpret mode on the CPU mesh, same discipline
-as the flash-attention tests) — plus device-vs-host-table parity."""
+"""The per-row CTR rule on the device (ops/sparse_optimizer.py through
+``cache_push``) against the HOST rules — ``MemorySparseTable`` and
+``ps/sgd_rule`` — across the whole rule family, both lazy-embedx create
+orders and the padded last chunk of the touched-rows walk; and the sweep
+against the touched rows."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.ops.sparse_optimizer import (ctr_sparse_rows,
-                                             rule_state_dim)
+from paddle_tpu.ops.sparse_optimizer import rule_state_dim
+from paddle_tpu.ps import embedding_cache
+from paddle_tpu.ps.accessor import AccessorConfig
 from paddle_tpu.ps.embedding_cache import CacheConfig, cache_push
-from paddle_tpu.ps.sgd_rule import SGDRuleConfig
+from paddle_tpu.ps.sgd_rule import SGDRuleConfig, make_sgd_rule
+from paddle_tpu.ps.table import MemorySparseTable, TableConfig
 
 RULES = ["naive", "adagrad", "std_adagrad", "adam"]
+PAIRS = [(r, r) for r in RULES] + [("adagrad", "adam"),
+                                   ("naive", "std_adagrad")]
+_COLS = ("show", "click", "embed_w", "embed_state", "embedx_w",
+         "embedx_state", "has_embedx")
 
 
 def _state(rng, C, dim, embed_rule="adagrad", embedx_rule="adagrad"):
@@ -35,44 +42,170 @@ def _state(rng, C, dim, embed_rule="adagrad", embedx_rule="adagrad"):
     return st
 
 
-@pytest.mark.parametrize("create_applies_grad", [True, False])
-@pytest.mark.parametrize("embed_rule,embedx_rule",
-                         [(r, r) for r in RULES] + [("adagrad", "adam"),
-                                                    ("naive", "std_adagrad")])
-def test_pallas_push_matches_jnp(rng, embed_rule, embedx_rule,
-                                 create_applies_grad):
-    C, dim, n = 512, 4, 300
-    state = _state(rng, C, dim, embed_rule, embedx_rule)
-    rows = jnp.asarray(rng.integers(0, C, n), jnp.int32)
-    grads = jnp.asarray(rng.normal(size=(n, 1 + dim)).astype(np.float32))
-    shows = jnp.ones((n,), jnp.float32)
-    clicks = jnp.asarray((rng.random(n) < 0.4).astype(np.float32))
+# ---------------------------------------------------------------------------
+# the touched-rows push against the host rules. Neither oracle below
+# reaches ops/sparse_optimizer: the CPU create order is the host table
+# itself, the GPU order a numpy loop over ps/sgd_rule's rules.
+# ---------------------------------------------------------------------------
 
-    kw = dict(capacity=C, embedx_dim=dim, embedx_threshold=3.0,
-              embed_rule=embed_rule, embedx_rule=embedx_rule,
-              create_applies_grad=create_applies_grad)
-    # pin the merge_grad-shaped path: "auto" would resolve to the dense
-    # push on TPU backends, which never calls the Pallas kernel — these
-    # tests exist to cover ctr_sparse_rows
-    cfg_j = CacheConfig(pallas_update=False, push_mode="sparse", **kw)
-    cfg_p = CacheConfig(pallas_update=True, push_mode="sparse", **kw)
-    a = jax.jit(lambda st: cache_push(st, rows, grads, shows, clicks, cfg_j))(state)
-    b = jax.jit(lambda st: cache_push(st, rows, grads, shows, clicks, cfg_p))(state)
-    for k in a:
-        np.testing.assert_allclose(np.asarray(b[k]), np.asarray(a[k]),
-                                   rtol=1e-6, atol=1e-7, err_msg=k)
-    # lifecycle flags are exact
-    np.testing.assert_array_equal(np.asarray(b["has_embedx"]),
-                                  np.asarray(a["has_embedx"]))
+_THRESHOLD = 3.0
+
+
+def _host_state(rng, C, dim, embed_rule, embedx_rule):
+    """A trained-looking table a host table can hold too: rows without
+    an embedx block have none of its weights or state (lazy creation
+    starts both from the rule's init)."""
+    st = {k: np.asarray(v) for k, v in
+          _state(rng, C, dim, embed_rule, embedx_rule).items()}
+    none = st["has_embedx"] == 0
+    st["embedx_w"] = np.where(none[:, None], 0.0, st["embedx_w"])
+    st["embedx_state"] = np.where(none[:, None], 0.0, st["embedx_state"])
+    return {k: v.astype(np.float32) for k, v in st.items()}
+
+
+def _accessor(dim, embed_rule, embedx_rule):
+    return AccessorConfig(embedx_dim=dim, embedx_threshold=_THRESHOLD,
+                          embed_sgd_rule=embed_rule,
+                          embedx_sgd_rule=embedx_rule,
+                          sgd=SGDRuleConfig(initial_range=0.0))
+
+
+def _full_rows(st):
+    """The host table's save layout: slot, unseen_days, delta_score, show,
+    click, embed_w, embed_state, has_embedx, embedx_w, embedx_state."""
+    z = np.zeros((len(st["show"]), 3), np.float32)
+    return np.concatenate(
+        [z, st["show"][:, None], st["click"][:, None], st["embed_w"],
+         st["embed_state"], st["has_embedx"][:, None], st["embedx_w"],
+         st["embedx_state"]], axis=1)
+
+
+def _table_push(st, acc, rows, grads, shows, clicks):
+    """CPU create order (create the block, then apply the creating push):
+    ``MemorySparseTable.push_sparse`` on a table holding ``st``."""
+    C = len(st["show"])
+    keys = np.arange(1, C + 1, dtype=np.uint64)
+    table = MemorySparseTable(TableConfig(shard_num=2, accessor_config=acc))
+    table.import_full(keys, _full_rows(st))
+    push = np.concatenate([np.zeros((len(rows), 1), np.float32),
+                           shows[:, None], clicks[:, None], grads], axis=1)
+    table.push_sparse(keys[rows], push)
+    full, found = table.export_full(keys)
+    assert found.all()
+    es = st["embed_state"].shape[1]
+    xd = st["embedx_w"].shape[1]
+    return {"show": full[:, 3], "click": full[:, 4], "embed_w": full[:, 5:6],
+            "embed_state": full[:, 6:6 + es], "has_embedx": full[:, 6 + es],
+            "embedx_w": full[:, 7 + es:7 + es + xd],
+            "embedx_state": full[:, 7 + es + xd:]}
+
+
+def _loop_push(st, acc, rows, grads, shows, clicks, create_applies_grad):
+    """Row by row over ``ps/sgd_rule``'s host rules: occurrences of a row
+    summed in batch order, show/click accumulated, the embed rule, lazy
+    creation on the score of the totals, the embedx rule where the block
+    exists — and, in the GPU order, NOT on the push that created it."""
+    st = {k: v.copy() for k, v in st.items()}
+    dim = st["embedx_w"].shape[1]
+    embed = make_sgd_rule(acc.embed_sgd_rule, 1, acc.sgd)
+    embedx = make_sgd_rule(acc.embedx_sgd_rule, dim, acc.sgd)
+    nothing = np.random.default_rng(0)   # initial_range 0: draws only zeros
+    for r in np.unique(rows):
+        at = np.flatnonzero(rows == r)
+        g = np.zeros((1, 1 + dim), np.float32)
+        dshow = dclick = np.float32(0.0)
+        for i in at:
+            g += grads[i]
+            dshow += shows[i]
+            dclick += clicks[i]
+        scale = np.asarray([dshow], np.float32)
+        st["show"][r] += dshow
+        st["click"][r] += dclick
+        w, s = st["embed_w"][r:r + 1], st["embed_state"][r:r + 1]
+        embed.update(w, s, g[:, :1], scale)
+        score = ((st["show"][r] - st["click"][r]) * np.float32(acc.nonclk_coeff)
+                 + st["click"][r] * np.float32(acc.click_coeff))
+        created = st["has_embedx"][r] == 0 and score >= acc.embedx_threshold
+        if created:
+            xw, xs = embedx.init_value(1, nothing)
+            st["embedx_w"][r], st["embedx_state"][r] = xw[0], xs[0]
+            st["has_embedx"][r] = 1.0
+        if st["has_embedx"][r] and (create_applies_grad or not created):
+            xw, xs = st["embedx_w"][r:r + 1], st["embedx_state"][r:r + 1]
+            embedx.update(xw, xs, g[:, 1:], scale)
+    return st
+
+
+def _assert_rows(got, want, what):
+    # bit for bit: rows round-trip between the device and the host
+    # engines (the hot tier), so the rule's bits are part of the contract
+    for k in _COLS:
+        np.testing.assert_array_equal(np.asarray(got[k]), want[k],
+                                      err_msg=f"{what}: {k}")
+
+
+def _touched_rows_against_host(rng, monkeypatch, embed_rule, embedx_rule,
+                               create_applies_grad, n):
+    # five chunks of 64 slots at n = 300, the last one padded
+    monkeypatch.setattr(embedding_cache, "PUSH_CHUNK", 64)
+    C, dim = 512, 4
+    st = _host_state(rng, C, dim, embed_rule, embedx_rule)
+    rows = rng.integers(0, C, n).astype(np.int32)
+    rows[n // 2:] = rows[:n - n // 2]               # every other row twice
+    grads = rng.normal(size=(n, 1 + dim)).astype(np.float32)
+    shows = np.ones(n, np.float32)
+    clicks = (rng.random(n) < 0.4).astype(np.float32)
+    acc = _accessor(dim, embed_rule, embedx_rule)
+    cfg = CacheConfig(capacity=C, embedx_dim=dim, embedx_threshold=_THRESHOLD,
+                      embed_rule=embed_rule, embedx_rule=embedx_rule,
+                      create_applies_grad=create_applies_grad,
+                      push_mode="sparse")
+    # a sentinel slot (a missing key) rides along and must drop
+    pad = lambda a, v: jnp.asarray(np.concatenate(
+        [a, np.full((1,) + a.shape[1:], v, a.dtype)]))
+    got = jax.jit(lambda s: cache_push(
+        s, pad(rows, C), pad(grads, 7.0), pad(shows, 1.0), pad(clicks, 1.0),
+        cfg))({k: jnp.asarray(v) for k, v in st.items()})
+    created = (np.asarray(got["has_embedx"]) != st["has_embedx"]).sum()
+    loop = _loop_push(st, acc, rows, grads, shows, clicks,
+                      create_applies_grad)
+    if create_applies_grad:
+        table = _table_push(st, acc, rows, grads, shows, clicks)
+        _assert_rows(got, table, "device against the host table")
+        # ... and the loop is the table's order with one flag turned
+        _assert_rows(loop, table, "numpy loop against the host table")
+    else:
+        _assert_rows(got, loop, "device against the numpy loop")
+    return created
+
+
+@pytest.mark.parametrize("create_applies_grad", [True, False],
+                         ids=["cpu_order", "gpu_order"])
+@pytest.mark.parametrize("embed_rule,embedx_rule", PAIRS,
+                         ids=["+".join(p) for p in PAIRS])
+def test_touched_rows_match_host_rules(rng, monkeypatch, embed_rule,
+                                       embedx_rule, create_applies_grad):
+    """``cache_push`` on the touched rows == the host rules for the same
+    batch, every rule pair, both create orders (CPU: the host table; GPU:
+    the numpy loop, which in the CPU order is held to the table too)."""
+    created = _touched_rows_against_host(
+        rng, monkeypatch, embed_rule, embedx_rule, create_applies_grad, 300)
+    assert created > 0      # the orders differ only on a creating push
+
+
+@pytest.mark.parametrize("n", [1, 129])
+def test_touched_rows_padded_last_chunk_matches_host(rng, monkeypatch, n):
+    """One slot (a chunk of one) and 129 + the sentinel (two chunks of 64
+    and a padded third) against the host table."""
+    _touched_rows_against_host(rng, monkeypatch, "adagrad", "adagrad", True,
+                               n)
 
 
 @pytest.mark.parametrize("rule", RULES)
 def test_cache_push_matches_host_table(rng, rule):
     """Device cache push == host MemorySparseTable push for the same
     merged records, for every rule (the parity-critical A.2 math)."""
-    from paddle_tpu.ps.accessor import AccessorConfig
     from paddle_tpu.ps.embedding_cache import HbmEmbeddingCache
-    from paddle_tpu.ps.table import MemorySparseTable, TableConfig
 
     dim = 4
     acc = AccessorConfig(embedx_dim=dim, embedx_threshold=0.0,
@@ -105,52 +238,8 @@ def test_cache_push_matches_host_table(rng, rule):
         mirror.pull_sparse(keys, create=False), rtol=1e-5, atol=1e-6)
 
 
-def test_pallas_push_unaligned_n(rng):
-    # n not a multiple of the kernel block exercises the padded tail —
-    # drive the kernel directly with block=64 over n=300
-    C, dim, n = 256, 8, 300
-    state = _state(rng, C, dim)
-    srows = jnp.asarray(rng.integers(0, C, n), jnp.int32)
-    gathered = tuple(state[k][srows] for k in
-                     ("show", "click", "embed_w", "embed_state",
-                      "embedx_w", "embedx_state", "has_embedx"))
-    dshow = jnp.ones((n,), jnp.float32)
-    dclick = jnp.asarray((rng.random(n) < 0.3).astype(np.float32))
-    ge = jnp.asarray(rng.normal(size=(n, 1)).astype(np.float32))
-    gx = jnp.asarray(rng.normal(size=(n, dim)).astype(np.float32))
-    kw = dict(embed_rule="adagrad", embedx_rule="adagrad",
-              lr=0.05, initial_g2sum=3.0, weight_bounds=(-10.0, 10.0),
-              beta1=0.9, beta2=0.999, eps=1e-8,
-              nonclk_coeff=0.1, click_coeff=1.0, embedx_threshold=0.0)
-    small = ctr_sparse_rows(gathered, dshow, dclick, ge, gx, block=64, **kw)
-    full = ctr_sparse_rows(gathered, dshow, dclick, ge, gx, block=1024, **kw)
-    for a, b in zip(small, full):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-7)
-
-
-def test_pallas_push_in_cache_small(rng):
-    C, dim, n = 256, 8, 129
-    state = _state(rng, C, dim)
-    rows = jnp.asarray(rng.integers(0, C, n), jnp.int32)
-    grads = jnp.asarray(rng.normal(size=(n, 1 + dim)).astype(np.float32))
-    shows = jnp.ones((n,), jnp.float32)
-    clicks = jnp.zeros((n,), jnp.float32)
-    cfg = CacheConfig(capacity=C, embedx_dim=dim, embedx_threshold=0.0,
-                      pallas_update=True, push_mode="sparse")
-    cfg_ref = CacheConfig(capacity=C, embedx_dim=dim, embedx_threshold=0.0,
-                          pallas_update=False, push_mode="sparse")
-    b = jax.jit(lambda st: cache_push(st, rows, grads, shows, clicks, cfg))(state)
-    a = jax.jit(lambda st: cache_push(st, rows, grads, shows, clicks, cfg_ref))(state)
-    for k in a:
-        np.testing.assert_allclose(np.asarray(b[k]), np.asarray(a[k]),
-                                   rtol=1e-6, atol=1e-7, err_msg=k)
-
-
 @pytest.mark.parametrize("create_applies_grad", [True, False])
-@pytest.mark.parametrize("embed_rule,embedx_rule",
-                         [(r, r) for r in RULES] + [("adagrad", "adam"),
-                                                    ("naive", "std_adagrad")])
+@pytest.mark.parametrize("embed_rule,embedx_rule", PAIRS)
 def test_dense_push_matches_sparse(rng, embed_rule, embedx_rule,
                                    create_applies_grad):
     """push_mode="dense" (scatter-add + masked full-table update — the
@@ -178,8 +267,7 @@ def test_dense_push_matches_sparse(rng, embed_rule, embedx_rule,
 
     kw = dict(capacity=C, embedx_dim=dim, embedx_threshold=3.0,
               embed_rule=embed_rule, embedx_rule=embedx_rule,
-              create_applies_grad=create_applies_grad,
-              pallas_update=False)
+              create_applies_grad=create_applies_grad)
     cfg_s = CacheConfig(push_mode="sparse", **kw)
     cfg_d = CacheConfig(push_mode="dense", **kw)
     a = jax.jit(lambda st: cache_push(st, rows, grads, shows, clicks, cfg_s))(state)

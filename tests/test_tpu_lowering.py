@@ -3,12 +3,12 @@
 ``jax.experimental.topologies.get_topology_desc(platform="tpu",
 topology_name="v5e:2x2")`` hands back four ``TPU v5 lite`` devices that
 need no hardware, and ``jit(f).lower(<ShapeDtypeStruct on them>).compile()``
-runs XLA:TPU and the real Mosaic compiler. So every Pallas entry point is
-compiled here with ``interpret=False`` on every PR (tier-1, ~1 s each): a
-kernel Mosaic refuses fails without spending chip time. The three
-hot-tier kernels ARE refused today; their tests pin the compiler's words
-(strict xfail) and are the reason ``HotTierConfig.kernels="auto"`` means
-jnp — a rewrite that compiles turns them into failures that say so.
+runs XLA:TPU and the real Mosaic compiler. So every Pallas entry point
+(the three flash-attention kernels) is compiled here with
+``interpret=False`` on every PR (tier-1, ~1 s each): a kernel Mosaic
+refuses fails without spending chip time. The sparse path has no kernel
+(``PERF.md`` section 6, PR 28); its steps are compiled here as XLA:TPU
+makes them, for every rule.
 
 Marked ``slow``: the four steps ``chip_smoke.py`` runs, at its widths,
 with ``jax.default_backend`` patched to "tpu" so every ``auto`` switch
@@ -26,10 +26,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 pytest.importorskip("libtpu", reason="compiling for TPU needs libtpu")
 
-from paddle_tpu.ops import hot_kernels  # noqa: E402
 from paddle_tpu.ops.flash_attention import flash_attention  # noqa: E402
-from paddle_tpu.ops.sparse_optimizer import (ctr_sparse_rows,  # noqa: E402
-                                             rule_state_dim)
+from paddle_tpu.ops.sparse_optimizer import rule_state_dim  # noqa: E402
 from paddle_tpu.ps.embedding_cache import CacheConfig  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -66,6 +64,15 @@ def _rng_key(sharding=None):
 
 def _z(*shape, dtype=jnp.float32):
     return np.zeros(shape, dtype)
+
+
+def _rows(C, xd, rule="adagrad"):
+    """The seven columns of a cache or tier of ``C`` rows under ``rule``."""
+    return {"show": _z(C), "click": _z(C), "embed_w": _z(C, 1),
+            "embed_state": _z(C, rule_state_dim(rule, 1)),
+            "embedx_w": _z(C, xd),
+            "embedx_state": _z(C, rule_state_dim(rule, xd)),
+            "has_embedx": _z(C)}
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -208,67 +215,6 @@ def test_olmoe_cell_step_compiles(v5e, as_tpu):
     assert live < 14.5 * 2**30
 
 
-@pytest.mark.parametrize("rule", ["naive", "adagrad", "std_adagrad", "adam"])
-def test_ctr_sparse_rows_compiles(v5e, rule):
-    n, dim = 2048, 8
-    es, xs = rule_state_dim(rule, 1), rule_state_dim(rule, dim)
-
-    def update(*cols):
-        return ctr_sparse_rows(
-            cols[:7], *cols[7:], embed_rule=rule, embedx_rule=rule, lr=0.05,
-            initial_g2sum=3.0, weight_bounds=(-10.0, 10.0), beta1=0.9,
-            beta2=0.999, eps=1e-8, nonclk_coeff=0.1, click_coeff=1.0,
-            embedx_threshold=0.0, interpret=False)
-
-    hlo = _compile(update, SingleDeviceSharding(v5e[0]),
-                   _z(n), _z(n), _z(n, 1), _z(n, es), _z(n, dim), _z(n, xs),
-                   _z(n), _z(n), _z(n), _z(n, 1), _z(n, dim)).as_text()
-    assert "tpu_custom_call" in hlo
-
-
-def _hot_args(C=4096, n=512, nb=1024):
-    u32 = lambda *s: _z(*s, dtype=jnp.uint32)
-    map_state = {"hi": u32(nb, 8), "lo": u32(nb, 8),
-                 "row": _z(nb, 8, dtype=jnp.int32), "seed": np.uint32(1)}
-    tier = {"show": _z(C), "click": _z(C), "embed_w": _z(C, 1),
-            "embed_state": _z(C, 1), "embedx_w": _z(C, 8),
-            "embedx_state": _z(C, 1), "has_embedx": _z(C)}
-    return map_state, tier, u32(n), n, C
-
-
-@pytest.mark.xfail(strict=True, raises=ValueError,
-                   reason="Mosaic: in-kernel jnp.take row gather — 'Shape "
-                          "mismatch in input, indices and output'")
-def test_hot_probe_gather_compiles(v5e):
-    map_state, tier, keys, _, _ = _hot_args()
-    _compile(lambda m, hi, lo, t: hot_kernels.hot_probe_gather(
-        m, hi, lo, t, probe_buckets=2, interpret=False),
-        SingleDeviceSharding(v5e[0]), map_state, keys, keys, tier)
-
-
-@pytest.mark.xfail(strict=True, raises=ValueError,
-                   reason="Mosaic: in-kernel jnp.take row gather — 'Shape "
-                          "mismatch in input, indices and output'")
-def test_hot_probe_compiles(v5e):
-    map_state, _, keys, _, _ = _hot_args()
-    _compile(lambda m, hi, lo: hot_kernels.hot_probe(
-        m, hi, lo, probe_buckets=2, interpret=False),
-        SingleDeviceSharding(v5e[0]), map_state, keys, keys)
-
-
-@pytest.mark.xfail(strict=True, raises=Exception,
-                   reason="Mosaic: scalar rows_ref[i] read from a VMEM vector "
-                          "— 'cannot statically prove that index in "
-                          "dimension 0 is a multiple of …'")
-def test_hot_scatter_apply_compiles(v5e):
-    _, tier, _, n, C = _hot_args()
-    cfg = CacheConfig(capacity=C, embedx_dim=8, embedx_threshold=0.0)
-    _compile(lambda t, r, g, s, c: hot_kernels.hot_scatter_apply(
-        t, r, g, s, c, cfg, interpret=False),
-        SingleDeviceSharding(v5e[0]), tier, _z(n, dtype=jnp.int32),
-        _z(n, 9), _z(n), _z(n))
-
-
 # ---------------------------------------------------------------------------
 # the steps chip_smoke.py runs, at its widths, as the chip compiles them
 # ---------------------------------------------------------------------------
@@ -297,12 +243,9 @@ def _ctr_state(n_keys):
     nb = 64
     while nb * 4 < 2 * n_keys:
         nb <<= 1
-    cache = {"show": _z(C), "click": _z(C), "embed_w": _z(C, 1),
-             "embed_state": _z(C, 1), "embedx_w": _z(C, xd),
-             "embedx_state": _z(C, 1), "has_embedx": _z(C)}
     cmap = {"hi": _z(nb, 4, dtype=jnp.uint32), "lo": _z(nb, 4, dtype=jnp.uint32),
             "row": _z(nb, 4, dtype=jnp.int32), "seed": np.uint32(0)}
-    return cache, cmap
+    return _rows(C, xd), cmap
 
 
 @pytest.mark.slow
@@ -330,14 +273,14 @@ def test_stream_step_compiles(v5e, as_tpu):
     dmap = DynamicDeviceKeyMap(SZ.capacity)
     step = make_hot_ctr_train_step(
         model, opt, cfg, slot_ids=np.arange(SZ.slots),
-        probe_buckets=dmap.probe_buckets, banks=dmap.banks)   # kernels="auto"
+        probe_buckets=dmap.probe_buckets, banks=dmap.banks)
     tier, _ = _ctr_state(1)
     hlo = _compile(step, SingleDeviceSharding(v5e[0]), params, opt.init(params),
                    tier, dmap.device_state(),
                    _z(SZ.batch, SZ.slots, dtype=jnp.uint32),
                    _z(SZ.batch, SZ.dense), _z(SZ.batch, dtype=jnp.int32)
                    ).as_text()
-    assert "tpu_custom_call" not in hlo   # auto = the jnp formulation
+    assert "tpu_custom_call" not in hlo   # the sparse path has no kernel
 
 
 @pytest.mark.slow
@@ -414,21 +357,22 @@ def test_hybrid_step_compiles(v5e, as_tpu):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("pallas", [None, True])
-def test_slab_step_on_the_touched_side_compiles(v5e, as_tpu, pallas):
-    """A slab step whose table dwarfs its batch (``auto`` → touched rows):
-    no operand has the sweep's accumulator in it (leading dimension C+1),
-    the scatters were told their indices are sorted and unique (XLA:TPU
-    sorts them itself otherwise: the only sort left is the dedup's), the
-    rule is jnp unless the kernel is asked for, and then Mosaic accepts
-    it at the chunk's shape."""
+RULES = ["naive", "adagrad", "std_adagrad", "adam"]
+
+
+@pytest.mark.parametrize("rule", RULES)
+def test_slab_step_on_the_touched_side_compiles(v5e, as_tpu, rule):
+    """A slab step whose table dwarfs its batch (``auto`` → touched rows),
+    for every rule (``naive``'s state columns are zero wide): no operand
+    has the sweep's accumulator in it (leading dimension C+1), the
+    scatters were told their indices are sorted and unique (XLA:TPU
+    sorts them itself otherwise: the only sort left is the dedup's), and
+    the rule is XLA's, not a kernel."""
     import re
 
     from paddle_tpu import optimizer
     from paddle_tpu.models.ctr import _packed_layout, make_ctr_train_step_slab
-    from paddle_tpu.ps.embedding_cache import resolve_push_mode
-
-    from paddle_tpu.ps.embedding_cache import PUSH_CHUNK
+    from paddle_tpu.ps.embedding_cache import PUSH_CHUNK, resolve_push_mode
 
     sz = chip_smoke.Sizes(tower=(32, 32), batch=512, slab=2,
                           capacity=1 << 21)
@@ -439,14 +383,11 @@ def test_slab_step_on_the_touched_side_compiles(v5e, as_tpu, pallas):
     opt = optimizer.Adam(learning_rate=1e-3)
     params = {"params": dict(model.named_parameters()), "buffers": {}}
     cfg = chip_smoke._cache_cfg(sz)
-    cfg.pallas_update = pallas
+    cfg.embed_rule = cfg.embedx_rule = rule
     step = make_ctr_train_step_slab(
         model, opt, cfg, slot_ids=np.arange(sz.slots), batch_size=sz.batch,
         num_dense=sz.dense, slab=sz.slab, with_weights=True, amp=True)
-    xd = sz.embedx_dim
-    cache = {"show": _z(C), "click": _z(C), "embed_w": _z(C, 1),
-             "embed_state": _z(C, 1), "embedx_w": _z(C, xd),
-             "embedx_state": _z(C, 1), "has_embedx": _z(C)}
+    cache = _rows(C, sz.embedx_dim, rule)
     nb = 1 << 18
     cmap = {"hi": _z(nb, 4, dtype=jnp.uint32), "lo": _z(nb, 4, dtype=jnp.uint32),
             "row": _z(nb, 4, dtype=jnp.int32), "seed": np.uint32(0)}
@@ -457,4 +398,35 @@ def test_slab_step_on_the_touched_side_compiles(v5e, as_tpu, pallas):
     assert f"[{C + 1}," not in hlo and f"[{C + 1}]" not in hlo
     sorts = re.findall(r"= \([^=]*\) sort\(.*?op_name=\"([^\"]*)\"", hlo)
     assert sorts and all("pt.push.accumulate" in s for s in sorts), sorts
-    assert ("tpu_custom_call" in hlo) == bool(pallas)
+    assert "tpu_custom_call" not in hlo
+
+
+def test_sharded_hot_step_compiles(v5e, as_tpu):
+    """The hot tier's mesh step on the 2x2: each chip probes its slice
+    of the batch against the replicated banked map, the rows ride the
+    all_to_all exchange, the owner pushes into its bank block."""
+    from jax.sharding import Mesh
+
+    from paddle_tpu import optimizer
+    from paddle_tpu.ps.device_hash import DynamicDeviceKeyMap
+    from paddle_tpu.ps.hot_tier import make_sharded_hot_train_step
+
+    sz = chip_smoke.Sizes(tower=(32, 32), batch=512, capacity=1 << 21)
+    mesh = Mesh(np.asarray(v5e), ("ps",))
+    model = chip_smoke._deepfm(sz)
+    opt = optimizer.Adam(learning_rate=1e-3)
+    params = {"params": dict(model.named_parameters()), "buffers": {}}
+    dmap = DynamicDeviceKeyMap(sz.capacity, banks=4)
+    step = make_sharded_hot_train_step(
+        model, opt, chip_smoke._cache_cfg(sz), mesh,
+        slot_ids=np.arange(sz.slots), axis="ps",
+        probe_buckets=dmap.probe_buckets, banks=dmap.banks)
+    rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P("ps"))
+    batch = (_z(sz.batch, sz.slots, dtype=jnp.uint32),
+             _z(sz.batch, sz.dense), _z(sz.batch, dtype=jnp.int32))
+    hlo = step.lower(
+        *_shapes((params, opt.init(params)), rep),
+        _shapes(_rows(sz.capacity, sz.embedx_dim), row),
+        _shapes(dmap.device_state(), rep), *_shapes(batch, row)
+    ).compile().as_text()
+    assert "all-to-all" in hlo and "tpu_custom_call" not in hlo

@@ -13,10 +13,10 @@ same seeded dispatches, and holds the two to each other: the same
 through the touched rows, whole tables compared on the host — show, click
 and ``has_embedx`` equal, weights and optimizer state by their largest
 absolute difference, rows no dispatch named bit-equal to the table they
-started from. ``--profile C,n`` also, at that shape: the touched rows with
-the Pallas rule kernel against the jnp rule, each traced for a few pushes
-with the device's operations listed by time, and the touched rows at
-other chunk lengths (``--chunks``, 0 = the batch in one piece).
+started from. ``--profile C,n`` also, at that shape: the touched rows
+traced for a few pushes with the device's operations listed by time, and
+the touched rows at other chunk lengths (``--chunks``, 0 = the batch in
+one piece).
 
 Rows are drawn as the benchmark's cells see them (``draw_batches``):
 n = 106,496 is a 4096 x 26 batch, Zipf 1.05 a slot over a half-full
@@ -124,13 +124,13 @@ def draw_batches(C: int, n: int, k: int, seed: int, distinct: bool = False):
     return out
 
 
-def pusher(C: int, mode: str, pallas):
+def pusher(C: int, mode: str):
     import jax
 
     from paddle_tpu.ps.embedding_cache import CacheConfig, cache_push
 
     cfg = CacheConfig(capacity=C, embedx_dim=DIM, embedx_threshold=0.0,
-                      push_mode=mode, pallas_update=pallas)
+                      push_mode=mode)
     return jax.jit(lambda st, r, g, s, c: cache_push(st, r, g, s, c, cfg),
                    donate_argnums=0)
 
@@ -259,7 +259,7 @@ def main() -> int:
         C = 1 << lg
         for n in (int(x) for x in args.slots.split(",")):
             batches = draw(lg, n)
-            sweep, touched = pusher(C, "dense", None), pusher(C, "sparse", None)
+            sweep, touched = pusher(C, "dense"), pusher(C, "sparse")
             distinct = [np.unique(b[0][b[0] < C]).size for b in batches]
             line = {"capacity": C, "slots": n, "rows_per_slot": C / n,
                     "distinct_rows": sum(distinct) / len(distinct),
@@ -274,15 +274,14 @@ def main() -> int:
         C, batches = 1 << lg, draw(lg, n)
         trace_dir = os.path.join(os.path.dirname(args.out), "push_trace")
         prof = result["profile"] = {"capacity": C, "slots": n, "chunks_ms": {}}
-        for pallas, name in ((True, "pallas"), (False, "jnp")):
-            push = pusher(C, "sparse", pallas)
-            prof[name] = dict(profile_ops(push, C, batches, trace_dir),
-                              ms=time_push(push, C, batches, args.iters))
+        push = pusher(C, "sparse")
+        prof["touched"] = dict(profile_ops(push, C, batches, trace_dir),
+                               ms=time_push(push, C, batches, args.iters))
         default = ec.PUSH_CHUNK
         for chunk in (int(x) for x in args.chunks.split(",")):
             ec.PUSH_CHUNK = chunk or n
             prof["chunks_ms"][chunk] = time_push(
-                pusher(C, "sparse", None), C, batches, args.iters)
+                pusher(C, "sparse"), C, batches, args.iters)
         ec.PUSH_CHUNK = default
         shutil.rmtree(trace_dir, ignore_errors=True)
         print(json.dumps(prof), flush=True)

@@ -29,8 +29,7 @@ counter), and the tier's hit-rate/occupancy stats. The headline
 
 Standalone: prints exactly ONE JSON line (driver contract). Importable:
 ``run()`` returns the record. Env knobs: SHB_BATCH, SHB_SAMPLES,
-SHB_NID, SHB_CAPACITY, SHB_SLOTS, SHB_SHARDED (0 skips the rung),
-SHB_KERNELS (hot-tier kernels knob: auto|pallas|jnp).
+SHB_NID, SHB_CAPACITY, SHB_SLOTS, SHB_SHARDED (0 skips the rung).
 """
 
 import json
@@ -50,7 +49,6 @@ def _params():
         "n_samples": int(os.environ.get("SHB_SAMPLES", 4096)),
         "nid": int(os.environ.get("SHB_NID", 1500)),
         "capacity": int(os.environ.get("SHB_CAPACITY", 1 << 14)),
-        "kernels": os.environ.get("SHB_KERNELS", "auto"),
     }
 
 
@@ -132,7 +130,6 @@ def _measure(p, ds, hot):
             rec["evictions"] = st["evictions"]
             rec["shards"] = st["shards"]
             rec["banks"] = st["banks"]
-            rec["kernels"] = st["kernels"]
         return rec
     finally:
         client.close()
@@ -168,13 +165,12 @@ def _exchange_bytes(p, mesh, routing):
     opt = optimizer.Adam(1e-2)
     table = MemorySparseTable(TableConfig(shard_num=4, accessor="ctr"))
     tier = HotEmbeddingTier(table, HotTierConfig(
-        capacity=p["capacity"], mesh=mesh, axis="ps", routing=routing,
-        kernels=p["kernels"]))
+        capacity=p["capacity"], mesh=mesh, axis="ps", routing=routing))
     step = make_sharded_hot_train_step(
         model, opt, tier.cache_config, mesh,
         slot_ids=np.arange(S), axis="ps", routing=routing, donate=False,
         probe_buckets=tier.device_map.probe_buckets,
-        banks=tier.device_map.banks, kernels=p["kernels"])
+        banks=tier.device_map.banks)
     params = {"params": dict(model.named_parameters()), "buffers": {}}
     opt_state = opt.init(params)
     lo32 = jnp.zeros((batch, S), jnp.uint32)
@@ -206,7 +202,7 @@ def _run_sharded(p):
     mesh = mesh_mod.make_mesh({"ps": 8})
     ds = _dataset(p)
     rec = _measure(p, ds, HotTierConfig(capacity=p["capacity"], mesh=mesh,
-                                        axis="ps", kernels=p["kernels"]))
+                                        axis="ps"))
     routed = _exchange_bytes(p, mesh, "alltoall")
     gathered = _exchange_bytes(p, mesh, "allgather")
     rec["exchange"] = {
@@ -266,8 +262,7 @@ def run() -> dict:
     p = _params()
     ds = _dataset(p)
     rpc_only = _measure(p, ds, None)
-    hot = _measure(p, ds, HotTierConfig(capacity=p["capacity"],
-                                        kernels=p["kernels"]))
+    hot = _measure(p, ds, HotTierConfig(capacity=p["capacity"]))
     sharded = _sharded_rung(p)
 
     out = {
