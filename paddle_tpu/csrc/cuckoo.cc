@@ -8,13 +8,17 @@
 // per pass (this file; the HeterComm build_ps bulk-insert analogue) into
 // flat arrays the Python layer uploads to HBM, and the per-batch probe
 // runs inside the compiled step (ps/device_hash.py) as two fixed bucket
-// gathers — bounded, branch-free, XLA-friendly.
+// probes — bounded, branch-free, XLA-friendly.
 //
-// Layout: nbuckets (power of two) buckets x 4 slots, SoA (hi, lo, row);
-// empty slots have row == -1. Two hash functions pick candidate buckets;
-// insertion uses random-walk eviction. Load factor <= 0.5 by
-// construction (python chooses nbuckets), so builds virtually never fail;
-// on failure the caller retries with a fresh seed.
+// Layout: nbuckets (power of two) buckets x 4 slots, two arrays. `key`
+// is u32[nbuckets, 8]: a bucket's row holds its four keys as halves,
+// [hi0 hi1 hi2 hi3 | lo0 lo1 lo2 lo3], so ONE row gather fetches every
+// key of the bucket (a gather of <= 8 columns costs the chip the same as
+// one of 4: it is paid by the index). `row` is i32[nbuckets, 4]. Empty
+// slots have row == -1 and zero key words. Two hash functions pick
+// candidate buckets; insertion uses random-walk eviction. Load factor
+// <= 0.5 by construction (python chooses nbuckets), so builds virtually
+// never fail; on failure the caller retries with a fresh seed.
 //
 // The 32-bit mixer below must match _mix32 in ps/device_hash.py
 // bit-for-bit — the device probe recomputes these hashes with jnp uint32
@@ -34,6 +38,7 @@
 namespace {
 
 constexpr int kSlots = 4;
+constexpr int kKeyWords = 2 * kSlots;  // a bucket's row of `key`: hi x4 | lo x4
 constexpr int kMaxKicks = 512;
 
 inline uint32_t mix32(uint32_t hi, uint32_t lo, uint32_t seed) {
@@ -53,13 +58,13 @@ extern "C" {
 
 // Build the table. Returns 0 on success, or the number of keys that could
 // not be placed (caller retries with a different seed). Buffers:
-//   out_hi, out_lo: nbuckets*4 uint32;  out_row: nbuckets*4 int32.
+//   out_key: nbuckets*8 uint32 (per bucket hi x4 | lo x4);
+//   out_row: nbuckets*4 int32.
 int64_t cuckoo_build(const uint64_t* keys, const int32_t* rows, int64_t n,
-                     int64_t nbuckets, uint32_t seed, uint32_t* out_hi,
-                     uint32_t* out_lo, int32_t* out_row) {
+                     int64_t nbuckets, uint32_t seed, uint32_t* out_key,
+                     int32_t* out_row) {
   const uint64_t mask = static_cast<uint64_t>(nbuckets) - 1;
-  std::memset(out_hi, 0, sizeof(uint32_t) * nbuckets * kSlots);
-  std::memset(out_lo, 0, sizeof(uint32_t) * nbuckets * kSlots);
+  std::memset(out_key, 0, sizeof(uint32_t) * nbuckets * kKeyWords);
   std::memset(out_row, 0xff, sizeof(int32_t) * nbuckets * kSlots);  // -1
 
   std::mt19937 rng(seed ^ 0x9e3779b9u);
@@ -77,8 +82,9 @@ int64_t cuckoo_build(const uint64_t* keys, const int32_t* rows, int64_t n,
         for (int s = 0; s < kSlots; ++s) {
           int64_t idx = static_cast<int64_t>(b) * kSlots + s;
           if (out_row[idx] < 0) {
-            out_hi[idx] = hi;
-            out_lo[idx] = lo;
+            uint32_t* k = out_key + static_cast<int64_t>(b) * kKeyWords + s;
+            k[0] = hi;
+            k[kSlots] = lo;
             out_row[idx] = row;
             placed = true;
             break;
@@ -91,10 +97,11 @@ int64_t cuckoo_build(const uint64_t* keys, const int32_t* rows, int64_t n,
         uint64_t b = (rng() & 1) ? b1 : b2;
         int s = static_cast<int>(rng() % kSlots);
         int64_t idx = static_cast<int64_t>(b) * kSlots + s;
-        uint32_t ehi = out_hi[idx], elo = out_lo[idx];
+        uint32_t* k = out_key + static_cast<int64_t>(b) * kKeyWords + s;
+        uint32_t ehi = k[0], elo = k[kSlots];
         int32_t erow = out_row[idx];
-        out_hi[idx] = hi;
-        out_lo[idx] = lo;
+        k[0] = hi;
+        k[kSlots] = lo;
         out_row[idx] = row;
         hi = ehi;
         lo = elo;

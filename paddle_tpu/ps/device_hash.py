@@ -12,9 +12,13 @@ architecture on TPU.
 The table is a static bucketized cuckoo hash (2 hash functions × 4-slot
 buckets, load ≤ ~0.5) BUILT on host once per pass (csrc/cuckoo.cc — the
 HeterComm build_ps bulk-insert analogue) and probed in-graph with two
-fixed bucket gathers + compares: branch-free, bounded, fuses into the
+fixed bucket probes + compares: branch-free, bounded, fuses into the
 train step. Keys are uint64 split into (hi, lo) uint32 halves — TPUs
-have no native 64-bit int path, and x64 mode stays off.
+have no native 64-bit int path, and x64 mode stays off. The map is two
+arrays: ``key`` u32[nbuckets, 8], a bucket's four hi halves then its
+four lo halves in ONE row, and ``row`` i32[nbuckets, 4] — so a probe is
+two row gathers a hash, four a step (the chip pays a gather of ≤ 8
+columns by the index, not by the byte: PERF.md §5).
 
 The 32-bit mixer must match ``mix32`` in csrc/cuckoo.cc bit-for-bit.
 """
@@ -59,8 +63,9 @@ def device_hash_lookup(table: Dict[str, jax.Array], keys_hi: jax.Array,
                        keys_lo: jax.Array) -> jax.Array:
     """In-graph probe: [n] int32 rows (−1 = missing) for (hi, lo) keys.
 
-    Two bucket-ROW gathers (HashTable::get analogue): the table arrays
-    are [nbuckets, 4], so each probe gathers whole buckets — the same
+    Two hashes × two bucket-ROW gathers (HashTable::get analogue): per
+    hash one row of ``key`` ([n, 8]: the bucket's four hi halves, then
+    its four lo) and one of ``row`` ([n, 4]) — whole buckets, the same
     efficient row-gather pattern as the embedding pull. (1-D scalar
     gathers lower to a pathological path on TPU; never probe slot-wise.)
     """
@@ -73,10 +78,10 @@ def device_hash_lookup(table: Dict[str, jax.Array], keys_hi: jax.Array,
         for which in (0, 1):
             s = seed if which == 0 else seed ^ _SEED2_XOR
             b = (_mix32(hi, lo, s) & mask).astype(jnp.int32)
-            bh = jnp.take(table["hi"], b, axis=0)    # [n, 4]
-            bl = jnp.take(table["lo"], b, axis=0)
-            br = jnp.take(table["row"], b, axis=0)
-            match = (bh == hi[:, None]) & (bl == lo[:, None]) & (br >= 0)
+            bk = jnp.take(table["key"], b, axis=0)   # [n, 8]: hi×4 | lo×4
+            br = jnp.take(table["row"], b, axis=0)   # [n, 4]
+            match = ((bk[:, :_SLOTS] == hi[:, None])
+                     & (bk[:, _SLOTS:] == lo[:, None]) & (br >= 0))
             hit = jnp.max(jnp.where(match, br, -1), axis=1)
             found = jnp.where(hit >= 0, hit, found)
         return found
@@ -109,14 +114,13 @@ class DeviceKeyMap:
         last_err: Optional[Exception] = None
         for seed in (0x1234ABCD, 0x9E3779B9, 0xDEADBEEF, 0x2545F491):
             try:
-                hi, lo, row = cuckoo_build(keys, rows, nb, seed)
+                key, row = cuckoo_build(keys, rows, nb, seed)
                 break
             except RuntimeError as e:  # placement failure: retry new seed
                 last_err = e
         else:
             raise RuntimeError(f"cuckoo build failed for {n} keys: {last_err}")
-        return {"hi": hi.reshape(nb, 4), "lo": lo.reshape(nb, 4),
-                "row": row.reshape(nb, 4), "seed": np.uint32(seed), "nb": nb}
+        return {"key": key, "row": row, "seed": np.uint32(seed), "nb": nb}
 
     def __init__(self, keys: Optional[np.ndarray] = None,
                  rows: Optional[np.ndarray] = None,
@@ -131,8 +135,7 @@ class DeviceKeyMap:
         put = (lambda a: jax.device_put(a, sharding)) if sharding is not None \
             else jnp.asarray
         self.state: Dict[str, jax.Array] = {
-            "hi": put(built["hi"]),
-            "lo": put(built["lo"]),
+            "key": put(built["key"]),
             "row": put(built["row"]),
             "seed": jnp.asarray(built["seed"]),
         }

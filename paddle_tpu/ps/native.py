@@ -91,11 +91,13 @@ def native_available() -> bool:
 
 
 def cuckoo_build(keys: np.ndarray, rows: np.ndarray, nbuckets: int,
-                 seed: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+                 seed: int) -> Tuple[np.ndarray, np.ndarray]:
     """Build a static bucketized-cuckoo table (csrc/cuckoo.cc) mapping
-    uint64 feasign → int32 row; returns (hi, lo, row) arrays of shape
-    [nbuckets*4] for upload to HBM (ps/device_hash.py probes them
-    in-graph). Raises RuntimeError if the native lib is unavailable or
+    uint64 feasign → int32 row; returns (key u32[nbuckets, 8], row
+    i32[nbuckets, 4]) for upload to HBM (ps/device_hash.py probes them
+    in-graph). A bucket's row of ``key`` is its four hi halves then its
+    four lo halves — the builder writes that layout itself, no host pass
+    after it. Raises RuntimeError if the native lib is unavailable or
     the build fails (caller retries with a new seed)."""
     lib = load_native()
     if lib is None:
@@ -107,20 +109,19 @@ def cuckoo_build(keys: np.ndarray, rows: np.ndarray, nbuckets: int,
         lib.cuckoo_build.restype = ctypes.c_int64
         lib.cuckoo_build.argtypes = [u64p, i32p, ctypes.c_int64,
                                      ctypes.c_int64, ctypes.c_uint32,
-                                     u32p, u32p, i32p]
+                                     u32p, i32p]
         lib._cuckoo_configured = True
     keys = np.ascontiguousarray(keys, np.uint64)
     rows = np.ascontiguousarray(rows, np.int32)
-    hi = np.empty(nbuckets * 4, np.uint32)
-    lo = np.empty(nbuckets * 4, np.uint32)
-    row = np.empty(nbuckets * 4, np.int32)
+    key = np.empty((nbuckets, 8), np.uint32)
+    row = np.empty((nbuckets, 4), np.int32)
     u32p = ctypes.POINTER(ctypes.c_uint32)
     fails = int(lib.cuckoo_build(
         _u64(keys), _i32(rows), len(keys), nbuckets, ctypes.c_uint32(seed),
-        hi.ctypes.data_as(u32p), lo.ctypes.data_as(u32p), _i32(row)))
+        key.ctypes.data_as(u32p), _i32(row)))
     if fails:
         raise RuntimeError(f"cuckoo build failed to place {fails} keys")
-    return hi, lo, row
+    return key, row
 
 
 def table_native_params(shard_num: int, accessor: str, acc_cfg,

@@ -3,6 +3,7 @@
 in-graph; and the key-fed CTR step that fuses the probe into the program.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -12,8 +13,10 @@ from paddle_tpu import optimizer
 from paddle_tpu.models.ctr import (CtrConfig, DeepFM, make_ctr_train_step,
                                    make_ctr_train_step_from_keys)
 from paddle_tpu.ps.accessor import AccessorConfig
-from paddle_tpu.ps.device_hash import (DeviceKeyMap, DynamicDeviceKeyMap,
-                                       dynamic_map_lookup, split_keys)
+from paddle_tpu.ps.device_hash import (_SEED2_XOR, DeviceKeyMap,
+                                       DynamicDeviceKeyMap, _mix32_np,
+                                       device_hash_lookup, dynamic_map_lookup,
+                                       split_keys)
 from paddle_tpu.ps.embedding_cache import CacheConfig, HbmEmbeddingCache
 from paddle_tpu.ps.table import MemorySparseTable, TableConfig
 
@@ -40,6 +43,129 @@ def test_device_map_low_bit_keys(rng):
     m = DeviceKeyMap(keys, rows)
     got = np.asarray(m.lookup(*[jnp.asarray(a) for a in split_keys(keys)]))
     np.testing.assert_array_equal(got, rows)
+
+
+# ---------------------------------------------------------------------------
+# the packed map: a bucket's four keys in ONE row of ``key`` (hi×4 | lo×4),
+# its rows in ``row`` — four bucket gathers a probe
+# ---------------------------------------------------------------------------
+
+
+def _probe(m, keys):
+    return np.asarray(m.lookup(*[jnp.asarray(a) for a in split_keys(keys)]))
+
+
+def _host_probe(state, keys):
+    """The probe spelled out over the host copy of the map, one slot at a
+    time (both candidate buckets, hi and lo compared apart, ``row >= 0``
+    guarding empty slots)."""
+    key, row = np.asarray(state["key"]), np.asarray(state["row"])
+    seed = int(state["seed"])
+    hi, lo = split_keys(keys)
+    found = np.full(len(keys), -1, np.int32)
+    for s in (seed, seed ^ int(_SEED2_XOR)):
+        b = _mix32_np(hi, lo, s) & np.uint32(len(row) - 1)
+        for slot in range(4):
+            hit = ((key[b, slot] == hi) & (key[b, 4 + slot] == lo)
+                   & (row[b, slot] >= 0))
+            found = np.where(hit, row[b, slot], found)
+    return found
+
+
+def _packed_case(name, rng):
+    """(keys in the map, keys to probe) of one parity case."""
+    base = np.unique(rng.integers(1, 1 << 63, size=3000, dtype=np.uint64))
+    if name == "random":
+        return base, np.concatenate([
+            base[rng.integers(0, len(base), 2000)],
+            rng.integers(1, 1 << 63, size=500, dtype=np.uint64)])
+    if name == "differ_only_in_hi":
+        # one lo half under many hi halves, half of them in the map
+        lo = np.uint64(0x9ABCDEF1)
+        pairs = (np.arange(1, 801, dtype=np.uint64) << np.uint64(32)) | lo
+        return pairs[::2], pairs
+    if name == "differ_only_in_lo":
+        hi = np.uint64(0x1234567) << np.uint64(32)
+        pairs = hi | np.arange(1, 801, dtype=np.uint64)
+        return pairs[::2], pairs
+    if name == "halves_swapped":
+        # (hi, lo) in the map, (lo, hi) probed: the halves must not be
+        # compared against each other's words
+        hi, lo = split_keys(base)
+        swapped = (lo.astype(np.uint64) << np.uint64(32)) | hi
+        return base, np.concatenate([base, swapped])
+    if name == "absent":
+        return base, rng.integers(1, 1 << 63, size=2000, dtype=np.uint64)
+    if name == "zero_absent":
+        # empty slots hold zero key words: key 0 must still read -1
+        return base, np.zeros(64, np.uint64)
+    if name == "zero_present":
+        return np.concatenate([np.zeros(1, np.uint64), base]), \
+            np.concatenate([np.zeros(3, np.uint64), base[:100]])
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("case", [
+    "random", "differ_only_in_hi", "differ_only_in_lo", "halves_swapped",
+    "absent", "zero_absent", "zero_present"])
+def test_packed_probe_matches_host_dict(case, rng):
+    """Every key the map holds reads the row it was given, every other
+    key -1 — against a host dict, and bit for bit against the probe
+    spelled out slot by slot over the host copy of the arrays."""
+    keys, probe = _packed_case(case, rng)
+    rows = rng.permutation(len(keys)).astype(np.int32)
+    m = DeviceKeyMap(keys, rows)
+    assert (np.asarray(m.state["row"]) == -1).any()      # empty slots exist
+    want = dict(zip(keys.tolist(), rows.tolist()))
+    got = _probe(m, probe)
+    np.testing.assert_array_equal(
+        got, np.asarray([want.get(k, -1) for k in probe.tolist()], np.int32))
+    np.testing.assert_array_equal(got, _host_probe(m.state, probe))
+
+
+def test_packed_map_layout():
+    """``state`` is {key u32[nb, 8], row i32[nb, 4], seed}: a bucket's
+    row of ``key`` is its four hi halves then its four lo halves, slot
+    for slot with ``row``; empty slots are row -1 over zero key words."""
+    rng = np.random.default_rng(3)
+    keys = np.unique(rng.integers(0, 1 << 63, size=700, dtype=np.uint64))
+    rows = np.arange(len(keys), dtype=np.int32)
+    m = DeviceKeyMap(keys, rows)
+    assert sorted(m.state) == ["key", "row", "seed"]
+    key, row = np.asarray(m.state["key"]), np.asarray(m.state["row"])
+    nb = m.nbuckets
+    assert key.shape == (nb, 8) and key.dtype == np.uint32
+    assert row.shape == (nb, 4) and row.dtype == np.int32
+    live = row >= 0
+    stored = (key[:, :4].astype(np.uint64) << np.uint64(32)) | key[:, 4:]
+    np.testing.assert_array_equal(np.sort(stored[live]), keys)
+    np.testing.assert_array_equal(stored[live][np.argsort(row[live])], keys)
+    assert (row[~live] == -1).all() and (stored[~live] == 0).all()
+
+
+def test_probe_is_four_gathers():
+    """Two hashes × (one row of ``key`` + one of ``row``): the jaxpr and
+    the lowered module of ``device_hash_lookup`` hold exactly four
+    gathers, two 8 wide and two 4 wide (a count: CPU)."""
+    nb, n = 256, 96
+    table = {"key": jnp.zeros((nb, 8), jnp.uint32),
+             "row": jnp.full((nb, 4), -1, jnp.int32),
+             "seed": jnp.uint32(7)}
+    k = jnp.zeros((n,), jnp.uint32)
+    jaxpr = jax.make_jaxpr(device_hash_lookup)(table, k, k)
+
+    def gathers(jp):
+        out = []
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "gather":
+                out.append(eqn.outvars[0].aval.shape)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                out += gathers(sub)
+        return out
+
+    assert sorted(gathers(jaxpr.jaxpr)) == [(n, 4), (n, 4), (n, 8), (n, 8)]
+    lowered = jax.jit(device_hash_lookup).lower(table, k, k).as_text()
+    assert lowered.count("stablehlo.gather") == 4, lowered
 
 
 def test_key_fed_step_matches_row_fed(rng):

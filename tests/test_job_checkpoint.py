@@ -627,6 +627,15 @@ def test_backpressured_save_does_not_hold_lifecycle_lock(tmp_path):
         wrote.append(snap.ckpt_id)
 
     mgr._write = slow_write
+    captured = []
+    real_capture = mgr._capture
+
+    def counting_capture(*a, **k):
+        snap = real_capture(*a, **k)
+        captured.append(snap.ckpt_id)
+        return snap
+
+    mgr._capture = counting_capture
     # writer busy on snap 0; snap 1 fills the queue; snap 2 must park
     # on the bounded put — formerly while holding _mu
     mgr.save(step=0, dense=_dense(0))
@@ -635,9 +644,14 @@ def test_backpressured_save_does_not_hold_lifecycle_lock(tmp_path):
                         mgr.save(step=2, dense=_dense(2))],
         name="ckpt-producer")
     t2.start()
+    # wait until snap 2 is ADMITTED (captured, counted in flight): a
+    # full queue alone says only that snap 1 landed, and a stop() that
+    # wins the race to snap 2's admission rightly refuses it
     deadline = time.perf_counter() + 10
-    while mgr._wq.qsize() < 1 and time.perf_counter() < deadline:
+    while not (len(captured) == 3 and mgr._inflight == 1) \
+            and time.perf_counter() < deadline:
         time.sleep(0.01)
+    assert len(captured) == 3 and mgr._inflight == 1 and mgr._wq.full()
     # the lifecycle lock must be FREE while the producer is parked
     got_mu = mgr._mu.acquire(timeout=2)
     assert got_mu, "_mu held through a backpressured queue put"

@@ -237,15 +237,46 @@ def _deepfm_and_adam():
             {"params": dict(model.named_parameters()), "buffers": {}})
 
 
+def _cmap(nb):
+    """Shapes of a per-pass cuckoo map of ``nb`` buckets
+    (``ps/device_hash.DeviceKeyMap.state``)."""
+    return {"key": _z(nb, 8, dtype=jnp.uint32),
+            "row": _z(nb, 4, dtype=jnp.int32), "seed": np.uint32(0)}
+
+
 def _ctr_state(n_keys):
     """Shapes of a pass cache + cuckoo map at the smoke's widths."""
     C, xd = SZ.capacity, SZ.embedx_dim
     nb = 64
     while nb * 4 < 2 * n_keys:
         nb <<= 1
-    cmap = {"hi": _z(nb, 4, dtype=jnp.uint32), "lo": _z(nb, 4, dtype=jnp.uint32),
-            "row": _z(nb, 4, dtype=jnp.int32), "seed": np.uint32(0)}
-    return _rows(C, xd), cmap
+    return _rows(C, xd), _cmap(nb)
+
+
+def test_probe_is_four_bucket_gathers_and_the_map_is_unpadded(v5e):
+    """The pass cell's probe (2^24 buckets, 106,496 keys a step) as
+    XLA:TPU makes it: ``key`` u32[nb, 8] takes 8 x 128 tiles with the
+    bucket index minor, so HBM holds exactly the bytes of the two
+    u32[nb, 4] arrays it replaced (a [nb, 12] map pads to 16 columns),
+    and ``pt.probe`` is four gather fusions: two 8 wide, two 4 wide."""
+    import re
+
+    from paddle_tpu.ps.device_hash import device_hash_lookup
+
+    nb, n = 1 << 24, 106496
+    cmap = _cmap(nb)
+    keys = _z(n, dtype=jnp.uint32)
+    compiled = _compile(device_hash_lookup, SingleDeviceSharding(v5e[0]),
+                        cmap, keys, keys)
+    hlo = compiled.as_text()
+    assert f"u32[{nb},8]{{0,1:T(8,128)}}" in hlo
+    assert f"s32[{nb},4]{{0,1:T(4,128)}}" in hlo
+    assert compiled.memory_analysis().argument_size_in_bytes \
+        <= nb * (8 + 4) * 4 + 2 * n * 4 + 4096
+    gathers = re.findall(
+        r"= [us]32\[%d,(\d)\]\S* fusion\([^\n]*kind=kCustom[^\n]*"
+        r"op_name=\"[^\"]*pt\.probe[^\"]*gather\"" % n, hlo)
+    assert sorted(gathers) == ["4", "4", "8", "8"], gathers
 
 
 @pytest.mark.slow
@@ -389,8 +420,7 @@ def test_slab_step_on_the_touched_side_compiles(v5e, as_tpu, rule):
         num_dense=sz.dense, slab=sz.slab, with_weights=True, amp=True)
     cache = _rows(C, sz.embedx_dim, rule)
     nb = 1 << 18
-    cmap = {"hi": _z(nb, 4, dtype=jnp.uint32), "lo": _z(nb, 4, dtype=jnp.uint32),
-            "row": _z(nb, 4, dtype=jnp.int32), "seed": np.uint32(0)}
+    cmap = _cmap(nb)
     total = _packed_layout(sz.batch, sz.slots, sz.dense, True)[3]
     hlo = _compile(step, SingleDeviceSharding(v5e[0]), params,
                    opt.init(params), cache, cmap,
