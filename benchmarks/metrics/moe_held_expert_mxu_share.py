@@ -1,0 +1,24 @@
+"""Layer: expert layer. The held experts' grouped matmuls' share of the
+chip's peak: the FLOPs the assignments that landed here REQUIRE a step
+(``harness/flops_mla.held_expert_flops`` of the window's mean
+``held_assignments``: three passes x three matrices) over the device time
+a step spends under ``pt.moe.experts`` (its share of the traced operation
+time x that time / the window's dispatches; the backward's second forward
+of the form that ran is inside, and counts as time, not as FLOPs), over the
+published bf16 peak. None without a trace, the scopes or the counter."""
+
+from harness import scopes
+
+
+def read(ctx):
+    red = ctx.get("trace")
+    share = scopes.share(ctx, "pt.moe.experts")
+    held = getattr(ctx["system"], "held_assignments_per_dispatch", None)
+    if not red or not share or not held or ctx["rehearse"]:
+        return None
+    from harness import device, flops_mla
+
+    step_s = share * sum(red["op_self_s"].values()) / ctx["window"]["dispatches"]
+    required = flops_mla.held_expert_flops(ctx["cell"].config,
+                                           held / ctx["chips"])
+    return required / step_s / device.peaks(ctx["device_kind"])["bf16_flops"]

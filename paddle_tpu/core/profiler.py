@@ -55,7 +55,8 @@ DEVICE_SCOPES = (
     "pt.embed", "pt.attn", "pt.ffn", "pt.head_loss", "pt.loss",
     "pt.flash_fwd", "pt.flash_bwd_dq", "pt.flash_bwd_dkv",
     "pt.rope", "pt.moe.route", "pt.moe.dispatch", "pt.moe.experts",
-    "pt.moe.combine",
+    "pt.moe.combine", "pt.moe.shared", "pt.mla.q", "pt.mla.kv", "pt.mtp",
+    "pt.ffn.dense",
 )
 
 #: completed spans kept in memory (newest win): a pass is about a dozen

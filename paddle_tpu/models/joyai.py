@@ -1,0 +1,440 @@
+"""JoyAI-LLM-Flash decoder — latent attention, a sigmoid bias-routed
+expert layer that holds a share of its experts, a shared expert, a leading
+dense layer and a multi-token-prediction module, on the dense path.
+
+``jdopensource/JoyAI-LLM-Flash`` (``model_type`` ``joyai_llm_flash``,
+48B-A2.7B); its configuration keys are DeepSeek-V3's (arXiv:2412.19437),
+so the equations below are that paper's. ``x`` is [tokens, hidden];
+RMSNorm everywhere, no bias anywhere, untied embedding and head.
+
+    x = embed[ids]
+    layer < first_dense:  h = x + MLA(N(x));  y = h + SwiGLU_dense(N(h))
+    other layers:         h = x + MLA(N(x));  y = h + MoE(N(h))
+    logits = N_f(y) @ W_head
+
+- ``MLA(u)``: ``c_q = N(u W_qa)``; ``[q_nope | q_rope] = c_q W_qb`` a head;
+  ``[c_kv | k_rope] = u W_kva``; ``[k_nope | v] = N(c_kv) W_kvb`` a head.
+  Rotary on ``q_rope`` (each head) and on the ONE ``k_rope`` all heads
+  share; ``q = [q_nope | rot q_rope]``, ``k = [k_nope | rot k_rope]``,
+  scores ``q.k / sqrt(nope + rope)``, causal softmax, ``o = P v`` at v's
+  own width, ``o W_o``. The key's rotary part is broadcast to the heads
+  BEFORE the kernel (``ops/flash_attention`` takes one k a head).
+- Rotary (``rotary_pairs``): adjacent pairs (2i, 2i+1) turn by
+  ``pos * theta^(-2i/D)`` (``rope_interleave`` true). The source
+  de-interleaves first — channel 2i to i, 2i+1 to D/2+i — and then rotates
+  halves: the same rotation followed by ONE fixed permutation of the D
+  channels, applied to q and k alike, so every q.k is unchanged. This file
+  leaves the permutation out.
+- ``MoE(u)`` (``parallel.moe.held_moe``): ``s = sigmoid(u W_r)`` float32;
+  the top k of ``s + b`` (``b``: the buffer ``e_score_correction_bias``,
+  never differentiated); weights ``s`` at the chosen experts without ``b``,
+  normalised, times ``routed_scale``; ``sum_i g_i SwiGLU_i(u)`` over the
+  chosen experts THAT THIS LAYER HOLDS (``cfg.held = (first, count)`` of
+  ``num_experts``: one expert-parallel rank's part, nothing standing in for
+  the others; ``(0, num_experts)`` is the whole layer) plus the shared
+  expert's ``SwiGLU(u)``, which every rank computes alike. ``n_group`` and
+  ``topk_group`` must be 1: group-limited choice is then the identity, and
+  there is no group code. After the forward ``b <- b + bias_update_rate *
+  sign(mean(c) - c)``, ``c`` this step's assignment counts over all
+  experts (DeepSeek-V3 section 2.1.2): a buffer update the train step
+  carries out through ``new_state["buffers"]``. No auxiliary loss.
+- Prediction module (``num_mtp`` 1; DeepSeek-V3 section 2.2): for position
+  i, ``h'_i = [N_e(embed[ids[i+1]]) | N_h(y_i)] W_eh`` with ``y`` the main
+  model's final hidden state AFTER ``N_f`` (as this family's public
+  implementations hand it over; the paper's figure leaves that open: a
+  reading, listed in the configuration's ``departures``), one block of the
+  expert kind with weights of its own, and ``logits'_i = N'(.) W_head``
+  with the model's own head: it predicts token i+2. The last position has
+  no ``ids[i+1]``: it is computed on ``ids[i]`` and masked in the loss, so
+  shapes stay static. ``forward(ids)`` returns ``(logits, logits')``;
+  ``joyai_loss`` is the loss over both.
+
+Matmuls go through ``nn.functional.linear`` and
+``parallel.moe.grouped_matmul``: ``Trainer(amp=True)`` means bf16 operands
+with float32 accumulation; norms, rotary, softmax and the router stay
+float32. Counters leave the forward in buffers: ``expert_counts``
+[layers, num_experts] (as routed, all experts), ``held_assignments``
+[layers], ``dispatch_rung`` [layers] (rows of the form that ran: the bounded
+buffer, or every held expert on every token),
+``tokens_dropped`` (held assignments less those the form that ran counted
+as computed: 0 unless the dispatch is at fault); the layer axis runs over the
+expert layers, the prediction module's last.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .. import nn
+from ..core.enforce import enforce, enforce_eq
+from ..core.profiler import RecordEvent
+from ..nn import functional as F
+from ..nn.layer import Layer
+from ..ops.flash_attention import flash_attention
+from ..parallel.moe import held_moe
+
+__all__ = ["JoyaiConfig", "JoyaiAttention", "JoyaiExperts", "JoyaiBlock",
+           "Joyai", "joyai_loss", "joyai_losses", "rotary_pairs",
+           "MTP_LOSS_WEIGHT"]
+
+#: weight of the prediction module's loss (DeepSeek-V3 section 4.2: 0.3
+#: for most of pre-training)
+MTP_LOSS_WEIGHT = 0.3
+
+
+@dataclasses.dataclass
+class JoyaiConfig:
+    vocab_size: int = 129280
+    hidden_size: int = 2048
+    num_heads: int = 32
+    num_layers: int = 40
+    first_dense: int = 1               # ``first_k_dense_replace``
+    dense_size: int = 7168             # ``intermediate_size``
+    q_rank: int = 1536                 # ``q_lora_rank``
+    kv_rank: int = 512                 # ``kv_lora_rank``
+    nope_dim: int = 128                # ``qk_nope_head_dim``
+    rope_dim: int = 64                 # ``qk_rope_head_dim``
+    v_dim: int = 128                   # ``v_head_dim``
+    num_experts: int = 256             # the router's width
+    experts_per_token: int = 8
+    expert_size: int = 768             # ``moe_intermediate_size``
+    num_shared: int = 1                # ``n_shared_experts``
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scale: float = 2.5          # ``routed_scaling_factor``
+    held: Tuple[int, int] = (0, 256)   # (first, count) of the experts held
+    num_mtp: int = 1                   # ``num_nextn_predict_layers``
+    max_seq_len: int = 131072
+    rope_theta: float = 32000000.0
+    rms_eps: float = 1e-6
+    bias_update_rate: float = 0.001
+    init_std: float = 0.006
+    # layers of the WHOLE model where ``num_layers`` is one pipeline
+    # stage's slice of it (the published ``num_hidden_layers``); None =
+    # ``num_layers``. It sets ``out_std``.
+    total_layers: Optional[int] = None
+    # attention impl: "auto" = Pallas flash kernel on TPU, einsum elsewhere
+    attn_impl: str = "auto"
+
+    @property
+    def expert_layers(self) -> int:
+        return self.num_layers - self.first_dense + self.num_mtp
+
+    @property
+    def out_std(self) -> float:
+        """std of the projections that write into the residual stream (W_o
+        and every FFN's down matrix): ``init_std / sqrt(2 * layers)``, the
+        scaled initialisation of GPT-2 / Megatron-LM, so that the stream's
+        scale does not grow with depth. The rest is ``init_std``."""
+        return self.init_std / math.sqrt(
+            2 * (self.total_layers or self.num_layers))
+
+
+def _normal(std: float):
+    return lambda key, shape, dtype: jax.random.normal(key, shape, dtype) * std
+
+
+def rotary_pairs(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary position embedding on adjacent pairs, positions 0..L-1.
+    ``x`` [B, L, H, D]: pair (2i, 2i+1) of every head turns by
+    ``pos * theta^(-2i/D)``. Float32; the cos / sin tables are constants of
+    the traced step, computed in float64."""
+    L, D = x.shape[1], x.shape[-1]
+    inv_freq = float(theta) ** (-np.arange(0, D, 2, dtype=np.float64) / D)
+    angle = np.arange(L, dtype=np.float64)[:, None] * inv_freq[None, :]
+    cos = jnp.asarray(np.repeat(np.cos(angle), 2, axis=1),
+                      jnp.float32)[None, :, None]
+    sin = jnp.asarray(np.repeat(np.sin(angle), 2, axis=1),
+                      jnp.float32)[None, :, None]
+    pairs = x.reshape(*x.shape[:-1], D // 2, 2)
+    turned = jnp.stack([-pairs[..., 1], pairs[..., 0]], axis=-1)
+    return x * cos + turned.reshape(x.shape) * sin
+
+
+#: heads whose [L, L] scores are alive at once in the einsum attention
+_HEAD_GROUP = 8
+
+
+def _causal_attention(q, k, v):
+    """Einsum attention over the full score matrix, q.k and v at their own
+    widths: the off-TPU stand-in for the kernel, and the float32 side of
+    the benchmark's check. [B, L, H, .]. A group of heads at a time,
+    rebuilt in the backward pass: 32 heads' [4096, 4096] scores and
+    probabilities of six blocks would be 25 GB of residuals."""
+    L, H = q.shape[1], q.shape[2]
+    causal = jnp.tril(jnp.ones((L, L), bool))[None, None]
+    scale = float(q.shape[-1]) ** -0.5
+
+    @jax.checkpoint
+    def group(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+        p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+    return jnp.concatenate(
+        [group(q[:, :, g:g + _HEAD_GROUP], k[:, :, g:g + _HEAD_GROUP],
+               v[:, :, g:g + _HEAD_GROUP])
+         for g in range(0, H, _HEAD_GROUP)], axis=2)
+
+
+class JoyaiAttention(Layer):
+    """Multi-head latent attention: low-rank q and kv projections with
+    their norms, one rotary key for all heads."""
+
+    def __init__(self, cfg: JoyaiConfig) -> None:
+        super().__init__()
+        self.cfg = cfg
+        h, H = cfg.hidden_size, cfg.num_heads
+        init = _normal(cfg.init_std)
+        qk = cfg.nope_dim + cfg.rope_dim
+        self.create_parameter("w_qa", (h, cfg.q_rank), initializer=init)
+        self.q_norm = nn.RMSNorm(cfg.q_rank, cfg.rms_eps)
+        self.create_parameter("w_qb", (cfg.q_rank, H * qk), initializer=init)
+        self.create_parameter("w_kva", (h, cfg.kv_rank + cfg.rope_dim),
+                              initializer=init)
+        self.kv_norm = nn.RMSNorm(cfg.kv_rank, cfg.rms_eps)
+        self.create_parameter("w_kvb", (cfg.kv_rank,
+                                        H * (cfg.nope_dim + cfg.v_dim)),
+                              initializer=init)
+        self.create_parameter("w_o", (H * cfg.v_dim, h),
+                              initializer=_normal(cfg.out_std))
+
+    def forward(self, x: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        B, L, _ = x.shape
+        H, nope, rope = cfg.num_heads, cfg.nope_dim, cfg.rope_dim
+        with jax.named_scope("pt.mla.q"):
+            q = F.linear(self.q_norm(F.linear(x, self.w_qa)), self.w_qb)
+            q = q.reshape(B, L, H, nope + rope)
+        with jax.named_scope("pt.mla.kv"):
+            kva = F.linear(x, self.w_kva)
+            kv = F.linear(self.kv_norm(kva[..., :cfg.kv_rank]), self.w_kvb)
+            kv = kv.reshape(B, L, H, nope + cfg.v_dim)
+            v = kv[..., nope:]
+        with jax.named_scope("pt.rope"):
+            q_rope = rotary_pairs(q[..., nope:], cfg.rope_theta)
+            k_rope = rotary_pairs(kva[..., None, cfg.kv_rank:],
+                                  cfg.rope_theta)
+        with jax.named_scope("pt.mla.q"):
+            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        with jax.named_scope("pt.mla.kv"):
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_rope, (B, L, H, rope))],
+                axis=-1)
+        impl = cfg.attn_impl
+        if impl == "auto":
+            impl = "flash" if jax.default_backend() == "tpu" else "einsum"
+        if impl == "flash":
+            out = flash_attention(q, k, v, causal=True)
+        else:
+            out = _causal_attention(q, k, v)
+        return F.linear(out.reshape(B, L, H * cfg.v_dim), self.w_o)
+
+
+class _SwiGLU(Layer):
+    """``down(silu(gate(u)) * up(u))``, no bias."""
+
+    def __init__(self, hidden: int, width: int, std: float,
+                 out_std: float) -> None:
+        super().__init__()
+        init = _normal(std)
+        self.create_parameter("w_gate", (hidden, width), initializer=init)
+        self.create_parameter("w_up", (hidden, width), initializer=init)
+        self.create_parameter("w_down", (width, hidden),
+                              initializer=_normal(out_std))
+
+    def forward(self, u: jax.Array) -> jax.Array:
+        return F.linear(jax.nn.silu(F.linear(u, self.w_gate))
+                        * F.linear(u, self.w_up), self.w_down)
+
+
+class JoyaiExperts(Layer):
+    """Router over all ``num_experts``, the banks of the experts held, and
+    the shared expert; ``forward`` returns the layer's output and the
+    router's record (``parallel.moe.held_moe``)."""
+
+    def __init__(self, cfg: JoyaiConfig) -> None:
+        super().__init__()
+        self.cfg = cfg
+        h, f, E = cfg.hidden_size, cfg.expert_size, cfg.num_experts
+        count = cfg.held[1]
+        init = _normal(cfg.init_std)
+        self.create_parameter("router_w", (h, E), initializer=init)
+        self.create_parameter("w_gate", (count, h, f), initializer=init)
+        self.create_parameter("w_up", (count, h, f), initializer=init)
+        self.create_parameter("w_down", (count, f, h),
+                              initializer=_normal(cfg.out_std))
+        self.shared = _SwiGLU(h, f * cfg.num_shared, cfg.init_std,
+                              cfg.out_std)
+        self.register_buffer("e_score_correction_bias",
+                             jnp.zeros((E,), jnp.float32))
+
+    def forward(self, x: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        cfg = self.cfg
+        lead = x.shape[:-1]
+        bias = self._buffers["e_score_correction_bias"]
+        out, route = held_moe(
+            x.reshape(-1, x.shape[-1]), self.router_w, bias, self.w_gate,
+            self.w_up, self.w_down, cfg.experts_per_token, cfg.held,
+            cfg.routed_scale)
+        counts = route["counts"].astype(jnp.float32)
+        self._buffers["e_score_correction_bias"] = (
+            bias + cfg.bias_update_rate * jnp.sign(jnp.mean(counts) - counts))
+        with jax.named_scope("pt.moe.shared"):
+            out = out.reshape(*lead, out.shape[-1]) + self.shared(x)
+        return out, route
+
+
+class JoyaiBlock(Layer):
+    """Attention then a feed-forward: the dense SwiGLU or the experts."""
+
+    def __init__(self, cfg: JoyaiConfig, dense: bool) -> None:
+        super().__init__()
+        self.norm1 = nn.RMSNorm(cfg.hidden_size, cfg.rms_eps)
+        self.attn = JoyaiAttention(cfg)
+        self.norm2 = nn.RMSNorm(cfg.hidden_size, cfg.rms_eps)
+        if dense:
+            self.mlp = _SwiGLU(cfg.hidden_size, cfg.dense_size, cfg.init_std,
+                               cfg.out_std)
+        else:
+            self.moe = JoyaiExperts(cfg)
+        self.dense = dense
+
+    def forward(self, x: jax.Array):
+        # each sublayer's scope takes its norm and its residual add; the
+        # latent projections, the rotary, the kernels and the expert
+        # layer's stages sit in scopes of their own inside
+        with jax.named_scope("pt.attn"):
+            x = x + self.attn(self.norm1(x))
+        if self.dense:
+            with jax.named_scope("pt.ffn.dense"):
+                return x + self.mlp(self.norm2(x)), None
+        with jax.named_scope("pt.ffn"):
+            y, route = self.moe(self.norm2(x))
+            return x + y, route
+
+
+class JoyaiPredictor(Layer):
+    """One multi-token-prediction module: the projection of the next
+    token's embedding beside the trunk's state, and a block of its own."""
+
+    def __init__(self, cfg: JoyaiConfig) -> None:
+        super().__init__()
+        h = cfg.hidden_size
+        self.norm_e = nn.RMSNorm(h, cfg.rms_eps)
+        self.norm_h = nn.RMSNorm(h, cfg.rms_eps)
+        self.create_parameter("w_eh", (2 * h, h),
+                              initializer=_normal(cfg.init_std))
+        self.block = JoyaiBlock(cfg, dense=False)
+        self.norm_f = nn.RMSNorm(h, cfg.rms_eps)
+
+    def forward(self, next_embed: jax.Array, trunk: jax.Array):
+        with jax.named_scope("pt.mtp"):
+            x = F.linear(jnp.concatenate(
+                [self.norm_e(next_embed), self.norm_h(trunk)], axis=-1),
+                self.w_eh)
+        x, route = self.block(x)
+        with jax.named_scope("pt.mtp"):
+            return self.norm_f(x), route
+
+
+class Joyai(Layer):
+    """Whole model. ``forward(ids)`` returns ``(logits, logits')``, both
+    [B, L, vocab]: the next token's and, from the prediction module, the
+    one after (its last position is no prediction: ``joyai_loss`` masks
+    it). With ``output_routing`` also the routers' ``logits``
+    [expert layers, B*L, num_experts] and ``index``."""
+
+    def __init__(self, cfg: JoyaiConfig) -> None:
+        super().__init__()
+        enforce(cfg.n_group == 1 and cfg.topk_group == 1,
+                f"group-limited routing (n_group {cfg.n_group}, topk_group "
+                f"{cfg.topk_group}) is not implemented: both must be 1")
+        enforce_eq(cfg.num_mtp, 1, "one prediction module")
+        enforce(cfg.num_layers > cfg.first_dense >= 0,
+                "at least one expert layer")
+        enforce(cfg.experts_per_token <= cfg.num_experts,
+                "more experts a token than experts")
+        enforce_eq(cfg.rope_dim % 2, 0, "rotary pairs")
+        first, count = cfg.held
+        enforce(0 <= first and count >= 1
+                and first + count <= cfg.num_experts,
+                f"held experts {cfg.held} outside 0..{cfg.num_experts}")
+        self.cfg = cfg
+        init = _normal(cfg.init_std)
+        self.create_parameter("embed", (cfg.vocab_size, cfg.hidden_size),
+                              initializer=init)
+        self.blocks = nn.LayerList([JoyaiBlock(cfg, i < cfg.first_dense)
+                                    for i in range(cfg.num_layers)])
+        self.norm_f = nn.RMSNorm(cfg.hidden_size, cfg.rms_eps)
+        self.mtp = JoyaiPredictor(cfg)
+        self.create_parameter("head_w", (cfg.hidden_size, cfg.vocab_size),
+                              initializer=init)
+        n = cfg.expert_layers
+        self.register_buffer("expert_counts",
+                             jnp.zeros((n, cfg.num_experts), jnp.int32))
+        self.register_buffer("held_assignments", jnp.zeros((n,), jnp.int32))
+        self.register_buffer("dispatch_rung", jnp.zeros((n,), jnp.int32))
+        self.register_buffer("tokens_dropped", jnp.zeros((), jnp.int32))
+
+    def forward(self, ids: jax.Array, output_routing: bool = False):
+        cfg = self.cfg
+        enforce(ids.shape[-1] <= cfg.max_seq_len,
+                f"sequence of {ids.shape[-1]} over max_seq_len {cfg.max_seq_len}")
+        # what this layer holds, read off the configuration: one host span
+        # a trace (``profiler.host_spans()``), none on the step path
+        with RecordEvent("pt.moe.held", first=cfg.held[0], count=cfg.held[1],
+                         experts=cfg.num_experts):
+            pass
+        with jax.named_scope("pt.embed"):
+            x = jnp.take(self.embed, ids, axis=0)
+            # the next token's embedding; the last position repeats its own
+            nxt = jnp.concatenate([x[:, 1:], x[:, -1:]], axis=1)
+        routes = []
+        for block in self.blocks:
+            x, route = block(x)
+            if route is not None:
+                routes.append(route)
+        with jax.named_scope("pt.head_loss"):
+            trunk = self.norm_f(x)
+            logits = F.linear(trunk, self.head_w)
+        y, route = self.mtp(nxt, trunk)
+        routes.append(route)
+        with jax.named_scope("pt.head_loss"):
+            logits_mtp = F.linear(y, self.head_w)
+        stack = lambda key: jnp.stack([r[key] for r in routes])
+        self._buffers["expert_counts"] = stack("counts")
+        self._buffers["held_assignments"] = stack(
+            "held_assignments").astype(jnp.int32)
+        self._buffers["dispatch_rung"] = stack("rung").astype(jnp.int32)
+        self._buffers["tokens_dropped"] = jnp.sum(stack("dropped")).astype(
+            jnp.int32)
+        if output_routing:
+            return (logits, logits_mtp), {"logits": stack("logits"),
+                                          "index": stack("index")}
+        return logits, logits_mtp
+
+
+def joyai_losses(outputs, labels: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """(next-token cross-entropy of ``logits``, cross-entropy of
+    ``logits'[:, :L-1]`` against ``labels[:, 1:]``): position i of the
+    module predicts token i+2, which is label i+1; its last position has
+    none and is masked, not dropped."""
+    logits, logits_mtp = outputs
+    ahead = jnp.concatenate(
+        [labels[:, 1:], jnp.full_like(labels[:, :1], -1)], axis=1)
+    return (F.cross_entropy(logits, labels, ignore_index=-1),
+            F.cross_entropy(logits_mtp, ahead, ignore_index=-1))
+
+
+def joyai_loss(outputs, labels: jax.Array) -> jax.Array:
+    """``Trainer``'s ``loss_fn``: main loss + ``MTP_LOSS_WEIGHT`` x the
+    prediction module's."""
+    main, mtp = joyai_losses(outputs, labels)
+    return main + MTP_LOSS_WEIGHT * mtp

@@ -9,7 +9,11 @@ without renormalizing through HBM.
 
 Layout: [B, L, H, D] (framework-wide attention layout); internally
 reshaped to [B*H, L, D] and padded to MXU tiles (D→128 multiples,
-L→block multiples). On the chip the minor dimension of a kernel operand
+L→block multiples). v may have a width of its own (latent attention:
+q.k at 192, P.v at 128): q and k are padded to their lane multiple (256),
+v, dO, the output and dv to v's (128) — never v to q's, which would
+double P.v and the result's bytes. For equal widths the kernels are the
+ones they were. On the chip the minor dimension of a kernel operand
 occupies whole 128-lane tiles in HBM whatever its logical size (an
 ``[…, 64]`` operand gets ``T(8,128)`` tiles too), so the pad costs no
 bytes beyond what the layout already does; what halves them is the dtype.
@@ -129,7 +133,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 def _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, mxu,
          dtype):
     BH, Lq, D = q.shape
-    Lk = k.shape[1]
+    Lk, Dv = k.shape[1], v.shape[-1]      # q.k at D, P.v and the result at Dv
     nq, nk = Lq // bq, Lk // bk
     offs = jnp.asarray(
         jnp.stack([jnp.asarray(q_offset, jnp.int32),
@@ -149,20 +153,20 @@ def _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, mxu,
                 in_specs=[
                     pl.BlockSpec((1, bq, D), lambda b, i, j, offs: (b, i, 0)),
                     pl.BlockSpec((1, bk, D), lambda b, i, j, offs: (b, j, 0)),
-                    pl.BlockSpec((1, bk, D), lambda b, i, j, offs: (b, j, 0)),
+                    pl.BlockSpec((1, bk, Dv), lambda b, i, j, offs: (b, j, 0)),
                 ],
                 out_specs=[
-                    pl.BlockSpec((1, bq, D), lambda b, i, j, offs: (b, i, 0)),
+                    pl.BlockSpec((1, bq, Dv), lambda b, i, j, offs: (b, i, 0)),
                     pl.BlockSpec((1, bq, 128), lambda b, i, j, offs: (b, i, 0)),
                 ],
                 scratch_shapes=[
-                    pltpu.VMEM((bq, D), jnp.float32),
+                    pltpu.VMEM((bq, Dv), jnp.float32),
                     pltpu.VMEM((bq, 128), jnp.float32),
                     pltpu.VMEM((bq, 128), jnp.float32),
                 ],
             ),
             out_shape=[
-                _out_struct((BH, Lq, D), dtype, q, k, v, offs),
+                _out_struct((BH, Lq, Dv), dtype, q, k, v, offs),
                 _out_struct((BH, Lq, 128), jnp.float32, q, k, v, offs),
             ],
             compiler_params=pltpu.CompilerParams(
@@ -279,7 +283,7 @@ def _bwd(scale, causal, bq, bk, interpret, mxu, res, grads):
     q, k, v, out, lse, offs = res          # q, k, v as the kernels read them
     do, dlse = grads
     BH, Lq, D = q.shape
-    Lk = k.shape[1]
+    Lk, Dv = k.shape[1], v.shape[-1]
     nq, nk = Lq // bq, Lk // bk
     dtype = out.dtype                      # the caller's: dq, dk, dv leave in it
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
@@ -300,8 +304,8 @@ def _bwd(scale, causal, bq, bk, interpret, mxu, res, grads):
     common_in = [
         pl.BlockSpec((1, bq, D), lambda b, i, j, offs: (b, i, 0)),      # q
         pl.BlockSpec((1, bk, D), lambda b, i, j, offs: (b, j, 0)),      # k
-        pl.BlockSpec((1, bk, D), lambda b, i, j, offs: (b, j, 0)),      # v
-        pl.BlockSpec((1, bq, D), lambda b, i, j, offs: (b, i, 0)),      # do
+        pl.BlockSpec((1, bk, Dv), lambda b, i, j, offs: (b, j, 0)),     # v
+        pl.BlockSpec((1, bq, Dv), lambda b, i, j, offs: (b, i, 0)),     # do
         pl.BlockSpec((1, bq, 128), lambda b, i, j, offs: (b, i, 0)),    # stats
     ]
     with jax.named_scope("pt.flash_bwd_dq"):
@@ -326,8 +330,8 @@ def _bwd(scale, causal, bq, bk, interpret, mxu, res, grads):
     dkv_in = [
         pl.BlockSpec((1, bq, D), lambda b, j, i, offs: (b, i, 0)),      # q
         pl.BlockSpec((1, bk, D), lambda b, j, i, offs: (b, j, 0)),      # k
-        pl.BlockSpec((1, bk, D), lambda b, j, i, offs: (b, j, 0)),      # v
-        pl.BlockSpec((1, bq, D), lambda b, j, i, offs: (b, i, 0)),      # do
+        pl.BlockSpec((1, bk, Dv), lambda b, j, i, offs: (b, j, 0)),     # v
+        pl.BlockSpec((1, bq, Dv), lambda b, j, i, offs: (b, i, 0)),     # do
         pl.BlockSpec((1, bq, 128), lambda b, j, i, offs: (b, i, 0)),    # stats
     ]
     with jax.named_scope("pt.flash_bwd_dkv"):
@@ -340,13 +344,13 @@ def _bwd(scale, causal, bq, bk, interpret, mxu, res, grads):
                 in_specs=dkv_in,
                 out_specs=[
                     pl.BlockSpec((1, bk, D), lambda b, j, i, offs: (b, j, 0)),
-                    pl.BlockSpec((1, bk, D), lambda b, j, i, offs: (b, j, 0)),
+                    pl.BlockSpec((1, bk, Dv), lambda b, j, i, offs: (b, j, 0)),
                 ],
                 scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                                pltpu.VMEM((bk, D), jnp.float32)],
+                                pltpu.VMEM((bk, Dv), jnp.float32)],
             ),
             out_shape=[_out_struct((BH, Lk, D), dtype, q, k, v, do, offs),
-                       _out_struct((BH, Lk, D), dtype, q, k, v, do, offs)],
+                       _out_struct((BH, Lk, Dv), dtype, q, k, v, do, offs)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             name="flash_bwd_dkv",
@@ -360,10 +364,11 @@ def _bwd(scale, causal, bq, bk, interpret, mxu, res, grads):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 7, 8, 9, 10))
-def _flash(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, precision):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 7, 8, 9, 10, 11))
+def _flash(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret,
+           precision, dv):
     (out, _), _ = _flash_fwd(q, k, v, scale, causal, q_offset, k_offset,
-                             bq, bk, interpret, precision)
+                             bq, bk, interpret, precision, dv)
     return out
 
 
@@ -392,7 +397,8 @@ def mxu_rounded(x: jax.Array) -> jax.Array:
 mxu_rounded.defvjp(lambda x: (mxu_rounded(x), None), lambda _, g: (g,))
 
 
-def _flash_fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, precision):
+def _flash_fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret,
+               precision, dv):
     mxu = _mxu_dtype(precision)
     offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(k_offset, jnp.int32),
@@ -406,22 +412,24 @@ def _flash_fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, pr
     q, k, v = q.astype(mxu), k.astype(mxu), v.astype(mxu)
     # what the kernels are handed, read off the arrays themselves: one host
     # span a trace (``profiler.host_spans()``), none on the step path.
-    # `scale` is 1/sqrt(D), all that is left here of the unpadded D
+    # `scale` is 1/sqrt(D), all that is left here of the unpadded D; `dv`
+    # is v's unpadded width (latent attention: q.k at 192, P.v at 128)
     with RecordEvent("pt.flash.operands", bits=8 * q.dtype.itemsize,
-                     head_dim=round(scale ** -2), lanes=q.shape[-1]):
+                     head_dim=round(scale ** -2), lanes=q.shape[-1],
+                     v_head_dim=dv):
         out, lse = _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk,
                         interpret, mxu, dtype)
     return (out, lse), (q, k, v, out, lse, offs)
 
 
 def _flash_fwd_rule(q, k, v, scale, causal, q_offset, k_offset, bq, bk,
-                    interpret, precision):
+                    interpret, precision, dv):
     (out, lse), res = _flash_fwd(q, k, v, scale, causal, q_offset, k_offset,
-                                 bq, bk, interpret, precision)
+                                 bq, bk, interpret, precision, dv)
     return out, (res, (q_offset, k_offset))
 
 
-def _flash_bwd_rule(scale, causal, bq, bk, interpret, precision, saved, g):
+def _flash_bwd_rule(scale, causal, bq, bk, interpret, precision, dv, saved, g):
     res, (q_offset, k_offset) = saved
     mxu = _mxu_dtype(precision)
     dq, dk, dv = _bwd(scale, causal, bq, bk, interpret, mxu, res, (g, None))
@@ -431,22 +439,23 @@ def _flash_bwd_rule(scale, causal, bq, bk, interpret, precision, saved, g):
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 7, 8, 9, 10, 11))
 def _flash_pair(q, k, v, scale, causal, q_offset, k_offset, bq, bk,
-                interpret, precision):
+                interpret, precision, dv):
     (out, lse), _ = _flash_fwd(q, k, v, scale, causal, q_offset, k_offset,
-                               bq, bk, interpret, precision)
+                               bq, bk, interpret, precision, dv)
     return out, lse
 
 
 def _flash_pair_fwd_rule(q, k, v, scale, causal, q_offset, k_offset, bq, bk,
-                         interpret, precision):
+                         interpret, precision, dv):
     (out, lse), res = _flash_fwd(q, k, v, scale, causal, q_offset, k_offset,
-                                 bq, bk, interpret, precision)
+                                 bq, bk, interpret, precision, dv)
     return (out, lse), res
 
 
-def _flash_pair_bwd_rule(scale, causal, bq, bk, interpret, precision, res, g):
+def _flash_pair_bwd_rule(scale, causal, bq, bk, interpret, precision, dv, res,
+                         g):
     do, dlse = g
     mxu = _mxu_dtype(precision)
     dq, dk, dv = _bwd(scale, causal, bq, bk, interpret, mxu, res, (do, dlse))
@@ -481,7 +490,11 @@ def flash_attention(
     interpret: Optional[bool] = None,
     precision: str = "default",
 ) -> jax.Array:
-    """Differentiable flash attention, [B, L, H, D] in and out."""
+    """Differentiable flash attention, [B, L, H, D] in and out. ``v`` may
+    be narrower or wider than q and k (latent attention: q.k at 192, P.v
+    at 128): the scale is q's ``1/sqrt(D)``, the result has v's width, and
+    each width is padded to its own lane multiple — v is never padded to
+    q's."""
     out, _, _ = _run_padded(q, k, v, causal, q_offset, k_offset,
                             block_q, block_k, interpret, precision,
                             with_lse=False)
@@ -493,12 +506,11 @@ def _run_padded(q, k, v, causal, q_offset, k_offset, block_q, block_k,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     B, Lq, H, D = q.shape
-    Lk = k.shape[1]
+    Lk, Dv = k.shape[1], v.shape[-1]
     scale = 1.0 / math.sqrt(D)
     bq = min(block_q, _round_up(Lq, 8))
     bk = min(block_k, _round_up(Lk, 8))
     Lq_p, Lk_p = _round_up(Lq, bq), _round_up(Lk, bk)
-    D_p = _round_up(D, 128)
 
     # one dtype serves the three operands and every result: the widest, so
     # that nothing is rounded which the kernels would have read whole
@@ -507,19 +519,20 @@ def _run_padded(q, k, v, causal, q_offset, k_offset, block_q, block_k,
     wide = jnp.result_type(q, k, v)
 
     def to_bh(x, L, L_p):
-        x = jnp.moveaxis(x, 2, 1).reshape(B * H, L, D).astype(wide)
-        return jnp.pad(x, ((0, 0), (0, L_p - L), (0, D_p - D)))
+        d = x.shape[-1]
+        x = jnp.moveaxis(x, 2, 1).reshape(B * H, L, d).astype(wide)
+        return jnp.pad(x, ((0, 0), (0, L_p - L), (0, -d % 128)))
 
     qp, kp, vp = to_bh(q, Lq, Lq_p), to_bh(k, Lk, Lk_p), to_bh(v, Lk, Lk_p)
 
     if with_lse:
         out, lse = _flash_pair(qp, kp, vp, scale, causal, q_offset,
-                               k_offset, bq, bk, interpret, precision)
+                               k_offset, bq, bk, interpret, precision, Dv)
     else:
         out = _flash(qp, kp, vp, scale, causal, q_offset, k_offset, bq, bk,
-                     interpret, precision)
+                     interpret, precision, Dv)
         lse = None
-    out = out[:, :Lq, :D].reshape(B, H, Lq, D).astype(q.dtype)
+    out = out[:, :Lq, :Dv].reshape(B, H, Lq, Dv).astype(q.dtype)
     out = jnp.moveaxis(out, 1, 2)
     if lse is not None:
         lse = lse[:, :Lq].reshape(B, H, Lq)

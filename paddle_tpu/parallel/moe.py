@@ -21,13 +21,29 @@ over the run-time group sizes and brings the rows back — no capacity, no
 (``topk_route`` -> ``sort_by_expert`` -> ``dispatch_rows`` ->
 ``expert_ffn`` -> ``combine_rows``): an expert-parallel exchange belongs
 between ``dispatch_rows`` and ``expert_ffn``.
+
+And the **held** formulation (JoyAI-LLM-Flash / DeepSeek-V3,
+``models/joyai.py``): ``held_moe`` is told which ``(first, count)`` of the
+E experts live here, routes over all E by the sigmoid rule
+(``sigmoid_route``), and computes the part of the result its own experts
+give — what one rank of an expert-parallel layer computes between the
+exchanges, with no exchange and nothing standing in for the absent ranks.
+The assignments that land on a held expert are sorted first, and a row
+buffer of twice their even-load number (``dispatch_ladder``) bounds what
+is gathered, multiplied and summed. A step whose routing sends more than
+that here takes the other branch of a ``lax.cond``: every held expert on
+every token, masked by the choice — 16 / 8 of the dropless layer's rows,
+no kernel, exact — so nothing is ever dropped; each form counts what it
+computed, and ``dropped`` is the held assignments less that count. The
+whole layer (``held = (0, E)``) has every assignment live and runs the
+dropless formulation's own stages.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,7 +61,8 @@ from ..ops import collectives as coll
 
 __all__ = ["top1_gate", "top2_gate", "MoELayer", "ExpertFFN",
            "topk_route", "sort_by_expert", "dispatch_rows", "combine_rows",
-           "grouped_matmul", "expert_ffn", "dropless_moe"]
+           "grouped_matmul", "expert_ffn", "dropless_moe",
+           "sigmoid_route", "dispatch_ladder", "held_moe"]
 
 
 def _one_hot(x, n):
@@ -305,11 +322,22 @@ def combine_rows(y: jax.Array, weight: jax.Array, order: jax.Array,
 _GMM_TILE = {2: (256, 1024, 1024), 4: (256, 512, 512)}
 
 
+def _fit_tile(tile: int, dim: int) -> int:
+    """``tile`` cut to ``dim``; where ``dim`` is larger and no multiple of
+    it (an expert width of 768 under 512), the largest multiple of 128
+    lanes under ``tile`` that divides ``dim`` (384), so that no tile of
+    the kernel is a partial one."""
+    if dim <= tile or dim % tile == 0:
+        return min(tile, dim)
+    fits = [t for t in range(tile - tile % 128, 0, -128) if dim % t == 0]
+    return fits[0] if fits else tile
+
+
 def _gmm_call(kernel, lhs, rhs, group_sizes, out_dtype, k, n, **kw):
     """``kernel`` on a [., k] x [k, n] product, tiles cut to k and n."""
     tm, tk, tn = _GMM_TILE[lhs.dtype.itemsize]
     return kernel(lhs, rhs, group_sizes, out_dtype,
-                  (tm, min(tk, k), min(tn, n)),
+                  (tm, _fit_tile(tk, k), _fit_tile(tn, n)),
                   interpret=jax.default_backend() != "tpu", **kw)
 
 
@@ -382,8 +410,6 @@ def dropless_moe(x: jax.Array, router_w: jax.Array, w_gate: jax.Array,
     The router runs in float32 at the highest matmul precision whatever
     ``amp`` says: the choice of experts is a comparison of near-equal
     numbers."""
-    from .. import amp
-
     T = x.shape[0]
     with jax.named_scope("pt.moe.route"):
         logits = jnp.matmul(x.astype(jnp.float32), router_w,
@@ -391,6 +417,14 @@ def dropless_moe(x: jax.Array, router_w: jax.Array, w_gate: jax.Array,
         route = topk_route(logits, k)
         route["logits"] = logits
         route["dropped"] = T * k - jnp.sum(route["counts"])
+    return _every_assignment(x, route, w_gate, w_up, w_down, k), route
+
+
+def _every_assignment(x, route, w_gate, w_up, w_down, k):
+    """The routed sum with all T*k assignments computed: sorted by expert,
+    gathered, three grouped matmuls, gathered back and summed a token."""
+    from .. import amp
+
     with jax.named_scope("pt.moe.dispatch"):
         order, inverse = sort_by_expert(route["index"])
         if amp.amp_enabled() and x.dtype == jnp.float32:
@@ -399,5 +433,292 @@ def dropless_moe(x: jax.Array, router_w: jax.Array, w_gate: jax.Array,
     with jax.named_scope("pt.moe.experts"):
         y = expert_ffn(rows, w_gate, w_up, w_down, route["counts"])
     with jax.named_scope("pt.moe.combine"):
-        out = combine_rows(y, route["weight"], order, inverse)
+        return combine_rows(y, route["weight"], order, inverse)
+
+
+# ---------------------------------------------------------------------------
+# Held experts: route over all E, compute the part this chip's experts give.
+# ---------------------------------------------------------------------------
+
+
+def sigmoid_route(logits: jax.Array, bias: jax.Array, k: int,
+                  scale: float) -> Dict[str, jax.Array]:
+    """DeepSeek-V3's auxiliary-loss-free router (``scoring_func`` sigmoid,
+    ``topk_method`` noaux_tc, one group). ``logits`` [T, E] float32;
+    ``bias`` [E], a buffer: it moves the CHOICE — the k largest of
+    ``sigmoid(logits) + bias``, ties to the lower expert — and never the
+    weight. ``weight`` [T, k] = the chosen scores without the bias,
+    divided by their sum + 1e-20 (``norm_topk_prob``), times ``scale``
+    (``routed_scaling_factor``). Also ``index`` [T, k] int32, ``counts``
+    [E] int32 (assignments an expert, over ALL experts). The choice is not differentiated; the weight is read through
+    the choice's one-hot, so its backward is a product, not a scatter."""
+    E = logits.shape[-1]
+    score = jax.nn.sigmoid(logits)
+    _, index = lax.top_k(lax.stop_gradient(score) + bias, k)
+    hot = _one_hot(index, E)                                  # [T, k, E]
+    picked = jnp.einsum("tke,te->tk", hot, score)
+    weight = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return {"index": index, "weight": weight * scale,
+            "counts": jnp.sum(hot, axis=(0, 1)).astype(jnp.int32)}
+
+
+def dispatch_ladder(tokens: int, k: int, experts: int,
+                    count: int) -> Tuple[int, ...]:
+    """Rows a layer that holds ``count`` of ``experts`` computes, by the
+    form that runs: the sorted buffer — twice the ``tokens * k * count /
+    experts`` assignments that land here when loads are even, in whole row
+    tiles of the grouped matmul — and, past it, ``tokens * count``: every
+    held expert on every token. The whole layer (``count == experts``)
+    computes its ``tokens * k`` assignments as ``dropless_moe`` does."""
+    if count == experts:
+        return (tokens * k,)
+    tile = _GMM_TILE[2][0]
+    rows = -(-2 * tokens * k * count // (experts * tile)) * tile
+    return (min(rows, tokens * k), tokens * count)
+
+
+class _HeldPlan(NamedTuple):
+    """Which token each row of the bounded buffer belongs to, and where a
+    token's rows lie once the buffer is sorted by token. ``tok`` [R]: the
+    row's token, T for a row past the held assignments; ``perm`` [R]: token
+    order -> buffer row; ``tok_sorted`` [R]; ``start`` [T]: a token's first
+    row in token order; ``has`` [T]: whether it has one."""
+    tok: jax.Array
+    perm: jax.Array
+    tok_sorted: jax.Array
+    start: jax.Array
+    has: jax.Array
+
+
+def _held_plan(order: jax.Array, held: jax.Array, n_held: jax.Array,
+               rows: int, k: int) -> _HeldPlan:
+    T = held.shape[0]
+    slots = jnp.arange(rows, dtype=jnp.int32)
+    tok = jnp.where(slots < n_held, order[:rows] // k, T)
+    tok_sorted, perm = lax.sort((tok, slots), num_keys=1, is_stable=True)
+    per_token = jnp.sum(held, axis=1, dtype=jnp.int32)
+    start = jnp.cumsum(per_token) - per_token
+    return _HeldPlan(tok, perm, tok_sorted, jnp.minimum(start, rows - 1),
+                     per_token > 0)
+
+
+def _sum_to_tokens(z: jax.Array, plan: _HeldPlan, k: int) -> jax.Array:
+    """Rows ``z`` [R, d] (zero where ``plan.tok`` is T) summed by token,
+    [T, d] float32, with gathers alone: the rows in token order (a
+    token's at most k rows are then neighbours), log2(k) shifted adds that
+    leave a token's sum in its first row, and one gather of T rows. A
+    token has 0 to k rows here, so ``combine_rows``' gather of k rows a
+    token would read T*k rows to find the R that exist (8 times as many
+    at a sixteenth held), and a scatter-add of rows this wide goes row by
+    row on the TPU (0.37 us a row, PERF.md section 5: ten times a gathered
+    row)."""
+    zs = jnp.take(z.astype(jnp.float32), plan.perm, axis=0)
+    tok = plan.tok_sorted
+    shift = 1
+    while shift < k:
+        same = jnp.concatenate(
+            [tok[shift:] == tok[:-shift], jnp.zeros((shift,), bool)])
+        nxt = jnp.concatenate([zs[shift:], jnp.zeros_like(zs[:shift])])
+        zs = zs + jnp.where(same[:, None], nxt, 0.0)
+        shift *= 2
+    return jnp.where(plan.has[:, None], jnp.take(zs, plan.start, axis=0), 0.0)
+
+
+def _rows_of_tokens(x: jax.Array, plan: _HeldPlan) -> jax.Array:
+    """[R, d]: the token rows of the buffer, zero past the held ones."""
+    rows = jnp.take(x, jnp.minimum(plan.tok, x.shape[0] - 1), axis=0)
+    return jnp.where((plan.tok < x.shape[0])[:, None], rows, 0)
+
+
+# the two are each other's transpose: neither backward is a scatter-add
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _gather_held(x, plan, k):
+    return _rows_of_tokens(x, plan)
+
+
+_gather_held.defvjp(
+    lambda x, plan, k: (_rows_of_tokens(x, plan), plan),
+    lambda k, plan, g: (_sum_to_tokens(g, plan, k).astype(g.dtype), None))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _combine_held(z, plan, k):
+    return _sum_to_tokens(z, plan, k)           # z float32, as the sum is
+
+
+_combine_held.defvjp(
+    lambda z, plan, k: (_sum_to_tokens(z, plan, k), plan),
+    lambda k, plan, g: (_rows_of_tokens(g, plan), None))
+
+
+@jax.custom_vjp
+def _weights_in_order(weight, order, inverse):
+    """[R]: the weights of the buffer's assignments (``order`` [R], the
+    head of the sort); backward: a gather through ``inverse`` [T*k]."""
+    return jnp.take(weight.reshape(-1), order)
+
+
+def _weights_in_order_bwd(res, g):
+    shape, inverse = res
+    got = jnp.take(g, jnp.minimum(inverse, g.shape[0] - 1))
+    return (jnp.where(inverse < g.shape[0], got, 0.0).reshape(shape),
+            None, None)
+
+
+_weights_in_order.defvjp(
+    lambda weight, order, inverse: (_weights_in_order(weight, order, inverse),
+                                    (weight.shape, inverse)),
+    _weights_in_order_bwd)
+
+
+def _held_sorted(rows: int, k: int, x, weight, w_gate, w_up, w_down, order,
+                 inverse, held, group_sizes):
+    """(the held experts' part of the layer's output through a buffer of
+    ``rows`` rows, the assignments it computed: its live rows)."""
+    from .. import amp
+
+    n_held = jnp.sum(group_sizes)
+    with jax.named_scope("pt.moe.dispatch"):
+        plan = _held_plan(order, held, n_held, rows, k)
+        if amp.amp_enabled() and x.dtype == jnp.float32:
+            x = x.astype(amp.amp_dtype())      # half the bytes to permute
+        buf = _gather_held(x, plan, k)
+    with jax.named_scope("pt.moe.experts"):
+        y = expert_ffn(buf, w_gate, w_up, w_down, group_sizes)
+    with jax.named_scope("pt.moe.combine"):
+        live = plan.tok < held.shape[0]
+        w = jnp.where(live, _weights_in_order(weight, order[:rows], inverse),
+                      0.0)
+        # the kernel writes nothing into rows past the last group
+        y = jnp.where(live[:, None], y, 0.0) * w[:, None]
+        return _combine_held(y, plan, k), jnp.sum(live, dtype=jnp.int32)
+
+
+#: tokens a block of the every-expert form
+_DENSE_BLOCK = 1024
+
+
+def _held_dense(k, x, weight, w_gate, w_up, w_down, local):
+    """(the held experts' part with no buffer at all, the assignments it
+    computed): every held expert's FFN on every token, times the token's
+    weight for that expert (0 where it did not choose it) — ``local``
+    [T, k] is the choice less ``first``; the count is of the choices its
+    mask let through. A block of tokens at a time; operands as
+    ``nn.functional.linear`` casts them under ``amp``."""
+    from .. import amp
+
+    T, d = x.shape
+    count = w_gate.shape[0]
+    dt = amp.amp_dtype() if amp.amp_enabled() else x.dtype
+    banks = [w.astype(dt) for w in (w_gate, w_up, w_down)]
+    mm = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+
+    @jax.checkpoint          # the backward rebuilds a block, keeps none
+    def block(args):
+        x, weight, local = args
+        hot = _one_hot(local, count)
+        g = jnp.einsum("tk,tke->te", weight, hot)
+        u = x.astype(dt)
+        act = jax.nn.silu(mm("td,edf->tef", u, banks[0])) \
+            * mm("td,edf->tef", u, banks[1])
+        return (mm("tef,efd->td", (act * g[..., None]).astype(dt), banks[2]),
+                jnp.sum(hot).astype(jnp.int32))
+
+    n = T // _DENSE_BLOCK if T % _DENSE_BLOCK == 0 else 1
+    split = lambda a: a.reshape(n, T // n, *a.shape[1:])
+    with jax.named_scope("pt.moe.experts"):
+        out, computed = lax.map(block,
+                                (split(x), split(weight), split(local)))
+        return out.reshape(T, d), jnp.sum(computed)
+
+
+def _held_forms(rows, k, x, weight, banks, ints):
+    """[the sorted buffer of ``rows`` rows, the every-expert form]."""
+    order, inverse, held, group_sizes, local = ints
+    return [lambda: _held_sorted(rows, k, x, weight, *banks, order, inverse,
+                                 held, group_sizes),
+            lambda: _held_dense(k, x, weight, *banks, local)]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_experts(rows, k, dense, x, weight, banks, ints):
+    return lax.cond(dense, *reversed(_held_forms(rows, k, x, weight, banks,
+                                                 ints)))
+
+
+def _held_experts_fwd(rows, k, dense, x, weight, banks, ints):
+    # nothing a form computes is kept: a ``cond`` under autodiff would keep
+    # BOTH forms' residuals. The backward runs the chosen form again (the
+    # held experts are a twentieth of the layer's required FLOPs) and
+    # differentiates that.
+    return (_held_experts(rows, k, dense, x, weight, banks, ints),
+            (dense, x, weight, banks, ints))
+
+
+def _held_experts_bwd(rows, k, res, g):
+    dense, x, weight, banks, ints = res
+
+    def back(form):
+        def run(x, weight, banks, g):
+            _, vjp = jax.vjp(lambda x, w, b: _held_forms(
+                rows, k, x, w, b, ints)[form]()[0], x, weight, banks)
+            return vjp(g)
+        return run
+
+    dx, dw, dbanks = lax.cond(dense, back(1), back(0), x, weight, banks,
+                              g[0])
+    return None, dx, dw, dbanks, None
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def held_moe(x: jax.Array, router_w: jax.Array, bias: jax.Array,
+             w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, k: int,
+             held: Tuple[int, int], scale: float
+             ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Top-k-of-E expert layer of which experts ``first .. first + count``
+    (``held``) live here. ``x`` [T, d]; ``router_w`` [d, E]; ``bias`` [E];
+    banks [count, d, f], [count, d, f], [count, f, d]: the absent experts'
+    weights do not exist. Routes over all E (``sigmoid_route``, float32 at
+    the highest precision whatever ``amp`` says) and returns the sum over
+    the chosen experts THAT ARE HELD — a partial result — and the router's
+    dict with ``logits``, ``held_assignments`` (how many of the T*k landed
+    here), ``rung`` (the rows of the form that ran, one of
+    ``dispatch_ladder``: the sorted buffer where it holds them, else every
+    held expert on every token) and ``dropped``: the held assignments less
+    those the form that ran computed (its live rows, or the choices its
+    mask let through). ``held = (0, E)`` is the whole layer: every
+    assignment is live, and it runs ``dropless_moe``'s stages."""
+    T = x.shape[0]
+    first, count = held
+    E = router_w.shape[-1]
+    enforce(0 <= first and count >= 1 and first + count <= E,
+            f"held experts {held} outside 0..{E}")
+    enforce_eq(w_gate.shape[0], count, "banks hold the held experts")
+    rungs = dispatch_ladder(T, k, E, count)
+    with jax.named_scope("pt.moe.route"):
+        logits = jnp.matmul(x.astype(jnp.float32), router_w,
+                            precision=lax.Precision.HIGHEST)
+        route = sigmoid_route(logits, bias, k, scale)
+        route["logits"] = logits
+    if count == E:
+        out = _every_assignment(x, route, w_gate, w_up, w_down, k)
+        n_held = jnp.sum(route["counts"])
+        route.update(held_assignments=n_held, dropped=T * k - n_held,
+                     rung=jnp.asarray(rungs[0], jnp.int32))
+        return out, route
+    with jax.named_scope("pt.moe.dispatch"):
+        # held assignments first, by expert; the others, all alike, last
+        local = route["index"] - first
+        held_mask = (local >= 0) & (local < count)
+        order, inverse = sort_by_expert(jnp.where(held_mask, local, count))
+        group_sizes = route["counts"][first:first + count]
+        n_held = jnp.sum(group_sizes)
+        dense = n_held > rungs[0]
+    out, computed = _held_experts(
+        rungs[0], k, dense, x, route["weight"], (w_gate, w_up, w_down),
+        (order, inverse, held_mask, group_sizes, local))
+    route.update(held_assignments=n_held, dropped=n_held - computed,
+                 rung=jnp.where(dense, rungs[1], rungs[0]).astype(jnp.int32))
     return out, route

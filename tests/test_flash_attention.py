@@ -318,4 +318,5 @@ def test_operand_span_is_recorded_once_a_compile(precision, bits):
     before = len(spans())
     for _ in range(3):
         jax.block_until_ready(step(q, k, v))
-    assert spans()[before:] == [{"bits": bits, "head_dim": 8, "lanes": 128}]
+    assert spans()[before:] == [{"bits": bits, "head_dim": 8, "lanes": 128,
+                                 "v_head_dim": 8}]

@@ -127,6 +127,26 @@ def _olmoe_step_text():
     return trainer.compiled_text(ids, ids)
 
 
+def _joyai_step_text():
+    from paddle_tpu.executor import Trainer
+    from paddle_tpu.models.joyai import Joyai, JoyaiConfig, joyai_loss
+
+    model = Joyai(JoyaiConfig(
+        vocab_size=128, hidden_size=64, num_heads=2, num_layers=2,
+        dense_size=96, q_rank=48, kv_rank=32, nope_dim=16, rope_dim=8,
+        v_dim=16, num_experts=8, experts_per_token=2, expert_size=32,
+        held=(2, 2), max_seq_len=128, attn_impl="flash"))
+    trainer = Trainer(model, optimizer.AdamW(1e-3, weight_decay=0.1),
+                      joyai_loss, amp=True)
+    ids = np.zeros((2, 128), np.int32)
+    profiler.start_timeline()
+    text = trainer.compiled_text(ids, ids)
+    held = [s.counts for s in profiler.host_spans()
+            if s.name == "pt.moe.held"]
+    assert held == [{"first": 2, "count": 2, "experts": 8}]   # once a trace
+    return text
+
+
 _PUSH = {"pt.push.accumulate", "pt.push.update"}
 STEPS = {
     "pass_slab": (_pass_step_text, {"pt.unpack", "pt.probe", "pt.pull",
@@ -143,6 +163,13 @@ STEPS = {
                                  "pt.head_loss", "pt.loss", "pt.dense_opt",
                                  "pt.flash_fwd", "pt.flash_bwd_dq",
                                  "pt.flash_bwd_dkv"}),
+    "joyai": (_joyai_step_text, {"pt.embed", "pt.attn", "pt.mla.q",
+                                 "pt.mla.kv", "pt.rope", "pt.ffn",
+                                 "pt.ffn.dense", "pt.moe.route", "pt.moe.dispatch",
+                                 "pt.moe.experts", "pt.moe.combine",
+                                 "pt.moe.shared", "pt.mtp", "pt.head_loss",
+                                 "pt.loss", "pt.dense_opt", "pt.flash_fwd",
+                                 "pt.flash_bwd_dq", "pt.flash_bwd_dkv"}),
 }
 # what computes nothing (and what XLA inserts without metadata), and the
 # collectives, which carry no scope by design

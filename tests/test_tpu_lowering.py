@@ -135,6 +135,147 @@ def test_flash_kernels_are_handed_bf16_and_one_statistics_array(
         assert got == ["s32[4]"] + ["bf16" + wide] * n + stats, (name, got)
 
 
+def _fingerprint(text):
+    """A compiled module's text without what follows the checkout and its
+    line numbers: the ``FileNames`` / ``FunctionNames`` / ``FileLocations``
+    / ``StackFrames`` tables, each instruction's ``metadata`` and
+    ``stack_frame_id``, and a kernel's ``backend_config`` (Mosaic's
+    bytecode embeds the source file's path; between PR 29's tree and PR
+    30's the bodies differed in that path alone). Shapes, layouts,
+    opcodes, operands and instruction names stay."""
+    import hashlib
+    import re
+
+    keep, skip = [], False
+    for line in text.splitlines():
+        if re.match(r"^(FileNames|FunctionNames|FileLocations|StackFrames)",
+                    line):
+            skip = True
+        if skip:
+            skip = line.strip() != ""
+            continue
+        line = re.sub(r", metadata=\{[^}]*\}", "", line)
+        line = re.sub(r"stack_frame_id=\d+", "", line)
+        line = re.sub(r'backend_config="[^"]*"', "", line)
+        keep.append(re.sub(r"backend_config=\{.*\}", "", line))
+    return hashlib.sha256("\n".join(keep).encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("shape,causal,want", [
+    ((32, 512, 12, 64), False, "6ff7e9f446afbeed"),
+    ((2, 4096, 16, 128), True, "09b7d1997ba9f9a7")])
+def test_equal_width_flash_compiles_to_the_program_of_pr29(
+        v5e, shape, causal, want):
+    """``flash_attention`` grew a value width of its own (PR 30). Where q,
+    k and v are equally wide — both older dense cells — the chip's
+    compiler must get the program it got from PR 29's tree: the
+    fingerprints were taken from that tree (``git archive``) and from
+    this one, and were equal. A PR that means to change these programs
+    re-takes them and says so in ``PERF.md``."""
+    q = _z(*shape)
+
+    def fwd_bwd(q, k, v):
+        loss = lambda q, k, v: (flash_attention(
+            q, k, v, causal=causal, interpret=False) ** 2).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = _compile(fwd_bwd, SingleDeviceSharding(v5e[0]), q, q, q).as_text()
+    assert _fingerprint(text) == want
+
+
+def test_dropless_moe_without_a_held_range_compiles_to_the_program_of_pr29(
+        v5e, as_tpu):
+    """``parallel/moe.py`` grew ``held_moe`` beside ``dropless_moe`` and a
+    tile rule for widths no tile divides (PR 30). OLMoE's layer, called as
+    before, compiles to PR 29's program (fingerprint taken from both
+    trees), and its tiles are the ones of before."""
+    from paddle_tpu import amp
+    from paddle_tpu.parallel import moe
+
+    for tile, dim in ((1024, 2048), (1024, 1024), (512, 2048), (512, 1024),
+                      (1024, 128), (512, 16)):
+        assert moe._fit_tile(tile, dim) == min(tile, dim)
+    assert moe._fit_tile(512, 768) == 384 and moe._fit_tile(1024, 768) == 768
+    T, d, E, k, f = 1024, 256, 8, 2, 128
+
+    def fwd_bwd(x, router, w_gate, w_up, w_down):
+        with amp.auto_cast(True):
+            loss = lambda *a: jnp.sum(moe.dropless_moe(*a, k)[0] ** 2)
+            return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(
+                x, router, w_gate, w_up, w_down)
+
+    text = _compile(fwd_bwd, SingleDeviceSharding(v5e[0]), _z(T, d),
+                    _z(d, E), _z(E, d, f), _z(E, d, f), _z(E, f, d)).as_text()
+    assert _fingerprint(text) == "a968e134d9ba2770"
+    assert "conditional" not in text
+
+
+def test_flash_qk192_v128_lowers_and_v_is_not_padded_to_q(v5e):
+    """JoyAI-LLM-Flash's attention shape — causal, 32 heads, q.k at 192,
+    P.v at 128, 4096 positions — as Mosaic compiles it: q and k are handed
+    256 lanes wide (192 is no multiple of 128), v, dO, the output and dv
+    128: the value side is never padded to q's width."""
+    import re
+
+    B, L, H = 1, 4096, 32
+    q, v = _z(B, L, H, 192), _z(B, L, H, 128)
+
+    def fwd_bwd(q, k, v):
+        loss = lambda q, k, v: (flash_attention(
+            q, k, v, causal=True, interpret=False) ** 2).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    hlo = _compile(fwd_bwd, SingleDeviceSharding(v5e[0]), q, q, v).as_text()
+    found = re.findall(
+        r"%(flash_[a-z_]+)[.\d]* = (.*?) custom-call\(.*?"
+        r"operand_layout_constraints=\{(.*?)\}, \w+=", hlo)
+    calls = {name: operands for name, _, operands in found}
+    made = {name: result for name, result, _ in found}
+    assert set(calls) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    qk, val = f"bf16[{B * H},{L},256]", f"bf16[{B * H},{L},128]"
+    stats = f"f32[{B * H},{L},128]"
+    operands = {n: re.findall(r"(\w+\[[\d,]*\])", c)
+                for n, c in calls.items()}
+    assert operands["flash_fwd"] == ["s32[4]", qk, qk, val]
+    for name in ("flash_bwd_dq", "flash_bwd_dkv"):
+        assert operands[name] == ["s32[4]", qk, qk, val, val, stats], name
+    results = lambda name: re.findall(r"(\w+\[[\d,]*\])", made[name])
+    wide, narrow = f"f32[{B * H},{L},256]", f"f32[{B * H},{L},128]"
+    assert results("flash_fwd") == [narrow, stats]
+    assert results("flash_bwd_dq") == [wide]
+    assert results("flash_bwd_dkv") == [wide, narrow]
+
+
+def test_joyai_step_compiles_small(v5e, as_tpu):
+    """The JoyAI-LLM-Flash train step for the chip at small widths with
+    the published head widths (192 / 128): three flash kernels a block
+    (one dense, one expert, the prediction module's), the two forms'
+    ``conditional`` forward and backward in each expert layer, every new
+    scope in the text."""
+    from paddle_tpu import nn, optimizer
+    from paddle_tpu.executor import make_train_step
+    from paddle_tpu.models.joyai import Joyai, JoyaiConfig, joyai_loss
+
+    model = Joyai(JoyaiConfig(
+        vocab_size=1024, hidden_size=256, num_heads=2, num_layers=2,
+        dense_size=512, q_rank=192, kv_rank=128, num_experts=16,
+        experts_per_token=4, expert_size=768, held=(4, 2), max_seq_len=512))
+    opt = optimizer.AdamW(learning_rate=4e-4, weight_decay=0.1, beta2=0.95)
+    step = make_train_step(model, opt, joyai_loss, amp=True)
+    state = nn.get_state(model)
+    ids = (_z(2, 512, dtype=jnp.int32),)
+    s = SingleDeviceSharding(v5e[0])
+    text = step.lower(
+        _shapes(state, s),
+        _shapes(jax.eval_shape(opt.init, state["params"]), s), _rng_key(s),
+        _shapes(ids, s), _shapes(ids, s)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 3 * 3
+    assert text.count(" conditional(") == 2 * 2
+    for scope in ("pt.mla.q", "pt.mla.kv", "pt.rope", "pt.moe.shared",
+                  "pt.moe.experts", "pt.mtp"):
+        assert scope in text, scope
+
+
 def test_ernie_layer_moves_its_bf16_under_a_name(v5e, as_tpu):
     """One ERNIE layer of the benchmark cell's widths, as the chip compiles
     its train step: every copy, convert and fusion of the entry computation

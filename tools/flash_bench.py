@@ -5,9 +5,12 @@ measurement behind the operand dtype of ``ops/flash_attention.py``
     python3 tools/flash_bench.py [--against DIR] [--calls 20] [--rehearse]
                                  [--out chiprun_out/flash_bench.json]
 
-At the two dense cells' attention shapes — ``[32, 512, 12, 64]``
-bidirectional (``ernie_base_seq512``) and ``[2, 4096, 16, 128]`` causal
-(``olmoe_1b7b_seq4096``), float32 in and out as the models call it — one
+At the dense cells' attention shapes — ``[32, 512, 12, 64]``
+bidirectional (``ernie_base_seq512``), ``[2, 4096, 16, 128]`` causal
+(``olmoe_1b7b_seq4096``) and q, k ``[2, 4096, 32, 192]`` with v
+``[2, 4096, 32, 128]`` causal (``joyai_flash_seq4096``: latent attention,
+the value side narrower than q.k), float32 in and out as the models call
+it — one
 jitted value-and-gradient of ``flash_attention`` is run ``--calls`` times
 under the device profiler. Per kernel (``flash_fwd``, ``flash_bwd_dq``,
 ``flash_bwd_dkv``, found in the trace by name): milliseconds a call,
@@ -19,7 +22,12 @@ block again for every grid step that fetches it anew: k and v once a q
 block in ``flash_fwd`` and ``flash_bwd_dq``, q, do and the statistics once
 a k block in ``flash_bwd_dkv``), and 2 FLOP a multiply-add of its matmuls
 over the blocks the causal mask leaves, over 197 TFLOP/s (``flop_ms``).
-``roofline_share`` = the larger floor over the measured time. Beside
+``roofline_share`` = the larger floor over the measured time: of what the
+kernel is HANDED (192 padded to 256 lanes, whole blocks on the diagonal).
+``required_share`` is the benchmark's own count
+(``benchmarks/harness/flops_mla.flash_kernel_floor``, what the
+``flash_*_roofline_share`` metrics read: logical widths, the positions the
+mask leaves, each operand once) over the same time. Beside
 them ``layout_ms``: every other device operation of the call (the
 transposes, pads, converts and slices round the kernels, delta and the
 statistics).
@@ -37,21 +45,23 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import re
 import shutil
 import subprocess
 import sys
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 
-#: (name, [B, L, H, D], causal): the attention calls of the two dense cells
-SHAPES = (("ernie_base_seq512", (32, 512, 12, 64), False),
-          ("olmoe_1b7b_seq4096", (2, 4096, 16, 128), True))
-REHEARSAL = (("rehearsal", (1, 1024, 1, 8), True),)
-#: matmuls a kernel runs on each (q block, k block) pair
-MATMULS = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
-_KERNEL_RE = re.compile(r"^%?(flash_fwd|flash_bwd_dq|flash_bwd_dkv)\b")
+from harness.flops_mla import KERNEL_MATMULS as MATMULS  # noqa: E402
+from harness.kernels import KERNEL_RE as _KERNEL_RE  # noqa: E402
+
+#: (name, q's and k's [B, L, H, D], causal, v's width): the attention calls
+#: of the dense cells
+SHAPES = (("ernie_base_seq512", (32, 512, 12, 64), False, 64),
+          ("olmoe_1b7b_seq4096", (2, 4096, 16, 128), True, 128),
+          ("joyai_flash_seq4096", (2, 4096, 32, 192), True, 128))
+REHEARSAL = (("rehearsal", (1, 1024, 1, 24), True, 16),)
 
 
 def handed(step, args):
@@ -97,11 +107,12 @@ def floors(name, call, causal, peaks):
         follows_inner = (n in (1, 2)) != dkv
         reread += nbytes(a) * (outer if follows_inner and inner > 1 else 1)
     nq, nk = (inner, outer) if dkv else (outer, inner)
-    (_, (_, Lq, D)), (_, (_, Lk, _)) = call["operands"][:2]
+    (_, (_, Lq, D)), (_, (_, Lk, _)), (_, (_, _, Dv)) = call["operands"][:3]
     bq, bk = Lq // nq, Lk // nk
     pairs = sum(1 for i in range(nq) for j in range(nk)
                 if not causal or i * bq + bq - 1 >= j * bk)
-    flop = 2.0 * MATMULS[name] * BH * pairs * bq * bk * D
+    n_qk, n_v = MATMULS[name]
+    flop = 2.0 * BH * pairs * bq * bk * (n_qk * D + n_v * Dv)
     return {"bytes": once, "bytes_reread": reread, "flop": flop,
             "bytes_ms": once / peaks["hbm_bytes_per_s"] * 1e3,
             "bytes_reread_ms": reread / peaks["hbm_bytes_per_s"] * 1e3,
@@ -110,14 +121,13 @@ def floors(name, call, causal, peaks):
 
 def measure(args) -> dict:
     """This process's tree (``--tree``), every shape."""
-    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
     sys.path.insert(0, args.tree)
     if args.rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
 
     import jax
     import jax.numpy as jnp
-    from harness import device, trace
+    from harness import device, flops_mla, trace
 
     import paddle_tpu
     from paddle_tpu.ops.flash_attention import flash_attention
@@ -132,15 +142,24 @@ def measure(args) -> dict:
     peaks = device.peaks("TPU v5 lite" if args.rehearse else dev.device_kind)
     rec = {"tree": args.tree, "device_kind": dev.device_kind,
            "calls": args.calls, "shapes": {}}
-    for cell, shape, causal in (REHEARSAL if args.rehearse else SHAPES):
+    for cell, shape, causal, dv in (REHEARSAL if args.rehearse else SHAPES):
         keys = jax.random.split(jax.random.key(args.seed), 3)
-        q, k, v = (jax.random.normal(kk, shape, jnp.float32) for kk in keys)
+        q, k = (jax.random.normal(kk, shape, jnp.float32) for kk in keys[:2])
+        v = jax.random.normal(keys[2], shape[:-1] + (dv,), jnp.float32)
+        widths = {"num_attention_heads": shape[2], "v_head_dim": dv,
+                  "qk_nope_head_dim": shape[3], "qk_rope_head_dim": 0}
 
         def loss(q, k, v):
             return jnp.sum(flash_attention(q, k, v, causal=causal) ** 2)
 
         step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
-        calls = handed(step, (q, k, v))
+        try:
+            calls = handed(step, (q, k, v))
+        except (TypeError, ValueError) as e:
+            # a tree from before PR 30 has one width for q, k and v
+            rec["shapes"][cell] = {"error": f"{type(e).__name__}: {e}"[:200]}
+            print(json.dumps({cell: rec["shapes"][cell]}), flush=True)
+            continue
         jax.block_until_ready(step(q, k, v))
         jax.block_until_ready(step(q, k, v))
         trace_dir = os.path.join(ROOT, ".bench_out", "flash_bench")
@@ -169,16 +188,20 @@ def measure(args) -> dict:
             else:
                 other += e["dur"] * 1e3 / args.calls
         # no device line off the chip: a time is never a CPU's
-        row = {"shape": list(shape), "causal": causal,
+        row = {"shape": list(shape), "v_dim": dv, "causal": causal,
                "wall_ms": wall_ms if ops else None,
                "layout_ms": other if ops else None, "kernels": {}}
         for name, call in calls.items():
             f = floors(name, call, causal, peaks)
             ms = kernel_ms[name] if ops else None
+            need = flops_mla.flash_kernel_floor(
+                name, widths, shape[0], shape[1], peaks, causal=causal)
             row["kernels"][name] = {
                 "ms": ms, **f,
                 "roofline_share": (max(f["bytes_ms"], f["flop_ms"]) / ms
                                    if ms else None),
+                "required_ms": need["floor_s"] * 1e3,
+                "required_share": need["floor_s"] * 1e3 / ms if ms else None,
                 "operands": [f"{d}{list(sh)}" for d, sh in call["operands"]],
                 "results": [f"{d}{list(sh)}" for d, sh in call["results"]]}
         rec["shapes"][cell] = row
@@ -194,10 +217,15 @@ def table(runs) -> str:
     for tree in trees:
         tag = os.path.relpath(tree, ROOT)
         head += [f"{tag}: ms a call", "bytes_ms", "reread_ms", "flop_ms",
-                 "roofline_share"]
+                 "roofline_share", "required_share"]
     lines = ["| " + " | ".join(head) + " |", "|" + " --- |" * len(head)]
     fmt = lambda x: "not measured" if x is None else f"{x:.3f}"
     for cell in runs[0]["shapes"]:
+        if any("error" in r["shapes"][cell] for r in runs):
+            lines.append(f"| {cell} | a tree cannot run this shape | "
+                         + " | ".join(r["shapes"][cell].get("error", "runs")
+                                      for r in runs) + " |")
+            continue
         for name in list(MATMULS) + ["layout", "wall"]:
             row = [cell, name]
             for tree in trees:
@@ -208,10 +236,11 @@ def table(runs) -> str:
                     row += [" / ".join(fmt(k["ms"]) for k in ks),
                             fmt(best["bytes_ms"]),
                             fmt(best["bytes_reread_ms"]),
-                            fmt(best["flop_ms"]), fmt(best["roofline_share"])]
+                            fmt(best["flop_ms"]), fmt(best["roofline_share"]),
+                            fmt(best["required_share"])]
                 else:
                     row += [" / ".join(fmt(s[name + "_ms"]) for s in shapes),
-                            "", "", "", ""]
+                            "", "", "", "", ""]
             lines.append("| " + " | ".join(row) + " |")
     return "\n".join(lines)
 

@@ -18,6 +18,7 @@ mantissa against 3), same routing. Prints what ``compare(fp8, f32,
 from __future__ import annotations
 
 import argparse
+import inspect
 import json
 import os
 import sys
@@ -60,11 +61,17 @@ def main() -> int:
     reference = cell.reference()
     ids, labels = system.check_items
     params = system.trainer.state["params"]
-    ref = reference.loss_and_grads(params, ids, labels, cell.config)
+    # a reference whose router has a bias buffer is handed the trained one
+    kw = ({"buffers": system.trainer.state["buffers"]} if "buffers" in
+          inspect.signature(reference.loss_and_grads).parameters else {})
+    ref = reference.loss_and_grads(params, ids, labels, cell.config, **kw)
     low = reference.loss_and_grads(params, ids, labels, cell.config,
                                    expert_index=ref["expert_index"],
-                                   operand_dtype=jnp.float8_e4m3fn)
+                                   operand_dtype=jnp.float8_e4m3fn, **kw)
     verdict = reference.compare(low, ref, "amp")
+    if hasattr(reference, "leaf_table"):
+        print(json.dumps({"fp8_leaf_table": reference.leaf_table(low, ref)}),
+              flush=True)
     print(json.dumps({"workload": cell.name, "steps": args.steps,
                       "platform": devices[0].platform,
                       "float8_e4m3fn_against_float32": verdict}), flush=True)
