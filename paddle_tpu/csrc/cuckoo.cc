@@ -14,11 +14,24 @@
 // is u32[nbuckets, 8]: a bucket's row holds its four keys as halves,
 // [hi0 hi1 hi2 hi3 | lo0 lo1 lo2 lo3], so ONE row gather fetches every
 // key of the bucket (a gather of <= 8 columns costs the chip the same as
-// one of 4: it is paid by the index). `row` is i32[nbuckets, 4]. Empty
-// slots have row == -1 and zero key words. Two hash functions pick
-// candidate buckets; insertion uses random-walk eviction. Load factor
-// <= 0.5 by construction (python chooses nbuckets), so builds virtually
-// never fail; on failure the caller retries with a fresh seed.
+// one of 4: it is paid by the index). `row` is i32[nbuckets, 4]: what
+// the caller passed as rows[i] for the key in that slot. Empty slots
+// have row == -1 and zero key words. Two hash functions pick candidate
+// buckets; insertion uses random-walk eviction. Load factor <= 0.5 by
+// construction (python chooses nbuckets), so builds virtually never
+// fail; on failure the caller retries with a fresh seed.
+//
+// Two callers, one build (ps/device_hash.py). EXPLICIT rows: rows[] are
+// the cache rows the caller chose, and `row` is uploaded beside `key`.
+// IMPLICIT rows (a pass whose slot table fits the cache): rows[] is
+// 0..n-1, so `row` comes back as the PLACEMENT (slot -> position in
+// keys[]); cuckoo_placement reads from it which slot, and so which cache
+// row, each key got, and `row` never leaves the host. The device then tells
+// an empty slot from a key by the key words alone, so the host
+// overwrites the zero words of the empty slots in the two buckets key 0
+// hashes to with a second filler key (DeviceKeyMap.build_host_implicit):
+// zero words everywhere else can never match a probe, because a probe
+// of key 0 reads only those two buckets.
 //
 // The 32-bit mixer below must match _mix32 in ps/device_hash.py
 // bit-for-bit — the device probe recomputes these hashes with jnp uint32
@@ -111,6 +124,31 @@ int64_t cuckoo_build(const uint64_t* keys, const int32_t* rows, int64_t n,
     if (!placed) ++failures;
   }
   return failures;
+}
+
+// Read back the placement of a build whose rows[] were 0..n-1 (IMPLICIT
+// rows), in CACHE-ROW order: slot s of bucket b is cache row
+// (b mod shards) * shard_rows + (b div shards) * 4 + s, so walking the
+// shards, then a shard's buckets, then the slots, meets the rows
+// ascending. Writes each occupied slot's key (rebuilt from the bucket's
+// words: no random read) and row; returns how many. One forward pass
+// over `key` and `row`, strided by `shards` buckets.
+int64_t cuckoo_placement(const uint32_t* key, const int32_t* row,
+                         int64_t nbuckets, int64_t shards, int64_t shard_rows,
+                         uint64_t* out_keys, int32_t* out_rows) {
+  int64_t j = 0;
+  for (int64_t r = 0; r < shards; ++r) {
+    for (int64_t q = 0, b = r; b < nbuckets; ++q, b += shards) {
+      const uint32_t* k = key + b * kKeyWords;
+      for (int s = 0; s < kSlots; ++s) {
+        if (row[b * kSlots + s] < 0) continue;
+        out_keys[j] = (static_cast<uint64_t>(k[s]) << 32) | k[kSlots + s];
+        out_rows[j] = static_cast<int32_t>(r * shard_rows + q * kSlots + s);
+        ++j;
+      }
+    }
+  }
+  return j;
 }
 
 }  // extern "C"

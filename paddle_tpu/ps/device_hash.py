@@ -14,17 +14,36 @@ buckets, load ≤ ~0.5) BUILT on host once per pass (csrc/cuckoo.cc — the
 HeterComm build_ps bulk-insert analogue) and probed in-graph with two
 fixed bucket probes + compares: branch-free, bounded, fuses into the
 train step. Keys are uint64 split into (hi, lo) uint32 halves — TPUs
-have no native 64-bit int path, and x64 mode stays off. The map is two
-arrays: ``key`` u32[nbuckets, 8], a bucket's four hi halves then its
-four lo halves in ONE row, and ``row`` i32[nbuckets, 4] — so a probe is
-two row gathers a hash, four a step (the chip pays a gather of ≤ 8
-columns by the index, not by the byte: PERF.md §5).
+have no native 64-bit int path, and x64 mode stays off. The map is
+``key`` u32[nbuckets, 8], a bucket's four hi halves then its four lo
+halves in ONE row (the chip pays a gather of ≤ 8 columns by the index,
+not by the byte: PERF.md §5), in one of two forms, told apart by the
+map's own contents (``"row" in state``):
+
+- **implicit rows** (a pass of ``HbmEmbeddingCache`` whose slot table
+  fits the cache, ``nbuckets·4 ≤ capacity``): a key's row IS the slot
+  the build put it in — slot ``s`` of bucket ``b`` is row
+  ``(b mod K)·shard_rows + (b div K)·4 + s`` over ``K`` shards (one
+  chip: ``b·4 + s``), so the probe computes it from the compare it
+  makes anyway: ONE row gather a hash, two a step, and no ``row``
+  array exists on the host after the build or on the device at all
+  (the reference's HBM table holds the values in the hash table
+  itself: no second indirection either). ``K`` and ``shard_rows`` ride
+  in the state beside ``seed``. Empty slots hold a FILLER key that
+  provably does not hash to their bucket (:func:`_filler_keys`), so no
+  key value is reserved and a lookup of key 0 or of a filler reads −1
+  unless the pass holds it;
+- **explicit rows** (caller-chosen rows, ``DeviceKeyMap(keys, rows)``;
+  or a cache more than half full): a second array ``row``
+  i32[nbuckets, 4] holds each slot's row, −1 in empty slots — two row
+  gathers a hash, four a step.
 
 The 32-bit mixer must match ``mix32`` in csrc/cuckoo.cc bit-for-bit.
 """
 
 from __future__ import annotations
 
+import itertools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -32,7 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.enforce import enforce
-from .native import cuckoo_build
+from .native import cuckoo_build, cuckoo_placement, native_available
 
 __all__ = ["DeviceKeyMap", "DynamicDeviceKeyMap", "device_hash_lookup",
            "dynamic_map_lookup", "dynamic_probe_buckets", "split_keys"]
@@ -63,14 +82,16 @@ def device_hash_lookup(table: Dict[str, jax.Array], keys_hi: jax.Array,
                        keys_lo: jax.Array) -> jax.Array:
     """In-graph probe: [n] int32 rows (−1 = missing) for (hi, lo) keys.
 
-    Two hashes × two bucket-ROW gathers (HashTable::get analogue): per
-    hash one row of ``key`` ([n, 8]: the bucket's four hi halves, then
-    its four lo) and one of ``row`` ([n, 4]) — whole buckets, the same
-    efficient row-gather pattern as the embedding pull. (1-D scalar
-    gathers lower to a pathological path on TPU; never probe slot-wise.)
+    Per hash one bucket-ROW gather of ``key`` ([n, 8]: the bucket's four
+    hi halves, then its four lo) — whole buckets, the same efficient
+    row-gather pattern as the embedding pull. (1-D scalar gathers lower
+    to a pathological path on TPU; never probe slot-wise.) A map with
+    implicit rows (no ``row`` in ``table``) needs nothing more: the row
+    is the matching slot's own position. A map with explicit rows
+    gathers the bucket's row of ``row`` ([n, 4]) too.
     """
     with jax.named_scope("pt.probe"):
-        mask = jnp.uint32(table["row"].shape[0] - 1)  # nbuckets (power of 2)
+        mask = jnp.uint32(table["key"].shape[0] - 1)  # nbuckets (power of 2)
         seed = table["seed"]  # scalar uint32 (device array, donated w/ state)
         hi = keys_hi.astype(jnp.uint32)
         lo = keys_lo.astype(jnp.uint32)
@@ -79,48 +100,131 @@ def device_hash_lookup(table: Dict[str, jax.Array], keys_hi: jax.Array,
             s = seed if which == 0 else seed ^ _SEED2_XOR
             b = (_mix32(hi, lo, s) & mask).astype(jnp.int32)
             bk = jnp.take(table["key"], b, axis=0)   # [n, 8]: hi×4 | lo×4
-            br = jnp.take(table["row"], b, axis=0)   # [n, 4]
             match = ((bk[:, :_SLOTS] == hi[:, None])
-                     & (bk[:, _SLOTS:] == lo[:, None]) & (br >= 0))
-            hit = jnp.max(jnp.where(match, br, -1), axis=1)
+                     & (bk[:, _SLOTS:] == lo[:, None]))
+            if "row" in table:
+                br = jnp.take(table["row"], b, axis=0)   # [n, 4]
+                hit = jnp.max(jnp.where(match & (br >= 0), br, -1), axis=1)
+            else:
+                # empty slots hold a filler no probe of this bucket can
+                # carry, so a match is a key of the pass
+                slot = jnp.max(jnp.where(
+                    match, jnp.arange(_SLOTS, dtype=jnp.int32), -1), axis=1)
+                shift = table["shard_shift"]  # log2 K: shifts and masks only
+                hit = jnp.where(
+                    slot >= 0,
+                    (b & ((1 << shift) - 1)) * table["shard_rows"]
+                    + (b >> shift) * _SLOTS + slot, -1)
             found = jnp.where(hit >= 0, hit, found)
         return found
+
+
+def _filler_keys(nb: int, seed: int):
+    """What the empty slots of an implicit-row map hold: key 0 (the zero
+    words the build leaves) in every bucket but the two that key 0
+    itself hashes to, and there the least key whose own two buckets are
+    neither of them. Returns that key and key 0's buckets. A probe of
+    key ``X`` reads only X's two buckets, and neither holds X as a
+    filler: so a filler never matches, whatever the key."""
+    def buckets(k: int):
+        hi, lo = np.uint32(k >> 32), np.uint32(k & 0xFFFFFFFF)
+        return {int(_mix32_np(hi, lo, s) & np.uint32(nb - 1))
+                for s in (seed, seed ^ int(_SEED2_XOR))}
+
+    of_zero = buckets(0)
+    other = next(k for k in itertools.count(1) if not buckets(k) & of_zero)
+    return other, sorted(of_zero)
 
 
 class DeviceKeyMap:
     """Per-pass static key→row map living in HBM.
 
-    build() on host (cuckoo.cc) after the pass dedup assigns rows;
-    ``state`` is a dict of device arrays a jitted step closes over (or
-    threads through, for donation).
+    Built on host (cuckoo.cc) once a pass; ``state`` is a dict of device
+    arrays a jitted step closes over (or threads through, for donation):
+    ``key`` and ``seed``, then either ``row`` (explicit rows: the caller
+    chose them) or ``shard_shift`` and ``shard_rows`` (implicit rows:
+    the build chose them, a key's row is its slot). See the module
+    docstring; :func:`device_hash_lookup` reads which from the dict.
     """
 
     @staticmethod
-    def build_host(keys: np.ndarray, rows: np.ndarray):
-        """Host-only cuckoo build (the pre_build_thread half): returns
-        the host arrays to upload later. Touches no device state, so it
-        can run in a background thread while the previous pass trains."""
-        from .native import native_available
+    def buckets_for(n: int) -> int:
+        """Buckets of a map of ``n`` keys: the least power of two (≥ 64)
+        with ``nb·4 ≥ 2·n``, load ≤ 0.5."""
+        nb = 64
+        while nb * _SLOTS < 2 * max(n, 1):
+            nb <<= 1
+        return nb
 
+    @staticmethod
+    def _build(keys: np.ndarray, rows: np.ndarray):
         if not native_available():
             raise RuntimeError(
                 "DeviceKeyMap needs the native library (csrc/cuckoo.cc); "
                 "use host-side HbmEmbeddingCache.lookup instead")
         n = len(keys)
         enforce(n == len(rows), "keys/rows length mismatch")
-        nb = 64
-        while nb * _SLOTS < 2 * max(n, 1):
-            nb <<= 1
+        nb = DeviceKeyMap.buckets_for(n)
         last_err: Optional[Exception] = None
         for seed in (0x1234ABCD, 0x9E3779B9, 0xDEADBEEF, 0x2545F491):
             try:
                 key, row = cuckoo_build(keys, rows, nb, seed)
-                break
+                return key, row, seed, nb
             except RuntimeError as e:  # placement failure: retry new seed
                 last_err = e
-        else:
-            raise RuntimeError(f"cuckoo build failed for {n} keys: {last_err}")
+        raise RuntimeError(f"cuckoo build failed for {n} keys: {last_err}")
+
+    @staticmethod
+    def build_host(keys: np.ndarray, rows: np.ndarray):
+        """Host-only cuckoo build (the pre_build_thread half) of a map
+        with EXPLICIT rows: returns the host arrays to upload later.
+        Touches no device state, so it can run in a background thread
+        while the previous pass trains."""
+        key, row, seed, nb = DeviceKeyMap._build(keys, rows)
         return {"key": key, "row": row, "seed": np.uint32(seed), "nb": nb}
+
+    @staticmethod
+    def rows_can_be_slots(n: int, capacity: int, shards: int = 1) -> bool:
+        """Whether a map of ``n`` keys can name the rows of a cache of
+        ``capacity`` rows over ``shards`` by its own slots: the slot
+        table fits the cache (it is at most half full) and the shards
+        are a power of two that deals the buckets out evenly."""
+        nb, K = DeviceKeyMap.buckets_for(n), int(shards)
+        return (nb * _SLOTS <= capacity and K & (K - 1) == 0 and K <= nb
+                and capacity % K == 0)
+
+    @staticmethod
+    def build_host_implicit(keys: np.ndarray, capacity: int, shards: int = 1):
+        """Host-only build of a map with IMPLICIT rows over a cache of
+        ``capacity`` rows block-partitioned over ``shards``
+        (:meth:`rows_can_be_slots` must hold): returns ``(built, keys,
+        rows)`` — the host arrays to upload (no ``row``), and the pass's
+        keys in the order of their rows ``rows``, ASCENDING, so that
+        everything the pass build and the flush index by row walks
+        memory forwards.
+
+        Slot ``s`` of bucket ``b`` is row ``(b mod K)·(capacity÷K) +
+        (b div K)·4 + s``: the bucket's LOW bits pick the shard, a
+        uniform hash of the key, so the shards stay balanced whatever
+        the ratio of capacity to slots, and a shard has at least as many
+        rows as the slots dealt to it."""
+        n, K = len(keys), int(shards)
+        enforce(DeviceKeyMap.rows_can_be_slots(n, capacity, K),
+                f"a map of {n} keys cannot name the rows of {capacity} "
+                f"over {K} shards by its slots")
+        # rows = arange(n): the row array the build returns IS the
+        # placement, slot → position in ``keys``; it stays on the host
+        key, placed, seed, nb = DeviceKeyMap._build(
+            keys, np.arange(n, dtype=np.int32))
+        keys, rows = cuckoo_placement(key, placed, n, K, capacity // K)
+        filler, of_zero = _filler_keys(nb, seed)
+        for b in of_zero:        # every other bucket's zero words ARE key 0
+            empty = placed[b] < 0
+            key[b, :_SLOTS][empty] = filler >> 32
+            key[b, _SLOTS:][empty] = filler & 0xFFFFFFFF
+        return ({"key": key, "seed": np.uint32(seed), "nb": nb,
+                 "shard_shift": np.int32(K.bit_length() - 1),
+                 "shard_rows": np.int32(capacity // K)}, keys, rows)
 
     def __init__(self, keys: Optional[np.ndarray] = None,
                  rows: Optional[np.ndarray] = None,
@@ -129,16 +233,16 @@ class DeviceKeyMap:
         # prebuilt host table — passing both invites a mismatched pair
         enforce((host_built is None) != (keys is None),
                 "pass either keys/rows or host_built, not both")
-        built = host_built if host_built is not None else \
-            self.build_host(keys, rows)
-        self.nbuckets = built["nb"]
+        built = dict(host_built if host_built is not None else
+                     self.build_host(keys, rows))
+        self.nbuckets = built.pop("nb")
         put = (lambda a: jax.device_put(a, sharding)) if sharding is not None \
             else jnp.asarray
+        # the bucket arrays go where the caller says, the scalars beside
+        # seed wherever jax puts a scalar
         self.state: Dict[str, jax.Array] = {
-            "key": put(built["key"]),
-            "row": put(built["row"]),
-            "seed": jnp.asarray(built["seed"]),
-        }
+            k: put(v) if np.ndim(v) else jnp.asarray(v)
+            for k, v in built.items()}
 
     def lookup(self, keys_hi: jax.Array, keys_lo: jax.Array) -> jax.Array:
         return device_hash_lookup(self.state, keys_hi, keys_lo)

@@ -5,9 +5,20 @@ reference keeps a per-GPU ``HashTable`` of hot features built per pass
 (``PSGPUWrapper`` PreBuildTask→BuildPull→BuildGPUTask, then
 PullSparse/PushSparseGrad during the pass, EndPass→dump_to_cpu). Here:
 
-- the **feasign→cache-row map stays on host** in the native FeasignIndex
-  (hash tables are hostile to XLA's static shapes — the reference's own
-  build/serve split validates this design);
+- the **feasign→cache-row map is built on host** once a pass: the
+  native FeasignIndex serves host ``lookup`` and the flush, and with
+  ``device_map=True`` a static cuckoo map (ps/device_hash.py) serves the
+  in-graph probe (hash tables that GROW are hostile to XLA's static
+  shapes — the reference's own build/serve split validates this design);
+- **what a row number is**: with ``device_map=True`` and the map's slot
+  table fitting the cache (``nbuckets·4 ≤ capacity``: the cache at most
+  half full) a key's row IS its slot in the key map — the probe
+  computes it, no ``row`` array exists, and the pass's keys are kept in
+  row order so the build and the flush walk memory forwards (implicit
+  rows; span count ``implicit_rows``). Otherwise it is the index's
+  dense number, dealt round-robin over the shards, and the map stores
+  it (explicit rows). The form follows from the sizes alone, and a
+  pass's result does not depend on it;
 - the **working set lives in HBM as dense row arrays** (values + per-row
   optimizer state), donated through the jitted train step so pull
   (gather), push (scatter) and the per-feature AdaGrad update
@@ -427,6 +438,10 @@ class HbmEmbeddingCache:
         self._index: Optional[FeasignIndex] = None
         self.state: Optional[Dict[str, jax.Array]] = None
         self._pass_keys: Optional[np.ndarray] = None
+        #: implicit rows only: the row of each of ``_pass_keys``, ascending
+        #: (the index's dense number of a key is its position there);
+        #: None = rows are the dense numbers, spread over the shards
+        self._pass_rows: Optional[np.ndarray] = None
         self._device_map_enabled = device_map
         #: per-pass in-HBM key→row map (ps/device_hash.py; the reference's
         #: GPU HashTable) — set by begin_pass when device_map=True
@@ -463,18 +478,33 @@ class HbmEmbeddingCache:
             uniq = dedup_u64(keys)  # parallel PreBuildTask-style dedup
         enforce_le(len(uniq), cfg.capacity,
                    "pass working set exceeds cache capacity")
-        with RecordEvent("pt.pass.index"):
-            index = FeasignIndex(len(uniq) * 2)
-            rows, _ = index.lookup_or_insert(uniq)
-            rows = self._spread(rows)
-        prepared = {"uniq": uniq, "index": index, "rows": rows,
-                    "map_host": None}
+        # With a device map the build comes first and DECIDES the rows
+        # wherever its slot table fits the cache: a key's row is its slot
+        # (DeviceKeyMap.build_host_implicit), the pass's keys go on in
+        # row order, and the index's dense numbers (0..n-1, the order of
+        # insertion) name positions in ``rows``. Otherwise rows are the
+        # dense numbers spread over the shards, and the map stores them.
+        map_host = None
+        implicit = False
         if self._device_map_enabled:
             from .device_hash import DeviceKeyMap
 
+            implicit = DeviceKeyMap.rows_can_be_slots(
+                len(uniq), cfg.capacity, self._n_shards)
+        if implicit:
             with RecordEvent("pt.pass.map_build"):
-                prepared["map_host"] = DeviceKeyMap.build_host(uniq, rows)
-        return prepared
+                map_host, uniq, rows = DeviceKeyMap.build_host_implicit(
+                    uniq, cfg.capacity, self._n_shards)
+        with RecordEvent("pt.pass.index"):
+            index = FeasignIndex(len(uniq) * 2)
+            dense, _ = index.lookup_or_insert(uniq)
+            if not implicit:
+                rows = self._spread(dense)
+        if self._device_map_enabled and not implicit:
+            with RecordEvent("pt.pass.map_build"):
+                map_host = DeviceKeyMap.build_host(uniq, rows)
+        return {"uniq": uniq, "index": index, "rows": rows,
+                "map_host": map_host, "implicit_rows": implicit}
 
     def begin_pass(self, keys: np.ndarray) -> int:
         """PreBuildTask + BuildPull + BuildGPUTask: dedup the pass's keys,
@@ -485,6 +515,7 @@ class HbmEmbeddingCache:
                          shards=self._n_shards) as ev:
             prepared = self._prepare(keys)
             ev["unique_keys"] = len(prepared["uniq"])
+            ev["implicit_rows"] = int(prepared["implicit_rows"])
             return self._activate(prepared)
 
     def activate_pass(self, prepared: dict) -> int:
@@ -494,7 +525,8 @@ class HbmEmbeddingCache:
         with RecordEvent("pt.pass.activate",
                          unique_keys=len(prepared["uniq"]),
                          capacity=self.config.capacity,
-                         shards=self._n_shards):
+                         shards=self._n_shards,
+                         implicit_rows=int(prepared["implicit_rows"])):
             return self._activate(prepared)
 
     def _activate(self, prepared: dict) -> int:
@@ -502,6 +534,7 @@ class HbmEmbeddingCache:
         uniq, rows = prepared["uniq"], prepared["rows"]
         self._index = prepared["index"]
         self._pass_keys = uniq
+        self._pass_rows = rows if prepared["implicit_rows"] else None
 
         # ONE shard traversal creates missing features and exports full
         # rows (values + optimizer state) — round 1 walked the table
@@ -572,7 +605,8 @@ class HbmEmbeddingCache:
         enforce(self._index is not None, "begin_pass first")
         rows = self._index.lookup(np.ascontiguousarray(keys, np.uint64))
         enforce(bool((rows >= 0).all()), "batch contains keys outside the pass working set")
-        return self._spread(rows)
+        return self._pass_rows[rows] if self._pass_rows is not None \
+            else self._spread(rows)
 
     def end_pass(self) -> None:
         """EndPass / dump_to_cpu: write the working set back into the host
@@ -584,6 +618,7 @@ class HbmEmbeddingCache:
         self._index = None
         self.state = None
         self._pass_keys = None
+        self._pass_rows = None
         self.device_map = None
 
     def _flush(self) -> None:
@@ -593,7 +628,9 @@ class HbmEmbeddingCache:
             ev["bytes"] = sum(v.nbytes for v in host.values())
         keys = self._pass_keys
         with RecordEvent("pt.pass.flush_index"):
-            rows = self._spread(self._index.lookup(keys))
+            # implicit rows: the pass's keys are kept beside their rows
+            rows = self._pass_rows if self._pass_rows is not None \
+                else self._spread(self._index.lookup(keys))
         acc = self.table.accessor
         es = acc.embed_rule.state_dim
         xs = acc.embedx_rule.state_dim
@@ -643,4 +680,5 @@ class HbmEmbeddingCache:
         self._index = None
         self.state = None
         self._pass_keys = None
+        self._pass_rows = None
         self.device_map = None

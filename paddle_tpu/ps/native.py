@@ -90,15 +90,8 @@ def native_available() -> bool:
     return load_native() is not None
 
 
-def cuckoo_build(keys: np.ndarray, rows: np.ndarray, nbuckets: int,
-                 seed: int) -> Tuple[np.ndarray, np.ndarray]:
-    """Build a static bucketized-cuckoo table (csrc/cuckoo.cc) mapping
-    uint64 feasign → int32 row; returns (key u32[nbuckets, 8], row
-    i32[nbuckets, 4]) for upload to HBM (ps/device_hash.py probes them
-    in-graph). A bucket's row of ``key`` is its four hi halves then its
-    four lo halves — the builder writes that layout itself, no host pass
-    after it. Raises RuntimeError if the native lib is unavailable or
-    the build fails (caller retries with a new seed)."""
+def _cuckoo_lib():
+    """The native library with csrc/cuckoo.cc's two entry points typed."""
     lib = load_native()
     if lib is None:
         raise RuntimeError("native library unavailable")
@@ -110,18 +103,54 @@ def cuckoo_build(keys: np.ndarray, rows: np.ndarray, nbuckets: int,
         lib.cuckoo_build.argtypes = [u64p, i32p, ctypes.c_int64,
                                      ctypes.c_int64, ctypes.c_uint32,
                                      u32p, i32p]
+        lib.cuckoo_placement.restype = ctypes.c_int64
+        lib.cuckoo_placement.argtypes = [u32p, i32p, ctypes.c_int64,
+                                         ctypes.c_int64, ctypes.c_int64,
+                                         u64p, i32p]
         lib._cuckoo_configured = True
+    return lib
+
+
+def _u32(a: np.ndarray):
+    return a.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32))
+
+
+def cuckoo_build(keys: np.ndarray, rows: np.ndarray, nbuckets: int,
+                 seed: int) -> Tuple[np.ndarray, np.ndarray]:
+    """Build a static bucketized-cuckoo table (csrc/cuckoo.cc) mapping
+    uint64 feasign → int32 row; returns (key u32[nbuckets, 8], row
+    i32[nbuckets, 4]) for upload to HBM (ps/device_hash.py probes them
+    in-graph). A bucket's row of ``key`` is its four hi halves then its
+    four lo halves — the builder writes that layout itself, no host pass
+    after it. Raises RuntimeError if the native lib is unavailable or
+    the build fails (caller retries with a new seed)."""
+    lib = _cuckoo_lib()
     keys = np.ascontiguousarray(keys, np.uint64)
     rows = np.ascontiguousarray(rows, np.int32)
     key = np.empty((nbuckets, 8), np.uint32)
     row = np.empty((nbuckets, 4), np.int32)
-    u32p = ctypes.POINTER(ctypes.c_uint32)
     fails = int(lib.cuckoo_build(
         _u64(keys), _i32(rows), len(keys), nbuckets, ctypes.c_uint32(seed),
-        key.ctypes.data_as(u32p), _i32(row)))
+        _u32(key), _i32(row)))
     if fails:
         raise RuntimeError(f"cuckoo build failed to place {fails} keys")
     return key, row
+
+
+def cuckoo_placement(key: np.ndarray, row: np.ndarray, n: int, shards: int,
+                     shard_rows: int) -> Tuple[np.ndarray, np.ndarray]:
+    """The placement of a :func:`cuckoo_build` whose rows were 0..n-1,
+    in cache-row order (csrc/cuckoo.cc): (keys u64[n], rows i32[n]
+    ascending), slot ``s`` of bucket ``b`` being row ``(b mod shards) *
+    shard_rows + (b div shards) * 4 + s``."""
+    keys = np.empty(n, np.uint64)
+    rows = np.empty(n, np.int32)
+    got = int(_cuckoo_lib().cuckoo_placement(
+        _u32(key), _i32(row), len(key), shards, shard_rows, _u64(keys),
+        _i32(rows)))
+    if got != n:
+        raise RuntimeError(f"cuckoo placement holds {got} keys, not {n}")
+    return keys, rows
 
 
 def table_native_params(shard_num: int, accessor: str, acc_cfg,

@@ -14,9 +14,9 @@ from paddle_tpu.models.ctr import (CtrConfig, DeepFM, make_ctr_train_step,
                                    make_ctr_train_step_from_keys)
 from paddle_tpu.ps.accessor import AccessorConfig
 from paddle_tpu.ps.device_hash import (_SEED2_XOR, DeviceKeyMap,
-                                       DynamicDeviceKeyMap, _mix32_np,
-                                       device_hash_lookup, dynamic_map_lookup,
-                                       split_keys)
+                                       DynamicDeviceKeyMap, _filler_keys,
+                                       _mix32_np, device_hash_lookup,
+                                       dynamic_map_lookup, split_keys)
 from paddle_tpu.ps.embedding_cache import CacheConfig, HbmEmbeddingCache
 from paddle_tpu.ps.table import MemorySparseTable, TableConfig
 
@@ -47,7 +47,8 @@ def test_device_map_low_bit_keys(rng):
 
 # ---------------------------------------------------------------------------
 # the packed map: a bucket's four keys in ONE row of ``key`` (hi×4 | lo×4),
-# its rows in ``row`` — four bucket gathers a probe
+# its rows in ``row`` (explicit rows: the caller chose them) — four bucket
+# gathers a probe
 # ---------------------------------------------------------------------------
 
 
@@ -143,14 +144,20 @@ def test_packed_map_layout():
     assert (row[~live] == -1).all() and (stored[~live] == 0).all()
 
 
-def test_probe_is_four_gathers():
-    """Two hashes × (one row of ``key`` + one of ``row``): the jaxpr and
-    the lowered module of ``device_hash_lookup`` hold exactly four
-    gathers, two 8 wide and two 4 wide (a count: CPU)."""
+@pytest.mark.parametrize("form,shapes", [
+    ("implicit", [(96, 8), (96, 8)]),
+    ("explicit", [(96, 4), (96, 4), (96, 8), (96, 8)])])
+def test_probe_gathers(form, shapes):
+    """One row of ``key`` a hash, and with explicit rows one of ``row``
+    too: the jaxpr and the lowered module of ``device_hash_lookup`` hold
+    exactly two gathers, both 8 wide, where a row is its slot, and four
+    (two 8 wide, two 4 wide) where the map stores rows (a count: CPU)."""
     nb, n = 256, 96
-    table = {"key": jnp.zeros((nb, 8), jnp.uint32),
-             "row": jnp.full((nb, 4), -1, jnp.int32),
-             "seed": jnp.uint32(7)}
+    table = {"key": jnp.zeros((nb, 8), jnp.uint32), "seed": jnp.uint32(7)}
+    if form == "explicit":
+        table["row"] = jnp.full((nb, 4), -1, jnp.int32)
+    else:
+        table.update(shard_shift=jnp.int32(2), shard_rows=jnp.int32(nb))
     k = jnp.zeros((n,), jnp.uint32)
     jaxpr = jax.make_jaxpr(device_hash_lookup)(table, k, k)
 
@@ -163,9 +170,141 @@ def test_probe_is_four_gathers():
                 out += gathers(sub)
         return out
 
-    assert sorted(gathers(jaxpr.jaxpr)) == [(n, 4), (n, 4), (n, 8), (n, 8)]
+    assert sorted(gathers(jaxpr.jaxpr)) == shapes
     lowered = jax.jit(device_hash_lookup).lower(table, k, k).as_text()
-    assert lowered.count("stablehlo.gather") == 4, lowered
+    assert lowered.count("stablehlo.gather") == len(shapes), lowered
+
+
+# ---------------------------------------------------------------------------
+# implicit rows: a key's row is the slot the build put it in, the map has
+# no ``row`` array, empty slots hold a filler key that cannot match
+# ---------------------------------------------------------------------------
+
+
+def _implicit_keys(name, rng):
+    if name == "load_half":
+        # 512 keys in 256 buckets x 4 slots: load exactly 0.5
+        return np.unique(rng.integers(
+            1 << 20, 1 << 63, size=700, dtype=np.uint64))[:512]
+    if name == "nearly_empty":
+        return np.asarray([11, 1 << 40, (7 << 32) | 3], np.uint64)
+    if name == "equal_low_halves":
+        return (np.arange(1, 401, dtype=np.uint64) << np.uint64(32)) \
+            | np.uint64(0x9ABCDEF1)
+    raise AssertionError(name)
+
+
+def _slot_words(state, rows):
+    """The key whose words sit in the slot that IS cache row ``rows``."""
+    key = np.asarray(state["key"])
+    K, block = 1 << int(state["shard_shift"]), int(state["shard_rows"])
+    shard, within = rows // block, rows % block
+    b, s = (within // 4) * K + shard, within % 4
+    return (key[b, s].astype(np.uint64) << np.uint64(32)) | key[b, 4 + s]
+
+
+@pytest.mark.parametrize("fillers_in_pass", [False, True])
+@pytest.mark.parametrize("case", ["load_half", "nearly_empty",
+                                  "equal_low_halves"])
+def test_implicit_probe_matches_host_dict(case, fillers_in_pass, rng):
+    """Every key of the pass reads the position of its own words in
+    ``key``, the row the build reported for it; every other key reads
+    -1 — key 0 and both filler keys among them, which read their slot
+    once the pass holds them: no key value is reserved."""
+    keys = _implicit_keys(case, rng)
+    nb = DeviceKeyMap.buckets_for(len(keys))
+    # the fillers depend on the seed alone: learn them from a first build
+    built, _, _ = DeviceKeyMap.build_host_implicit(keys, nb * 4)
+    other, _ = _filler_keys(nb, int(built["seed"]))
+    assert other != 0
+    special = np.asarray([0, other], np.uint64)
+    if fillers_in_pass:
+        keys = np.concatenate([special, keys[:len(keys) - 2]])
+        built, placed_keys, rows = DeviceKeyMap.build_host_implicit(keys, nb * 4)
+        assert _filler_keys(nb, int(built["seed"]))[0] == other
+    else:
+        built, placed_keys, rows = DeviceKeyMap.build_host_implicit(keys, nb * 4)
+    m = DeviceKeyMap(host_built=built)
+    assert sorted(m.state) == ["key", "seed", "shard_rows", "shard_shift"]
+    assert len(rows) == len(keys) and (np.diff(rows) > 0).all()
+    np.testing.assert_array_equal(np.sort(placed_keys), np.sort(keys))
+    assert 0 <= rows.min() and rows.max() < nb * 4
+    want = dict(zip(placed_keys.tolist(), rows.tolist()))
+
+    got = _probe(m, keys)
+    np.testing.assert_array_equal(
+        got, np.asarray([want[k] for k in keys.tolist()], np.int32))
+    np.testing.assert_array_equal(_slot_words(m.state, got), keys)
+
+    absent = np.concatenate([
+        rng.integers(1 << 20, 1 << 63, size=500, dtype=np.uint64),
+        keys ^ np.uint64(1 << 33), keys ^ np.uint64(1), special])
+    absent = absent[~np.isin(absent, keys)]
+    assert (_probe(m, absent) == -1).all()
+    if fillers_in_pass:
+        assert (_probe(m, special) >= 0).all()
+    else:
+        assert np.isin(special, absent).all()
+    # every slot that is no key's row holds a filler
+    empty = np.setdiff1d(np.arange(nb * 4), rows)
+    assert np.isin(_slot_words(m.state, empty), special).all()
+
+
+@pytest.mark.parametrize("shards,slack", [(4, 1), (4, 4), (8, 2)])
+def test_implicit_rows_over_shards(shards, slack, rng):
+    """Over K shards a bucket's LOW bits pick the shard, whatever the
+    ratio of capacity to slots: every shard holds n/K keys within 5
+    sigma, inside the first slots of its block, and the probe's row is
+    the key's own slot."""
+    keys = np.unique(rng.integers(1, 1 << 63, size=5000, dtype=np.uint64))
+    n, nb = len(keys), DeviceKeyMap.buckets_for(len(keys))
+    capacity = nb * 4 * slack
+    built, placed_keys, rows = DeviceKeyMap.build_host_implicit(
+        keys, capacity, shards)
+    m = DeviceKeyMap(host_built=built)
+    block = capacity // shards
+    assert int(m.state["shard_rows"]) == block
+    assert (np.diff(rows) > 0).all()
+    assert (rows % block < nb * 4 // shards).all()
+    counts = np.bincount(rows // block, minlength=shards)
+    sigma = np.sqrt(n * (1 / shards) * (1 - 1 / shards))
+    assert np.abs(counts - n / shards).max() < 5 * sigma, counts
+    got = _probe(m, placed_keys)
+    np.testing.assert_array_equal(got, rows)
+    np.testing.assert_array_equal(_slot_words(m.state, got), placed_keys)
+    # shards that cannot deal the buckets out evenly keep explicit rows
+    assert not DeviceKeyMap.rows_can_be_slots(n, 3 * capacity, 3)
+    assert not DeviceKeyMap.rows_can_be_slots(n, nb * 4 - shards, shards)
+
+
+@pytest.mark.parametrize("capacity,implicit", [
+    (1024, True),     # 300 keys: 256 buckets, 1024 slots fit
+    (4096, True),
+    (512, False),     # more than half full: the slot table does not fit
+    (128, False)])    # under the map's 256-slot minimum (100 keys)
+def test_cache_chooses_the_form_by_what_fits(capacity, implicit, rng):
+    """``HbmEmbeddingCache(device_map=True)``: no ``row`` in the map's
+    state when nb*4 <= capacity, today's explicit rows (dense numbers)
+    when not; either way ``lookup`` and the probe agree and every row is
+    a row of the cache."""
+    keys = np.unique(rng.integers(0, 1 << 63, size=400, dtype=np.uint64))
+    keys = keys[:300 if capacity >= 512 else 100]
+    table = MemorySparseTable(TableConfig(
+        shard_num=2, accessor_config=AccessorConfig(embedx_dim=4)))
+    cache = HbmEmbeddingCache(
+        table, CacheConfig(capacity=capacity, embedx_dim=4), device_map=True)
+    cache.begin_pass(keys)
+    state = cache.device_map.state
+    assert ("row" not in state) == implicit
+    rows = cache.lookup(keys)
+    np.testing.assert_array_equal(_probe(cache.device_map, keys), rows)
+    assert len(np.unique(rows)) == len(keys)
+    assert rows.min() >= 0 and rows.max() < capacity
+    if not implicit:
+        np.testing.assert_array_equal(np.sort(rows), np.arange(len(keys)))
+        np.testing.assert_array_equal(_probe(cache.device_map, keys),
+                                      _host_probe(state, keys))
+    cache.discard_pass()
 
 
 def test_key_fed_step_matches_row_fed(rng):

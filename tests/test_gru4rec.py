@@ -136,12 +136,14 @@ def test_gru4rec_tower_exports(rng, tmp_path):
                             embedx_threshold=0.0)
     cache = HbmEmbeddingCache(table, cache_cfg, device_map=True)
     cache.begin_pass(item_keys(np.arange(N_ITEMS)))
-    cache.state["embedx_w"] = jnp.asarray(
-        rng.normal(scale=0.1,
-                   size=cache.state["embedx_w"].shape).astype(np.float32))
-    cache.state["embed_w"] = jnp.asarray(
-        rng.normal(scale=0.1,
-                   size=cache.state["embed_w"].shape).astype(np.float32))
+    # an item's embedding is the item's, wherever the pass put its row
+    # (large enough that no tower output is small beside the smoothed
+    # normalisation's eps: the norms below are 1 to 1e-3)
+    at = cache.lookup(item_keys(np.arange(N_ITEMS)))
+    for col in ("embedx_w", "embed_w"):
+        vals = rng.normal(scale=0.3, size=cache.state[col].shape)
+        cache.state[col] = jnp.zeros_like(cache.state[col]).at[at].set(
+            vals[:N_ITEMS].astype(np.float32))
 
     model = GRU4Rec(embedx_dim=dim, hidden=16, out_dim=8)
     export_gru4rec_towers(str(tmp_path), model, cache, max_len=T)

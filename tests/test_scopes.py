@@ -341,8 +341,13 @@ def test_removed_profiler_names_are_gone():
 
 # -- (c) the pass lifecycle's span tree ------------------------------------
 
-BEGIN = ["pt.pass.dedup", "pt.pass.index", "pt.pass.map_build",
-         "pt.pass.export", "pt.pass.layout", "pt.pass.upload"]
+# implicit rows (the slot table fits the cache): the map is built first
+# and its placement names the rows; explicit rows: the index names them
+# and the map stores them
+BEGIN = {1: ["pt.pass.dedup", "pt.pass.map_build", "pt.pass.index",
+             "pt.pass.export", "pt.pass.layout", "pt.pass.upload"],
+         0: ["pt.pass.dedup", "pt.pass.index", "pt.pass.map_build",
+             "pt.pass.export", "pt.pass.layout", "pt.pass.upload"]}
 END = ["pt.pass.fetch", "pt.pass.flush_index", "pt.pass.flush_export",
        "pt.pass.merge", "pt.pass.import"]
 
@@ -351,28 +356,34 @@ def _children(spans, root):
     return [s for s in spans if s.parent_id == root.span_id]
 
 
-def test_pass_lifecycle_span_tree():
+@pytest.mark.parametrize("capacity,implicit", [(256, 1), (128, 0)])
+def test_pass_lifecycle_span_tree(capacity, implicit):
+    """256 rows hold the 256-slot map of the pass's keys (implicit rows);
+    128 rows do not (explicit rows)."""
     profiler.start_timeline()
     table = _table()
-    cache = HbmEmbeddingCache(table, _cache_cfg(), device_map=True)
+    cache = HbmEmbeddingCache(table, _cache_cfg(capacity), device_map=True)
     keys = _keys().reshape(-1)
     n = cache.begin_pass(keys)
     state_bytes = sum(a.nbytes for a in cache.state.values())
-    map_bytes = sum(a.nbytes for a in cache.device_map.state.values())
+    cache_map_state = dict(cache.device_map.state)
+    map_bytes = sum(a.nbytes for a in cache_map_state.values())
     cache.end_pass()
     spans = host_spans()
     (begin,) = [s for s in spans if s.name == "pt.pass.begin"]
     (end,) = [s for s in spans if s.name == "pt.pass.end"]
     assert begin.parent_id == 0 and end.parent_id == 0
     kids = _children(spans, begin)
-    assert [s.name for s in kids] == BEGIN
+    assert [s.name for s in kids] == BEGIN[implicit]
+    assert ("row" in cache_map_state) == (not implicit)
     assert [s.name for s in _children(spans, end)] == END
     for root in (begin, end):
         assert sum(s.dur for s in _children(spans, root)) <= root.dur
     uniq = len(np.unique(keys))
     assert n == uniq
     assert begin.counts == {"keys": len(keys), "unique_keys": uniq,
-                            "capacity": 256, "shards": 1}
+                            "capacity": capacity, "shards": 1,
+                            "implicit_rows": implicit}
     assert end.counts == {"keys": uniq}
     by = {s.name: s for s in spans}
     full_dim = table.export_full(keys[:1])[0].shape[1]
@@ -399,10 +410,13 @@ def test_prepare_and_activate_apart_make_two_roots():
     assert by["pt.pass.prepare"].counts == {"keys": B * S,
                                             "unique_keys": B * S}
     assert [s.name for s in _children(spans, by["pt.pass.prepare"])] \
-        == BEGIN[:3]
+        == BEGIN[1][:3]
     assert by["pt.pass.activate"].parent_id == by["ctr_pass_build"].span_id
+    assert by["pt.pass.activate"].counts == {
+        "unique_keys": B * S, "capacity": 256, "shards": 1,
+        "implicit_rows": 1}
     assert [s.name for s in _children(spans, by["pt.pass.activate"])] \
-        == BEGIN[3:]
+        == BEGIN[1][3:]
 
 
 def test_pass_phases_picks_the_larger_pass():

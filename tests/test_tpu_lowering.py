@@ -378,46 +378,57 @@ def _deepfm_and_adam():
             {"params": dict(model.named_parameters()), "buffers": {}})
 
 
-def _cmap(nb):
+def _cmap(nb, capacity=None):
     """Shapes of a per-pass cuckoo map of ``nb`` buckets
-    (``ps/device_hash.DeviceKeyMap.state``)."""
-    return {"key": _z(nb, 8, dtype=jnp.uint32),
-            "row": _z(nb, 4, dtype=jnp.int32), "seed": np.uint32(0)}
+    (``ps/device_hash.DeviceKeyMap.state``) over a cache of ``capacity``
+    rows: implicit rows where the slot table fits it (the default: a
+    cache of exactly the slots, as in both DeepFM cells), else explicit."""
+    cmap = {"key": _z(nb, 8, dtype=jnp.uint32), "seed": np.uint32(0)}
+    if capacity is not None and nb * 4 > capacity:
+        return dict(cmap, row=_z(nb, 4, dtype=jnp.int32))
+    return dict(cmap, shard_shift=np.int32(0), shard_rows=np.int32(nb * 4))
 
 
 def _ctr_state(n_keys):
     """Shapes of a pass cache + cuckoo map at the smoke's widths."""
+    from paddle_tpu.ps.device_hash import DeviceKeyMap
+
     C, xd = SZ.capacity, SZ.embedx_dim
-    nb = 64
-    while nb * 4 < 2 * n_keys:
-        nb <<= 1
-    return _rows(C, xd), _cmap(nb)
+    return _rows(C, xd), _cmap(DeviceKeyMap.buckets_for(n_keys), C)
 
 
-def test_probe_is_four_bucket_gathers_and_the_map_is_unpadded(v5e):
+@pytest.mark.parametrize("form,widths,map_words", [
+    ("implicit", ["8", "8"], 8),
+    ("explicit", ["4", "4", "8", "8"], 8 + 4)])
+def test_probe_bucket_gathers_and_the_map_is_unpadded(v5e, form, widths,
+                                                      map_words):
     """The pass cell's probe (2^24 buckets, 106,496 keys a step) as
     XLA:TPU makes it: ``key`` u32[nb, 8] takes 8 x 128 tiles with the
-    bucket index minor, so HBM holds exactly the bytes of the two
-    u32[nb, 4] arrays it replaced (a [nb, 12] map pads to 16 columns),
-    and ``pt.probe`` is four gather fusions: two 8 wide, two 4 wide."""
+    bucket index minor, so HBM holds exactly its 512 MiB (a [nb, 12] map
+    would pad to 16 columns). With implicit rows — what the cell's pass
+    builds, 2^26 slots in 2^26 rows — ``pt.probe`` is two gather
+    fusions, both 8 wide, and the map's state is ``key`` and three
+    scalars; with explicit rows (a fuller cache) ``row`` s32[nb, 4]
+    joins it and ``pt.probe`` is four: two 8 wide, two 4 wide."""
     import re
 
     from paddle_tpu.ps.device_hash import device_hash_lookup
 
     nb, n = 1 << 24, 106496
-    cmap = _cmap(nb)
+    cmap = _cmap(nb, nb * 4 if form == "implicit" else nb * 3)
+    assert ("row" in cmap) == (form == "explicit")
     keys = _z(n, dtype=jnp.uint32)
     compiled = _compile(device_hash_lookup, SingleDeviceSharding(v5e[0]),
                         cmap, keys, keys)
     hlo = compiled.as_text()
     assert f"u32[{nb},8]{{0,1:T(8,128)}}" in hlo
-    assert f"s32[{nb},4]{{0,1:T(4,128)}}" in hlo
+    assert (f"s32[{nb},4]{{0,1:T(4,128)}}" in hlo) == (form == "explicit")
     assert compiled.memory_analysis().argument_size_in_bytes \
-        <= nb * (8 + 4) * 4 + 2 * n * 4 + 4096
+        <= nb * map_words * 4 + 2 * n * 4 + 4096
     gathers = re.findall(
         r"= [us]32\[%d,(\d)\]\S* fusion\([^\n]*kind=kCustom[^\n]*"
         r"op_name=\"[^\"]*pt\.probe[^\"]*gather\"" % n, hlo)
-    assert sorted(gathers) == ["4", "4", "8", "8"], gathers
+    assert sorted(gathers) == widths, gathers
 
 
 @pytest.mark.slow
