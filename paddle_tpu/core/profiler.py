@@ -57,6 +57,8 @@ DEVICE_SCOPES = (
     "pt.rope", "pt.moe.route", "pt.moe.dispatch", "pt.moe.experts",
     "pt.moe.combine", "pt.moe.shared", "pt.mla.q", "pt.mla.kv", "pt.mtp",
     "pt.ffn.dense",
+    "pt.conv", "pt.conv.in", "pt.conv.mix", "pt.conv.out",
+    "pt.gqa.qkv", "pt.gqa.repeat",
 )
 
 #: completed spans kept in memory (newest win): a pass is about a dozen

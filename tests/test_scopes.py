@@ -147,6 +147,30 @@ def _joyai_step_text():
     return text
 
 
+def _lfm2_step_text():
+    from paddle_tpu.executor import Trainer
+    from paddle_tpu.models.lfm2 import Lfm2, Lfm2Config, lfm2_loss
+
+    model = Lfm2(Lfm2Config(
+        vocab_size=128, hidden_size=64, num_heads=4, num_kv_heads=2,
+        layer_types=("conv", "full_attention", "conv"), num_dense_layers=1,
+        dense_size=96, num_experts=8, experts_per_token=2, expert_size=32,
+        held=(2, 2), max_seq_len=128, attn_impl="flash"))
+    trainer = Trainer(model, optimizer.AdamW(1e-3, weight_decay=0.1),
+                      lfm2_loss, amp=True)
+    ids = np.zeros((2, 128), np.int32)
+    profiler.start_timeline()
+    text = trainer.compiled_text(ids, ids)
+    spans = {s.name: s.counts for s in profiler.host_spans()
+             if s.name in ("pt.lfm2.layers", "pt.moe.held")}
+    assert [s.name for s in profiler.host_spans()].count(
+        "pt.lfm2.layers") == 1                              # once a trace
+    assert spans == {"pt.lfm2.layers": {"conv": 2, "attention": 1,
+                                        "dense": 1, "experts": 2},
+                     "pt.moe.held": {"first": 2, "count": 2, "experts": 8}}
+    return text
+
+
 _PUSH = {"pt.push.accumulate", "pt.push.update"}
 STEPS = {
     "pass_slab": (_pass_step_text, {"pt.unpack", "pt.probe", "pt.pull",
@@ -170,6 +194,14 @@ STEPS = {
                                  "pt.moe.shared", "pt.mtp", "pt.head_loss",
                                  "pt.loss", "pt.dense_opt", "pt.flash_fwd",
                                  "pt.flash_bwd_dq", "pt.flash_bwd_dkv"}),
+    "lfm2": (_lfm2_step_text, {"pt.embed", "pt.conv", "pt.conv.in",
+                               "pt.conv.mix", "pt.conv.out", "pt.attn",
+                               "pt.gqa.qkv", "pt.rope", "pt.gqa.repeat",
+                               "pt.ffn", "pt.ffn.dense", "pt.moe.route",
+                               "pt.moe.dispatch", "pt.moe.experts",
+                               "pt.moe.combine", "pt.head_loss", "pt.loss",
+                               "pt.dense_opt", "pt.flash_fwd",
+                               "pt.flash_bwd_dq", "pt.flash_bwd_dkv"}),
 }
 # what computes nothing (and what XLA inserts without metadata), and the
 # collectives, which carry no scope by design

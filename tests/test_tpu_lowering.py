@@ -276,6 +276,39 @@ def test_joyai_step_compiles_small(v5e, as_tpu):
         assert scope in text, scope
 
 
+def test_lfm2_step_compiles_small(v5e, as_tpu):
+    """The LFM2 train step for the chip at small widths with the published
+    head shape (4 query heads on 1 key-value head of 64, causal: the
+    kernels' first causal call at head width 64): three flash kernels for
+    its one attention block, the two forms' ``conditional`` forward and
+    backward in each expert layer, every new scope in the text."""
+    from paddle_tpu import nn, optimizer
+    from paddle_tpu.executor import make_train_step
+    from paddle_tpu.models.lfm2 import Lfm2, Lfm2Config, lfm2_loss
+
+    model = Lfm2(Lfm2Config(
+        vocab_size=1024, hidden_size=256, num_heads=4, num_kv_heads=1,
+        layer_types=("conv", "full_attention", "conv"), num_dense_layers=1,
+        dense_size=512, num_experts=8, experts_per_token=4, expert_size=256,
+        held=(4, 2), max_seq_len=512))
+    opt = optimizer.AdamW(learning_rate=4e-4, weight_decay=0.1, beta2=0.95)
+    step = make_train_step(model, opt, lfm2_loss, amp=True)
+    state = nn.get_state(model)
+    ids = (_z(2, 512, dtype=jnp.int32),)
+    s = SingleDeviceSharding(v5e[0])
+    text = step.lower(
+        _shapes(state, s),
+        _shapes(jax.eval_shape(opt.init, state["params"]), s), _rng_key(s),
+        _shapes(ids, s), _shapes(ids, s)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 3
+    assert text.count(" conditional(") == 2 * 2
+    for scope in ("pt.conv.in", "pt.conv.mix", "pt.conv.out", "pt.gqa.qkv",
+                  "pt.gqa.repeat", "pt.rope", "pt.moe.experts",
+                  "pt.ffn.dense"):
+        assert scope in text, scope
+    assert "bf16[8,512,128]" in text      # q as handed: 64 in 128 lanes
+
+
 def test_ernie_layer_moves_its_bf16_under_a_name(v5e, as_tpu):
     """One ERNIE layer of the benchmark cell's widths, as the chip compiles
     its train step: every copy, convert and fusion of the entry computation
