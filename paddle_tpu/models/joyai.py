@@ -55,7 +55,9 @@ with float32 accumulation; norms, rotary, softmax and the router stay
 float32. Counters leave the forward in buffers: ``expert_counts``
 [layers, num_experts] (as routed, all experts), ``held_assignments``
 [layers], ``dispatch_rung`` [layers] (rows of the form that ran: the bounded
-buffer, or every held expert on every token),
+buffer, or every held expert on every token), ``dispatch_rows_walked``
+[layers] (the rows that form's row movement passed over: whole chunks up to
+the buffer's last live row, ``parallel.moe.held_moe``),
 ``tokens_dropped`` (held assignments less those the form that ran counted
 as computed: 0 unless the dispatch is at fault); the layer axis runs over the
 expert layers, the prediction module's last.
@@ -381,6 +383,8 @@ class Joyai(Layer):
                              jnp.zeros((n, cfg.num_experts), jnp.int32))
         self.register_buffer("held_assignments", jnp.zeros((n,), jnp.int32))
         self.register_buffer("dispatch_rung", jnp.zeros((n,), jnp.int32))
+        self.register_buffer("dispatch_rows_walked",
+                             jnp.zeros((n,), jnp.int32))
         self.register_buffer("tokens_dropped", jnp.zeros((), jnp.int32))
 
     def forward(self, ids: jax.Array, output_routing: bool = False):
@@ -413,6 +417,8 @@ class Joyai(Layer):
         self._buffers["held_assignments"] = stack(
             "held_assignments").astype(jnp.int32)
         self._buffers["dispatch_rung"] = stack("rung").astype(jnp.int32)
+        self._buffers["dispatch_rows_walked"] = stack("rows_walked").astype(
+            jnp.int32)
         self._buffers["tokens_dropped"] = jnp.sum(stack("dropped")).astype(
             jnp.int32)
         if output_routing:

@@ -44,8 +44,8 @@ Matmuls go through ``nn.functional.linear`` and
 with float32 accumulation; norms, the convolution's gates and taps, rotary,
 softmax and the router stay float32. Counters leave the forward in buffers
 as ``models/joyai.py``'s do: ``expert_counts`` [expert layers,
-num_experts], ``held_assignments``, ``dispatch_rung`` [expert layers],
-``tokens_dropped``.
+num_experts], ``held_assignments``, ``dispatch_rung``,
+``dispatch_rows_walked`` [expert layers], ``tokens_dropped``.
 """
 
 from __future__ import annotations
@@ -311,6 +311,8 @@ class Lfm2(Layer):
                              jnp.zeros((n, cfg.num_experts), jnp.int32))
         self.register_buffer("held_assignments", jnp.zeros((n,), jnp.int32))
         self.register_buffer("dispatch_rung", jnp.zeros((n,), jnp.int32))
+        self.register_buffer("dispatch_rows_walked",
+                             jnp.zeros((n,), jnp.int32))
         self.register_buffer("tokens_dropped", jnp.zeros((), jnp.int32))
 
     def forward(self, ids: jax.Array, output_routing: bool = False):
@@ -343,6 +345,8 @@ class Lfm2(Layer):
         self._buffers["held_assignments"] = stack(
             "held_assignments").astype(jnp.int32)
         self._buffers["dispatch_rung"] = stack("rung").astype(jnp.int32)
+        self._buffers["dispatch_rows_walked"] = stack("rows_walked").astype(
+            jnp.int32)
         self._buffers["tokens_dropped"] = jnp.sum(stack("dropped")).astype(
             jnp.int32)
         if output_routing:

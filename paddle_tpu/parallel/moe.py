@@ -28,15 +28,15 @@ E experts live here, routes over all E by the sigmoid rule
 (``sigmoid_route``), and computes the part of the result its own experts
 give — what one rank of an expert-parallel layer computes between the
 exchanges, with no exchange and nothing standing in for the absent ranks.
-The assignments that land on a held expert are sorted first, and a row
-buffer of twice their even-load number (``dispatch_ladder``) bounds what
-is gathered, multiplied and summed. A step whose routing sends more than
-that here takes the other branch of a ``lax.cond``: every held expert on
-every token, masked by the choice — 16 / 8 of the dropless layer's rows,
-no kernel, exact — so nothing is ever dropped; each form counts what it
-computed, and ``dropped`` is the held assignments less that count. The
-whole layer (``held = (0, E)``) has every assignment live and runs the
-dropless formulation's own stages.
+The held assignments are sorted first, and a row buffer of twice their
+even-load number (``dispatch_ladder``) bounds what is gathered, multiplied
+and summed; the gathers and token sums walk it ``_HELD_CHUNK`` rows at a
+time as far as the last chunk that holds an assignment (half of it at even
+loads). A step whose routing sends more than the buffer holds takes the
+other branch of a ``lax.cond``: every held expert on every token, masked by
+the choice (16 / 8 of the dropless layer's rows, no kernel, exact), so none
+is dropped; each form counts what it computed, ``dropped`` is the held ones
+less that. ``held = (0, E)`` runs the dropless formulation's own stages.
 """
 
 from __future__ import annotations
@@ -469,7 +469,13 @@ def dispatch_ladder(tokens: int, k: int, experts: int,
     experts`` assignments that land here when loads are even, in whole row
     tiles of the grouped matmul — and, past it, ``tokens * count``: every
     held expert on every token. The whole layer (``count == experts``)
-    computes its ``tokens * k`` assignments as ``dropless_moe`` does."""
+    computes its ``tokens * k`` assignments as ``dropless_moe`` does.
+
+    The buffer's rows are its SHAPE, what a step may land here before it
+    takes the other form. What a step walks of it is read off the step:
+    the grouped matmul visits the row tiles of its run-time group sizes,
+    the gathers and token sums the ``_HELD_CHUNK``-row chunks up to the
+    last live row (``held_moe``'s ``rows_walked``)."""
     if count == experts:
         return (tokens * k,)
     tile = _GMM_TILE[2][0]
@@ -477,17 +483,47 @@ def dispatch_ladder(tokens: int, k: int, experts: int,
     return (min(rows, tokens * k), tokens * count)
 
 
+#: rows a chunk of the bounded buffer's row movement: a whole multiple of
+#: the grouped matmul's row tile (``_GMM_TILE[2][0]``). ``_rows_of_tokens``
+#: and ``_sum_to_tokens`` walk the buffer a chunk at a time, as far as the
+#: last chunk that holds an assignment. Chosen on the chip among 512, 1024
+#: and 2048 (``tools/held_rows_bench.py``; PERF.md section 6, PR 35).
+#: Inside the loops a buffer is [chunks, chunk, d] and a chunk is named by
+#: its index in that untiled leading dimension: an update at a ROW offset,
+#: which the compiler cannot see is aligned to the tiles, stays a copy of
+#: its own (28 us a [1024, 2048] f32 chunk where the fused in-place write
+#: takes 13; my chip runs, PR 35).
+_HELD_CHUNK = 1024
+
+
+def _chunk_rows(rows: int) -> int:
+    """Rows a chunk of a ``rows``-row buffer: a buffer smaller than
+    ``_HELD_CHUNK`` is one chunk; the last chunk may be part empty."""
+    return min(_HELD_CHUNK, rows)
+
+
+def _live_chunks(n_held: jax.Array, rows: int) -> jax.Array:
+    """Chunks of a ``rows``-row buffer that hold one of its first
+    ``n_held`` rows: the trip count of the row movement."""
+    chunk = _chunk_rows(rows)
+    return jnp.minimum(-(-n_held // chunk), -(-rows // chunk))
+
+
 class _HeldPlan(NamedTuple):
     """Which token each row of the bounded buffer belongs to, and where a
     token's rows lie once the buffer is sorted by token. ``tok`` [R]: the
     row's token, T for a row past the held assignments; ``perm`` [R]: token
     order -> buffer row; ``tok_sorted`` [R]; ``start`` [T]: a token's first
-    row in token order; ``has`` [T]: whether it has one."""
+    row in token order; ``has`` [T]: whether it has one; ``live``: the
+    chunks that hold a live row (``_live_chunks``) — in buffer order and
+    in token order alike the live rows are the first ``n_held``, so both
+    walks stop there."""
     tok: jax.Array
     perm: jax.Array
     tok_sorted: jax.Array
     start: jax.Array
     has: jax.Array
+    live: jax.Array
 
 
 def _held_plan(order: jax.Array, held: jax.Array, n_held: jax.Array,
@@ -499,56 +535,132 @@ def _held_plan(order: jax.Array, held: jax.Array, n_held: jax.Array,
     per_token = jnp.sum(held, axis=1, dtype=jnp.int32)
     start = jnp.cumsum(per_token) - per_token
     return _HeldPlan(tok, perm, tok_sorted, jnp.minimum(start, rows - 1),
-                     per_token > 0)
+                     per_token > 0, _live_chunks(n_held, rows))
 
 
-def _sum_to_tokens(z: jax.Array, plan: _HeldPlan, k: int) -> jax.Array:
-    """Rows ``z`` [R, d] (zero where ``plan.tok`` is T) summed by token,
-    [T, d] float32, with gathers alone: the rows in token order (a
-    token's at most k rows are then neighbours), log2(k) shifted adds that
-    leave a token's sum in its first row, and one gather of T rows. A
-    token has 0 to k rows here, so ``combine_rows``' gather of k rows a
-    token would read T*k rows to find the R that exist (8 times as many
-    at a sixteenth held), and a scatter-add of rows this wide goes row by
-    row on the TPU (0.37 us a row, PERF.md section 5: ten times a gathered
-    row)."""
-    zs = jnp.take(z.astype(jnp.float32), plan.perm, axis=0)
-    tok = plan.tok_sorted
-    shift = 1
-    while shift < k:
-        same = jnp.concatenate(
-            [tok[shift:] == tok[:-shift], jnp.zeros((shift,), bool)])
-        nxt = jnp.concatenate([zs[shift:], jnp.zeros_like(zs[:shift])])
-        zs = zs + jnp.where(same[:, None], nxt, 0.0)
-        shift *= 2
-    return jnp.where(plan.has[:, None], jnp.take(zs, plan.start, axis=0), 0.0)
+# jitted by shape, dtype, k and scope: a model's expert layers are alike, so
+# the walk is traced once a process — not once a layer, once more where the
+# backward re-runs the form and twice in a set-up program that runs the
+# forward pass (a second of Python on the chip's host, PERF.md section 6)
+@functools.partial(jax.jit, static_argnames=("k", "scope", "chunk"))
+def _sum_to_tokens(z: jax.Array, plan: _HeldPlan, k: int, scope: str,
+                   chunk: int) -> jax.Array:
+    """Rows ``z`` [R, d] summed by token, [T, d] float32, with gathers
+    alone: the rows in token order (a token's at most k rows are then
+    neighbours), log2(k) shifted adds that leave a token's sum in its first
+    row, and one gather of T rows. A token has 0 to k rows here, so
+    ``combine_rows``' gather of k rows a token would read T*k rows to find
+    the R that exist (8 times as many at a sixteenth held), and a
+    scatter-add of rows this wide goes row by row on the TPU (0.37 us a
+    row, PERF.md section 5: ten times a gathered row).
+
+    Walked ``chunk`` rows at a time (``_chunk_rows``) over ``plan.live``
+    chunks: in token order the rows of no token (``plan.tok`` T) sort
+    last, so what lies past the live chunks is never read — rows of ``z``
+    there may hold anything, and the sums' buffer is not even filled. A
+    chunk gathers the rows its tokens can reach past its end as well (a
+    token straddling the edge still sums whole, in the same order of
+    additions as over the whole buffer). The last gather reads a token's first row, or for a token
+    with no row a row of one more chunk, of zeros, kept past the buffer (a
+    select over [T, d] after the gather was a pass of its own; spread over
+    the chunk, because a gather whose padding all names ONE row is the
+    slower, PERF.md section 7). The loop's body carries ``scope`` (a
+    jitted function is lowered once: what it names must not be the first
+    caller's)."""
+    R, d = z.shape
+    T = plan.has.shape[0]
+    chunks = -(-R // chunk)
+    shifts = [1 << i for i in range(max(k - 1, 0).bit_length())]
+    halo = -(-sum(shifts) // 8) * 8                # whole sublanes
+    past = chunks * chunk + halo - R
+    perm = jnp.concatenate([plan.perm, jnp.zeros((past,), jnp.int32)])
+    tok = jnp.concatenate([plan.tok_sorted, jnp.full((past,), T, jnp.int32)])
+
+    def sum_chunk(c, sums):
+        with jax.named_scope(scope):
+            t = lax.dynamic_slice(tok, (c * chunk,), (chunk + halo,))
+            rows = lax.dynamic_slice(perm, (c * chunk,), (chunk + halo,))
+            zs = jnp.take(z, rows, axis=0, mode="clip").astype(jnp.float32)
+            for shift in shifts:
+                same = jnp.concatenate(
+                    [t[shift:] == t[:-shift], jnp.zeros((shift,), bool)])
+                nxt = jnp.concatenate([zs[shift:], jnp.zeros_like(zs[:shift])])
+                zs = zs + jnp.where(same[:, None], nxt, 0.0)
+            return lax.dynamic_update_index_in_dim(sums, zs[:chunk], c, 0)
+
+    sums = lax.dynamic_update_index_in_dim(
+        lax.empty((chunks + 1, chunk, d), jnp.float32),
+        jnp.zeros((chunk, d), jnp.float32), chunks, 0)
+    sums = lax.fori_loop(0, plan.live, sum_chunk, sums)
+    none = chunks * chunk + jnp.arange(T, dtype=jnp.int32) % chunk
+    return jnp.take(sums.reshape(-1, d),
+                    jnp.where(plan.has, plan.start, none), axis=0,
+                    mode="clip")
 
 
-def _rows_of_tokens(x: jax.Array, plan: _HeldPlan) -> jax.Array:
-    """[R, d]: the token rows of the buffer, zero past the held ones."""
-    rows = jnp.take(x, jnp.minimum(plan.tok, x.shape[0] - 1), axis=0)
-    return jnp.where((plan.tok < x.shape[0])[:, None], rows, 0)
+@functools.partial(jax.jit, static_argnames=("scope", "chunk"))
+def _rows_of_tokens(x: jax.Array, plan: _HeldPlan, scope: str,
+                    chunk: int) -> jax.Array:
+    """[R, d]: the token rows of the buffer, gathered ``chunk`` rows at a
+    time over ``plan.live`` chunks; zero past the held ones — the chunks
+    with no live row are written once, with zeros. The loops' bodies carry
+    ``scope``."""
+    T, d = x.shape
+    R = plan.tok.shape[0]
+    chunks = -(-R // chunk)
+    toks = jnp.concatenate(
+        [plan.tok, jnp.full((chunks * chunk - R,), T, jnp.int32)]).reshape(
+            chunks, chunk)
+
+    def zero_chunk(c, buf):
+        with jax.named_scope(scope):
+            return lax.dynamic_update_index_in_dim(
+                buf, jnp.zeros((chunk, d), x.dtype), c, 0)
+
+    def gather_chunk(c, buf):
+        with jax.named_scope(scope):
+            tok = lax.dynamic_index_in_dim(toks, c, 0, keepdims=False)
+            live = tok < T
+            # a dead row reads a row of its own, as a token with no row does
+            own = (c * chunk + jnp.arange(chunk, dtype=jnp.int32)) % T
+            rows = jnp.take(x, jnp.where(live, tok, own), axis=0,
+                            mode="clip")
+            return lax.dynamic_update_index_in_dim(
+                buf, jnp.where(live[:, None], rows, 0), c, 0)
+
+    buf = lax.fori_loop(plan.live, chunks, zero_chunk,
+                        lax.empty((chunks, chunk, d), x.dtype))
+    buf = lax.fori_loop(0, plan.live, gather_chunk, buf)
+    return buf.reshape(-1, d)[:R]
+
+
+def _chunk(plan: _HeldPlan) -> int:
+    return _chunk_rows(plan.tok.shape[0])
 
 
 # the two are each other's transpose: neither backward is a scatter-add
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _gather_held(x, plan, k):
-    return _rows_of_tokens(x, plan)
+    return _rows_of_tokens(x, plan, "pt.moe.dispatch", _chunk(plan))
 
 
 _gather_held.defvjp(
-    lambda x, plan, k: (_rows_of_tokens(x, plan), plan),
-    lambda k, plan, g: (_sum_to_tokens(g, plan, k).astype(g.dtype), None))
+    lambda x, plan, k: (_rows_of_tokens(
+        x, plan, "pt.moe.dispatch", _chunk(plan)), plan),
+    lambda k, plan, g: (_sum_to_tokens(
+        g, plan, k, "pt.moe.dispatch", _chunk(plan)).astype(g.dtype), None))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _combine_held(z, plan, k):
-    return _sum_to_tokens(z, plan, k)           # z float32, as the sum is
+def _combine_held(z, plan, k):                   # z float32, as the sum is
+    return _sum_to_tokens(z, plan, k, "pt.moe.combine", _chunk(plan))
 
 
 _combine_held.defvjp(
-    lambda z, plan, k: (_sum_to_tokens(z, plan, k), plan),
-    lambda k, plan, g: (_rows_of_tokens(g, plan), None))
+    lambda z, plan, k: (_sum_to_tokens(
+        z, plan, k, "pt.moe.combine", _chunk(plan)), plan),
+    lambda k, plan, g: (_rows_of_tokens(
+        g, plan, "pt.moe.combine", _chunk(plan)), None))
 
 
 @jax.custom_vjp
@@ -686,10 +798,14 @@ def held_moe(x: jax.Array, router_w: jax.Array, bias: jax.Array,
     dict with ``logits``, ``held_assignments`` (how many of the T*k landed
     here), ``rung`` (the rows of the form that ran, one of
     ``dispatch_ladder``: the sorted buffer where it holds them, else every
-    held expert on every token) and ``dropped``: the held assignments less
-    those the form that ran computed (its live rows, or the choices its
-    mask let through). ``held = (0, E)`` is the whole layer: every
-    assignment is live, and it runs ``dropless_moe``'s stages."""
+    held expert on every token), ``rows_walked`` (the rows the form's row
+    movement passed over: the buffer's ``_HELD_CHUNK``-row chunks up to the
+    last that holds an assignment — about half of ``rung`` at even loads,
+    all of it where loads fill the buffer — else ``rung`` itself) and
+    ``dropped``: the held assignments less those the form that ran computed
+    (its live rows, or the choices its mask let through). ``held = (0, E)``
+    is the whole layer: every assignment is live, and it runs
+    ``dropless_moe``'s stages."""
     T = x.shape[0]
     first, count = held
     E = router_w.shape[-1]
@@ -705,8 +821,9 @@ def held_moe(x: jax.Array, router_w: jax.Array, bias: jax.Array,
     if count == E:
         out = _every_assignment(x, route, w_gate, w_up, w_down, k)
         n_held = jnp.sum(route["counts"])
+        rung = jnp.asarray(rungs[0], jnp.int32)
         route.update(held_assignments=n_held, dropped=T * k - n_held,
-                     rung=jnp.asarray(rungs[0], jnp.int32))
+                     rung=rung, rows_walked=rung)
         return out, route
     with jax.named_scope("pt.moe.dispatch"):
         # held assignments first, by expert; the others, all alike, last
@@ -719,6 +836,10 @@ def held_moe(x: jax.Array, router_w: jax.Array, bias: jax.Array,
     out, computed = _held_experts(
         rungs[0], k, dense, x, route["weight"], (w_gate, w_up, w_down),
         (order, inverse, held_mask, group_sizes, local))
+    walked = jnp.minimum(
+        _live_chunks(n_held, rungs[0]) * _chunk_rows(rungs[0]), rungs[0])
     route.update(held_assignments=n_held, dropped=n_held - computed,
-                 rung=jnp.where(dense, rungs[1], rungs[0]).astype(jnp.int32))
+                 rung=jnp.where(dense, rungs[1], rungs[0]).astype(jnp.int32),
+                 rows_walked=jnp.where(dense, rungs[1], walked).astype(
+                     jnp.int32))
     return out, route

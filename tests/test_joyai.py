@@ -193,6 +193,28 @@ def _uncut_layer(p, x, bias, k, E):
     return REF._experts(p, "", x, bias, cfg, None, lambda a: a)[0]
 
 
+@pytest.mark.parametrize("held", [(0, 8), (2, 2), (6, 2)],
+                         ids=["whole", "held_2_3", "held_6_7"])
+def test_rows_walked_is_stacked_a_layer_within_the_rung(held):
+    """``dispatch_rows_walked`` leaves the step beside ``dispatch_rung``:
+    one count an expert layer (the module's last), the whole chunks the
+    bounded buffer's row movement passed over — never more than the rung,
+    and the rung itself where every assignment is live."""
+    pt.seed(3)
+    cfg = JoyaiConfig(**SMALL, held=held)
+    ids, labels = _batch(cfg, 2, 5)
+    _, _, buffers, _ = _sgd_step(Joyai(cfg), ids, labels)
+    walked = np.asarray(buffers["dispatch_rows_walked"])
+    rung = np.asarray(buffers["dispatch_rung"])
+    assert walked.shape == rung.shape == (cfg.expert_layers,)
+    assert walked.dtype == np.int32 and (walked <= rung).all()
+    landed = np.asarray(buffers["held_assignments"])
+    if held == (0, cfg.num_experts):
+        np.testing.assert_array_equal(walked, rung)
+    else:       # a 32-row buffer is one chunk: walked whole, or not at all
+        np.testing.assert_array_equal(walked, np.where(landed > 0, rung, 0))
+
+
 def _share(p, x, bias, k, held):
     first, n = held
     return moe.held_moe(x, p["router_w"], bias, p["w_gate"][first:first + n],

@@ -188,6 +188,30 @@ STACKS = {
 }
 
 
+@pytest.mark.parametrize("stack", ["slice_whole", "slice_held_2_3",
+                                   "slice_held_4_7"])
+def test_rows_walked_is_stacked_a_layer_within_the_rung(stack):
+    """``dispatch_rows_walked`` leaves the step beside ``dispatch_rung``:
+    one count an expert layer, the whole chunks the bounded buffer's row
+    movement passed over — never more than the rung, and the rung itself
+    where every assignment is live."""
+    kinds, dense, held = STACKS[stack]
+    pt.seed(3)
+    cfg = Lfm2Config(**SMALL, layer_types=kinds, num_dense_layers=dense,
+                     held=held)
+    ids, labels = _batch(cfg, 2, 5)
+    _, _, buffers, _ = _sgd_step(Lfm2(cfg), ids, labels)
+    walked = np.asarray(buffers["dispatch_rows_walked"])
+    rung = np.asarray(buffers["dispatch_rung"])
+    assert walked.shape == rung.shape == (cfg.expert_layers,)
+    assert walked.dtype == np.int32 and (walked <= rung).all()
+    landed = np.asarray(buffers["held_assignments"])
+    if held == (0, cfg.num_experts):
+        np.testing.assert_array_equal(walked, rung)
+    else:       # a buffer this small is one chunk: walked whole, or not
+        np.testing.assert_array_equal(walked, np.where(landed > 0, rung, 0))
+
+
 @pytest.mark.parametrize("stack", sorted(STACKS))
 def test_train_step_matches_reference(stack):
     """The loss, EVERY gradient leaf and the router biases after the step,

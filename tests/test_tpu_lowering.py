@@ -309,6 +309,53 @@ def test_lfm2_step_compiles_small(v5e, as_tpu):
     assert "bf16[8,512,128]" in text      # q as handed: 64 in 128 lanes
 
 
+@pytest.mark.parametrize("cell", ["lfm2", "joyai"])
+def test_held_row_movement_walks_chunks_at_the_cells_shapes(v5e, cell):
+    """``held_moe``'s bounded buffer at the two held cells' full shapes
+    (LFM2: 16,384 tokens, 4 of 32, 8 held, 32,768 rows; JoyAI: 8,192, 8 of
+    256, 16 held, 8,192 rows; width 2048, bf16 rows in, f32 rows back):
+    both movements and their transposes compile for the chip as loops
+    over chunks whose bodies carry the layer's scopes and write their
+    chunk in place, the buffers are allocated and never filled whole."""
+    import re
+
+    from paddle_tpu.parallel import moe
+
+    T, k, E, count = {"lfm2": (16384, 4, 32, 8),
+                      "joyai": (8192, 8, 256, 16)}[cell]
+    R, d = moe.dispatch_ladder(T, k, E, count)[0], 2048
+
+    def both_ways(index, x, z, g_buf, g_out):
+        held = index < count
+        order, _ = moe.sort_by_expert(jnp.where(held, index, count))
+        plan = moe._held_plan(order, held, jnp.sum(held, dtype=jnp.int32),
+                              R, k)
+        buf, to_x = jax.vjp(lambda x: moe._gather_held(x, plan, k), x)
+        out, to_z = jax.vjp(lambda z: moe._combine_held(z, plan, k), z)
+        return buf, out, to_x(g_buf)[0], to_z(g_out)[0]
+
+    text = _compile(both_ways, SingleDeviceSharding(v5e[0]),
+                    _z(T, k, dtype=jnp.int32), _z(T, d, dtype=jnp.bfloat16),
+                    _z(R, d), _z(R, d, dtype=jnp.bfloat16),
+                    _z(T, d)).as_text()
+    # two gathers (the dead chunks zeroed by a loop of their own), two sums
+    assert text.count(" while(") == 2 * 2 + 2
+    assert text.count('custom_call_target="AllocateBuffer"') == 4
+    filled = [m.group(0) for m in re.finditer(
+        r"\w+\[([\d,]+)\]\S* broadcast\(", text)
+        if np.prod([int(n) for n in m.group(1).split(",")]) >= R * d]
+    assert not filled, filled
+    # a chunk is written in place by the operation that made it: the
+    # update is the root of a fusion, under the layer's scope
+    writes = [line for line in text.splitlines()
+              if " dynamic-update-slice(" in line and "while/body" in line]
+    assert len(writes) == 6, writes
+    for line in writes:
+        assert line.lstrip().startswith("ROOT "), line
+        assert ("while/body/pt.moe.dispatch" in line
+                or "while/body/pt.moe.combine" in line), line
+
+
 def test_ernie_layer_moves_its_bf16_under_a_name(v5e, as_tpu):
     """One ERNIE layer of the benchmark cell's widths, as the chip compiles
     its train step: every copy, convert and fusion of the entry computation
