@@ -72,32 +72,6 @@ class Sizes:
     ernie_steps: int = 3
 
 
-class CompileLog:
-    """Counts what jax compiled: every backend compile request, and of
-    those the persistent-cache hits and the entries written (jax writes
-    an entry for each program that took over
-    ``jax_persistent_cache_min_compile_time_secs`` = 1 s to compile)."""
-
-    def __init__(self) -> None:
-        import jax.monitoring as mon
-
-        self.requests = 0
-        self.hits = 0
-        self.written = 0
-        mon.register_event_listener(self._on_event)
-        mon.register_event_duration_secs_listener(self._on_duration)
-
-    def _on_event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.written += 1
-
-    def _on_duration(self, event: str, _secs: float, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.requests += 1
-
-
 def make_ctr_dataset(sz: Sizes, n_batches: int, seed: int):
     """A seeded MultiSlot text dataset with a planted signal the tower
     can learn: the label is a noisy threshold on two dense features.
@@ -204,11 +178,12 @@ def _max_diff(a, b, relative: bool = False) -> float:
 # ---------------------------------------------------------------------------
 
 
-def leg_pass(sz: Sizes, log: CompileLog) -> Dict:
+def leg_pass(sz: Sizes) -> Dict:
     import jax
 
     import paddle_tpu as pt
     from paddle_tpu import optimizer
+    from paddle_tpu.core.profiler import compile_counts
     from paddle_tpu.models.ctr import (make_ctr_train_step_packed,
                                        pack_ctr_batch)
     from paddle_tpu.ps.embedding_cache import HbmEmbeddingCache
@@ -223,9 +198,9 @@ def leg_pass(sz: Sizes, log: CompileLog) -> Dict:
                              table, cache_cfg, sparse, dense, "label",
                              slab=sz.slab, amp=True)
     r1 = trainer.train_from_dataset(ds, batch_size=sz.batch)
-    before = log.requests
+    before = compile_counts()["requests"]
     r2 = trainer.train_from_dataset(ds, batch_size=sz.batch)
-    recompiled = log.requests - before
+    recompiled = compile_counts()["requests"] - before
     assert r1["steps"] == r2["steps"] == sz.pass_batches, (r1, r2)
     assert np.isfinite(r1["loss"]) and np.isfinite(r2["loss"]), (r1, r2)
     assert r2["loss"] < r1["loss"], \
@@ -279,9 +254,10 @@ def leg_pass(sz: Sizes, log: CompileLog) -> Dict:
 # ---------------------------------------------------------------------------
 
 
-def leg_stream(sz: Sizes, log: CompileLog) -> Dict:
+def leg_stream(sz: Sizes) -> Dict:
     import paddle_tpu as pt
     from paddle_tpu import optimizer
+    from paddle_tpu.core.profiler import compile_counts
     from paddle_tpu.ps import rpc
     from paddle_tpu.ps.accessor import AccessorConfig
     from paddle_tpu.ps.communicator import SyncCommunicator
@@ -311,10 +287,10 @@ def leg_stream(sz: Sizes, log: CompileLog) -> Dict:
         st_cold = tier.stats()
         assert st_cold["misses"] > 0, "the cold epoch admitted nothing"
         client.reset_op_counts()
-        before = log.requests
+        before = compile_counts()["requests"]
         warm = tr.train_from_dataset(ds, batch_size=sz.batch)
         rpcs = client.reset_op_counts()
-        recompiled = log.requests - before
+        recompiled = compile_counts()["requests"] - before
         assert cold["steps"] == warm["steps"] == sz.stream_batches, (cold, warm)
         assert np.isfinite(cold["loss"]) and np.isfinite(warm["loss"])
         assert rpcs == {}, f"the warm epoch performed PS RPCs: {rpcs}"
@@ -539,7 +515,6 @@ def main() -> int:
     cache_dir = enable_compile_cache()
     held = (sum(f.endswith("-cache") for f in os.listdir(cache_dir))
             if os.path.isdir(cache_dir) else 0)
-    log = CompileLog()
     devices = jax.devices()
     dev = devices[0]
     try:
@@ -557,8 +532,8 @@ def main() -> int:
             f"({dev.device_kind})")
 
     sz = Sizes()
-    legs = [("pass", lambda: leg_pass(sz, log)),
-            ("stream", lambda: leg_stream(sz, log)),
+    legs = [("pass", lambda: leg_pass(sz)),
+            ("stream", lambda: leg_stream(sz)),
             ("dense", lambda: leg_dense(sz))]
     if len(devices) >= 4:
         legs.append(("four", lambda: leg_four(sz, devices[:4])))
@@ -574,12 +549,21 @@ def main() -> int:
     # (its default threshold), so a second run finds them all; one that
     # compiled in just under a second before may cross the line and be
     # written now
-    state = (f"warm: {log.hits} of the {held} programs the cache held were "
-             f"found, {log.written} written" if held else
-             f"cold: the cache was empty, the {log.written} programs that "
-             "took over 1 s to compile were written")
-    print(f"cache: requests={log.requests} hits={log.hits} "
-          f"written={log.written} — {state}", flush=True)
+    from paddle_tpu.core.profiler import compile_counts, host_spans
+
+    # the program's own counters: backend compile requests, persistent-
+    # cache hits, entries written
+    log = compile_counts()
+    state = (f"warm: {log['cache_hits']} of the {held} programs the cache "
+             f"held were found, {log['cache_written']} written" if held else
+             f"cold: the cache was empty, the {log['cache_written']} programs "
+             "that took over 1 s to compile were written")
+    print(f"cache: requests={log['requests']} hits={log['cache_hits']} "
+          f"written={log['cache_written']} — {state}", flush=True)
+    compiled = sorted((s for s in host_spans() if s.name == "pt.compile"
+                       and not s.counts["hit"]), key=lambda s: -s.dur)[:5]
+    print("compiled, not read (costliest): " + json.dumps(
+        [[s.counts["fun"], round(s.dur, 2)] for s in compiled]), flush=True)
     print(f"wall: {time.perf_counter() - t_start:.1f}s", flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -53,7 +53,6 @@ from .profiler import (
     RecordEvent,
     host_event_stats,
     host_spans,
-    record_event,
     reset_host_events,
 )
 

@@ -11,11 +11,20 @@ per-name wall-time aggregate (the reference's CostProfiler,
 ``distributed/common/cost_timer.h``) and (d) a bounded in-memory ring of
 completed spans with parent ids and counts (``host_spans``).
 
+JAX's compile pipeline lands in (c) and (d) too: a ``jax.monitoring``
+listener, installed once at import, records every outermost trace, every
+lowering and every backend compile request (a persistent-cache read or
+an XLA compile) as ``pt.compile.trace`` / ``pt.compile.lower`` /
+``pt.compile`` spans with the function's name, under the ``RecordEvent``
+that was open on that thread. A compiled function's next call fires
+nothing, so the step path pays nothing.
+
 One vocabulary, ``pt.*``: ``DEVICE_SCOPES`` are the ``jax.named_scope``
 names inside the jitted steps (HLO metadata, trace-time only; an
 operation belongs to the LAST ``pt.`` token of its ``op_name``);
 ``pt.pass.*`` are the host spans of the pass lifecycle
-(``ps/embedding_cache.py``). docs/OPERATIONS.md §10 lists both.
+(``ps/embedding_cache.py``); ``pt.compile*`` are the compile pipeline's.
+docs/OPERATIONS.md §10 lists all three.
 """
 
 from __future__ import annotations
@@ -28,13 +37,14 @@ import time
 from typing import Any, Dict, Iterator, List, NamedTuple
 
 import jax
+import jax.monitoring
 
+from ..obs import registry as _obs_registry
 from ..obs import trace as _obs_trace
 
 __all__ = [
     "RecordEvent",
     "timed",
-    "record_event",
     "host_event_stats",
     "reset_host_events",
     "host_spans",
@@ -42,7 +52,8 @@ __all__ = [
     "DEVICE_SCOPES",
     "export_chrome_tracing",
     "start_timeline",
-    "stop_timeline",
+    "install_compile_listener",
+    "compile_counts",
 ]
 
 #: every ``jax.named_scope`` the program opens in a jitted step or round a
@@ -62,7 +73,8 @@ DEVICE_SCOPES = (
 )
 
 #: completed spans kept in memory (newest win): a pass is about a dozen
-#: spans, a step one, so this holds the last few thousand steps
+#: spans, a step one, a compiled program three, so this holds the last few
+#: thousand steps
 SPAN_RING = 4096
 
 
@@ -99,10 +111,10 @@ class _HostEvents:
 
 
 class HostSpan(NamedTuple):
-    """One completed ``RecordEvent``. ``t0`` is ``time.perf_counter``
-    seconds (add ``obs.trace.EPOCH_ANCHOR_US`` for the wall clock);
-    ``parent_id`` is the enclosing open ``RecordEvent`` of the same
-    thread, 0 for a root."""
+    """One completed ``RecordEvent`` or ``pt.compile*`` span. ``t0`` is
+    ``time.perf_counter`` seconds (add ``obs.trace.EPOCH_ANCHOR_US`` for
+    the wall clock); ``parent_id`` is the enclosing open ``RecordEvent``
+    of the same thread, 0 for a root."""
 
     name: str
     t0: float
@@ -132,10 +144,6 @@ def start_timeline() -> None:
     Recording itself is always on."""
     with _SPANS_LOCK:
         _SPANS.clear()
-
-
-def stop_timeline() -> None:
-    """Kept for callers that bracket a region; the ring needs no stop."""
 
 
 def export_chrome_tracing(path: str) -> str:
@@ -198,14 +206,115 @@ def RecordEvent(name: str, **counts) -> Iterator[Dict[str, Any]]:
             if obs_span is not None:
                 for k, v in counts.items():
                     obs_span.add_attr(k, v)
-            _EVENTS.add(name, dt)
-            with _SPANS_LOCK:
-                _SPANS.append(HostSpan(
-                    name, t0, dt, span_id, parent_id,
-                    threading.get_ident() % 1_000_000, counts))
+            _complete(name, t0, dt, span_id, parent_id, counts)
 
 
-record_event = RecordEvent
+def _complete(name: str, t0: float, dur: float, span_id: int,
+              parent_id: int, counts: Dict[str, Any]) -> None:
+    _EVENTS.add(name, dur)
+    with _SPANS_LOCK:
+        _SPANS.append(HostSpan(name, t0, dur, span_id, parent_id,
+                               threading.get_ident() % 1_000_000, counts))
+
+
+# -- the compile pipeline, from jax.monitoring -----------------------------
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+#: jax fires it when it WRITES an entry: a program it compiled, not read
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+_REQUESTS = _obs_registry.counter("pt_compile_requests")
+_CACHE_HITS = _obs_registry.counter("pt_compile_cache_hits")
+_CACHE_MISSES = _obs_registry.counter("pt_compile_cache_misses")
+
+#: this thread's place in the pipeline. ``.depth`` / ``.traces``: the
+#: traces and lowerings open, and the traces finished since the outermost
+#: of them was entered (inner jits and even ``add`` trace inside the outer
+#: function; a lowering rule written in jax.numpy traces its operations
+#: too: hundreds an initialiser); ``.hit`` / ``.cache_read_s``: what the
+#: cache said since the backend request was entered
+_COMPILING = threading.local()
+_LISTENING = False
+
+
+def _compile_span(name: str, start: float, end: float,
+                  counts: Dict[str, Any]) -> None:
+    """jax stamps its events with ``time.time()``; the ring's clock is
+    ``perf_counter``, the anchor between the two is ``obs.trace``'s."""
+    stack = getattr(_OPEN, "stack", None)
+    _complete(name, start - _obs_trace.EPOCH_ANCHOR_US / 1e6, end - start,
+              next(_IDS), stack[-1] if stack else 0, counts)
+
+
+def _on_compile_entry(event: str, _start: float, **_kw) -> None:
+    if event == _TRACE_EVENT or event == _LOWER_EVENT:
+        depth = getattr(_COMPILING, "depth", 0)
+        if depth == 0:
+            _COMPILING.traces = 0
+        _COMPILING.depth = depth + 1
+    elif event == _BACKEND_EVENT:
+        _COMPILING.hit = 0
+        _COMPILING.cache_read_s = 0.0
+
+
+def _on_cache_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _COMPILING.hit = 1
+        _CACHE_HITS.inc()
+    elif event == _CACHE_MISS_EVENT:
+        _CACHE_MISSES.inc()
+
+
+def _on_cache_read(event: str, seconds: float, **_kw) -> None:
+    if event == _CACHE_READ_EVENT:
+        _COMPILING.cache_read_s = seconds
+
+
+def _on_compile_exit(event: str, start: float, end: float,
+                     fun_name: str = "", **_kw) -> None:
+    if event == _TRACE_EVENT:
+        _COMPILING.traces = getattr(_COMPILING, "traces", 0) + 1
+        _COMPILING.depth = max(getattr(_COMPILING, "depth", 1) - 1, 0)
+        if _COMPILING.depth == 0:     # else: inside that trace or lowering
+            _compile_span("pt.compile.trace", start, end,
+                          {"fun": fun_name, "traces": _COMPILING.traces})
+    elif event == _LOWER_EVENT:
+        _COMPILING.depth = max(getattr(_COMPILING, "depth", 1) - 1, 0)
+        _compile_span("pt.compile.lower", start, end, {"fun": fun_name})
+    elif event == _BACKEND_EVENT:
+        _REQUESTS.inc()
+        _compile_span("pt.compile", start, end, {
+            "fun": fun_name, "hit": getattr(_COMPILING, "hit", 0),
+            "cache_read_s": getattr(_COMPILING, "cache_read_s", 0.0)})
+
+
+def install_compile_listener() -> bool:
+    """Register the four ``jax.monitoring`` listeners above; False (and
+    nothing registered) when they already are. Called at import."""
+    global _LISTENING
+    if _LISTENING:
+        return False
+    _LISTENING = True
+    jax.monitoring.register_scalar_listener(_on_compile_entry)
+    jax.monitoring.register_event_listener(_on_cache_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_cache_read)
+    jax.monitoring.register_event_time_span_listener(_on_compile_exit)
+    return True
+
+
+install_compile_listener()
+
+
+def compile_counts() -> Dict[str, int]:
+    """This process's backend compile ``requests`` and, of those, the
+    persistent cache's ``cache_hits`` and the entries it wrote
+    (``cache_written``): the three ``pt_compile_*`` counters."""
+    return {"requests": _REQUESTS.value, "cache_hits": _CACHE_HITS.value,
+            "cache_written": _CACHE_MISSES.value}
 
 
 def host_event_stats() -> Dict[str, Dict[str, float]]:

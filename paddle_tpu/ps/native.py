@@ -20,6 +20,8 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from ..core.profiler import RecordEvent
+
 __all__ = ["FeasignIndex", "NativeSparseTableEngine", "SsdTableEngine",
            "native_available", "load_native", "build_native", "dedup_u64"]
 
@@ -34,18 +36,30 @@ def build_native(force: bool = False) -> bool:
     """``make`` the library from csrc/ (``force`` rebuilds everything —
     chip_smoke.py uses it so the library it loads was compiled in that
     run, on that machine). Returns False when the host has no ``make``;
-    raises RuntimeError with the build output when the build fails."""
-    try:
-        out = subprocess.run(
-            ["make", "-s"] + (["-B"] if force else []), cwd=_CSRC,
-            capture_output=True, text=True, timeout=600)
-    except FileNotFoundError:
-        return False
+    raises RuntimeError with the build output when the build fails. The
+    span ``pt.native.build`` says whether make wrote a library
+    (``built`` 1) or found it current (0)."""
+    with RecordEvent("pt.native.build", built=0) as ev:
+        before = _lib_mtime()
+        try:
+            out = subprocess.run(
+                ["make", "-s"] + (["-B"] if force else []), cwd=_CSRC,
+                capture_output=True, text=True, timeout=600)
+        except FileNotFoundError:
+            return False
+        ev["built"] = int(_lib_mtime() != before)
     if out.returncode != 0:
         raise RuntimeError(
             f"native build failed (make rc={out.returncode} in {_CSRC}):\n"
             f"{out.stdout[-2000:]}{out.stderr[-4000:]}")
     return True
+
+
+def _lib_mtime() -> Optional[int]:
+    try:
+        return os.stat(_LIB_PATH).st_mtime_ns
+    except FileNotFoundError:
+        return None
 
 
 def load_native() -> Optional[ctypes.CDLL]:

@@ -368,14 +368,13 @@ def test_timeline_merges_worker_traces(tmp_path):
     from timeline import merge_traces
 
     from paddle_tpu.core.profiler import (RecordEvent, export_chrome_tracing,
-                                          start_timeline, stop_timeline)
+                                          start_timeline)
 
     files = []
     for w in range(2):
         start_timeline()
         with RecordEvent(f"work_{w}"):
             pass
-        stop_timeline()
         p = tmp_path / f"worker{w}.json"
         export_chrome_tracing(str(p))
         files.append(str(p))

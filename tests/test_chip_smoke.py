@@ -21,11 +21,6 @@ TINY = chip_smoke.Sizes(
     seq=16, ernie_batch=2, ernie_steps=3)
 
 
-@pytest.fixture(scope="module")
-def log():
-    return chip_smoke.CompileLog()
-
-
 def test_script_refuses_a_cpu_platform_by_name():
     """Under JAX_PLATFORMS=cpu the script must exit non-zero, say which
     platform it found, and print no result line."""
@@ -39,8 +34,8 @@ def test_script_refuses_a_cpu_platform_by_name():
     assert '"ok"' not in out.stdout
 
 
-def test_leg_pass_tiny(log):
-    facts = chip_smoke.leg_pass(TINY, log)
+def test_leg_pass_tiny():
+    facts = chip_smoke.leg_pass(TINY)
     assert facts["loss"][1] < facts["loss"][0]
     assert facts["push_mode"] == "sparse"   # what auto resolves to off-TPU
     # ... with the shapes it was resolved for, one entry a compiled shape
@@ -48,8 +43,8 @@ def test_leg_pass_tiny(log):
             "mode": "sparse"} in facts["push_select"]
 
 
-def test_leg_stream_tiny(log):
-    facts = chip_smoke.leg_stream(TINY, log)
+def test_leg_stream_tiny():
+    facts = chip_smoke.leg_stream(TINY)
     assert facts["warm_rpcs"] == {}
     assert facts["push_mode"] == "sparse" and all(
         f["capacity"] == TINY.capacity for f in facts["push_select"])

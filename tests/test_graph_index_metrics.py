@@ -13,7 +13,6 @@ from paddle_tpu.core.profiler import (
     RecordEvent,
     export_chrome_tracing,
     start_timeline,
-    stop_timeline,
 )
 from paddle_tpu.data import LayerWiseSampler, TreeIndex
 from paddle_tpu.metrics import MAE, RMSE, WuAUC
@@ -201,7 +200,6 @@ class TestChromeTracing:
         with RecordEvent("phase_a"):
             with RecordEvent("phase_b"):
                 pass
-        stop_timeline()
         out = export_chrome_tracing(str(tmp_path / "trace.json"))
         blob = json.load(open(out))
         names = [e["name"] for e in blob["traceEvents"]]

@@ -367,7 +367,7 @@ def test_removed_profiler_names_are_gone():
     import paddle_tpu.core as core
 
     for name in ("CostTimer", "start_profiler", "stop_profiler",
-                 "profiler_enabled"):
+                 "profiler_enabled", "stop_timeline", "record_event"):
         assert not hasattr(profiler, name) and not hasattr(core, name)
 
 

@@ -15,7 +15,11 @@ tree in a process of its own. Prints one JSON line: ``spans`` as ``run.py``
 prints them, ``setup_s``, ``program_setup_s``, the compile counts with
 ``cache_written``, the window's rate a chip, the step's
 ``dispatch_rows_walked`` / ``dispatch_rung`` where the program has the
-buffer, and the size of each cache entry the run wrote. On a TPU only;
+buffer, and the size of each cache entry the run wrote; and, where the
+tree's program records ``pt.compile*`` spans (``core/profiler``, PR 38),
+``setup_spans``: the five sums the ``setup_*`` metrics read, before it a
+line with the costliest functions of each kind and the ring's fill
+(``harness/setup_spans.py``). On a TPU only;
 ``--rehearse`` drives the same path at ``run.py``'s rehearsal sizes on the
 CPU (its times are no device numbers).
 """
@@ -100,6 +104,18 @@ def main() -> int:
     if "dispatch_rows_walked" in buffers:
         out["rows_walked"] = buffers["dispatch_rows_walked"].tolist()
         out["rung"] = buffers["dispatch_rung"].tolist()
+    try:        # a tree older than the spans has no reader for them
+        from harness import setup_spans as ss
+    except ImportError:
+        ss = None
+    ctx = {"window": win}
+    if ss is not None and ss.before_t0(ctx) is not None:
+        out["setup_spans"] = {
+            "trace_s": ss.seconds(ctx, ss.TRACE),
+            "lower_s": ss.seconds(ctx, ss.LOWER),
+            "compile_s": ss.seconds(ctx, ss.COMPILE, hit=0),
+            "cache_read_s": ss.seconds(ctx, ss.COMPILE, hit=1),
+            "compile_misses": ss.count(ctx, ss.COMPILE, hit=0)}
     if os.path.isdir(cache_dir):
         out["cache_entries_written_mib"] = sorted(
             round(os.path.getsize(os.path.join(cache_dir, n)) / 2 ** 20, 2)
