@@ -32,6 +32,16 @@ array with lse in lane 0 and Δ in lane 1. ``q_offset``/``k_offset`` shift
 the causal mask for sequence-sharded (cp) blocks; they may be traced
 values (axis_index).
 
+A causal call whose offsets are Python ints walks a LIST of (q block,
+k block) pairs, built with numpy at trace time: the pairs the mask leaves
+something of, ``grid = (BH, pairs)``, the block numbers read from
+scalar-prefetch tables. A grid step of the rectangle that the mask empties
+still fetches its blocks and costs 0.6–1.0 µs (PERF.md §6, PR 41); at
+L = 4096 in 512-blocks that is 28 of 64 a head. The kernel bodies are the
+rectangle's own — the same mask, the same ``pl.when`` — so a walked pair
+does what its grid step did. Bidirectional calls, traced offsets and a
+call in which some block would have no pair keep the rectangle.
+
 Backward: standard flash backward — recompute P = exp(S - lse) blockwise;
 dV = P^T dO, dS = P ∘ (dO V^T - Δ), dQ = dS K, dK = dS^T Q with
 Δ = rowsum(dO ∘ O) computed outside (one fused elementwise pass).
@@ -68,23 +78,110 @@ def _out_struct(shape, dtype, *inputs):
 
 
 # ---------------------------------------------------------------------------
+# The block pairs a causal call walks
+# ---------------------------------------------------------------------------
+
+
+def _static_offsets(q_offset, k_offset):
+    """``(q_off, k_off)`` where both are known at trace time, else None.
+    Read where the caller's values still are what they were: inside the
+    ``custom_vjp``s a Python 0 is a tracer like the cp ring's
+    ``axis_index``."""
+    if all(isinstance(o, (int, np.integer)) for o in (q_offset, k_offset)):
+        return int(q_offset), int(k_offset)
+    return None
+
+
+def _causal_pairs(nq, nk, bq, bk, causal, offsets):
+    """bool ``[nq, nk]``: the block pairs a causal call walks — those with
+    ``q_off + (i+1)·bq − 1 ≥ k_off + j·bk``, the kernels' own ``pl.when``.
+    None where the call keeps the rectangle: it is bidirectional,
+    ``offsets`` is None (they are data), or some block would have no pair
+    to name it — a q block before the first key, a k block past the last
+    query's reach — whose zeros only the rectangle's first and last steps
+    write."""
+    if not causal or offsets is None:
+        return None
+    q_off, k_off = offsets
+    last_row = q_off + (np.arange(nq)[:, None] + 1) * bq - 1
+    first_col = k_off + np.arange(nk)[None, :] * bk
+    keep = last_row >= first_col
+    if not (keep.any(axis=1).all() and keep.any(axis=0).all()):
+        return None
+    return keep
+
+
+def _pair_tables(keep, k_major=False):
+    """The scalar-prefetch tables of a pair list, int32 ``[pairs]`` each:
+    the q block, the k block, and ``ends`` — bit 0 on the first pair of a
+    run, bit 1 on its last. A run is the pairs of one q block, ascending
+    in k (``flash_fwd``, ``flash_bwd_dq``: q blocks ascending), or with
+    ``k_major`` of one k block, ascending in q (``flash_bwd_dkv``)."""
+    if k_major:
+        run, i = np.nonzero(keep.T)
+        j = run
+    else:
+        run, j = np.nonzero(keep)
+        i = run
+    edge = run[1:] != run[:-1]
+    ends = np.r_[True, edge] + 2 * np.r_[edge, True]
+    return tuple(jnp.asarray(t, jnp.int32) for t in (i, j, ends))
+
+
+def _grid(BH, nq, nk, keep, k_major=False):
+    """``(grid, tables, q-side index map, k-side index map)`` of a call:
+    the pair list ``keep`` holds, else the rectangle — ``(BH, nq, nk)``,
+    or ``(BH, nk, nq)`` with ``k_major`` (the inner axis is the run)."""
+    if keep is not None:
+        tables = _pair_tables(keep, k_major)
+        return ((BH, len(tables[0])), tables,
+                lambda b, p, offs, qi, kj, ends: (b, qi[p], 0),
+                lambda b, p, offs, qi, kj, ends: (b, kj[p], 0))
+    if k_major:
+        return ((BH, nk, nq), (),
+                lambda b, j, i, offs: (b, i, 0),
+                lambda b, j, i, offs: (b, j, 0))
+    return ((BH, nq, nk), (),
+            lambda b, i, j, offs: (b, i, 0),
+            lambda b, i, j, offs: (b, j, 0))
+
+
+def _grid_step(refs, listed, k_major=False):
+    """Inside a kernel: ``(q block, k block, first step of its run, last
+    step)`` of this grid step, and the kernel's own refs — from the pair
+    list's tables, the first three of ``refs``, where the call is
+    ``listed``, else off the rectangle's grid."""
+    if listed:
+        (qi_ref, kj_ref, ends_ref), refs = refs[:3], refs[3:]
+        p = pl.program_id(1)
+        ends = ends_ref[p]
+        return (qi_ref[p], kj_ref[p], (ends & 1) != 0, (ends & 2) != 0), refs
+    outer, inner = pl.program_id(1), pl.program_id(2)
+    i, j = (inner, outer) if k_major else (outer, inner)
+    return (i, j, inner == 0, inner == pl.num_programs(2) - 1), refs
+
+
+def _semantics(grid):
+    return pltpu.CompilerParams(dimension_semantics=(
+        ("parallel",) * (len(grid) - 1) + ("arbitrary",)))
+
+
+# ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc, m_scr, l_scr, *, scale, causal, bq, bk, mxu):
-    j = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _fwd_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed):
+    (i, j, first, last), refs = _grid_step(refs, listed)
+    q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr = refs
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _():
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, NEG)
         l_scr[:] = jnp.zeros_like(l_scr)
 
     q_off, k_off, k_len = offs_ref[0], offs_ref[1], offs_ref[3]
-    i = pl.program_id(1)
     row0 = q_off + i * bq
     col0 = k_off + j * bk
 
@@ -122,7 +219,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     else:
         body()
 
-    @pl.when(j == nk - 1)
+    @pl.when(last)
     def _():
         l = l_scr[:, :1]
         o_ref[0] = (acc[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
@@ -131,7 +228,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, mxu,
-         dtype):
+         dtype, keep):
     BH, Lq, D = q.shape
     Lk, Dv = k.shape[1], v.shape[-1]      # q.k at D, P.v and the result at Dv
     nq, nk = Lq // bq, Lk // bk
@@ -141,23 +238,23 @@ def _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, mxu,
                    jnp.asarray(Lq, jnp.int32),
                    jnp.asarray(k.shape[1], jnp.int32)]), jnp.int32)
 
-    grid = (BH, nq, nk)
+    grid, tables, at_q, at_k = _grid(BH, nq, nk, keep)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               bq=bq, bk=bk, mxu=mxu)
+                               bq=bq, bk=bk, mxu=mxu, listed=bool(tables))
     with jax.named_scope("pt.flash_fwd"):
         out, lse = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
+                num_scalar_prefetch=1 + len(tables),
                 grid=grid,
                 in_specs=[
-                    pl.BlockSpec((1, bq, D), lambda b, i, j, offs: (b, i, 0)),
-                    pl.BlockSpec((1, bk, D), lambda b, i, j, offs: (b, j, 0)),
-                    pl.BlockSpec((1, bk, Dv), lambda b, i, j, offs: (b, j, 0)),
+                    pl.BlockSpec((1, bq, D), at_q),
+                    pl.BlockSpec((1, bk, D), at_k),
+                    pl.BlockSpec((1, bk, Dv), at_k),
                 ],
                 out_specs=[
-                    pl.BlockSpec((1, bq, Dv), lambda b, i, j, offs: (b, i, 0)),
-                    pl.BlockSpec((1, bq, 128), lambda b, i, j, offs: (b, i, 0)),
+                    pl.BlockSpec((1, bq, Dv), at_q),
+                    pl.BlockSpec((1, bq, 128), at_q),
                 ],
                 scratch_shapes=[
                     pltpu.VMEM((bq, Dv), jnp.float32),
@@ -169,11 +266,10 @@ def _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, mxu,
                 _out_struct((BH, Lq, Dv), dtype, q, k, v, offs),
                 _out_struct((BH, Lq, 128), jnp.float32, q, k, v, offs),
             ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            compiler_params=_semantics(grid),
             name="flash_fwd",
             interpret=interpret,
-        )(offs, q, k, v)
+        )(offs, *tables, q, k, v)
     return out, lse[:, :, 0]
 
 
@@ -182,17 +278,15 @@ def _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, mxu,
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
-                   dq_ref, dq_acc, *, scale, causal, bq, bk, mxu):
-    j = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _bwd_dq_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed):
+    (i, j, first, last), refs = _grid_step(refs, listed)
+    q_ref, k_ref, v_ref, do_ref, stats_ref, dq_ref, dq_acc = refs
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     q_off, k_off, k_len = offs_ref[0], offs_ref[1], offs_ref[3]
-    i = pl.program_id(1)
     row0 = q_off + i * bq
     col0 = k_off + j * bk
 
@@ -223,23 +317,23 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
     else:
         body()
 
-    @pl.when(j == nk - 1)
+    @pl.when(last)
     def _():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, bq, bk, mxu):
-    i = pl.program_id(2)           # q-block index (inner loop)
-    nq = pl.num_programs(2)
+def _bwd_dkv_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed):
+    # a run is one k block: the q blocks are the inner loop
+    (i, j, first, last), refs = _grid_step(refs, listed, k_major=True)
+    (q_ref, k_ref, v_ref, do_ref, stats_ref, dk_ref, dv_ref,
+     dk_acc, dv_acc) = refs
 
-    @pl.when(i == 0)
+    @pl.when(first)
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     q_off, k_off, k_len = offs_ref[0], offs_ref[1], offs_ref[3]
-    j = pl.program_id(1)           # k-block index (outer grid dim)
     row0 = q_off + i * bq
     col0 = k_off + j * bk
 
@@ -273,13 +367,13 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
     else:
         body()
 
-    @pl.when(i == nq - 1)
+    @pl.when(last)
     def _():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd(scale, causal, bq, bk, interpret, mxu, res, grads):
+def _bwd(scale, causal, bq, bk, interpret, mxu, offsets, res, grads):
     q, k, v, out, lse, offs = res          # q, k, v as the kernels read them
     do, dlse = grads
     BH, Lq, D = q.shape
@@ -301,61 +395,54 @@ def _bwd(scale, causal, bq, bk, interpret, mxu, res, grads):
     lane = jax.lax.broadcasted_iota(jnp.int32, (BH, Lq, 128), 2)
     stats = jnp.where(lane == 0, lse[..., None], delta[..., None])
 
-    common_in = [
-        pl.BlockSpec((1, bq, D), lambda b, i, j, offs: (b, i, 0)),      # q
-        pl.BlockSpec((1, bk, D), lambda b, i, j, offs: (b, j, 0)),      # k
-        pl.BlockSpec((1, bk, Dv), lambda b, i, j, offs: (b, j, 0)),     # v
-        pl.BlockSpec((1, bq, Dv), lambda b, i, j, offs: (b, i, 0)),     # do
-        pl.BlockSpec((1, bq, 128), lambda b, i, j, offs: (b, i, 0)),    # stats
-    ]
+    keep = _causal_pairs(nq, nk, bq, bk, causal, offsets)
+
+    def operands(at_q, at_k):
+        return [pl.BlockSpec((1, bq, D), at_q),       # q
+                pl.BlockSpec((1, bk, D), at_k),       # k
+                pl.BlockSpec((1, bk, Dv), at_k),      # v
+                pl.BlockSpec((1, bq, Dv), at_q),      # do
+                pl.BlockSpec((1, bq, 128), at_q)]     # stats
+
+    grid, tables, at_q, at_k = _grid(BH, nq, nk, keep)
     with jax.named_scope("pt.flash_bwd_dq"):
         dq = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                              bq=bq, bk=bk, mxu=mxu),
+                              bq=bq, bk=bk, mxu=mxu, listed=bool(tables)),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(BH, nq, nk),
-                in_specs=common_in,
-                out_specs=[pl.BlockSpec((1, bq, D), lambda b, i, j, offs: (b, i, 0))],
+                num_scalar_prefetch=1 + len(tables),
+                grid=grid,
+                in_specs=operands(at_q, at_k),
+                out_specs=[pl.BlockSpec((1, bq, D), at_q)],
                 scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
             ),
             out_shape=[_out_struct((BH, Lq, D), dtype, q, k, v, do, offs)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            compiler_params=_semantics(grid),
             name="flash_bwd_dq",
             interpret=interpret,
-        )(offs, q, k, v, do, stats)[0]
+        )(offs, *tables, q, k, v, do, stats)[0]
 
-    # swap block index roles: outer dim walks k blocks, inner walks q
-    dkv_in = [
-        pl.BlockSpec((1, bq, D), lambda b, j, i, offs: (b, i, 0)),      # q
-        pl.BlockSpec((1, bk, D), lambda b, j, i, offs: (b, j, 0)),      # k
-        pl.BlockSpec((1, bk, Dv), lambda b, j, i, offs: (b, j, 0)),     # v
-        pl.BlockSpec((1, bq, Dv), lambda b, j, i, offs: (b, i, 0)),     # do
-        pl.BlockSpec((1, bq, 128), lambda b, j, i, offs: (b, i, 0)),    # stats
-    ]
+    # swap block index roles: a run is one k block, walked over its q blocks
+    grid, tables, at_q, at_k = _grid(BH, nq, nk, keep, k_major=True)
     with jax.named_scope("pt.flash_bwd_dkv"):
         dk, dv = pl.pallas_call(
             functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                              bq=bq, bk=bk, mxu=mxu),
+                              bq=bq, bk=bk, mxu=mxu, listed=bool(tables)),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(BH, nk, nq),
-                in_specs=dkv_in,
-                out_specs=[
-                    pl.BlockSpec((1, bk, D), lambda b, j, i, offs: (b, j, 0)),
-                    pl.BlockSpec((1, bk, Dv), lambda b, j, i, offs: (b, j, 0)),
-                ],
+                num_scalar_prefetch=1 + len(tables),
+                grid=grid,
+                in_specs=operands(at_q, at_k),
+                out_specs=[pl.BlockSpec((1, bk, D), at_k),
+                           pl.BlockSpec((1, bk, Dv), at_k)],
                 scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                                 pltpu.VMEM((bk, Dv), jnp.float32)],
             ),
             out_shape=[_out_struct((BH, Lk, D), dtype, q, k, v, do, offs),
                        _out_struct((BH, Lk, Dv), dtype, q, k, v, do, offs)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            compiler_params=_semantics(grid),
             name="flash_bwd_dkv",
             interpret=interpret,
-        )(offs, q, k, v, do, stats)
+        )(offs, *tables, q, k, v, do, stats)
     return dq, dk, dv
 
 
@@ -364,11 +451,12 @@ def _bwd(scale, causal, bq, bk, interpret, mxu, res, grads):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 7, 8, 9, 10, 11, 12))
 def _flash(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret,
-           precision, dv):
+           precision, dv, offsets):
     (out, _), _ = _flash_fwd(q, k, v, scale, causal, q_offset, k_offset,
-                             bq, bk, interpret, precision, dv)
+                             bq, bk, interpret, precision, dv, offsets)
     return out
 
 
@@ -398,8 +486,12 @@ mxu_rounded.defvjp(lambda x: (mxu_rounded(x), None), lambda _, g: (g,))
 
 
 def _flash_fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret,
-               precision, dv):
+               precision, dv, offsets):
     mxu = _mxu_dtype(precision)
+    nq, nk = q.shape[1] // bq, k.shape[1] // bk
+    # `offsets`: the two offsets where they are Python ints (None where
+    # they are data) — q_offset and k_offset themselves are tracers here
+    keep = _causal_pairs(nq, nk, bq, bk, causal, offsets)
     offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(k_offset, jnp.int32),
                       jnp.asarray(q.shape[1], jnp.int32),
@@ -413,52 +505,59 @@ def _flash_fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret,
     # what the kernels are handed, read off the arrays themselves: one host
     # span a trace (``profiler.host_spans()``), none on the step path.
     # `scale` is 1/sqrt(D), all that is left here of the unpadded D; `dv`
-    # is v's unpadded width (latent attention: q.k at 192, P.v at 128)
+    # is v's unpadded width (latent attention: q.k at 192, P.v at 128);
+    # the block pairs a head's grid walks, of the rectangle's nq x nk
     with RecordEvent("pt.flash.operands", bits=8 * q.dtype.itemsize,
                      head_dim=round(scale ** -2), lanes=q.shape[-1],
-                     v_head_dim=dv):
+                     v_head_dim=dv,
+                     pairs_walked=nq * nk if keep is None else int(keep.sum()),
+                     pairs_rectangle=nq * nk):
         out, lse = _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk,
-                        interpret, mxu, dtype)
+                        interpret, mxu, dtype, keep)
     return (out, lse), (q, k, v, out, lse, offs)
 
 
 def _flash_fwd_rule(q, k, v, scale, causal, q_offset, k_offset, bq, bk,
-                    interpret, precision, dv):
+                    interpret, precision, dv, offsets):
     (out, lse), res = _flash_fwd(q, k, v, scale, causal, q_offset, k_offset,
-                                 bq, bk, interpret, precision, dv)
+                                 bq, bk, interpret, precision, dv, offsets)
     return out, (res, (q_offset, k_offset))
 
 
-def _flash_bwd_rule(scale, causal, bq, bk, interpret, precision, dv, saved, g):
+def _flash_bwd_rule(scale, causal, bq, bk, interpret, precision, dv, offsets,
+                    saved, g):
     res, (q_offset, k_offset) = saved
     mxu = _mxu_dtype(precision)
-    dq, dk, dv = _bwd(scale, causal, bq, bk, interpret, mxu, res, (g, None))
+    dq, dk, dv = _bwd(scale, causal, bq, bk, interpret, mxu, offsets, res,
+                      (g, None))
     return dq, dk, dv, None, None
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 7, 8, 9, 10, 11, 12))
 def _flash_pair(q, k, v, scale, causal, q_offset, k_offset, bq, bk,
-                interpret, precision, dv):
+                interpret, precision, dv, offsets):
     (out, lse), _ = _flash_fwd(q, k, v, scale, causal, q_offset, k_offset,
-                               bq, bk, interpret, precision, dv)
+                               bq, bk, interpret, precision, dv, offsets)
     return out, lse
 
 
 def _flash_pair_fwd_rule(q, k, v, scale, causal, q_offset, k_offset, bq, bk,
-                         interpret, precision, dv):
+                         interpret, precision, dv, offsets):
     (out, lse), res = _flash_fwd(q, k, v, scale, causal, q_offset, k_offset,
-                                 bq, bk, interpret, precision, dv)
+                                 bq, bk, interpret, precision, dv, offsets)
     return (out, lse), res
 
 
-def _flash_pair_bwd_rule(scale, causal, bq, bk, interpret, precision, dv, res,
-                         g):
+def _flash_pair_bwd_rule(scale, causal, bq, bk, interpret, precision, dv,
+                         offsets, res, g):
     do, dlse = g
     mxu = _mxu_dtype(precision)
-    dq, dk, dv = _bwd(scale, causal, bq, bk, interpret, mxu, res, (do, dlse))
+    dq, dk, dv = _bwd(scale, causal, bq, bk, interpret, mxu, offsets, res,
+                      (do, dlse))
     return dq, dk, dv, None, None
 
 
@@ -524,13 +623,15 @@ def _run_padded(q, k, v, causal, q_offset, k_offset, block_q, block_k,
         return jnp.pad(x, ((0, 0), (0, L_p - L), (0, -d % 128)))
 
     qp, kp, vp = to_bh(q, Lq, Lq_p), to_bh(k, Lk, Lk_p), to_bh(v, Lk, Lk_p)
+    offsets = _static_offsets(q_offset, k_offset)
 
     if with_lse:
         out, lse = _flash_pair(qp, kp, vp, scale, causal, q_offset,
-                               k_offset, bq, bk, interpret, precision, Dv)
+                               k_offset, bq, bk, interpret, precision, Dv,
+                               offsets)
     else:
         out = _flash(qp, kp, vp, scale, causal, q_offset, k_offset, bq, bk,
-                     interpret, precision, Dv)
+                     interpret, precision, Dv, offsets)
         lse = None
     out = out[:, :Lq, :Dv].reshape(B, H, Lq, Dv).astype(q.dtype)
     out = jnp.moveaxis(out, 1, 2)

@@ -360,6 +360,69 @@ def test_rows_walked_share_reads_the_steps_own_buffers():
     assert read({"system": types.SimpleNamespace()}) is None
 
 
+FLASH = "pt.flash.operands"
+_WIDTHS = dict(bits=16, head_dim=128, lanes=128, v_head_dim=128)
+
+
+def test_pairs_walked_share_sums_the_calls_set_up_traced(monkeypatch):
+    """``flash_pairs_walked_share``: walked over rectangle, over the
+    ``pt.flash.operands`` spans that start before ``t0`` — the check's
+    programs, traced after the window, are not the step's calls."""
+    listed = dict(_WIDTHS, pairs_walked=36, pairs_rectangle=64)
+    ring = [_span(FLASH, 10.0 + i, 0.01, **listed) for i in range(6)]
+    assert _read(monkeypatch, "flash_pairs_walked_share", ring) == 0.5625
+    after = _span(FLASH, T0 + 5.0, 0.01,
+                  **dict(_WIDTHS, pairs_walked=64, pairs_rectangle=64))
+    assert _read(monkeypatch, "flash_pairs_walked_share",
+                 ring + [after]) == 0.5625
+    whole = dict(_WIDTHS, pairs_walked=1, pairs_rectangle=1)     # ERNIE's
+    assert _read(monkeypatch, "flash_pairs_walked_share",
+                 [_span(FLASH, 10.0, 0.01, **whole)] * 36) == 1.0
+    mixed = ring[:1] + [_span(FLASH, 20.0, 0.01, **dict(
+        _WIDTHS, pairs_walked=64, pairs_rectangle=64))]
+    assert _read(monkeypatch, "flash_pairs_walked_share",
+                 mixed) == pytest.approx(100 / 128)
+
+
+def test_pairs_walked_share_is_none_on_a_span_without_the_counts(monkeypatch):
+    """The parent's program: ``pt.flash.operands`` with the widths alone;
+    a program with no flash call; one older than the ring."""
+    ring = [_span(FLASH, 10.0, 0.01, **_WIDTHS),
+            _span(TRACE, 11.0, 2.0, fun="step", traces=3)]
+    assert _read(monkeypatch, "flash_pairs_walked_share", ring) is None
+    assert _read(monkeypatch, "flash_pairs_walked_share", ring[1:]) is None
+    assert _read(monkeypatch, "flash_pairs_walked_share", []) is None
+    monkeypatch.delattr(profiler, "host_spans")
+    ctx = {"window": {"t0": T0}, "spans": {}}
+    assert spec.load_module(
+        "metrics", "flash_pairs_walked_share").read(ctx) is None
+
+
+def test_pairs_walked_share_reads_the_programs_own_spans():
+    """From the ring itself: a causal call in 4 x 4 blocks walks 10 of 16
+    pairs, a bidirectional one beside it all 16 — one span a call a trace,
+    none from the warm calls."""
+    from paddle_tpu.ops.flash_attention import flash_attention
+
+    q = jnp.ones((1, 64, 2, 8))
+
+    @jax.jit
+    def both(q):
+        kw = dict(block_q=16, block_k=16, interpret=True)
+        return (flash_attention(q, q, q, causal=True, **kw)
+                + flash_attention(q, q, q, **kw))
+
+    profiler.start_timeline()
+    for _ in range(2):
+        jax.block_until_ready(both(q))
+    assert [(s.counts["pairs_walked"], s.counts["pairs_rectangle"])
+            for s in _named(FLASH)] == [(10, 16), (16, 16)]
+    read = spec.load_module("metrics", "flash_pairs_walked_share").read
+    now = max(s.t0 + s.dur for s in _named(FLASH)) + 1.0
+    assert read({"window": {"t0": now}}) == pytest.approx(26 / 32)
+    assert read({"window": {"t0": 0.0}}) is None      # all after this t0
+
+
 def test_benchmark_lists_the_new_metrics_in_every_cell():
     bench = spec.load_benchmark()
     assert spec.check_contract(bench) == []
@@ -371,5 +434,12 @@ def test_benchmark_lists_the_new_metrics_in_every_cell():
         assert m["layer"] == "entry points" and m["better"] == "lower"
     assert by["moe_rows_walked_share"]["workloads"] == [
         "joyai_flash_seq4096", "lfm2_8b_a1b_seq4096"]
-    assert [m["name"] for m in bench["per_layer"][-7:]] == list(
-        SPAN_READERS) + ["setup_devices_s", "moe_rows_walked_share"]
+    walked = by["flash_pairs_walked_share"]              # PR 41
+    assert walked["workloads"] == [
+        "ernie_base_seq512", "olmoe_1b7b_seq4096", "joyai_flash_seq4096",
+        "lfm2_8b_a1b_seq4096"]
+    assert (walked["layer"], walked["moves"], walked["source"]) == (
+        "kernels", "tokens_per_s_per_chip", "program_counter")
+    assert [m["name"] for m in bench["per_layer"][-8:]] == list(
+        SPAN_READERS) + ["setup_devices_s", "moe_rows_walked_share",
+                         "flash_pairs_walked_share"]

@@ -5,7 +5,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
+from paddle_tpu.ops import flash_attention as fa
 from paddle_tpu.ops.flash_attention import (flash_attention,
                                             flash_attention_with_lse)
 from paddle_tpu.parallel.ring_attention import local_attention
@@ -304,19 +306,283 @@ def test_kernel_operands_are_the_dtype_they_multiply_in(precision, operand):
         assert ops[name] == [(operand, wide)] * 4 + [(jnp.float32, wide)], name
 
 
+@pytest.mark.parametrize("causal,walked", [(False, 4), (True, 3)])
 @pytest.mark.parametrize("precision,bits", [("default", 16), ("highest", 32)])
-def test_operand_span_is_recorded_once_a_compile(precision, bits):
+def test_operand_span_is_recorded_once_a_compile(precision, bits, causal,
+                                                 walked):
     """``pt.flash.operands``: one host span a trace with the width of the
-    arrays the forward kernel was handed, none on the step path."""
+    arrays the forward kernel was handed and the block pairs a head's grid
+    walks of the rectangle's (PR 41: 2 x 2 blocks, the causal list leaves
+    one out), none on the step path."""
     from paddle_tpu.core import profiler
 
     rng = np.random.default_rng(9)
     q, k, v = _qkv(rng, B=1, L=32, H=2, D=8)
-    step = jax.jit(lambda q, k, v: _out_and_grads(q, k, v, False, precision))
+    step = jax.jit(lambda q, k, v: _out_and_grads(q, k, v, causal, precision))
     spans = lambda: [s.counts for s in profiler.host_spans()
                      if s.name == "pt.flash.operands"]
     before = len(spans())
     for _ in range(3):
         jax.block_until_ready(step(q, k, v))
     assert spans()[before:] == [{"bits": bits, "head_dim": 8, "lanes": 128,
-                                 "v_head_dim": 8}]
+                                 "v_head_dim": 8, "pairs_walked": walked,
+                                 "pairs_rectangle": 4}]
+
+
+# ---------------------------------------------------------------------------
+# the block pairs a causal call walks (PR 41): a list built at trace time,
+# against the same list holding the whole rectangle and against the
+# rectangle's own three-dimensional grid
+# ---------------------------------------------------------------------------
+
+#: the TPU interpreter with scratch, outputs and every VMEM window filled
+#: with NaN before the kernel writes them: a block read before it is
+#: written, or never written, fails here as it would on the chip
+NAN_FILLED = pltpu.InterpretParams(uninitialized_memory="nan")
+
+
+def _whole_rectangle(*a):
+    """``_causal_pairs`` answering with every pair: the rectangle walked
+    as a list, the emptied pairs skipped by the body's own ``pl.when``."""
+    keep = _PAIRS(*a)
+    return keep if keep is None else np.ones_like(keep)
+
+
+_PAIRS = fa._causal_pairs
+_WALKS = {"list": _PAIRS, "rectangle as a list": _whole_rectangle,
+          "the parent's grid": lambda *a: None}
+
+
+def _results(monkeypatch, walk, q, k, v, **kw):
+    """{name: array} of out, lse, dq, dk, dv of one causal call with an
+    lse cotangent, under ``walk`` of ``_WALKS``, NaN-filled memory."""
+    monkeypatch.setattr(fa, "_causal_pairs", _WALKS[walk])
+    w = jnp.cos(jnp.arange(q.shape[1] * q.shape[2], dtype=jnp.float32)
+                ).reshape(1, q.shape[1], q.shape[2])
+
+    def loss(q, k, v):
+        out, lse = flash_attention_with_lse(q, k, v, causal=True,
+                                            interpret=NAN_FILLED, **kw)
+        return jnp.sum(out ** 2) + jnp.sum(lse * w), (out, lse)
+
+    (_, aux), g = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                     has_aux=True)(q, k, v)
+    return dict(zip(("out", "lse", "dq", "dk", "dv"), aux + g))
+
+
+def _grids(fn, *args):
+    """{kernel name: its grid} of every ``pallas_call`` in ``fn``'s jaxpr."""
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = tuple(
+                    eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+# (B, Lq, Lk, H, D), blocks, offsets, pairs walked of the rectangle's —
+# every q block and k block is named (one q block has to name them all)
+LISTED = {
+    "bq256_bk512": ((1, 1024, 1024, 1, 8), (256, 512), (0, 0), (6, 8)),
+    "bq512_bk256": ((1, 1024, 1024, 1, 8), (512, 256), (0, 0), (6, 8)),
+    "q_off_over_k_off": ((1, 64, 64, 2, 8), (16, 16), (32, 0), (15, 16)),
+    "q_off_is_k_off": ((1, 64, 64, 2, 8), (16, 16), (16, 16), (10, 16)),
+    "bh1": ((1, 64, 64, 1, 8), (16, 16), (0, 0), (10, 16)),
+    "lq_one_block": ((2, 16, 64, 2, 8), (16, 16), (48, 0), (4, 4)),
+    "unaligned_lengths": ((1, 50, 50, 3, 12), (16, 16), (0, 0), (10, 16)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LISTED))
+def test_pair_list_equals_the_rectangle_bit_for_bit(monkeypatch, case):
+    """The triangular list gives, bit for bit, what the full-rectangle
+    list gives and what the parent's ``(BH, nq, nk)`` grid gives — out,
+    lse, dq, dk, dv — with every scratch and output block NaN until a
+    kernel writes it."""
+    (B, Lq, Lk, H, D), (bq, bk), (q_off, k_off), walked = LISTED[case]
+    rng = np.random.default_rng(20)
+    q = jnp.asarray(rng.normal(size=(B, Lq, H, D)).astype(np.float32))
+    k, v = (jnp.asarray(rng.normal(size=(B, Lk, H, D)).astype(np.float32))
+            for _ in range(2))
+    kw = dict(q_offset=q_off, k_offset=k_off, block_q=bq, block_k=bk)
+    got = {walk: _results(monkeypatch, walk, q, k, v, **kw)
+           for walk in _WALKS}
+    monkeypatch.setattr(fa, "_causal_pairs", _PAIRS)
+    nq, nk = -(-Lq // bq), -(-Lk // bk)
+    keep = _PAIRS(nq, nk, min(bq, Lq), min(bk, Lk), True, (q_off, k_off))
+    assert (int(keep.sum()), keep.size) == walked
+    for name, want in got["the parent's grid"].items():
+        assert np.isfinite(np.asarray(want)).all(), name
+        for walk in ("list", "rectangle as a list"):
+            np.testing.assert_array_equal(
+                np.asarray(got[walk][name]), np.asarray(want),
+                err_msg=f"{name}: {walk}")
+
+
+def test_forward_alone_walks_the_list_and_equals_the_rectangle(monkeypatch):
+    """``flash_attention`` outside a gradient — the ``routing`` program's
+    path: the forward kernel alone, no residual kept."""
+    rng = np.random.default_rng(21)
+    q, k, v = _qkv(rng, B=2, L=64, H=2, D=8)
+    out = {}
+    for walk, pairs in _WALKS.items():
+        monkeypatch.setattr(fa, "_causal_pairs", pairs)
+        fn = lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=16, block_k=16,
+            interpret=NAN_FILLED)
+        out[walk] = np.asarray(fn(q, k, v))
+        grid = _grids(fn, q, k, v)
+        assert set(grid) == {"flash_fwd"}
+        assert grid["flash_fwd"] == {"list": (4, 10),
+                                     "rectangle as a list": (4, 16),
+                                     "the parent's grid": (4, 4, 4)}[walk]
+    assert np.isfinite(out["list"]).all()
+    np.testing.assert_array_equal(out["list"], out["the parent's grid"])
+    np.testing.assert_array_equal(out["rectangle as a list"],
+                                  out["the parent's grid"])
+
+
+@pytest.mark.parametrize("nq,nk,bq,bk,offsets", [
+    (8, 8, 512, 512, (0, 0)), (16, 8, 256, 512, (0, 0)),
+    (8, 16, 512, 256, (0, 0)), (4, 4, 16, 16, (32, 0)),
+    (4, 4, 16, 16, (16, 16)), (1, 4, 16, 16, (48, 0)), (1, 1, 8, 8, (0, 0)),
+    (3, 5, 24, 8, (7, 0))])
+@pytest.mark.parametrize("k_major", [False, True])
+def test_pair_tables_name_every_block_in_contiguous_runs(
+        nq, nk, bq, bk, offsets, k_major):
+    """The tables' own properties: the pairs are exactly the kernels'
+    ``pl.when``; every q block and every k block is named; a run (one q
+    block, or with ``k_major`` one k block) is contiguous and ascending,
+    flagged first once and last once."""
+    keep = fa._causal_pairs(nq, nk, bq, bk, True, offsets)
+    q_off, k_off = offsets
+    want = {(i, j) for i in range(nq) for j in range(nk)
+            if q_off + (i + 1) * bq - 1 >= k_off + j * bk}
+    qi, kj, ends = (np.asarray(t) for t in fa._pair_tables(keep, k_major))
+    assert all(t.dtype == np.int32 for t in (qi, kj, ends))
+    pairs = list(zip(qi.tolist(), kj.tolist()))
+    assert set(pairs) == want and len(pairs) == len(want)
+    assert set(qi.tolist()) == set(range(nq))
+    assert set(kj.tolist()) == set(range(nk))
+    run, inner = (kj, qi) if k_major else (qi, kj)
+    order = list(zip(run.tolist(), inner.tolist()))
+    assert order == sorted(order)               # runs ascending, contiguous
+    first, last = (ends & 1) != 0, (ends & 2) != 0
+    starts = np.r_[True, run[1:] != run[:-1]]
+    stops = np.r_[run[1:] != run[:-1], True]
+    np.testing.assert_array_equal(first, starts)
+    np.testing.assert_array_equal(last, stops)
+    assert first.sum() == last.sum() == len(set(run.tolist()))
+
+
+def test_the_cells_calls_walk_36_of_64_pairs():
+    keep = fa._causal_pairs(8, 8, 512, 512, True, (0, 0))
+    assert int(keep.sum()) == 36 and keep.size == 64
+    assert fa._causal_pairs(8, 8, 512, 512, False, (0, 0)) is None
+    assert fa._causal_pairs(8, 8, 512, 512, True, None) is None
+    assert fa._static_offsets(0, 0) == (0, 0)
+    assert fa._static_offsets(np.int32(3), 0) == (3, 0)
+    assert fa._static_offsets(jnp.int32(0), 0) is None      # data
+
+
+@pytest.mark.parametrize("case,shape,offsets", [
+    ("q block before the first key", (1, 64, 64, 2, 8), (0, 32)),
+    ("k blocks past the last query", (1, 32, 64, 2, 8), (0, 0))])
+def test_a_call_with_an_unnamed_block_takes_the_rectangle(
+        case, shape, offsets):
+    """Where some q block has no key to see, or some k block no query that
+    reaches it, no pair would name that block and nothing would write it:
+    the call keeps the rectangle, whose first and last steps write the
+    zeros — dk and dv of an unreachable k block are exact zeros, under
+    NaN-filled memory too."""
+    B, Lq, Lk, H, D = shape
+    q_off, k_off = offsets
+    assert fa._causal_pairs(Lq // 16, Lk // 16, 16, 16, True, offsets) is None
+    rng = np.random.default_rng(22)
+    q = jnp.asarray(rng.normal(size=(B, Lq, H, D)).astype(np.float32))
+    k, v = (jnp.asarray(rng.normal(size=(B, Lk, H, D)).astype(np.float32))
+            for _ in range(2))
+
+    def fwd_bwd(q, k, v):
+        loss = lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, q_offset=q_off, k_offset=k_off,
+            block_q=16, block_k=16, interpret=NAN_FILLED,
+            precision="highest") ** 2)
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    assert all(len(g) == 3 for g in _grids(fwd_bwd, q, k, v).values())
+    dq, dk, dv = (np.asarray(g) for g in fwd_bwd(q, k, v))
+    assert all(np.isfinite(g).all() for g in (dq, dk, dv))
+    # the reference: masked einsum attention at the same offsets
+    rows = q_off + np.arange(Lq)[:, None]
+    cols = k_off + np.arange(Lk)[None, :]
+    mask = jnp.asarray(cols <= rows)
+
+    def ref_loss(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                       precision="highest") / np.sqrt(D)
+        p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+        p = jnp.where(mask.any(axis=1)[:, None], p, 0.0)   # rows that see none
+        return jnp.sum(jnp.einsum("bhqk,bkhd->bqhd", p, v,
+                                  precision="highest") ** 2)
+
+    for a, b, name in zip((dq, dk, dv), jax.grad(
+            ref_loss, argnums=(0, 1, 2))(q, k, v), ("dq", "dk", "dv")):
+        np.testing.assert_allclose(a, np.asarray(b), rtol=2e-3, atol=2e-4,
+                                   err_msg=name)
+    unseen = ~np.asarray(mask).any(axis=0)             # keys no query sees
+    if case.startswith("k blocks"):
+        assert unseen[32:].all()
+    assert (dk[:, unseen] == 0).all() and (dv[:, unseen] == 0).all()
+
+
+def test_only_causal_calls_with_int_offsets_walk_a_list():
+    """What the code can observe decides the grid: ``causal`` and whether
+    the offsets are Python ints. A bidirectional call and a call whose
+    offsets are data (the cp ring's ``axis_index``) lower to the parent's
+    three-dimensional grid, forward and backward."""
+    rng = np.random.default_rng(23)
+    q, k, v = _qkv(rng, B=1, L=64, H=2, D=8)
+
+    def fwd_bwd(causal, q_off, k_off):
+        loss = lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=causal, q_offset=q_off, k_offset=k_off,
+            block_q=16, block_k=16, interpret=True) ** 2)
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    names = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    listed = _grids(lambda: fwd_bwd(True, 0, 0))
+    assert listed == dict.fromkeys(names, (2, 10))
+    assert _grids(lambda: fwd_bwd(False, 0, 0)) == {
+        "flash_fwd": (2, 4, 4), "flash_bwd_dq": (2, 4, 4),
+        "flash_bwd_dkv": (2, 4, 4)}
+    traced = _grids(lambda o: fwd_bwd(True, o, o), jnp.int32(0))
+    assert traced == dict.fromkeys(names, (2, 4, 4))
+    # and the traced-offset call gives what the list gives at the same offsets
+    for a, b in zip(jax.jit(lambda o: fwd_bwd(True, o, o))(jnp.int32(0)),
+                    fwd_bwd(True, 0, 0)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_nan_filled_memory_shows_a_block_no_pair_names(monkeypatch):
+    """The instrument sees the fault it is there for: a list that leaves a
+    q block out leaves that block of the output unwritten — NaN under
+    ``NAN_FILLED``, where the plain interpreter reads zeros."""
+    def one_block_short(*a):
+        keep = _PAIRS(*a).copy()
+        keep[1] = False
+        return keep
+
+    monkeypatch.setattr(fa, "_causal_pairs", one_block_short)
+    rng = np.random.default_rng(24)
+    q, k, v = _qkv(rng, B=1, L=64, H=1, D=8)
+    out = np.asarray(flash_attention(q, k, v, causal=True, block_q=16,
+                                     block_k=16, interpret=NAN_FILLED))
+    assert np.isnan(out[:, 16:32]).all()
+    assert np.isfinite(out[:, :16]).all() and np.isfinite(out[:, 32:]).all()

@@ -255,6 +255,42 @@ def test_step_names_its_work(name):
     assert total > 100 and scoped / total >= 0.90, (scoped, total)
 
 
+@pytest.mark.parametrize("model,causal", [("olmoe", True), ("ernie", False)])
+def test_flash_operand_span_counts_a_models_block_pairs(model, causal):
+    """``pt.flash.operands`` in a model's step (PR 41): still one span a
+    ``flash_attention`` call a trace — two layers, two spans, the backward
+    adds none — and each says how many of its head's 2 x 2 block pairs the
+    grid walks: the causal decoder's list leaves one out, the bidirectional
+    encoder's rectangle none."""
+    from paddle_tpu.executor import make_train_step
+
+    if model == "olmoe":
+        from paddle_tpu.models.olmoe import Olmoe, OlmoeConfig
+
+        net = Olmoe(OlmoeConfig(vocab_size=128, hidden_size=64, num_heads=2,
+                                num_layers=2, num_experts=8,
+                                experts_per_token=2, expert_size=32,
+                                max_seq_len=1024, attn_impl="flash"))
+    else:
+        from paddle_tpu.models.ernie import Ernie, ErnieConfig
+
+        net = Ernie(ErnieConfig(vocab_size=128, hidden_size=64, num_heads=2,
+                                ffn_size=128, num_layers=2, max_seq_len=1024,
+                                attn_impl="flash"))
+    opt = optimizer.Adam(1e-3)
+    step = make_train_step(net, opt, nn.functional.cross_entropy, amp=True)
+    state = nn.get_state(net)
+    shapes = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype), tree)
+    ids = (jax.ShapeDtypeStruct((1, 1024), jnp.int32),)
+    profiler.start_timeline()
+    step.trace(shapes(state), shapes(jax.eval_shape(opt.init, state["params"])),
+               jax.ShapeDtypeStruct((), jax.random.key(0).dtype), ids, ids)
+    got = [(s.counts["pairs_walked"], s.counts["pairs_rectangle"])
+           for s in host_spans() if s.name == "pt.flash.operands"]
+    assert got == [(3 if causal else 4, 4)] * 2
+
+
 def test_every_pallas_call_is_named():
     src = ROOT / "paddle_tpu"
     calls = 0

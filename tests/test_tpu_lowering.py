@@ -128,11 +128,15 @@ def test_flash_kernels_are_handed_bf16_and_one_statistics_array(
         r"%(flash_[a-z_]+)[.\d]* = .*?operand_layout_constraints=\{(.*?)\}, \w+=",
         hlo))
     assert set(calls) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    # a causal call's pair list (PR 41): 36 of the 8 x 8 block pairs, as
+    # three scalar-prefetch tables beside the offsets
+    tables = ["s32[36]"] * 3 if causal else []
     for name, operands in calls.items():
         got = re.findall(r"(\w+\[[\d,]*\])", operands)
         n = 3 if name == "flash_fwd" else 4
         stats = [] if name == "flash_fwd" else ["f32" + wide]
-        assert got == ["s32[4]"] + ["bf16" + wide] * n + stats, (name, got)
+        assert got == ["s32[4]"] + tables + ["bf16" + wide] * n + stats, (
+            name, got)
 
 
 def _fingerprint(text):
@@ -163,7 +167,7 @@ def _fingerprint(text):
 
 @pytest.mark.parametrize("shape,causal,want", [
     ((32, 512, 12, 64), False, "6ff7e9f446afbeed"),
-    ((2, 4096, 16, 128), True, "09b7d1997ba9f9a7")])
+    ((2, 4096, 16, 128), True, "81fbae24ce38b455")])
 def test_equal_width_flash_compiles_to_the_program_of_pr29(
         v5e, shape, causal, want):
     """``flash_attention`` grew a value width of its own (PR 30). Where q,
@@ -171,7 +175,11 @@ def test_equal_width_flash_compiles_to_the_program_of_pr29(
     compiler must get the program it got from PR 29's tree: the
     fingerprints were taken from that tree (``git archive``) and from
     this one, and were equal. A PR that means to change these programs
-    re-takes them and says so in ``PERF.md``."""
+    re-takes them and says so in ``PERF.md``. PR 41 did, for the causal
+    call alone: its kernels walk a pair list (three more scalar operands,
+    a two-dimensional grid; ``09b7d1997ba9f9a7`` before). The
+    bidirectional call's is still PR 29's: a call the list does not serve
+    compiles to the program it always did."""
     q = _z(*shape)
 
     def fwd_bwd(q, k, v):
@@ -236,14 +244,47 @@ def test_flash_qk192_v128_lowers_and_v_is_not_padded_to_q(v5e):
     stats = f"f32[{B * H},{L},128]"
     operands = {n: re.findall(r"(\w+\[[\d,]*\])", c)
                 for n, c in calls.items()}
-    assert operands["flash_fwd"] == ["s32[4]", qk, qk, val]
+    scalars = ["s32[4]"] + ["s32[36]"] * 3      # offsets, the pair list
+    assert operands["flash_fwd"] == scalars + [qk, qk, val]
     for name in ("flash_bwd_dq", "flash_bwd_dkv"):
-        assert operands[name] == ["s32[4]", qk, qk, val, val, stats], name
+        assert operands[name] == scalars + [qk, qk, val, val, stats], name
     results = lambda name: re.findall(r"(\w+\[[\d,]*\])", made[name])
     wide, narrow = f"f32[{B * H},{L},256]", f"f32[{B * H},{L},128]"
     assert results("flash_fwd") == [narrow, stats]
     assert results("flash_bwd_dq") == [wide]
     assert results("flash_bwd_dkv") == [wide, narrow]
+
+
+@pytest.mark.parametrize("cell,shape,dv", [
+    ("olmoe_1b7b_seq4096 window", (2, 4096, 16, 128), 128),
+    ("olmoe_1b7b_seq4096 check", (1, 4096, 16, 128), 128),
+    ("joyai_flash_seq4096 window", (2, 4096, 32, 192), 128),
+    ("joyai_flash_seq4096 check", (1, 4096, 32, 192), 128),
+    ("lfm2_8b_a1b_seq4096 window and check", (4, 4096, 32, 64), 64)])
+def test_causal_pair_list_kernels_lower_at_the_cells_shapes(
+        v5e, cell, shape, dv):
+    """The three causal cells' attention calls, at their windows' shapes
+    and at their checks' (OLMoE's and JoyAI's checks run one sequence), as
+    Mosaic compiles them with the pair list (PR 41): each of the three
+    kernels reads the 36 walked pairs of the 8 x 8 rectangle from three
+    scalar-prefetch tables beside the offsets."""
+    import re
+
+    q, v = _z(*shape), _z(*shape[:3], dv)
+
+    def fwd_bwd(q, k, v):
+        loss = lambda q, k, v: (flash_attention(
+            q, k, v, causal=True, interpret=False) ** 2).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    hlo = _compile(fwd_bwd, SingleDeviceSharding(v5e[0]), q, q, v).as_text()
+    calls = dict(re.findall(
+        r"%(flash_[a-z_]+)[.\d]* = .*?operand_layout_constraints=\{(.*?)\}, \w+=",
+        hlo))
+    assert set(calls) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    for name, operands in calls.items():
+        got = re.findall(r"(\w+\[[\d,]*\])", operands)
+        assert got[:4] == ["s32[4]"] + ["s32[36]"] * 3, (name, got)
 
 
 def test_joyai_step_compiles_small(v5e, as_tpu):

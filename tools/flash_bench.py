@@ -4,14 +4,15 @@ measurement behind the operand dtype of ``ops/flash_attention.py``
 
     python3 tools/flash_bench.py [--against DIR] [--calls 20] [--rehearse]
                                  [--out chiprun_out/flash_bench.json]
+    python3 tools/flash_bench.py --equal [--rehearse]
 
 At the dense cells' attention shapes — ``[32, 512, 12, 64]``
 bidirectional (``ernie_base_seq512``), ``[2, 4096, 16, 128]`` causal
 (``olmoe_1b7b_seq4096``) and q, k ``[2, 4096, 32, 192]`` with v
 ``[2, 4096, 32, 128]`` causal (``joyai_flash_seq4096``: latent attention,
-the value side narrower than q.k), float32 in and out as the models call
-it — one
-jitted value-and-gradient of ``flash_attention`` is run ``--calls`` times
+the value side narrower than q.k), ``[4, 4096, 32, 64]`` causal
+(``lfm2_8b_a1b_seq4096``: k and v as the model repeats them to its 32
+query heads), float32 in and out as the models call it — one jitted value-and-gradient of ``flash_attention`` is run ``--calls`` times
 under the device profiler. Per kernel (``flash_fwd``, ``flash_bwd_dq``,
 ``flash_bwd_dkv``, found in the trace by name): milliseconds a call,
 beside the two floors of a v5e computed from what the kernel is HANDED —
@@ -24,6 +25,8 @@ a k block in ``flash_bwd_dkv``), and 2 FLOP a multiply-add of its matmuls
 over the blocks the causal mask leaves, over 197 TFLOP/s (``flop_ms``).
 ``roofline_share`` = the larger floor over the measured time: of what the
 kernel is HANDED (192 padded to 256 lanes, whole blocks on the diagonal).
+The grid is read off the jaxpr too: a causal call's pair list
+(``(BH, pairs)``, PR 41) fetches per walked pair, the rectangle per step.
 ``required_share`` is the benchmark's own count
 (``benchmarks/harness/flops_mla.flash_kernel_floor``, what the
 ``flash_*_roofline_share`` metrics read: logical widths, the positions the
@@ -38,12 +41,30 @@ tree in a process of its own, and prints both beside each other. Because
 the bytes are read off each tree's own jaxpr, the table follows whatever
 formulation the tree has. A builder's tool, not a metric: needs a TPU
 (``--rehearse``: tiny shapes on the CPU, kernels interpreted, no times).
+
+``--equal`` times nothing: it shows ON THE CHIP that a causal call's pair
+list (``ops/flash_attention._causal_pairs``) gives what the rectangle
+gives. The rectangle is a list too — every ``nq·nk`` pair through the same
+tables, the emptied ones skipped by the body's own ``pl.when`` — so the
+tool traces each case three times: with ``_causal_pairs`` answering
+all-true (the reference), as the program does, and with it answering None
+(the rectangle's own ``(BH, nq, nk)`` grid, what the tree before PR 41
+compiled), and compares ``out``, ``lse``, ``dq``, ``dk``, ``dv`` bit for
+bit: at the three causal cells' window shapes AND their checks' (OLMoE's
+and JoyAI's checks run ONE sequence), operands from ``jax.random`` at two
+seeds, through ``flash_attention`` under a gradient (the step's path),
+outside one (the ``routing`` program's: forward alone) and through
+``flash_attention_with_lse`` with an lse cotangent. Each program runs
+twice with ERNIE's bidirectional call between the runs: a block read
+before it is written holds other bytes the second time. Exit 1 where
+anything differs.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import shutil
 import subprocess
@@ -60,14 +81,24 @@ from harness.kernels import KERNEL_RE as _KERNEL_RE  # noqa: E402
 #: of the dense cells
 SHAPES = (("ernie_base_seq512", (32, 512, 12, 64), False, 64),
           ("olmoe_1b7b_seq4096", (2, 4096, 16, 128), True, 128),
-          ("joyai_flash_seq4096", (2, 4096, 32, 192), True, 128))
+          ("joyai_flash_seq4096", (2, 4096, 32, 192), True, 128),
+          ("lfm2_8b_a1b_seq4096", (4, 4096, 32, 64), True, 64))
 REHEARSAL = (("rehearsal", (1, 1024, 1, 24), True, 16),)
+#: the causal calls `--equal` compares: the cells' windows and their checks
+EQUAL_SHAPES = (("olmoe_1b7b_seq4096 window", (2, 4096, 16, 128), 128),
+                ("olmoe_1b7b_seq4096 check", (1, 4096, 16, 128), 128),
+                ("joyai_flash_seq4096 window", (2, 4096, 32, 192), 128),
+                ("joyai_flash_seq4096 check", (1, 4096, 32, 192), 128),
+                ("lfm2_8b_a1b_seq4096 window and check",
+                 (4, 4096, 32, 64), 64))
+EQUAL_REHEARSAL = (("rehearsal", (2, 1024, 2, 24), 16),)
 
 
 def handed(step, args):
-    """{kernel: {"grid", "operands": [(dtype, shape)], "results": [...]}}
-    of every ``pallas_call`` in the jaxpr of ``step(*args)``: the array
-    operands (the scalar-prefetch vector left out) and the results."""
+    """{kernel: {"grid", "blocks": (bq, bk), "operands": [(dtype, shape)],
+    "results": [...]}} of every ``pallas_call`` in the jaxpr of
+    ``step(*args)``: the array operands (the scalar-prefetch operands left
+    out), the results, and the rows of q's and k's blocks."""
     import jax
 
     found = {}
@@ -77,8 +108,11 @@ def handed(step, args):
             if eqn.primitive.name == "pallas_call":
                 avals = lambda vs: [(v.aval.dtype, tuple(v.aval.shape))
                                     for v in vs if v.aval.ndim == 3]
+                grid = eqn.params["grid_mapping"]
                 found[eqn.params["name"]] = {
-                    "grid": tuple(eqn.params["grid_mapping"].grid),
+                    "grid": tuple(grid.grid),
+                    "blocks": tuple(m.block_shape[1].block_size
+                                    for m in grid.block_mappings[:2]),
                     "operands": avals(eqn.invars),
                     "results": avals(eqn.outvars)}
             for sub in jax.core.jaxprs_in_params(eqn.params):
@@ -95,22 +129,25 @@ def floors(name, call, causal, peaks):
 
     nbytes = lambda a: int(np.prod(a[1])) * np.dtype(a[0]).itemsize
     once = sum(nbytes(a) for a in call["operands"] + call["results"])
-    # Grids: fwd and dq (BH, q blocks, k blocks), dkv (BH, k blocks, q
-    # blocks). An operand whose block follows the innermost axis (k and v,
-    # operands 1 and 2, in fwd and dq; all the others in dkv) is fetched
-    # anew at every grid step — masked-out steps too: the pipeline fetches
-    # before the body decides — unless that axis has one block.
-    BH, outer, inner = call["grid"]
+    (_, (_, Lq, D)), (_, (_, Lk, _)), (_, (_, _, Dv)) = call["operands"][:3]
+    bq, bk = call["blocks"]
+    nq, nk = Lq // bq, Lk // bk
+    pairs = sum(1 for i in range(nq) for j in range(nk)
+                if not causal or i * bq + bq - 1 >= j * bk)
+    # Grids: the rectangle's — fwd and dq (BH, q blocks, k blocks), dkv (BH,
+    # k blocks, q blocks) — or a pair list's (BH, pairs). An operand whose
+    # block follows the run's inner index (k and v, operands 1 and 2, in
+    # fwd and dq; all the others in dkv) is fetched anew at every grid step
+    # of a head — in the rectangle the masked-out steps too: the pipeline
+    # fetches before the body decides — unless that index has one block.
+    BH, steps = call["grid"][0], math.prod(call["grid"][1:])
     dkv = name == "flash_bwd_dkv"
+    inner = nq if dkv else nk
     reread = sum(nbytes(a) for a in call["results"])
     for n, a in enumerate(call["operands"]):
         follows_inner = (n in (1, 2)) != dkv
-        reread += nbytes(a) * (outer if follows_inner and inner > 1 else 1)
-    nq, nk = (inner, outer) if dkv else (outer, inner)
-    (_, (_, Lq, D)), (_, (_, Lk, _)), (_, (_, _, Dv)) = call["operands"][:3]
-    bq, bk = Lq // nq, Lk // nk
-    pairs = sum(1 for i in range(nq) for j in range(nk)
-                if not causal or i * bq + bq - 1 >= j * bk)
+        reread += nbytes(a) * (steps / inner
+                               if follows_inner and inner > 1 else 1)
     n_qk, n_v = MATMULS[name]
     flop = 2.0 * BH * pairs * bq * bk * (n_qk * D + n_v * Dv)
     return {"bytes": once, "bytes_reread": reread, "flop": flop,
@@ -209,6 +246,110 @@ def measure(args) -> dict:
     return rec
 
 
+def equal(args) -> int:
+    """``--equal``: the pair list against the whole rectangle walked as a
+    list and against the rectangle's own grid, bit for bit (module
+    docstring). Prints one JSON line a case and returns the number of
+    cases that differ."""
+    if args.rehearse:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+    sys.path.insert(0, ROOT)
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import flash_attention as fa
+
+    dev = jax.devices()[0]
+    if not args.rehearse and dev.platform != "tpu":
+        raise SystemExit(f"needs a TPU, jax found {dev.platform}")
+    blocks = {"block_q": 256, "block_k": 256} if args.rehearse else {}
+    listed = fa._causal_pairs
+
+    def whole(*a):
+        keep = listed(*a)
+        return keep if keep is None else np.ones_like(keep)
+
+    # the first is the reference the others are held to
+    walks = (("rectangle_as_list", whole), ("list", listed),
+             ("rectangle_grid", lambda *a: None))
+
+    def step(q, k, v, w):
+        def loss(q, k, v):
+            out = fa.flash_attention(q, k, v, causal=True, **blocks)
+            return jnp.sum(out ** 2), out
+        (_, out), g = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return dict(zip(("out", "dq", "dk", "dv"), (out,) + g))
+
+    def forward(q, k, v, w):
+        return {"out": fa.flash_attention(q, k, v, causal=True, **blocks)}
+
+    def with_lse(q, k, v, w):
+        def loss(q, k, v):
+            out, lse = fa.flash_attention_with_lse(
+                q, k, v, causal=True, **blocks)
+            return jnp.sum(out ** 2) + jnp.sum(lse * w), (out, lse)
+        (_, aux), g = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return dict(zip(("out", "lse", "dq", "dk", "dv"), aux + g))
+
+    def other_kernel():
+        # ERNIE's bidirectional call: another program over the same VMEM
+        ks = jax.random.split(jax.random.key(99), 3)
+        shape = (2, 256, 2, 24) if args.rehearse else (32, 512, 12, 64)
+        q, k, v = (jax.random.normal(kk, shape, jnp.float32) for kk in ks)
+        jax.block_until_ready(jax.grad(lambda q: jnp.sum(
+            fa.flash_attention(q, k, v, **blocks) ** 2))(q))
+
+    bits = lambda a: jax.lax.bitcast_convert_type(a, jnp.uint32)
+    same = lambda a, b: bool(jnp.all(bits(a) == bits(b)))
+    differ = 0
+    for cell, shape, dv in (EQUAL_REHEARSAL if args.rehearse
+                            else EQUAL_SHAPES):
+        for seed in (args.seed, args.seed + 1):
+            ks = jax.random.split(jax.random.key(seed), 4)
+            q, k = (jax.random.normal(kk, shape, jnp.float32)
+                    for kk in ks[:2])
+            v = jax.random.normal(ks[2], shape[:-1] + (dv,), jnp.float32)
+            w = jax.random.normal(ks[3], shape[:3], jnp.float32)
+            for case in (step, forward, with_lse):
+                row = {"cell": cell, "shape": list(shape), "v_dim": dv,
+                       "seed": seed, "case": case.__name__,
+                       "device_kind": dev.device_kind, "grids": {},
+                       "equal": {}}
+                ref = None
+                for walk, pairs in walks:
+                    fa._causal_pairs = pairs
+                    try:
+                        # a function of its own, or jit hands back the
+                        # first walk's trace
+                        fn = jax.jit(lambda *a: case(*a))
+                        row["grids"][walk] = {
+                            n: list(c["grid"]) for n, c in
+                            handed(fn, (q, k, v, w)).items()}
+                        runs = [jax.block_until_ready(fn(q, k, v, w))]
+                        other_kernel()
+                        runs.append(jax.block_until_ready(fn(q, k, v, w)))
+                    finally:
+                        fa._causal_pairs = listed
+                    if ref is None:
+                        ref = runs[0]
+                        row["finite"] = all(bool(jnp.isfinite(a).all())
+                                            for a in ref.values())
+                    for n, run in enumerate(runs, 1):
+                        row["equal"][f"{walk}_run{n}"] = {
+                            name: same(run[name], ref[name]) for name in ref}
+                    del runs
+                row["ok"] = row["finite"] and all(
+                    all(e.values()) for e in row["equal"].values())
+                differ += not row["ok"]
+                print(json.dumps(row), flush=True)
+    print(json.dumps({"equal_cases_that_differ": differ}), flush=True)
+    return differ
+
+
 def table(runs) -> str:
     """Markdown: one row a (cell, kernel), one column group a tree (its
     runs' times side by side; the share is of the fastest)."""
@@ -255,7 +396,12 @@ def main() -> int:
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--equal", action="store_true",
+                    help="no times: the causal pair list against the whole "
+                    "rectangle walked as a list, bit for bit")
     args = ap.parse_args()
+    if args.equal:
+        return 1 if equal(args) else 0
     if args.rehearse:
         args.calls = 2
     if not args.against:
