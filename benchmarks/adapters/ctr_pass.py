@@ -380,6 +380,7 @@ class CtrPassSystem:
         verdict = reference.compare(got, ref)
         lap("reference_steps")
         verdict["overflow"] = overflow
+        verdict["tol"] = dict(verdict["tol"], overflow=0)
         verdict["ok"] = bool(verdict["ok"] and overflow == 0)
 
         # (2) the step as the window dispatches it

@@ -231,4 +231,4 @@ def compare_updates(got: Mapping[str, Any], ref: Mapping[str, Any],
                 [ref["loss"][0], ref["loss"][-1]]],
             "loss_rel": loss_rel, "counts_exact": bool(exact),
             "params_upd_rel": max(params.values()),
-            "rows_upd_rel": rows, "tol": TOL_AMP}
+            "rows_upd_rel": rows, "tol": dict(TOL_AMP, counts_exact=True)}

@@ -59,7 +59,9 @@ near-ties, is held to the same function.
 
 from __future__ import annotations
 
+import importlib.util
 import math
+import os
 from typing import Any, Dict, Mapping, Optional
 
 import numpy as np
@@ -77,11 +79,19 @@ import numpy as np
 #: near-tie differently (``near_ties_resolved_differently``) the token's
 #: experts differ, which is no error of either: losses and gradients are
 #: then compared with this reference GIVEN the system's index, as ``amp``
-#: always is. (Where routing has collapsed, thousands of look-alike tokens
-#: sit at one near-tie together: PERF.md section 6, PR 30.) The bias after the step must be
-#: EQUAL for every expert whose count is further from the mean than the
-#: layer has tokens inside ``gap`` (a flipped near-tie moves a count by
-#: one; nothing else can).
+#: always is — and the system's k experts there must still be a top-k of
+#: this reference's own ``s + b`` to within ``gap`` (``near_tie_excess``,
+#: ``harness/near_tie.py``: the other expert of an exact tie reads 0.0,
+#: one inside the tie ``gap`` at the most, any other the distance to it.
+#: The limit is ``gap`` by that construction and NOT by two readings:
+#: the cell's three runs with it read 0.0 with no near-tie resolved
+#: differently, no control was run at its size; OLMoE's, between its
+#: readings, sits a fifth under its ``gap``: PERF.md sections 6 and 7, PR
+#: 43). (Where routing has collapsed, thousands of look-alike tokens sit
+#: at one near-tie together: PERF.md section 6, PR 30.) The bias after
+#: the step must be EQUAL for every expert whose count is further from
+#: the mean than the layer has tokens inside ``gap`` (a flipped near-tie
+#: moves a count by one; nothing else can).
 #:
 #: ``amp``: the step as measured — bf16 operands in every dense and grouped
 #: matmul and in the flash kernels, float32 accumulation, float32 router,
@@ -350,9 +360,21 @@ def loss_and_grads(params: Mapping[str, Any], ids, labels,
             "router_scores": 1.0 / (1.0 + np.exp(-np.asarray(z, np.float64))),
             "expert_index": np.asarray(index if given else own),
             "own_index": np.asarray(own),
+            "bias": np.stack([np.asarray(b) for b in biases]),
             "gap": np.asarray(gap), "counts": np.asarray(counts),
             "bias_after": {n: np.asarray(b) for n, b in zip(names, after)},
             "grads": grads}
+
+
+def _harness(name: str):
+    """``../harness/<name>.py`` by its path: this file is itself loaded by
+    path, from places that have no ``harness`` to import."""
+    spec = importlib.util.spec_from_file_location(
+        "_bench_harness_" + name, os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "harness", name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _overlap(a: np.ndarray, b: np.ndarray):
@@ -378,22 +400,33 @@ def compare_routing(got: Mapping[str, Any], ref: Mapping[str, Any],
             - ref["router_scores"]), axis=(1, 2))
         out["score_abs"] = float(np.max(by_layer))
         out["score_abs_by_layer"] = [float(x) for x in by_layer]
-        clear = ref["gap"] > tol["gap"]
-        out["clear_tokens_share"] = float(np.mean(clear))
-        out["topk_match_where_clear"] = float(np.mean(same[clear])) \
-            if clear.any() else 1.0
-        # near-ties the two resolved differently. Past such a token the
+        # near-ties the two resolved differently: past such a token the
         # two compute different functions (its later scores differ by
         # 5e-3: my chip run, PR 30), so scores, losses and gradients are
-        # then compared with this reference GIVEN the system's index
-        out["near_ties_resolved_differently"] = int(np.sum(~same & ~clear))
-        out["ok"] = bool(out["score_abs"] <= tol["score_abs"]
-                         and out["topk_match_where_clear"] == 1.0)
+        # then compared with this reference GIVEN the system's index; the
+        # system's experts there must still be a top-k of what this
+        # reference ranks by, ``s + b`` (``harness/near_tie.py``). A
+        # ``ref`` made by hand without ``bias`` (tests/test_joyai.py) is
+        # ranked by its scores alone, and the result says so
+        select = ref["router_scores"]
+        if "bias" in ref:                   # [layers, E]
+            select = select + ref["bias"][:, None, :]
+        out["ranked_by"] = "s + b" if "bias" in ref else "s alone"
+        out.update(_harness("near_tie").readings(
+            select, ref["gap"], same, got["expert_index"], tol["gap"]))
+        limits = {"score_abs": tol["score_abs"],
+                  "topk_match_where_clear": 1.0,
+                  "near_tie_excess": tol["gap"]}
+        out["ok"] = bool(out["score_abs"] <= limits["score_abs"]
+                         and out["topk_match_where_clear"] == 1.0
+                         and out["near_tie_excess"]
+                         <= limits["near_tie_excess"])
     else:
         out["topk_overlap"] = overlap
         out["topk_match"] = float(np.mean(same))
-        out["ok"] = bool(overlap >= tol["topk_overlap"])
-    out["tol"] = tol
+        limits = {"topk_overlap": tol["topk_overlap"]}
+        out["ok"] = bool(overlap >= limits["topk_overlap"])
+    out["tol"] = limits
     return out
 
 
@@ -410,7 +443,8 @@ def _bias_agrees(got: Mapping[str, Any], ref: Mapping[str, Any],
         compared += int(sure.sum())
         wrong += int(np.sum(np.asarray(got["bias_after"][name])[sure]
                             != want[sure]))
-    return {"experts_compared": compared, "experts_wrong": wrong}
+    return {"experts_compared": compared, "experts_wrong": wrong,
+            "tol": {"experts_wrong": 0}}
 
 
 def leaf_table(got: Mapping[str, Any], ref: Mapping[str, Any]

@@ -67,7 +67,9 @@ near-ties, is held to the same function.
 
 from __future__ import annotations
 
+import importlib.util
 import math
+import os
 from typing import Any, Dict, Mapping, Optional
 
 import numpy as np
@@ -89,7 +91,14 @@ import numpy as np
 #: noise). Where the two resolve such a near-tie differently the token's
 #: experts differ, which is no error of either: loss and gradients are
 #: then compared with this reference GIVEN the system's index, as ``amp``
-#: always is. The bias after the step must be EQUAL for every expert whose
+#: always is — and the system's k experts there must still be a top-k of
+#: this reference's own ``s + b`` to within ``gap`` (``near_tie_excess``,
+#: ``harness/near_tie.py``: the other expert of an exact tie reads 0.0,
+#: one inside the tie ``gap`` at the most, any other the distance to it.
+#: The limit is ``gap`` by that construction and NOT by two readings:
+#: the cell's three runs with it read 0.0 with no near-tie resolved
+#: differently, no control was run at its size: PERF.md sections 6 and 7,
+#: PR 43). The bias after the step must be EQUAL for every expert whose
 #: count is further from the mean than the layer has tokens inside
 #: ``gap``. ``loss_rel`` 3e-7 is three units in the last place of a float32
 #: loss of 10: the f32 function read 0 or one (<= 9.5e-8) in all twenty-four
@@ -408,9 +417,21 @@ def loss_and_grads(params: Mapping[str, Any], ids, labels,
             "router_scores": 1.0 / (1.0 + np.exp(-z.astype(np.float64))),
             "expert_index": index if given else own,
             "own_index": own, "gap": gap, "counts": counts,
+            "bias": np.stack([np.asarray(b) for b in biases]),
             "bias_after": {name: np.asarray(b) for name, b in zip(
                 names, bias_after(biases, counts, cfg))},
             "grads": grads}
+
+
+def _harness(name: str):
+    """``../harness/<name>.py`` by its path: this file is itself loaded by
+    path, from places that have no ``harness`` to import."""
+    spec = importlib.util.spec_from_file_location(
+        "_bench_harness_" + name, os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "harness", name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _overlap(a: np.ndarray, b: np.ndarray):
@@ -436,21 +457,32 @@ def compare_routing(got: Mapping[str, Any], ref: Mapping[str, Any],
             - ref["router_scores"]), axis=(1, 2))
         out["score_abs"] = float(np.max(by_layer))
         out["score_abs_by_layer"] = [float(x) for x in by_layer]
-        clear = ref["gap"] > tol["gap"]
-        out["clear_tokens_share"] = float(np.mean(clear))
-        out["topk_match_where_clear"] = float(np.mean(same[clear])) \
-            if clear.any() else 1.0
         # near-ties the two resolved differently: past such a token the
         # two compute different functions, so losses and gradients are
-        # then compared with this reference GIVEN the system's index
-        out["near_ties_resolved_differently"] = int(np.sum(~same & ~clear))
-        out["ok"] = bool(out["score_abs"] <= tol["score_abs"]
-                         and out["topk_match_where_clear"] == 1.0)
+        # then compared with this reference GIVEN the system's index; the
+        # system's experts there must still be a top-k of what this
+        # reference ranks by, ``s + b`` (``harness/near_tie.py``). A
+        # ``ref`` made by hand without ``bias`` (tests/test_joyai.py) is
+        # ranked by its scores alone, and the result says so
+        select = ref["router_scores"]
+        if "bias" in ref:                   # [layers, E]
+            select = select + ref["bias"][:, None, :]
+        out["ranked_by"] = "s + b" if "bias" in ref else "s alone"
+        out.update(_harness("near_tie").readings(
+            select, ref["gap"], same, got["expert_index"], tol["gap"]))
+        limits = {"score_abs": tol["score_abs"],
+                  "topk_match_where_clear": 1.0,
+                  "near_tie_excess": tol["gap"]}
+        out["ok"] = bool(out["score_abs"] <= limits["score_abs"]
+                         and out["topk_match_where_clear"] == 1.0
+                         and out["near_tie_excess"]
+                         <= limits["near_tie_excess"])
     else:
         out["topk_overlap"] = overlap
         out["topk_match"] = float(np.mean(same))
-        out["ok"] = bool(overlap >= tol["topk_overlap"])
-    out["tol"] = tol
+        limits = {"topk_overlap": tol["topk_overlap"]}
+        out["ok"] = bool(overlap >= limits["topk_overlap"])
+    out["tol"] = limits
     return out
 
 
@@ -467,7 +499,8 @@ def _bias_agrees(got: Mapping[str, Any], ref: Mapping[str, Any],
         compared += int(sure.sum())
         wrong += int(np.sum(np.asarray(got["bias_after"][name])[sure]
                             != want[sure]))
-    return {"experts_compared": compared, "experts_wrong": wrong}
+    return {"experts_compared": compared, "experts_wrong": wrong,
+            "tol": {"experts_wrong": 0}}
 
 
 def leaf_table(got: Mapping[str, Any], ref: Mapping[str, Any]

@@ -7,9 +7,11 @@ files (found by name, ``harness/spec.py``), warms up, measures whole
 dispatches over their own elapsed time (``harness/window.py``), checks the
 outputs against the plain reference on the device, and prints ONE JSON
 object as the last line of stdout: ``correct``, ``attempted``, ``failed``,
-``metrics``, ``device`` (and ``breakdown`` when traced). Earlier lines
-carry the details: compile counts, every dispatch's completion time, the
-losses, what the checks compared.
+``metrics``, ``device`` (and ``breakdown`` when traced), then
+``reference``, whose ``numbers`` hold every reading the reference was
+compared by beside its limit (the last lines of stderr say the same).
+Earlier lines carry the details: compile counts, every dispatch's
+completion time, the losses, what the checks compared.
 
 A run needs a TPU with at least the cell's chips and fails without one.
 ``--rehearse`` is the explicit exception: tiny sizes on the CPU (virtual
@@ -26,6 +28,7 @@ _T_START = time.perf_counter()
 
 import argparse
 import json
+import math
 import os
 import shutil
 import sys
@@ -41,6 +44,36 @@ def say(**kw) -> None:
     since the process started."""
     kw["at_s"] = round(time.perf_counter() - _T_START, 3)
     print(json.dumps(kw), flush=True)
+
+
+def compared(check, prefix: str = "") -> dict:
+    """{name: [reading, limit]} of one check's result, flat: every reading
+    that the ``tol`` beside it names (a ``tol`` may nest, a leaf a limit),
+    and, for a comparison nested in it, whether that was ``ok`` beside 1.
+    Copies what the ``checks`` line prints; computes nothing. A reading
+    that is not finite goes in as its name ("nan", "inf"): the line stays
+    JSON that any parser reads."""
+    out = {}
+
+    def pair(name, reading, limit):
+        if isinstance(limit, dict):
+            for k, v in limit.items():
+                if isinstance(reading, dict) and k in reading:
+                    pair(f"{name}.{k}", reading[k], v)
+        else:
+            reading = float(reading)
+            out[name] = [reading if math.isfinite(reading) else repr(reading),
+                         float(limit)]
+
+    if prefix and "ok" in check:
+        out[prefix + "ok"] = [float(check["ok"]), 1.0]
+    for k, limit in check.get("tol", {}).items():
+        if k in check:
+            pair(prefix + k, check[k], limit)
+    for k, v in check.items():
+        if k != "tol" and isinstance(v, dict):
+            out.update(compared(v, f"{prefix}{k}."))
+    return out
 
 
 def main() -> int:
@@ -162,11 +195,14 @@ def main() -> int:
            "device_kind": devices[0].device_kind, "rehearse": args.rehearse,
            "trace": None, "hlo_text": ""}
     out = {"correct": bool(correct), "attempted": int(attempted),
-           "failed": int(failed),
-           "reference": {"config": cell.config_name,
-                         "file": f"{os.path.relpath(HERE, ROOT)}/configs/"
-                                 f"{cell.config['reference']}.py",
-                         "agrees": bool(checks["reference"]["ok"])}}
+           "failed": int(failed)}
+    numbers = compared(checks["reference"])
+    # last in the line: what a refusal's record keeps is the line's end
+    reference = {"config": cell.config_name,
+                 "file": f"{os.path.relpath(HERE, ROOT)}/configs/"
+                         f"{cell.config['reference']}.py",
+                 "agrees": bool(checks["reference"]["ok"]),
+                 "numbers": numbers}
     if args.trace:
         events, layout = trace.load_events(trace.find_xplane(trace_dir))
         say(trace_layout=layout)
@@ -196,7 +232,12 @@ def main() -> int:
     out["device"] = facts
     if args.rehearse:
         out["rehearsal"] = True
+    out["reference"] = reference
     print(json.dumps(out), flush=True)
+    # the driver's record of a run that is not correct keeps the END of
+    # both streams: the same pairs are the last lines of stderr
+    for name, (reading, limit) in numbers.items():
+        print(f"{name} {reading!r} limit {limit!r}", file=sys.stderr)
     return 0
 
 

@@ -1,4 +1,4 @@
-"""Check the yardstick itself, on the CPU, in under a minute.
+"""Check the yardstick itself, on the CPU, in about two minutes.
 
     python3 benchmarks/selfcheck.py
 
@@ -13,8 +13,11 @@
 4. The window's estimator on a fake system with a known dispatch time.
 5. Every cell end to end at a tiny size through ``run.py --rehearse``
    (4 virtual devices for the mesh cell), traced and untraced: exit 0,
-   ``correct``, and no metric printed under a device metric's name.
+   ``correct``, no metric printed under a device metric's name, and the
+   line ends in ``reference.numbers``: each reading beside its limit.
 6. ``run.py`` without ``--rehearse`` off-TPU: non-zero exit, no result.
+7. ``pytest benchmarks/tests`` (beside the rehearsals): what ``correct``
+   excuses at a router's near-tie and the planted faults it refuses.
 
 No number this prints is a device number.
 """
@@ -161,6 +164,10 @@ def rehearsals(bench) -> None:
                                   "--seed", "1", "--seconds", "1"],
                            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
                            env=cpu, text=True)
+    tests = subprocess.Popen(
+        [sys.executable, "-m", "pytest", os.path.join(HERE, "tests"), "-q",
+         "-p", "no:cacheprovider"], stdout=subprocess.PIPE,
+        stderr=subprocess.STDOUT, env=cpu, text=True)
     names = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]}
     for name, traced, proc in jobs:
         out, _ = proc.communicate(timeout=300)
@@ -177,9 +184,25 @@ def rehearsals(bench) -> None:
                       for k in res["metrics"]))
         check(bool(ok), f"rehearsal: {name} --trace {traced} "
               f"({sorted(res.get('metrics', {}))})")
+        # what a refusal's record keeps: every reading beside its limit,
+        # under the key that ends the line
+        ref = res.get("reference", {})
+        numbers = ref.get("numbers", {})
+        check(list(res)[-1:] == ["reference"] and ref.get("agrees") is True
+              and list(ref)[-1:] == ["numbers"] and len(numbers) >= 2
+              and all(len(v) == 2 and all(isinstance(x, float) for x in v)
+                      for v in numbers.values()),
+              f"rehearsal: {name} --trace {traced} ends in the "
+              f"{len(numbers)} numbers its reference was compared by")
     out, _ = off.communicate(timeout=300)
     check(off.returncode != 0 and '"correct"' not in out,
           "run.py off-TPU without --rehearse: fails, prints no result")
+    out, _ = tests.communicate(timeout=900)
+    last = out.strip().splitlines()[-1] if out.strip() else ""
+    check(tests.returncode == 0 and " passed" in last,
+          f"pytest benchmarks/tests: {last.strip('= ')}")
+    if tests.returncode:
+        print(out[-4000:], flush=True)
 
 
 def main() -> int:
