@@ -17,7 +17,8 @@ exit code is non-zero on any. With every ``auto`` switch left at ``auto``:
 - **dense**  — ERNIE-1.0 base (vocab 18000, hidden 768, 12 heads, ffn 3072,
   12 layers, seq 512, batch 16) through ``Trainer(amp=True)``, three
   steps; the Pallas flash kernel must be compiled (not interpreted) and
-  agree with ``local_attention`` at that head shape.
+  agree with ``local_attention`` at that head shape, and once more under
+  a sliding window at 28 query / 4 key-value heads of 128.
 - **four**   — with ≥ 4 devices: the key-routed sharded CTR step on
   ``{"ps": 4}`` at the same widths against the one-device step, then the
   hybrid ERNIE step of ``__graft_entry__`` on the four real devices.
@@ -70,6 +71,12 @@ class Sizes:
     seq: int = 512
     ernie_batch: int = 16
     ernie_steps: int = 3
+    # one windowed flash call at SmallThinker's head shape: 28 query heads
+    # on 4 key-value heads of 128, a 512-key window over 2048 positions
+    # (4 x 4 blocks of 512: 7 of the 10 causal pairs walked)
+    window_heads: Tuple[int, int, int] = (28, 4, 128)
+    window_seq: int = 2048
+    window: int = 512
 
 
 def make_ctr_dataset(sz: Sizes, n_batches: int, seed: int):
@@ -403,10 +410,35 @@ def leg_dense(sz: Sizes) -> Dict:
     err_fast = _max_diff(fast, ref, relative=True)
     assert err_exact <= 5e-4, f"flash(highest) vs local_attention: {err_exact}"
     assert err_fast <= 3e-2, f"flash(default) vs local_attention: {err_fast}"
+
+    # the same, causal under a sliding window at grouped-query heads (k and
+    # v repeated first, as ``models/smallthinker.py`` hands them over): the
+    # pair list's second bound and the kernels' second mask, compiled. The
+    # bounds are the bidirectional call's.
+    from paddle_tpu.models.lfm2 import repeat_kv
+
+    H, G, Dw = sz.window_heads
+    W = sz.window
+    qw = jnp.asarray(rng.normal(size=(1, sz.window_seq, H, Dw)), jnp.float32)
+    kw, vw = repeat_kv(*(jnp.asarray(rng.normal(
+        size=(1, sz.window_seq, G, Dw)), jnp.float32) for _ in range(2)), H)
+    with jax.default_matmul_precision("highest"):
+        ref = fwd_bwd(lambda q, k, v: local_attention(
+            q, k, v, causal=True, window=W))(qw, kw, vw)
+        exact = fwd_bwd(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=W, precision="highest"))(qw, kw, vw)
+    fast = fwd_bwd(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=W))(qw, kw, vw)
+    win_exact = _max_diff(exact, ref, relative=True)
+    win_fast = _max_diff(fast, ref, relative=True)
+    assert win_exact <= 5e-4, f"windowed flash(highest) vs einsum: {win_exact}"
+    assert win_fast <= 3e-2, f"windowed flash(default) vs einsum: {win_fast}"
     return {"loss": [round(l, 4) for l in losses],
             "attn_impl": "flash" if on_tpu else "einsum",
             "mosaic_calls": mosaic_calls,
-            "flash_rel_err": {"highest": err_exact, "default": err_fast}}
+            "flash_rel_err": {"highest": err_exact, "default": err_fast,
+                              "window_highest": win_exact,
+                              "window_default": win_fast}}
 
 
 # ---------------------------------------------------------------------------

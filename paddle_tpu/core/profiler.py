@@ -70,6 +70,7 @@ DEVICE_SCOPES = (
     "pt.ffn.dense",
     "pt.conv", "pt.conv.in", "pt.conv.mix", "pt.conv.out",
     "pt.gqa.qkv", "pt.gqa.repeat",
+    "pt.attn.full", "pt.attn.window",
 )
 
 #: completed spans kept in memory (newest win): a pass is about a dozen

@@ -3,6 +3,8 @@ from .ernie import Ernie, ErnieConfig
 from .olmoe import Olmoe, OlmoeConfig
 from .joyai import Joyai, JoyaiConfig, joyai_loss
 from .lfm2 import Lfm2, Lfm2Config, lfm2_loss
+from .smallthinker import (SmallThinker, SmallThinkerConfig,
+                           smallthinker_loss)
 from .ctr import (CtrConfig, DCN, DeepFM, WideDeep, XDeepFM,
                   make_ctr_train_step)
 from .din import DIN, make_ctr_attention_train_step
@@ -27,6 +29,7 @@ from .shufflenetv2 import (ShuffleNetV2, shufflenet_v2_x0_25,
 __all__ = ["LeNet", "Ernie", "ErnieConfig", "Olmoe", "OlmoeConfig",
            "Joyai", "JoyaiConfig", "joyai_loss",
            "Lfm2", "Lfm2Config", "lfm2_loss",
+           "SmallThinker", "SmallThinkerConfig", "smallthinker_loss",
            "CtrConfig", "DeepFM", "WideDeep", "make_ctr_train_step",
            "DCN", "XDeepFM", "DIN", "DSSM", "ESMM", "MMoE",
            "DeepWalkConfig", "make_deepwalk_train_step",

@@ -69,7 +69,7 @@ from .joyai import _SwiGLU, _causal_attention, _normal
 from .olmoe import rotary
 
 __all__ = ["Lfm2Config", "Lfm2ShortConv", "Lfm2Attention", "Lfm2Experts",
-           "Lfm2Block", "Lfm2", "lfm2_loss", "LAYER_TYPES"]
+           "Lfm2Block", "Lfm2", "lfm2_loss", "LAYER_TYPES", "repeat_kv"]
 
 #: the published mixer of each of the 24 layers
 LAYER_TYPES = tuple(
@@ -139,6 +139,18 @@ class Lfm2Config:
         return total
 
 
+def repeat_kv(k: jax.Array, v: jax.Array,
+              heads: int) -> Tuple[jax.Array, jax.Array]:
+    """k and v [B, L, G, d] copied to ``heads`` heads, key-value head j to
+    the ``heads / G`` consecutive query heads from ``j * that``, under
+    ``pt.gqa.repeat``: the kernels take one k and one v a query head
+    (ROADMAP R8). The one repeat of the grouped-query models (this file's
+    8 -> 32, ``models/smallthinker.py``'s 4 -> 28)."""
+    with jax.named_scope("pt.gqa.repeat"):
+        groups = heads // k.shape[2]
+        return (jnp.repeat(k, groups, axis=2), jnp.repeat(v, groups, axis=2))
+
+
 class Lfm2ShortConv(Layer):
     """The gated short convolution between its two projections."""
 
@@ -192,10 +204,7 @@ class Lfm2Attention(Layer):
             v = F.linear(x, self.wv).reshape(B, L, G, d)
         with jax.named_scope("pt.rope"):
             q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
-        with jax.named_scope("pt.gqa.repeat"):
-            # the kernels take one k and one v a query head (ROADMAP R8)
-            k = jnp.repeat(k, H // G, axis=2)
-            v = jnp.repeat(v, H // G, axis=2)
+        k, v = repeat_kv(k, v, H)
         impl = cfg.attn_impl
         if impl == "auto":
             impl = "flash" if jax.default_backend() == "tpu" else "einsum"

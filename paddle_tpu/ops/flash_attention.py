@@ -42,6 +42,14 @@ rectangle's own — the same mask, the same ``pl.when`` — so a walked pair
 does what its grid step did. Bidirectional calls, traced offsets and a
 call in which some block would have no pair keep the rectangle.
 
+``window`` (a Python int; causal calls with Python-int offsets only) is a
+second bound on that list and a second term of that mask: query ``i``
+sees the keys ``i - window < j <= i``. The pairs wholly below the band are
+left out as those wholly above the diagonal are; the body masks both edges
+wherever it runs, which changes nothing inside the band. A window that
+reaches every key of the call is no window: ``window=None`` and such a
+call trace to the same program, the one a causal call always had.
+
 Backward: standard flash backward — recompute P = exp(S - lse) blockwise;
 dV = P^T dO, dS = P ∘ (dO V^T - Δ), dQ = dS K, dK = dS^T Q with
 Δ = rowsum(dO ∘ O) computed outside (one fused elementwise pass).
@@ -59,6 +67,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.enforce import enforce
 from ..core.profiler import RecordEvent
 
 __all__ = ["flash_attention", "flash_attention_with_lse", "mxu_rounded"]
@@ -92,9 +101,11 @@ def _static_offsets(q_offset, k_offset):
     return None
 
 
-def _causal_pairs(nq, nk, bq, bk, causal, offsets):
+def _causal_pairs(nq, nk, bq, bk, causal, offsets, window=None):
     """bool ``[nq, nk]``: the block pairs a causal call walks — those with
-    ``q_off + (i+1)·bq − 1 ≥ k_off + j·bk``, the kernels' own ``pl.when``.
+    ``q_off + (i+1)·bq − 1 ≥ k_off + j·bk``, the kernels' own ``pl.when``,
+    and under a ``window`` those that also reach into the band: the
+    block's last key ``k_off + (j+1)·bk − 1 > q_off + i·bq − window``.
     None where the call keeps the rectangle: it is bidirectional,
     ``offsets`` is None (they are data), or some block would have no pair
     to name it — a q block before the first key, a k block past the last
@@ -106,6 +117,8 @@ def _causal_pairs(nq, nk, bq, bk, causal, offsets):
     last_row = q_off + (np.arange(nq)[:, None] + 1) * bq - 1
     first_col = k_off + np.arange(nk)[None, :] * bk
     keep = last_row >= first_col
+    if window is not None:
+        keep &= first_col + bk - 1 > last_row - (bq - 1) - window
     if not (keep.any(axis=1).all() and keep.any(axis=0).all()):
         return None
     return keep
@@ -161,6 +174,15 @@ def _grid_step(refs, listed, k_major=False):
     return (i, j, inner == 0, inner == pl.num_programs(2) - 1), refs
 
 
+def _reached(row0, col0, bq, bk, window):
+    """Inside a kernel: whether the causal mask, and the window's where
+    there is one, leave anything of the block at ``(row0, col0)``."""
+    some = row0 + bq - 1 >= col0
+    if window is not None:
+        some = some & (col0 + bk - 1 > row0 - window)
+    return some
+
+
 def _semantics(grid):
     return pltpu.CompilerParams(dimension_semantics=(
         ("parallel",) * (len(grid) - 1) + ("arbitrary",)))
@@ -171,7 +193,8 @@ def _semantics(grid):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed):
+def _fwd_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed,
+                window=None):
     (i, j, first, last), refs = _grid_step(refs, listed)
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr = refs
 
@@ -198,6 +221,8 @@ def _fwd_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed):
         if causal:
             rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             mask = mask & (cols <= rows)
+            if window is not None:
+                mask = mask & (cols > rows - window)
         s = jnp.where(mask, s, NEG)
 
         m_prev = m_scr[:, :1]                      # [bq, 1]
@@ -215,7 +240,7 @@ def _fwd_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed):
 
     if causal:
         # causal block skip: block fully in the future → nothing to do
-        pl.when(row0 + bq - 1 >= col0)(body)
+        pl.when(_reached(row0, col0, bq, bk, window))(body)
     else:
         body()
 
@@ -228,7 +253,7 @@ def _fwd_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed):
 
 
 def _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, mxu,
-         dtype, keep):
+         dtype, keep, window=None):
     BH, Lq, D = q.shape
     Lk, Dv = k.shape[1], v.shape[-1]      # q.k at D, P.v and the result at Dv
     nq, nk = Lq // bq, Lk // bk
@@ -240,7 +265,8 @@ def _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, mxu,
 
     grid, tables, at_q, at_k = _grid(BH, nq, nk, keep)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               bq=bq, bk=bk, mxu=mxu, listed=bool(tables))
+                               bq=bq, bk=bk, mxu=mxu, listed=bool(tables),
+                               window=window)
     with jax.named_scope("pt.flash_fwd"):
         out, lse = pl.pallas_call(
             kernel,
@@ -278,7 +304,8 @@ def _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, mxu,
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed):
+def _bwd_dq_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed,
+                   window=None):
     (i, j, first, last), refs = _grid_step(refs, listed)
     q_ref, k_ref, v_ref, do_ref, stats_ref, dq_ref, dq_acc = refs
 
@@ -300,6 +327,8 @@ def _bwd_dq_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed):
         if causal:
             rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             mask = mask & (cols <= rows)
+            if window is not None:
+                mask = mask & (cols > rows - window)
         stats = stats_ref[0]
         lse, delta = stats[:, :1], stats[:, 1:2]
         p = jnp.where(mask & (lse > NEG / 2), jnp.exp(s - lse), 0.0)
@@ -313,7 +342,7 @@ def _bwd_dq_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed):
                                          preferred_element_type=jnp.float32) * scale
 
     if causal:
-        pl.when(row0 + bq - 1 >= col0)(body)
+        pl.when(_reached(row0, col0, bq, bk, window))(body)
     else:
         body()
 
@@ -322,7 +351,8 @@ def _bwd_dq_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed):
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed):
+def _bwd_dkv_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed,
+                    window=None):
     # a run is one k block: the q blocks are the inner loop
     (i, j, first, last), refs = _grid_step(refs, listed, k_major=True)
     (q_ref, k_ref, v_ref, do_ref, stats_ref, dk_ref, dv_ref,
@@ -347,6 +377,8 @@ def _bwd_dkv_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed):
         if causal:
             rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             mask = mask & (cols <= rows)
+            if window is not None:
+                mask = mask & (cols > rows - window)
         stats = stats_ref[0]
         lse, delta = stats[:, :1], stats[:, 1:2]
         p = jnp.where(mask & (lse > NEG / 2), jnp.exp(s - lse), 0.0)
@@ -363,7 +395,7 @@ def _bwd_dkv_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed):
                                          preferred_element_type=jnp.float32) * scale
 
     if causal:
-        pl.when(row0 + bq - 1 >= col0)(body)
+        pl.when(_reached(row0, col0, bq, bk, window))(body)
     else:
         body()
 
@@ -373,7 +405,7 @@ def _bwd_dkv_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed):
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd(scale, causal, bq, bk, interpret, mxu, offsets, res, grads):
+def _bwd(scale, causal, bq, bk, interpret, mxu, offsets, window, res, grads):
     q, k, v, out, lse, offs = res          # q, k, v as the kernels read them
     do, dlse = grads
     BH, Lq, D = q.shape
@@ -395,7 +427,7 @@ def _bwd(scale, causal, bq, bk, interpret, mxu, offsets, res, grads):
     lane = jax.lax.broadcasted_iota(jnp.int32, (BH, Lq, 128), 2)
     stats = jnp.where(lane == 0, lse[..., None], delta[..., None])
 
-    keep = _causal_pairs(nq, nk, bq, bk, causal, offsets)
+    keep = _causal_pairs(nq, nk, bq, bk, causal, offsets, window)
 
     def operands(at_q, at_k):
         return [pl.BlockSpec((1, bq, D), at_q),       # q
@@ -408,7 +440,8 @@ def _bwd(scale, causal, bq, bk, interpret, mxu, offsets, res, grads):
     with jax.named_scope("pt.flash_bwd_dq"):
         dq = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                              bq=bq, bk=bk, mxu=mxu, listed=bool(tables)),
+                              bq=bq, bk=bk, mxu=mxu, listed=bool(tables),
+                              window=window),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1 + len(tables),
                 grid=grid,
@@ -427,7 +460,8 @@ def _bwd(scale, causal, bq, bk, interpret, mxu, offsets, res, grads):
     with jax.named_scope("pt.flash_bwd_dkv"):
         dk, dv = pl.pallas_call(
             functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                              bq=bq, bk=bk, mxu=mxu, listed=bool(tables)),
+                              bq=bq, bk=bk, mxu=mxu, listed=bool(tables),
+                              window=window),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1 + len(tables),
                 grid=grid,
@@ -452,11 +486,12 @@ def _bwd(scale, causal, bq, bk, interpret, mxu, offsets, res, grads):
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 7, 8, 9, 10, 11, 12))
+                   nondiff_argnums=(3, 4, 7, 8, 9, 10, 11, 12, 13))
 def _flash(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret,
-           precision, dv, offsets):
+           precision, dv, offsets, window):
     (out, _), _ = _flash_fwd(q, k, v, scale, causal, q_offset, k_offset,
-                             bq, bk, interpret, precision, dv, offsets)
+                             bq, bk, interpret, precision, dv, offsets,
+                             window)
     return out
 
 
@@ -486,12 +521,12 @@ mxu_rounded.defvjp(lambda x: (mxu_rounded(x), None), lambda _, g: (g,))
 
 
 def _flash_fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret,
-               precision, dv, offsets):
+               precision, dv, offsets, window):
     mxu = _mxu_dtype(precision)
     nq, nk = q.shape[1] // bq, k.shape[1] // bk
     # `offsets`: the two offsets where they are Python ints (None where
     # they are data) — q_offset and k_offset themselves are tracers here
-    keep = _causal_pairs(nq, nk, bq, bk, causal, offsets)
+    keep = _causal_pairs(nq, nk, bq, bk, causal, offsets, window)
     offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(k_offset, jnp.int32),
                       jnp.asarray(q.shape[1], jnp.int32),
@@ -506,30 +541,32 @@ def _flash_fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret,
     # span a trace (``profiler.host_spans()``), none on the step path.
     # `scale` is 1/sqrt(D), all that is left here of the unpadded D; `dv`
     # is v's unpadded width (latent attention: q.k at 192, P.v at 128);
-    # the block pairs a head's grid walks, of the rectangle's nq x nk
+    # the block pairs a head's grid walks, of the rectangle's nq x nk;
+    # the window's keys a query (0: none)
     with RecordEvent("pt.flash.operands", bits=8 * q.dtype.itemsize,
                      head_dim=round(scale ** -2), lanes=q.shape[-1],
                      v_head_dim=dv,
                      pairs_walked=nq * nk if keep is None else int(keep.sum()),
-                     pairs_rectangle=nq * nk):
+                     pairs_rectangle=nq * nk, window=window or 0):
         out, lse = _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk,
-                        interpret, mxu, dtype, keep)
+                        interpret, mxu, dtype, keep, window)
     return (out, lse), (q, k, v, out, lse, offs)
 
 
 def _flash_fwd_rule(q, k, v, scale, causal, q_offset, k_offset, bq, bk,
-                    interpret, precision, dv, offsets):
+                    interpret, precision, dv, offsets, window):
     (out, lse), res = _flash_fwd(q, k, v, scale, causal, q_offset, k_offset,
-                                 bq, bk, interpret, precision, dv, offsets)
+                                 bq, bk, interpret, precision, dv, offsets,
+                                 window)
     return out, (res, (q_offset, k_offset))
 
 
 def _flash_bwd_rule(scale, causal, bq, bk, interpret, precision, dv, offsets,
-                    saved, g):
+                    window, saved, g):
     res, (q_offset, k_offset) = saved
     mxu = _mxu_dtype(precision)
-    dq, dk, dv = _bwd(scale, causal, bq, bk, interpret, mxu, offsets, res,
-                      (g, None))
+    dq, dk, dv = _bwd(scale, causal, bq, bk, interpret, mxu, offsets, window,
+                      res, (g, None))
     return dq, dk, dv, None, None
 
 
@@ -537,27 +574,29 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 7, 8, 9, 10, 11, 12))
+                   nondiff_argnums=(3, 4, 7, 8, 9, 10, 11, 12, 13))
 def _flash_pair(q, k, v, scale, causal, q_offset, k_offset, bq, bk,
-                interpret, precision, dv, offsets):
+                interpret, precision, dv, offsets, window):
     (out, lse), _ = _flash_fwd(q, k, v, scale, causal, q_offset, k_offset,
-                               bq, bk, interpret, precision, dv, offsets)
+                               bq, bk, interpret, precision, dv, offsets,
+                               window)
     return out, lse
 
 
 def _flash_pair_fwd_rule(q, k, v, scale, causal, q_offset, k_offset, bq, bk,
-                         interpret, precision, dv, offsets):
+                         interpret, precision, dv, offsets, window):
     (out, lse), res = _flash_fwd(q, k, v, scale, causal, q_offset, k_offset,
-                                 bq, bk, interpret, precision, dv, offsets)
+                                 bq, bk, interpret, precision, dv, offsets,
+                                 window)
     return (out, lse), res
 
 
 def _flash_pair_bwd_rule(scale, causal, bq, bk, interpret, precision, dv,
-                         offsets, res, g):
+                         offsets, window, res, g):
     do, dlse = g
     mxu = _mxu_dtype(precision)
-    dq, dk, dv = _bwd(scale, causal, bq, bk, interpret, mxu, offsets, res,
-                      (do, dlse))
+    dq, dk, dv = _bwd(scale, causal, bq, bk, interpret, mxu, offsets, window,
+                      res, (do, dlse))
     return dq, dk, dv, None, None
 
 
@@ -571,13 +610,14 @@ def flash_attention_with_lse(
     block_q: int = 512, block_k: int = 512,
     interpret: Optional[bool] = None,
     precision: str = "default",
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """flash attention returning (out, lse) — lse: [B, L, H] fp32.
     Differentiable in q/k/v including through lse (the cp ring merges
     per-device partials with lse weights, so its VJP needs dlse)."""
     out, lse, meta = _run_padded(q, k, v, causal, q_offset, k_offset,
                                  block_q, block_k, interpret, precision,
-                                 with_lse=True)
+                                 window, with_lse=True)
     return out, lse
 
 
@@ -588,20 +628,22 @@ def flash_attention(
     block_q: int = 512, block_k: int = 512,
     interpret: Optional[bool] = None,
     precision: str = "default",
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Differentiable flash attention, [B, L, H, D] in and out. ``v`` may
     be narrower or wider than q and k (latent attention: q.k at 192, P.v
     at 128): the scale is q's ``1/sqrt(D)``, the result has v's width, and
     each width is padded to its own lane multiple — v is never padded to
-    q's."""
+    q's. ``window``: query ``i`` sees the keys ``i - window < j <= i`` (a
+    sliding window; causal calls whose offsets are Python ints)."""
     out, _, _ = _run_padded(q, k, v, causal, q_offset, k_offset,
                             block_q, block_k, interpret, precision,
-                            with_lse=False)
+                            window, with_lse=False)
     return out
 
 
 def _run_padded(q, k, v, causal, q_offset, k_offset, block_q, block_k,
-                interpret, precision, with_lse):
+                interpret, precision, window, with_lse):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     B, Lq, H, D = q.shape
@@ -624,14 +666,24 @@ def _run_padded(q, k, v, causal, q_offset, k_offset, block_q, block_k,
 
     qp, kp, vp = to_bh(q, Lq, Lq_p), to_bh(k, Lk, Lk_p), to_bh(v, Lk, Lk_p)
     offsets = _static_offsets(q_offset, k_offset)
+    if window is not None:
+        enforce(isinstance(window, (int, np.integer)) and window >= 1,
+                f"window {window!r}: a Python int of at least 1 key")
+        enforce(causal and offsets is not None,
+                "a window needs a causal call whose offsets are Python "
+                "ints: which pairs a band leaves of a bidirectional call, "
+                "or under traced offsets, is not guessed")
+        # a window that reaches the first key from the last query is none
+        if window > offsets[0] + Lq - 1 - offsets[1]:
+            window = None
 
     if with_lse:
         out, lse = _flash_pair(qp, kp, vp, scale, causal, q_offset,
                                k_offset, bq, bk, interpret, precision, Dv,
-                               offsets)
+                               offsets, window)
     else:
         out = _flash(qp, kp, vp, scale, causal, q_offset, k_offset, bq, bk,
-                     interpret, precision, Dv, offsets)
+                     interpret, precision, Dv, offsets, window)
         lse = None
     out = out[:, :Lq, :Dv].reshape(B, H, Lq, Dv).astype(q.dtype)
     out = jnp.moveaxis(out, 1, 2)

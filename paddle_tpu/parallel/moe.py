@@ -37,6 +37,11 @@ other branch of a ``lax.cond``: every held expert on every token, masked by
 the choice (16 / 8 of the dropless layer's rows, no kernel, exact), so none
 is dropped; each form counts what it computed, ``dropped`` is the held ones
 less that. ``held = (0, E)`` runs the dropless formulation's own stages.
+The router may sit elsewhere (SmallThinker, ``models/smallthinker.py``:
+it reads the stream BEFORE attention): ``held_moe(route=...)`` takes the
+route it is given — ``topk_route``'s softmax, renormalised over the chosen
+— and dispatches other rows than those it was made from; the experts' gate
+is an argument (``activation``: SiLU, or that model's ReLU).
 """
 
 from __future__ import annotations
@@ -62,7 +67,7 @@ from ..ops import collectives as coll
 __all__ = ["top1_gate", "top2_gate", "MoELayer", "ExpertFFN",
            "topk_route", "sort_by_expert", "dispatch_rows", "combine_rows",
            "grouped_matmul", "expert_ffn", "dropless_moe",
-           "sigmoid_route", "dispatch_ladder", "held_moe"]
+           "sigmoid_route", "dispatch_ladder", "router_logits", "held_moe"]
 
 
 def _one_hot(x, n):
@@ -230,12 +235,15 @@ class MoELayer(Layer):
 # ---------------------------------------------------------------------------
 
 
-def topk_route(logits: jax.Array, k: int) -> Dict[str, jax.Array]:
-    """Softmax router with the k largest probabilities a token, for any k,
-    NOT renormalised (OLMoE's ``norm_topk_prob`` false). ``logits`` [T, E]
-    float32. Returns ``index`` [T, k] int32 (ties: the lower expert),
-    ``weight`` [T, k] (those probabilities as they are), ``counts`` [E]
-    int32 (assignments an expert), and the two router losses:
+def topk_route(logits: jax.Array, k: int,
+               renormalise: bool = False) -> Dict[str, jax.Array]:
+    """Softmax router with the k largest probabilities a token, for any k:
+    as they are (OLMoE's ``norm_topk_prob`` false), or with ``renormalise``
+    divided by their sum — the softmax over the chosen logits alone
+    (SmallThinker's ``norm_topk_prob`` true). ``logits`` [T, E] float32.
+    Returns ``index`` [T, k] int32 (ties: the lower expert), ``weight``
+    [T, k], ``counts`` [E] int32 (assignments an expert), and the two
+    router losses:
     ``lb = E * sum_e f_e * P_e`` with ``f_e`` = assignments to e over T (so
     ``sum_e f_e = k``) and ``P_e`` the mean probability of e, and
     ``z = mean_t logsumexp(logits_t)^2``.
@@ -249,6 +257,8 @@ def topk_route(logits: jax.Array, k: int) -> Dict[str, jax.Array]:
     _, index = lax.top_k(lax.stop_gradient(probs), k)
     hot = _one_hot(index, E)                                  # [T, k, E]
     weight = jnp.einsum("tke,te->tk", hot, probs)
+    if renormalise:
+        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
     per_expert = jnp.sum(hot, axis=(0, 1))                    # exact < 2^24
     lb = E * jnp.sum(per_expert / T * jnp.mean(probs, axis=0))
     return {"index": index, "weight": weight,
@@ -390,12 +400,14 @@ def grouped_matmul(x: jax.Array, bank: jax.Array,
 
 
 def expert_ffn(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
-               w_down: jax.Array, group_sizes: jax.Array) -> jax.Array:
-    """Gated SiLU feed-forward of every row through its own expert:
-    ``down(silu(gate(x)) * up(x))``, three grouped matmuls, no bias."""
+               w_down: jax.Array, group_sizes: jax.Array,
+               activation: Callable = jax.nn.silu) -> jax.Array:
+    """Gated feed-forward of every row through its own expert:
+    ``down(activation(gate(x)) * up(x))`` (SiLU unless told otherwise),
+    three grouped matmuls, no bias."""
     gate = grouped_matmul(x, w_gate, group_sizes)
     up = grouped_matmul(x, w_up, group_sizes)
-    return grouped_matmul(jax.nn.silu(gate) * up, w_down, group_sizes)
+    return grouped_matmul(activation(gate) * up, w_down, group_sizes)
 
 
 def dropless_moe(x: jax.Array, router_w: jax.Array, w_gate: jax.Array,
@@ -412,15 +424,15 @@ def dropless_moe(x: jax.Array, router_w: jax.Array, w_gate: jax.Array,
     numbers."""
     T = x.shape[0]
     with jax.named_scope("pt.moe.route"):
-        logits = jnp.matmul(x.astype(jnp.float32), router_w,
-                            precision=lax.Precision.HIGHEST)
+        logits = router_logits(x, router_w)
         route = topk_route(logits, k)
         route["logits"] = logits
         route["dropped"] = T * k - jnp.sum(route["counts"])
     return _every_assignment(x, route, w_gate, w_up, w_down, k), route
 
 
-def _every_assignment(x, route, w_gate, w_up, w_down, k):
+def _every_assignment(x, route, w_gate, w_up, w_down, k,
+                      activation=jax.nn.silu):
     """The routed sum with all T*k assignments computed: sorted by expert,
     gathered, three grouped matmuls, gathered back and summed a token."""
     from .. import amp
@@ -431,7 +443,8 @@ def _every_assignment(x, route, w_gate, w_up, w_down, k):
             x = x.astype(amp.amp_dtype())      # half the bytes to permute
         rows = dispatch_rows(x, order, inverse, k)
     with jax.named_scope("pt.moe.experts"):
-        y = expert_ffn(rows, w_gate, w_up, w_down, route["counts"])
+        y = expert_ffn(rows, w_gate, w_up, w_down, route["counts"],
+                       activation)
     with jax.named_scope("pt.moe.combine"):
         return combine_rows(y, route["weight"], order, inverse)
 
@@ -683,8 +696,8 @@ _weights_in_order.defvjp(
     _weights_in_order_bwd)
 
 
-def _held_sorted(rows: int, k: int, x, weight, w_gate, w_up, w_down, order,
-                 inverse, held, group_sizes):
+def _held_sorted(rows: int, k: int, activation, x, weight, w_gate, w_up,
+                 w_down, order, inverse, held, group_sizes):
     """(the held experts' part of the layer's output through a buffer of
     ``rows`` rows, the assignments it computed: its live rows)."""
     from .. import amp
@@ -696,7 +709,7 @@ def _held_sorted(rows: int, k: int, x, weight, w_gate, w_up, w_down, order,
             x = x.astype(amp.amp_dtype())      # half the bytes to permute
         buf = _gather_held(x, plan, k)
     with jax.named_scope("pt.moe.experts"):
-        y = expert_ffn(buf, w_gate, w_up, w_down, group_sizes)
+        y = expert_ffn(buf, w_gate, w_up, w_down, group_sizes, activation)
     with jax.named_scope("pt.moe.combine"):
         live = plan.tok < held.shape[0]
         w = jnp.where(live, _weights_in_order(weight, order[:rows], inverse),
@@ -710,7 +723,7 @@ def _held_sorted(rows: int, k: int, x, weight, w_gate, w_up, w_down, order,
 _DENSE_BLOCK = 1024
 
 
-def _held_dense(k, x, weight, w_gate, w_up, w_down, local):
+def _held_dense(k, activation, x, weight, w_gate, w_up, w_down, local):
     """(the held experts' part with no buffer at all, the assignments it
     computed): every held expert's FFN on every token, times the token's
     weight for that expert (0 where it did not choose it) — ``local``
@@ -731,7 +744,7 @@ def _held_dense(k, x, weight, w_gate, w_up, w_down, local):
         hot = _one_hot(local, count)
         g = jnp.einsum("tk,tke->te", weight, hot)
         u = x.astype(dt)
-        act = jax.nn.silu(mm("td,edf->tef", u, banks[0])) \
+        act = activation(mm("td,edf->tef", u, banks[0])) \
             * mm("td,edf->tef", u, banks[1])
         return (mm("tef,efd->td", (act * g[..., None]).astype(dt), banks[2]),
                 jnp.sum(hot).astype(jnp.int32))
@@ -744,36 +757,37 @@ def _held_dense(k, x, weight, w_gate, w_up, w_down, local):
         return out.reshape(T, d), jnp.sum(computed)
 
 
-def _held_forms(rows, k, x, weight, banks, ints):
+def _held_forms(rows, k, activation, x, weight, banks, ints):
     """[the sorted buffer of ``rows`` rows, the every-expert form]."""
     order, inverse, held, group_sizes, local = ints
-    return [lambda: _held_sorted(rows, k, x, weight, *banks, order, inverse,
-                                 held, group_sizes),
-            lambda: _held_dense(k, x, weight, *banks, local)]
+    return [lambda: _held_sorted(rows, k, activation, x, weight, *banks,
+                                 order, inverse, held, group_sizes),
+            lambda: _held_dense(k, activation, x, weight, *banks, local)]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _held_experts(rows, k, dense, x, weight, banks, ints):
-    return lax.cond(dense, *reversed(_held_forms(rows, k, x, weight, banks,
-                                                 ints)))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_experts(rows, k, activation, dense, x, weight, banks, ints):
+    return lax.cond(dense, *reversed(_held_forms(rows, k, activation, x,
+                                                 weight, banks, ints)))
 
 
-def _held_experts_fwd(rows, k, dense, x, weight, banks, ints):
+def _held_experts_fwd(rows, k, activation, dense, x, weight, banks, ints):
     # nothing a form computes is kept: a ``cond`` under autodiff would keep
     # BOTH forms' residuals. The backward runs the chosen form again (the
     # held experts are a twentieth of the layer's required FLOPs) and
     # differentiates that.
-    return (_held_experts(rows, k, dense, x, weight, banks, ints),
+    return (_held_experts(rows, k, activation, dense, x, weight, banks, ints),
             (dense, x, weight, banks, ints))
 
 
-def _held_experts_bwd(rows, k, res, g):
+def _held_experts_bwd(rows, k, activation, res, g):
     dense, x, weight, banks, ints = res
 
     def back(form):
         def run(x, weight, banks, g):
             _, vjp = jax.vjp(lambda x, w, b: _held_forms(
-                rows, k, x, w, b, ints)[form]()[0], x, weight, banks)
+                rows, k, activation, x, w, b, ints)[form]()[0],
+                x, weight, banks)
             return vjp(g)
         return run
 
@@ -785,15 +799,32 @@ def _held_experts_bwd(rows, k, res, g):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
-def held_moe(x: jax.Array, router_w: jax.Array, bias: jax.Array,
-             w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, k: int,
-             held: Tuple[int, int], scale: float
+def router_logits(x: jax.Array, router_w: jax.Array) -> jax.Array:
+    """``x`` [T, d] times ``router_w`` [d, E] in float32 at the highest
+    matmul precision whatever ``amp`` says: the choice of experts is a
+    comparison of near-equal numbers."""
+    return jnp.matmul(x.astype(jnp.float32), router_w,
+                      precision=lax.Precision.HIGHEST)
+
+
+def held_moe(x: jax.Array, router_w: Optional[jax.Array],
+             bias: Optional[jax.Array], w_gate: jax.Array, w_up: jax.Array,
+             w_down: jax.Array, k: int, held: Tuple[int, int],
+             scale: float = 1.0, *,
+             route: Optional[Dict[str, jax.Array]] = None,
+             activation: Callable = jax.nn.silu
              ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Top-k-of-E expert layer of which experts ``first .. first + count``
     (``held``) live here. ``x`` [T, d]; ``router_w`` [d, E]; ``bias`` [E];
     banks [count, d, f], [count, d, f], [count, f, d]: the absent experts'
-    weights do not exist. Routes over all E (``sigmoid_route``, float32 at
-    the highest precision whatever ``amp`` says) and returns the sum over
+    weights do not exist; ``activation`` gates an expert (SiLU unless told
+    otherwise). Routes over all E (``sigmoid_route``, float32 at
+    the highest precision whatever ``amp`` says) — or takes a ``route``
+    made elsewhere, from another tensor than the rows dispatched here (a
+    router that reads the stream before attention): the router's dict with
+    ``logits`` [T, E], ``index`` and ``weight`` [T, k] and ``counts`` [E]
+    over ALL experts (``topk_route``'s, ``sigmoid_route``'s); ``router_w``,
+    ``bias`` and ``scale`` are then not read — and returns the sum over
     the chosen experts THAT ARE HELD — a partial result — and the router's
     dict with ``logits``, ``held_assignments`` (how many of the T*k landed
     here), ``rung`` (the rows of the form that ran, one of
@@ -808,18 +839,22 @@ def held_moe(x: jax.Array, router_w: jax.Array, bias: jax.Array,
     ``dropless_moe``'s stages."""
     T = x.shape[0]
     first, count = held
-    E = router_w.shape[-1]
+    if route is None:
+        with jax.named_scope("pt.moe.route"):
+            logits = router_logits(x, router_w)
+            route = sigmoid_route(logits, bias, k, scale)
+            route["logits"] = logits
+    else:
+        route = dict(route)
+        enforce_eq(route["index"].shape, (T, k),
+                   "a route made elsewhere names k experts a row")
+    E = route["logits"].shape[-1]
     enforce(0 <= first and count >= 1 and first + count <= E,
             f"held experts {held} outside 0..{E}")
     enforce_eq(w_gate.shape[0], count, "banks hold the held experts")
     rungs = dispatch_ladder(T, k, E, count)
-    with jax.named_scope("pt.moe.route"):
-        logits = jnp.matmul(x.astype(jnp.float32), router_w,
-                            precision=lax.Precision.HIGHEST)
-        route = sigmoid_route(logits, bias, k, scale)
-        route["logits"] = logits
     if count == E:
-        out = _every_assignment(x, route, w_gate, w_up, w_down, k)
+        out = _every_assignment(x, route, w_gate, w_up, w_down, k, activation)
         n_held = jnp.sum(route["counts"])
         rung = jnp.asarray(rungs[0], jnp.int32)
         route.update(held_assignments=n_held, dropped=T * k - n_held,
@@ -834,7 +869,8 @@ def held_moe(x: jax.Array, router_w: jax.Array, bias: jax.Array,
         n_held = jnp.sum(group_sizes)
         dense = n_held > rungs[0]
     out, computed = _held_experts(
-        rungs[0], k, dense, x, route["weight"], (w_gate, w_up, w_down),
+        rungs[0], k, activation, dense, x, route["weight"],
+        (w_gate, w_up, w_down),
         (order, inverse, held_mask, group_sizes, local))
     walked = jnp.minimum(
         _live_chunks(n_held, rungs[0]) * _chunk_rows(rungs[0]), rungs[0])

@@ -41,14 +41,19 @@ def _block_scores(q, k, scale):
 def local_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False,
     q_offset: int | jax.Array = 0, k_offset: int | jax.Array = 0,
+    window: int | None = None,
 ) -> jax.Array:
-    """Plain softmax attention on local blocks ([B, L, H, D] layout)."""
+    """Plain softmax attention on local blocks ([B, L, H, D] layout);
+    under ``window`` (causal) query i sees the keys i - window < j <= i."""
     scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(q.dtype)
     scores = _block_scores(q, k, scale)
     if causal:
         qi = jnp.arange(q.shape[1])[:, None] + q_offset
         ki = jnp.arange(k.shape[1])[None, :] + k_offset
-        scores = jnp.where(ki <= qi, scores, -jnp.inf)
+        seen = ki <= qi
+        if window is not None:
+            seen = seen & (ki > qi - window)
+        scores = jnp.where(seen, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 

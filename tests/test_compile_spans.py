@@ -433,13 +433,17 @@ def test_benchmark_lists_the_new_metrics_in_every_cell():
         assert m["workloads"] == cells and m["moves"] == "setup_s"
         assert m["layer"] == "entry points" and m["better"] == "lower"
     assert by["moe_rows_walked_share"]["workloads"] == [
-        "joyai_flash_seq4096", "lfm2_8b_a1b_seq4096"]
+        "joyai_flash_seq4096", "lfm2_8b_a1b_seq4096",
+        "smallthinker_21b_seq16384"]                     # PR 44: appended
     walked = by["flash_pairs_walked_share"]              # PR 41
     assert walked["workloads"] == [
         "ernie_base_seq512", "olmoe_1b7b_seq4096", "joyai_flash_seq4096",
-        "lfm2_8b_a1b_seq4096"]
+        "lfm2_8b_a1b_seq4096", "smallthinker_21b_seq16384"]
     assert (walked["layer"], walked["moves"], walked["source"]) == (
         "kernels", "tokens_per_s_per_chip", "program_counter")
-    assert [m["name"] for m in bench["per_layer"][-8:]] == list(
+    # PR 44's seven come after them: nothing was put in the middle
+    assert [m["name"] for m in bench["per_layer"][-15:-7]] == list(
         SPAN_READERS) + ["setup_devices_s", "moe_rows_walked_share",
                          "flash_pairs_walked_share"]
+    assert all(m["workloads"] == ["smallthinker_21b_seq16384"]
+               for m in bench["per_layer"][-7:])

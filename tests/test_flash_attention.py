@@ -326,7 +326,7 @@ def test_operand_span_is_recorded_once_a_compile(precision, bits, causal,
         jax.block_until_ready(step(q, k, v))
     assert spans()[before:] == [{"bits": bits, "head_dim": 8, "lanes": 128,
                                  "v_head_dim": 8, "pairs_walked": walked,
-                                 "pairs_rectangle": 4}]
+                                 "pairs_rectangle": 4, "window": 0}]
 
 
 # ---------------------------------------------------------------------------
@@ -586,3 +586,144 @@ def test_nan_filled_memory_shows_a_block_no_pair_names(monkeypatch):
                                      block_k=16, interpret=NAN_FILLED))
     assert np.isnan(out[:, 16:32]).all()
     assert np.isfinite(out[:, :16]).all() and np.isfinite(out[:, 32:]).all()
+
+
+# ---------------------------------------------------------------------------
+# a sliding window (PR 44): a second bound on the pair list and a second
+# term of the kernels' mask, against the einsum under the same mask
+# ---------------------------------------------------------------------------
+
+
+def _banded_reference(q, k, v, window, q_off=0, k_off=0):
+    """Einsum attention in which query ``i`` sees the keys ``i - window <
+    j <= i`` (positions counted from the two offsets)."""
+    return local_attention(q, k, v, causal=True, q_offset=q_off,
+                           k_offset=k_off, window=window)
+
+
+_WINDOWED = {}
+
+
+def _windowed_case(window):
+    """{name: (kernels' array, einsum's)} of out, dq, dk, dv of one
+    windowed call on 64 positions in 16-blocks, NaN-filled memory: the
+    three kernels under one weighted loss."""
+    if window not in _WINDOWED:
+        rng = np.random.default_rng(31)
+        q, k, v = _qkv(rng, B=1, L=64, H=2, D=8)
+        w = jnp.asarray(rng.normal(size=q.shape).astype(np.float32))
+        kernels = lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window, block_q=16, block_k=16,
+            interpret=NAN_FILLED, precision="highest")
+        einsum = lambda q, k, v: _banded_reference(q, k, v, window)
+        got, want = [
+            (f(q, k, v),) + jax.grad(lambda *a: jnp.sum(f(*a) * w),
+                                     argnums=(0, 1, 2))(q, k, v)
+            for f in (kernels, einsum)]
+        _WINDOWED[window] = dict(zip(("out", "dq", "dk", "dv"),
+                                     zip(got, want)))
+    return _WINDOWED[window]
+
+
+# shorter than a block, a block, one more, no block multiple, the
+# sequence less one, the sequence, past it
+@pytest.mark.parametrize("window", [1, 5, 16, 17, 40, 63, 64, 100])
+@pytest.mark.parametrize("what", ["out", "dq", "dk", "dv"])
+def test_window_matches_the_einsum_mask(window, what):
+    got, want = _windowed_case(window)[what]
+    assert np.isfinite(np.asarray(got)).all()       # every block written
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=3e-6)
+
+
+@pytest.mark.parametrize("window,offsets,Lk", [
+    (8, (16, 0), 32), (24, (32, 16), 32), (8, (48, 0), 64)])
+def test_window_counts_positions_from_the_offsets(window, offsets, Lk):
+    """Python-int offsets shift the band as they shift the diagonal; the
+    last case leaves k blocks below every query's band, so no pair would
+    name them and the call keeps the rectangle, whose body skips what the
+    band empties and whose last step writes their zeros."""
+    rng = np.random.default_rng(33)
+    q_off, k_off = offsets
+    q = jnp.asarray(rng.normal(size=(1, 16, 2, 8)).astype(np.float32))
+    k, v = (jnp.asarray(rng.normal(size=(1, Lk, 2, 8)).astype(np.float32))
+            for _ in range(2))
+    listed = fa._causal_pairs(1, Lk // 16, 16, 16, True, offsets, window)
+    assert (listed is None) == (offsets == (48, 0))
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(f(q, k, v) ** 2)
+
+    kernels = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, q_offset=q_off, k_offset=k_off,
+        block_q=16, block_k=16, interpret=NAN_FILLED, precision="highest")
+    einsum = lambda q, k, v: _banded_reference(q, k, v, window, q_off, k_off)
+    np.testing.assert_allclose(np.asarray(kernels(q, k, v)),
+                               np.asarray(einsum(q, k, v)), atol=3e-6)
+    for a, b in zip(jax.grad(loss(kernels), argnums=(0, 1, 2))(q, k, v),
+                    jax.grad(loss(einsum), argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+
+
+def test_the_windowed_cells_calls_walk_252_of_1024_pairs():
+    """16,384 positions in 512-blocks: the causal list is 528 of the 32 x
+    32 rectangle; a 4096-key window leaves i + 1 pairs in each of the
+    first 8 block rows and 9 in each of the other 24 (the ninth is the
+    block the band's lower edge crosses)."""
+    causal = fa._causal_pairs(32, 32, 512, 512, True, (0, 0))
+    banded = fa._causal_pairs(32, 32, 512, 512, True, (0, 0), 4096)
+    assert int(causal.sum()) == 528
+    assert int(banded.sum()) == 252 == sum(range(1, 9)) + 24 * 9
+    assert not (banded & ~causal).any()
+    assert banded.sum(axis=1).tolist() == list(range(1, 9)) + [9] * 24
+    # a window of one more key reaches a tenth block from a block's first row
+    assert int(fa._causal_pairs(32, 32, 512, 512, True, (0, 0),
+                                4097).sum()) == 252
+    assert int(fa._causal_pairs(32, 32, 512, 512, True, (0, 0),
+                                4098).sum()) == 252 + 23
+    for k_major in (False, True):
+        qi, kj, ends = fa._pair_tables(banded, k_major)
+        assert len(qi) == 252 and int((np.asarray(ends) & 1).sum()) == 32
+
+
+@pytest.mark.parametrize("window", [64, 65, 1000])
+def test_a_window_that_reaches_every_key_is_no_window(window):
+    """``window >= L`` traces to the program ``window=None`` traces to —
+    the same jaxpr, kernels and all — and the span says 0."""
+    from paddle_tpu.core import profiler
+
+    rng = np.random.default_rng(34)
+    q, k, v = _qkv(rng, B=1, L=64, H=2, D=8)
+
+    def traced(window):
+        f = lambda q, k, v: jax.grad(lambda *a: jnp.sum(flash_attention(
+            *a, causal=True, window=window, block_q=16, block_k=16,
+            interpret=True) ** 2), argnums=(0, 1, 2))(q, k, v)
+        return str(jax.make_jaxpr(f)(q, k, v))
+
+    spans = lambda: [s.counts["window"] for s in profiler.host_spans()
+                     if s.name == "pt.flash.operands"]
+    before = len(spans())
+    assert traced(window) == traced(None)
+    assert traced(63) != traced(None)
+    assert spans()[before:] == [0, 0, 63, 0]
+
+
+@pytest.mark.parametrize("case", ["bidirectional", "traced offset",
+                                  "no key", "not an int"])
+def test_a_window_that_would_be_guessed_is_refused(case):
+    from paddle_tpu.core.enforce import EnforceNotMet
+
+    rng = np.random.default_rng(35)
+    q, k, v = _qkv(rng, B=1, L=32, H=1, D=8)
+    kw = {"bidirectional": dict(causal=False, window=8),
+          "traced offset": dict(causal=True, window=8),
+          "no key": dict(causal=True, window=0),
+          "not an int": dict(causal=True, window=8.0)}[case]
+    call = lambda o: flash_attention(q, k, v, q_offset=o, interpret=True,
+                                     **kw)
+    with pytest.raises(EnforceNotMet):
+        if case == "traced offset":
+            jax.jit(call)(jnp.int32(0))
+        else:
+            call(0)

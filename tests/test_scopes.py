@@ -171,6 +171,37 @@ def _lfm2_step_text():
     return text
 
 
+def _smallthinker_step_text():
+    from paddle_tpu.executor import Trainer
+    from paddle_tpu.models.smallthinker import (SmallThinker,
+                                                SmallThinkerConfig,
+                                                smallthinker_loss)
+
+    model = SmallThinker(SmallThinkerConfig(
+        vocab_size=128, hidden_size=64, num_heads=4, num_kv_heads=2,
+        head_dim=16, sliding_window_size=48, num_layers=2, router_width=8,
+        experts_per_token=2, expert_size=32, held=(2, 2), max_seq_len=128,
+        attn_impl="flash", recompute="experts"))
+    trainer = Trainer(model, optimizer.AdamW(1e-3, weight_decay=0.1),
+                      smallthinker_loss, amp=True)
+    ids = np.zeros((2, 128), np.int32)
+    profiler.start_timeline()
+    text = trainer.compiled_text(ids, ids)
+    names = [s.name for s in profiler.host_spans()]
+    assert names.count("pt.smallthinker.layers") == 1       # once a trace
+    spans = {s.name: s.counts for s in profiler.host_spans()
+             if s.name in ("pt.smallthinker.layers", "pt.moe.held")}
+    assert spans == {
+        "pt.smallthinker.layers": {"full": 1, "window": 1, "experts": 2,
+                                   "window_size": 48},
+        "pt.moe.held": {"first": 2, "count": 2, "experts": 8}}
+    # one flash call a layer: the global one, then the windowed one
+    assert [(s.counts["window"], s.counts["pairs_walked"],
+             s.counts["pairs_rectangle"]) for s in profiler.host_spans()
+            if s.name == "pt.flash.operands"] == [(0, 1, 1), (48, 1, 1)]
+    return text
+
+
 _PUSH = {"pt.push.accumulate", "pt.push.update"}
 STEPS = {
     "pass_slab": (_pass_step_text, {"pt.unpack", "pt.probe", "pt.pull",
@@ -202,6 +233,13 @@ STEPS = {
                                "pt.moe.combine", "pt.head_loss", "pt.loss",
                                "pt.dense_opt", "pt.flash_fwd",
                                "pt.flash_bwd_dq", "pt.flash_bwd_dkv"}),
+    # ``pt.attn.full`` / ``pt.attn.window`` sit OUTSIDE ``pt.attn``: never
+    # an operation's last token (test_attention_kinds_are_read_off_...)
+    "smallthinker": (_smallthinker_step_text, {
+        "pt.embed", "pt.attn", "pt.gqa.qkv", "pt.rope", "pt.gqa.repeat",
+        "pt.ffn", "pt.moe.route", "pt.moe.dispatch", "pt.moe.experts",
+        "pt.moe.combine", "pt.head_loss", "pt.loss", "pt.dense_opt",
+        "pt.flash_fwd", "pt.flash_bwd_dq", "pt.flash_bwd_dkv"}),
 }
 # what computes nothing (and what XLA inserts without metadata), and the
 # collectives, which carry no scope by design
@@ -289,6 +327,32 @@ def test_flash_operand_span_counts_a_models_block_pairs(model, causal):
     got = [(s.counts["pairs_walked"], s.counts["pairs_rectangle"])
            for s in host_spans() if s.name == "pt.flash.operands"]
     assert got == [(3 if causal else 4, 4)] * 2
+
+
+@pytest.mark.parametrize("kind", ["pt.attn.full", "pt.attn.window"])
+def test_attention_kinds_are_read_off_the_whole_name(kind):
+    """``benchmarks/harness/scope_paths.py`` (PR 44): the kind of an
+    attention block is a scope OUTSIDE ``pt.attn``, so the last-token
+    readers see ``pt.attn`` / ``pt.flash_*`` as in every other model, and
+    the kind's reader finds, under each kind, all three kernels (forward
+    and transposed), the projections and the repeat — and no operation of
+    the router, which runs before the block, or of the other kind."""
+    from harness import scope_paths
+
+    assert kind in DEVICE_SCOPES
+    text = _smallthinker_step_text()
+    under = scope_paths.ops_under(text, kind)
+    other = scope_paths.ops_under(text, ({"pt.attn.full", "pt.attn.window"}
+                                         - {kind}).pop())
+    last = scopes.scope_of_ops(text)
+    mine = {last[name] for name, inside in under.items() if inside}
+    assert {"pt.attn", "pt.gqa.qkv", "pt.gqa.repeat", "pt.flash_fwd",
+            "pt.flash_bwd_dq", "pt.flash_bwd_dkv"} <= mine, sorted(mine)
+    assert ("pt.rope" in mine) == (kind == "pt.attn.window")
+    assert not mine & {"pt.moe.route", "pt.moe.experts", "pt.head_loss",
+                       "pt.embed", "pt.dense_opt"}
+    assert not any(under[name] and other[name] for name in under)
+    assert not any(s == kind for s in last.values())    # never the last
 
 
 def test_every_pallas_call_is_named():

@@ -287,6 +287,87 @@ def test_causal_pair_list_kernels_lower_at_the_cells_shapes(
         assert got[:4] == ["s32[4]"] + ["s32[36]"] * 3, (name, got)
 
 
+@pytest.mark.parametrize("window,pairs", [(None, 528), (4096, 252)])
+def test_windowed_pair_list_kernels_lower_at_the_cells_shape(
+        v5e, window, pairs):
+    """``smallthinker_21b_seq16384``'s two attention calls (PR 44) — one
+    16,384-token sequence, 28 heads of 128 — as Mosaic compiles them: the
+    global layer's kernels read the 528 causal pairs of the 32 x 32
+    rectangle from their tables, a windowed layer's the 252 its 4096-key
+    band leaves."""
+    import re
+
+    q = _z(1, 16384, 28, 128)
+
+    def fwd_bwd(q, k, v):
+        loss = lambda q, k, v: (flash_attention(
+            q, k, v, causal=True, window=window, interpret=False) ** 2).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    hlo = _compile(fwd_bwd, SingleDeviceSharding(v5e[0]), q, q, q).as_text()
+    calls = dict(re.findall(
+        r"%(flash_[a-z_]+)[.\d]* = .*?operand_layout_constraints=\{(.*?)\}, \w+=",
+        hlo))
+    assert set(calls) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    for name, operands in calls.items():
+        got = re.findall(r"(\w+\[[\d,]*\])", operands)
+        assert got[:4] == ["s32[4]"] + [f"s32[{pairs}]"] * 3, (name, got)
+
+
+@pytest.mark.parametrize("window", [4096, 5000])
+def test_a_window_that_binds_nowhere_compiles_to_the_causal_program(
+        v5e, window):
+    """A window as long as the sequence is no window (PR 44): the call
+    compiles to the causal call's program, the one every accepted cell's
+    attention compiles to (the fingerprint of
+    ``test_equal_width_flash_compiles_to_the_program_of_pr29``)."""
+    q = _z(2, 4096, 16, 128)
+
+    def fwd_bwd(q, k, v):
+        loss = lambda q, k, v: (flash_attention(
+            q, k, v, causal=True, window=window, interpret=False) ** 2).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = _compile(fwd_bwd, SingleDeviceSharding(v5e[0]), q, q, q).as_text()
+    assert _fingerprint(text) == "81fbae24ce38b455"
+
+
+def test_smallthinker_step_compiles_small(v5e, as_tpu):
+    """The SmallThinker train step for the chip at small widths with the
+    published head shape (14 query heads on 2 key-value heads of 128) and
+    a window that binds: three flash kernels a layer under each mask, the
+    two forms' ``conditional`` in each expert layer — forward, its
+    recomputation and the backward's own — every new scope in the text."""
+    from paddle_tpu import nn, optimizer
+    from paddle_tpu.executor import make_train_step
+    from paddle_tpu.models.smallthinker import (SmallThinker,
+                                                SmallThinkerConfig,
+                                                smallthinker_loss)
+
+    model = SmallThinker(SmallThinkerConfig(
+        vocab_size=1024, hidden_size=256, num_heads=14, num_kv_heads=2,
+        head_dim=128, sliding_window_size=512, num_layers=2,
+        router_width=16, experts_per_token=4, expert_size=768, held=(4, 2),
+        max_seq_len=2048, recompute="experts"))
+    opt = optimizer.AdamW(learning_rate=4e-4, weight_decay=0.1, beta2=0.95)
+    step = make_train_step(model, opt, smallthinker_loss, amp=True)
+    state = nn.get_state(model)
+    ids = (_z(1, 2048, dtype=jnp.int32),)
+    s = SingleDeviceSharding(v5e[0])
+    text = step.lower(
+        _shapes(state, s),
+        _shapes(jax.eval_shape(opt.init, state["params"]), s), _rng_key(s),
+        _shapes(ids, s), _shapes(ids, s)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 2 * 3
+    assert text.count(" conditional(") >= 2 * 2
+    for scope in ("pt.attn.full", "pt.attn.window", "pt.gqa.qkv",
+                  "pt.gqa.repeat", "pt.rope", "pt.moe.route",
+                  "pt.moe.experts"):
+        assert scope in text, scope
+    # the global call's 4 x 4 causal list, the windowed call's band of it
+    assert "s32[10]" in text and "s32[7]" in text
+
+
 def test_joyai_step_compiles_small(v5e, as_tpu):
     """The JoyAI-LLM-Flash train step for the chip at small widths with
     the published head widths (192 / 128): three flash kernels a block
