@@ -28,9 +28,15 @@ trace). A producer that does that rounding itself (``mxu_rounded`` on the
 QKV matmul's output) keeps the moved bytes under its own scope's name.
 Row statistics travel lane-padded, ``f32[B*H, L, 128]``: the
 forward writes lse broadcast over the lanes, the backward reads ONE such
-array with lse in lane 0 and Δ in lane 1. ``q_offset``/``k_offset`` shift
-the causal mask for sequence-sharded (cp) blocks; they may be traced
-values (axis_index).
+array with lse in lane 0 and Δ in lane 1. Inside the forward they STAY
+lane-wide from pair to pair (PERF.md §6, PR 45): the running max is held
+replicated over its 128 lanes and read and written whole, the running sum
+as 128 lane-partial sums (VPU adds of the tile's lane groups) that are
+reduced across lanes once, on a run's last step — a ``[rows, 1]`` statistic
+sliced out of and broadcast back into the lanes is a lane shuffle a vreg,
+and those, not the matmuls, were what a pair waited for.
+``q_offset``/``k_offset`` shift the causal mask for sequence-sharded (cp)
+blocks; they may be traced values (axis_index).
 
 A causal call whose offsets are Python ints walks a LIST of (q block,
 k block) pairs, built with numpy at trace time: the pairs the mask leaves
@@ -193,10 +199,34 @@ def _semantics(grid):
 # ---------------------------------------------------------------------------
 
 
+def _lane_sums(p):
+    """``[rows, 128]`` whose sum across lanes is ``p``'s row sum: ``p``'s
+    128-lane groups added to each other (VPU adds, no cross-lane
+    reduction); where ``p``'s width is no lane multiple (blocks under 128
+    keys: the CPU tests'), the row sum in lane 0."""
+    rows, n = p.shape
+    if n % 128:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1)
+        return jnp.where(lane == 0, jnp.sum(p, axis=-1, keepdims=True), 0.0)
+    return functools.reduce(
+        jnp.add, (p[:, t:t + 128] for t in range(0, n, 128)))
+
+
+def _across(x, n):
+    """A lane-replicated ``[rows, 128]`` statistic beside ``n`` columns:
+    its vregs again for every 128-lane group (no shuffle); one column to
+    broadcast where ``n`` is no lane multiple."""
+    if n % 128:
+        return x[:, :1]
+    return x if n == 128 else jnp.tile(x, (1, n // 128))
+
+
 def _fwd_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed,
                 window=None):
     (i, j, first, last), refs = _grid_step(refs, listed)
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr = refs
+    # m_scr: the running max, replicated over its lanes; l_scr: the running
+    # sum as lane-partial sums — both [bq, 128], read and written whole
 
     @pl.when(first)
     def _():
@@ -225,18 +255,20 @@ def _fwd_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed,
                 mask = mask & (cols > rows - window)
         s = jnp.where(mask, s, NEG)
 
-        m_prev = m_scr[:, :1]                      # [bq, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        m_prev = m_scr[:]                          # [bq, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # a masked key is exp(NEG - m) = 0 beside any key the row has met;
+        # a row that has met none yet (m_new = NEG: a band edge inside the
+        # block) takes its exponent from 0, which gives the same zeros
+        m_exp = jnp.where(m_new > NEG / 2, m_new, 0.0)
+        p = jnp.exp(s - _across(m_exp, bk))
         corr = jnp.exp(m_prev - m_new)             # m_prev=NEG → 0
-        l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc[:] = acc[:] * corr + jax.lax.dot_general(
+        l_scr[:] = l_scr[:] * corr + _lane_sums(p)
+        acc[:] = acc[:] * _across(corr, acc.shape[-1]) + jax.lax.dot_general(
             p.astype(mxu), v_ref[0].astype(mxu),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_scr[:] = m_new
 
     if causal:
         # causal block skip: block fully in the future → nothing to do
@@ -246,10 +278,13 @@ def _fwd_kernel(offs_ref, *refs, scale, causal, bq, bk, mxu, listed,
 
     @pl.when(last)
     def _():
-        l = l_scr[:, :1]
-        o_ref[0] = (acc[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        lse = jnp.where(l > 0, m_scr[:, :1] + jnp.log(jnp.maximum(l, 1e-30)), NEG)
-        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+        # the run's one reduction of the sum across lanes, replicated over
+        # them again: lse leaves as the statistics were kept
+        l = jnp.broadcast_to(jnp.sum(l_scr[:], axis=-1, keepdims=True),
+                             l_scr.shape)
+        safe = jnp.maximum(l, 1e-30)
+        o_ref[0] = (acc[:] / _across(safe, acc.shape[-1])).astype(o_ref.dtype)
+        lse_ref[0] = jnp.where(l > 0, m_scr[:] + jnp.log(safe), NEG)
 
 
 def _fwd(q, k, v, scale, causal, q_offset, k_offset, bq, bk, interpret, mxu,

@@ -727,3 +727,184 @@ def test_a_window_that_would_be_guessed_is_refused(case):
             jax.jit(call)(jnp.int32(0))
         else:
             call(0)
+
+
+# ---------------------------------------------------------------------------
+# the forward's lane-wide row statistics (PR 45): the running max replicated
+# over 128 lanes, the running sum as lane-partial sums reduced once a run —
+# at blocks of whole 128-key lane groups, against the plain softmax and
+# against the same call in blocks under 128 keys, where the row sum is taken
+# across lanes every pair as the step before PR 45 took it
+# ---------------------------------------------------------------------------
+
+
+def _softmax_reference(q, k, v, causal, window=None, q_off=0, k_off=0):
+    """(out, lse) of plain ``jax.numpy`` softmax attention, [B, L, H, ·]
+    and [B, L, H]; v may have a width of its own."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   precision="highest") / np.sqrt(q.shape[-1])
+    if causal:
+        rows = q_off + np.arange(q.shape[1])[:, None]
+        cols = k_off + np.arange(k.shape[1])[None, :]
+        seen = cols <= rows
+        if window is not None:
+            seen &= cols > rows - window
+        s = jnp.where(jnp.asarray(seen), s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(s - lse[..., None]), v,
+                     precision="highest")
+    return out, jnp.moveaxis(lse, 1, 2)
+
+
+# (B, Lq, Lk, H, D, Dv), (block_q, block_k), keywords of the call
+LANE_WIDE = {
+    "causal_listed": ((1, 512, 512, 2, 16, 16), (128, 128),
+                      dict(causal=True)),
+    # rows 355.. of the second q block meet keys 128..255 wholly below
+    # their band first: their max is still NEG when the step ends
+    "window_edge_inside_a_block": ((1, 512, 512, 2, 16, 16), (256, 128),
+                                   dict(causal=True, window=100)),
+    "bidirectional_one_pair_a_step": ((2, 256, 256, 2, 16, 16), (256, 256),
+                                      dict(causal=False)),
+    "v_narrower_than_qk": ((1, 512, 512, 2, 24, 16), (128, 256),
+                           dict(causal=True)),
+    "unaligned_lengths": ((1, 300, 300, 3, 12, 12), (128, 128),
+                          dict(causal=True)),
+    "traced_offsets_rectangle": ((1, 256, 256, 2, 16, 16), (128, 128),
+                                 dict(causal=True, traced=True)),
+    "the_cells_blocks": ((1, 1024, 1024, 1, 8, 8), (512, 512),
+                         dict(causal=True)),
+}
+
+
+def _lane_wide_operands(case):
+    (B, Lq, Lk, H, D, Dv), blocks, kw = LANE_WIDE[case]
+    rng = np.random.default_rng(45)
+    mk = lambda *shape: jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    return (mk(B, Lq, H, D), mk(B, Lk, H, D), mk(B, Lk, H, Dv)), blocks, \
+        dict(kw)
+
+
+def _lane_wide_call(qkv, blocks, kw, precision, with_lse=True):
+    kw = dict(kw, block_q=blocks[0], block_k=blocks[1],
+              interpret=NAN_FILLED, precision=precision)
+    fn = flash_attention_with_lse if with_lse else flash_attention
+    if kw.pop("traced", False):
+        return jax.jit(lambda o: fn(*qkv, q_offset=o, k_offset=o, **kw))(
+            jnp.int32(0))
+    return fn(*qkv, **kw)
+
+
+@pytest.mark.parametrize("precision", ["highest", "default"])
+@pytest.mark.parametrize("case", sorted(LANE_WIDE))
+def test_lane_wide_statistics_match_the_plain_softmax(case, precision):
+    """out and lse, every scratch and window NaN until the kernel writes
+    it: float32 operands under "highest" to 2e-6 of the plain softmax AND
+    of the same call in 64-key blocks (the row sum across lanes every
+    pair); at the default precision to the file's bf16 limits of the
+    reference on the operands rounded to bf16."""
+    qkv, blocks, kw = _lane_wide_operands(case)
+    out, lse = _lane_wide_call(qkv, blocks, kw, precision)
+    assert lse.shape == qkv[0].shape[:3] and lse.dtype == jnp.float32
+    ref_kw = {k: v for k, v in kw.items() if k != "traced"}
+    if precision == "highest":
+        want = _softmax_reference(*qkv, **ref_kw)
+        narrow = _lane_wide_call(qkv, (blocks[0], 64), kw, precision)
+        for got, a, b, name in zip((out, lse), want, narrow, ("out", "lse")):
+            assert np.isfinite(np.asarray(got)).all(), name
+            np.testing.assert_allclose(np.asarray(got), np.asarray(a),
+                                       rtol=0, atol=2e-6, err_msg=name)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(b),
+                                       rtol=0, atol=2e-6,
+                                       err_msg=name + " in 64-key blocks")
+    else:
+        rounded = [x.astype(jnp.bfloat16).astype(jnp.float32) for x in qkv]
+        want = _softmax_reference(*rounded, **ref_kw)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want[0]),
+                                   rtol=2e-2, atol=2e-3, err_msg="out")
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(want[1]),
+                                   rtol=2e-2, atol=2e-3, err_msg="lse")
+
+
+def test_lane_wide_forward_alone_outside_a_gradient():
+    """``flash_attention`` with no gradient round it (the ``routing``
+    program's path) gives the out of the pair's forward, bit for bit."""
+    qkv, blocks, kw = _lane_wide_operands("window_edge_inside_a_block")
+    alone = _lane_wide_call(qkv, blocks, kw, "default", with_lse=False)
+    out, _ = _lane_wide_call(qkv, blocks, kw, "default")
+    assert np.isfinite(np.asarray(alone)).all()
+    np.testing.assert_array_equal(np.asarray(alone), np.asarray(out))
+
+
+def test_lane_wide_rows_that_meet_no_key_stay_empty():
+    """A q block before the first key (the rectangle: no pair would name
+    it): its rows never leave ``m = NEG``, every exponent is taken from 0,
+    and the last step writes zeros and ``lse = NEG`` — at 128-key blocks,
+    under NaN-filled memory."""
+    rng = np.random.default_rng(46)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 256, 2, 16)).astype(
+        np.float32)) for _ in range(3))
+    out, lse = flash_attention_with_lse(
+        q, k, v, causal=True, q_offset=0, k_offset=128, block_q=128,
+        block_k=128, interpret=NAN_FILLED, precision="highest")
+    assert (np.asarray(out[:, :128]) == 0).all()
+    assert (np.asarray(lse[:, :128]) == fa.NEG).all()
+    want, want_lse = _softmax_reference(q[:, 128:], k[:, :128], v[:, :128],
+                                        True)
+    np.testing.assert_allclose(np.asarray(out[:, 128:]), np.asarray(want),
+                               rtol=0, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(lse[:, 128:]),
+                               np.asarray(want_lse), rtol=0, atol=2e-6)
+
+
+@pytest.mark.parametrize("case", ["causal_listed",
+                                  "window_edge_inside_a_block",
+                                  "v_narrower_than_qk"])
+def test_grads_through_the_lane_wide_forward_match_reference(case):
+    """The backward kernels read the forward's lse: dq, dk, dv of a loss
+    on out AND lse against the plain softmax's, at
+    ``test_grads_match_reference``'s limits."""
+    qkv, blocks, kw = _lane_wide_operands(case)
+    w = jnp.cos(jnp.arange(qkv[0].shape[1] * qkv[0].shape[2],
+                           dtype=jnp.float32)).reshape(
+        1, qkv[0].shape[1], qkv[0].shape[2])
+
+    def loss(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v)
+            return jnp.sum(out ** 2) + jnp.sum(lse * w)
+        return f
+
+    kernels = lambda *a: _lane_wide_call(a, blocks, kw, "highest")
+    plain = lambda *a: _softmax_reference(*a, **kw)
+    for a, b, name in zip(jax.grad(loss(kernels), argnums=(0, 1, 2))(*qkv),
+                          jax.grad(loss(plain), argnums=(0, 1, 2))(*qkv),
+                          ("dq", "dk", "dv")):
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-3, atol=2e-4, err_msg=name)
+
+
+def test_lane_sums_and_across_keep_a_rows_sum_and_a_rows_statistic():
+    """``_lane_sums``: 128 lane-partial sums whose sum is the row's, the
+    lane groups added in order; the row sum in lane 0 where the width is
+    no lane multiple. ``_across``: the same 128 lanes beside every lane
+    group; one column where the width is no lane multiple."""
+    rng = np.random.default_rng(47)
+    p = jnp.asarray(rng.uniform(size=(8, 384)).astype(np.float32))
+    part = fa._lane_sums(p)
+    assert part.shape == (8, 128)
+    np.testing.assert_array_equal(
+        np.asarray(part), np.asarray((p[:, :128] + p[:, 128:256])
+                                     + p[:, 256:]))
+    narrow = fa._lane_sums(p[:, :24])
+    assert narrow.shape == (8, 128) and not np.asarray(narrow[:, 1:]).any()
+    np.testing.assert_array_equal(np.asarray(narrow[:, 0]),
+                                  np.asarray(jnp.sum(p[:, :24], axis=-1)))
+    stat = jnp.broadcast_to(p[:, :1], (8, 128))
+    assert fa._across(stat, 128) is stat
+    assert fa._across(stat, 512).shape == (8, 512)
+    np.testing.assert_array_equal(np.asarray(fa._across(stat, 512)),
+                                  np.asarray(jnp.broadcast_to(p[:, :1],
+                                                              (8, 512))))
+    assert fa._across(stat, 24).shape == (8, 1)
