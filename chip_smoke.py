@@ -17,8 +17,10 @@ exit code is non-zero on any. With every ``auto`` switch left at ``auto``:
 - **dense**  — ERNIE-1.0 base (vocab 18000, hidden 768, 12 heads, ffn 3072,
   12 layers, seq 512, batch 16) through ``Trainer(amp=True)``, three
   steps; the Pallas flash kernel must be compiled (not interpreted) and
-  agree with ``local_attention`` at that head shape, and once more under
-  a sliding window at 28 query / 4 key-value heads of 128.
+  agree with ``local_attention`` at that head shape, once more under
+  a sliding window at 28 query / 4 key-value heads of 128, and under
+  EvaByte's stated mask (aligned windows beside chunk summaries) at 32
+  heads of 128.
 - **four**   — with ≥ 4 devices: the key-routed sharded CTR step on
   ``{"ps": 4}`` at the same widths against the one-device step, then the
   hybrid ERNIE step of ``__graft_entry__`` on the four real devices.
@@ -77,6 +79,13 @@ class Sizes:
     window_heads: Tuple[int, int, int] = (28, 4, 128)
     window_seq: int = 2048
     window: int = 512
+    # one call under EvaByte's mask at its head shape: 32 heads of 128,
+    # aligned windows of 2048 keys in chunks of 16 over 4096 positions
+    # (8 x 9 blocks of 512: 2 x 10 local pairs + 4 on the summaries)
+    eva_heads: Tuple[int, int] = (32, 128)
+    eva_seq: int = 4096
+    eva_window: int = 2048
+    eva_chunk: int = 16
 
 
 def make_ctr_dataset(sz: Sizes, n_batches: int, seed: int):
@@ -433,12 +442,46 @@ def leg_dense(sz: Sizes) -> Dict:
     win_fast = _max_diff(fast, ref, relative=True)
     assert win_exact <= 5e-4, f"windowed flash(highest) vs einsum: {win_exact}"
     assert win_fast <= 3e-2, f"windowed flash(default) vs einsum: {win_fast}"
+
+    # and under a STATED mask (``ops/eva.py``): the row's own aligned
+    # window beside the chunk summaries of the windows before it, one
+    # softmax — the pair list and the kernels' tile from the one statement,
+    # through the pooling to φ and μ. The bounds are the bidirectional
+    # call's.
+    from paddle_tpu.ops import eva
+
+    He, De = sz.eva_heads
+    qe, ke, ve = (jnp.asarray(rng.normal(size=(1, sz.eva_seq, He, De)),
+                              jnp.float32) for _ in range(3))
+    phi, mu = (jnp.asarray(rng.normal(size=(He, De)), jnp.float32)
+               for _ in range(2))
+
+    def eva_fwd_bwd(attn):
+        def f(*a):
+            out = attn(*a, sz.eva_window, sz.eva_chunk)
+            return jnp.sum(out ** 2), out
+
+        return jax.jit(lambda *a: jax.value_and_grad(
+            f, argnums=(0, 1, 2, 3, 4), has_aux=True)(*a))
+
+    operands = (qe, ke, ve, phi, mu)
+    with jax.default_matmul_precision("highest"):
+        ref = eva_fwd_bwd(eva.eva_attention_einsum)(*operands)
+        exact = eva_fwd_bwd(lambda *a: eva.eva_attention(
+            *a, precision="highest"))(*operands)
+    fast = eva_fwd_bwd(eva.eva_attention)(*operands)
+    eva_exact = _max_diff(exact, ref, relative=True)
+    eva_fast = _max_diff(fast, ref, relative=True)
+    assert eva_exact <= 5e-4, f"EVA flash(highest) vs einsum: {eva_exact}"
+    assert eva_fast <= 3e-2, f"EVA flash(default) vs einsum: {eva_fast}"
     return {"loss": [round(l, 4) for l in losses],
             "attn_impl": "flash" if on_tpu else "einsum",
             "mosaic_calls": mosaic_calls,
             "flash_rel_err": {"highest": err_exact, "default": err_fast,
                               "window_highest": win_exact,
-                              "window_default": win_fast}}
+                              "window_default": win_fast,
+                              "eva_highest": eva_exact,
+                              "eva_default": eva_fast}}
 
 
 # ---------------------------------------------------------------------------

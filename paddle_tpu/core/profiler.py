@@ -71,6 +71,7 @@ DEVICE_SCOPES = (
     "pt.conv", "pt.conv.in", "pt.conv.mix", "pt.conv.out",
     "pt.gqa.qkv", "pt.gqa.repeat",
     "pt.attn.full", "pt.attn.window",
+    "pt.eva.qkv", "pt.eva.prep",
 )
 
 #: completed spans kept in memory (newest win): a pass is about a dozen

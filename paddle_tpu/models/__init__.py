@@ -5,6 +5,7 @@ from .joyai import Joyai, JoyaiConfig, joyai_loss
 from .lfm2 import Lfm2, Lfm2Config, lfm2_loss
 from .smallthinker import (SmallThinker, SmallThinkerConfig,
                            smallthinker_loss)
+from .evabyte import EvaByte, EvaByteConfig, evabyte_loss
 from .ctr import (CtrConfig, DCN, DeepFM, WideDeep, XDeepFM,
                   make_ctr_train_step)
 from .din import DIN, make_ctr_attention_train_step
@@ -30,6 +31,7 @@ __all__ = ["LeNet", "Ernie", "ErnieConfig", "Olmoe", "OlmoeConfig",
            "Joyai", "JoyaiConfig", "joyai_loss",
            "Lfm2", "Lfm2Config", "lfm2_loss",
            "SmallThinker", "SmallThinkerConfig", "smallthinker_loss",
+           "EvaByte", "EvaByteConfig", "evabyte_loss",
            "CtrConfig", "DeepFM", "WideDeep", "make_ctr_train_step",
            "DCN", "XDeepFM", "DIN", "DSSM", "ESMM", "MMoE",
            "DeepWalkConfig", "make_deepwalk_train_step",

@@ -154,15 +154,23 @@ class LayerNorm(Layer):
 
 class RMSNorm(Layer):
     """``x * rsqrt(mean(x^2) + epsilon) * weight`` over the last axis, the
-    statistics in float32 whatever ``x`` is."""
+    statistics in float32 whatever ``x`` is. With ``unit_offset`` the scale
+    is ``1 + weight`` and the weight starts at 0 (EvaByte's
+    ``norm_add_unit_offset``): weight decay then pulls the scale to 1, not
+    to 0."""
 
-    def __init__(self, size: int, epsilon: float = 1e-5) -> None:
+    def __init__(self, size: int, epsilon: float = 1e-5,
+                 unit_offset: bool = False) -> None:
         super().__init__()
         self.epsilon = epsilon
+        self.unit_offset = unit_offset
+        start = np.zeros if unit_offset else np.ones
         self.create_parameter("weight", (size,),
-                              init_value=np.ones((size,), np.float32))
+                              init_value=start((size,), np.float32))
 
     def forward(self, x: jax.Array) -> jax.Array:
+        if self.unit_offset:
+            return F.rms_norm(x, 1.0 + self.weight, self.epsilon)
         return F.rms_norm(x, self.weight, self.epsilon)
 
 

@@ -19,7 +19,8 @@ TINY = chip_smoke.Sizes(
     capacity=1 << 12, ids_per_slot=200, pass_batches=8, slab=4,
     stream_batches=4, vocab=64, hidden=32, heads=2, ffn=64, layers=2,
     seq=16, ernie_batch=2, ernie_steps=3, window_heads=(4, 2, 8),
-    window_seq=48, window=16)
+    window_seq=48, window=16, eva_heads=(2, 8), eva_seq=64, eva_window=16,
+    eva_chunk=4)
 
 
 def test_script_refuses_a_cpu_platform_by_name():
@@ -54,8 +55,9 @@ def test_leg_stream_tiny():
 def test_leg_dense_tiny():
     facts = chip_smoke.leg_dense(TINY)
     assert facts["attn_impl"] == "einsum" and facts["mosaic_calls"] == 0
-    assert set(facts["flash_rel_err"]) == {"highest", "default",
-                                           "window_highest", "window_default"}
+    assert set(facts["flash_rel_err"]) == {
+        "highest", "default", "window_highest", "window_default",
+        "eva_highest", "eva_default"}
 
 
 def test_leg_four_tiny():

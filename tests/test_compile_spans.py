@@ -438,12 +438,16 @@ def test_benchmark_lists_the_new_metrics_in_every_cell():
     walked = by["flash_pairs_walked_share"]              # PR 41
     assert walked["workloads"] == [
         "ernie_base_seq512", "olmoe_1b7b_seq4096", "joyai_flash_seq4096",
-        "lfm2_8b_a1b_seq4096", "smallthinker_21b_seq16384"]
+        "lfm2_8b_a1b_seq4096", "smallthinker_21b_seq16384",
+        "evabyte_6b5_seq8192"]                           # PR 46: appended
     assert (walked["layer"], walked["moves"], walked["source"]) == (
         "kernels", "tokens_per_s_per_chip", "program_counter")
-    # PR 44's seven come after them: nothing was put in the middle
-    assert [m["name"] for m in bench["per_layer"][-15:-7]] == list(
+    # PR 44's seven come after them, PR 46's six after those: nothing was
+    # put in the middle
+    assert [m["name"] for m in bench["per_layer"][-21:-13]] == list(
         SPAN_READERS) + ["setup_devices_s", "moe_rows_walked_share",
                          "flash_pairs_walked_share"]
     assert all(m["workloads"] == ["smallthinker_21b_seq16384"]
-               for m in bench["per_layer"][-7:])
+               for m in bench["per_layer"][-13:-6])
+    assert all(m["workloads"] == ["evabyte_6b5_seq8192"]
+               for m in bench["per_layer"][-6:])

@@ -326,7 +326,8 @@ def test_operand_span_is_recorded_once_a_compile(precision, bits, causal,
         jax.block_until_ready(step(q, k, v))
     assert spans()[before:] == [{"bits": bits, "head_dim": 8, "lanes": 128,
                                  "v_head_dim": 8, "pairs_walked": walked,
-                                 "pairs_rectangle": 4, "window": 0}]
+                                 "pairs_rectangle": 4, "window": 0,
+                                 "summary_keys": 0}]
 
 
 # ---------------------------------------------------------------------------
@@ -415,7 +416,8 @@ def test_pair_list_equals_the_rectangle_bit_for_bit(monkeypatch, case):
            for walk in _WALKS}
     monkeypatch.setattr(fa, "_causal_pairs", _PAIRS)
     nq, nk = -(-Lq // bq), -(-Lk // bk)
-    keep = _PAIRS(nq, nk, min(bq, Lq), min(bk, Lk), True, (q_off, k_off))
+    keep = _PAIRS(nq, nk, min(bq, Lq), min(bk, Lk), fa.CAUSAL,
+                  (q_off, k_off))
     assert (int(keep.sum()), keep.size) == walked
     for name, want in got["the parent's grid"].items():
         assert np.isfinite(np.asarray(want)).all(), name
@@ -460,7 +462,7 @@ def test_pair_tables_name_every_block_in_contiguous_runs(
     ``pl.when``; every q block and every k block is named; a run (one q
     block, or with ``k_major`` one k block) is contiguous and ascending,
     flagged first once and last once."""
-    keep = fa._causal_pairs(nq, nk, bq, bk, True, offsets)
+    keep = fa._causal_pairs(nq, nk, bq, bk, fa.CAUSAL, offsets)
     q_off, k_off = offsets
     want = {(i, j) for i in range(nq) for j in range(nk)
             if q_off + (i + 1) * bq - 1 >= k_off + j * bk}
@@ -482,10 +484,10 @@ def test_pair_tables_name_every_block_in_contiguous_runs(
 
 
 def test_the_cells_calls_walk_36_of_64_pairs():
-    keep = fa._causal_pairs(8, 8, 512, 512, True, (0, 0))
+    keep = fa._causal_pairs(8, 8, 512, 512, fa.CAUSAL, (0, 0))
     assert int(keep.sum()) == 36 and keep.size == 64
-    assert fa._causal_pairs(8, 8, 512, 512, False, (0, 0)) is None
-    assert fa._causal_pairs(8, 8, 512, 512, True, None) is None
+    assert fa._causal_pairs(8, 8, 512, 512, None, (0, 0)) is None
+    assert fa._causal_pairs(8, 8, 512, 512, fa.CAUSAL, None) is None
     assert fa._static_offsets(0, 0) == (0, 0)
     assert fa._static_offsets(np.int32(3), 0) == (3, 0)
     assert fa._static_offsets(jnp.int32(0), 0) is None      # data
@@ -503,7 +505,7 @@ def test_a_call_with_an_unnamed_block_takes_the_rectangle(
     NaN-filled memory too."""
     B, Lq, Lk, H, D = shape
     q_off, k_off = offsets
-    assert fa._causal_pairs(Lq // 16, Lk // 16, 16, 16, True, offsets) is None
+    assert fa._causal_pairs(Lq // 16, Lk // 16, 16, 16, fa.CAUSAL, offsets) is None
     rng = np.random.default_rng(22)
     q = jnp.asarray(rng.normal(size=(B, Lq, H, D)).astype(np.float32))
     k, v = (jnp.asarray(rng.normal(size=(B, Lk, H, D)).astype(np.float32))
@@ -648,7 +650,8 @@ def test_window_counts_positions_from_the_offsets(window, offsets, Lk):
     q = jnp.asarray(rng.normal(size=(1, 16, 2, 8)).astype(np.float32))
     k, v = (jnp.asarray(rng.normal(size=(1, Lk, 2, 8)).astype(np.float32))
             for _ in range(2))
-    listed = fa._causal_pairs(1, Lk // 16, 16, 16, True, offsets, window)
+    listed = fa._causal_pairs(1, Lk // 16, 16, 16, fa.Mask(window=window),
+                               offsets)
     assert (listed is None) == (offsets == (48, 0))
 
     def loss(f):
@@ -670,17 +673,18 @@ def test_the_windowed_cells_calls_walk_252_of_1024_pairs():
     32 rectangle; a 4096-key window leaves i + 1 pairs in each of the
     first 8 block rows and 9 in each of the other 24 (the ninth is the
     block the band's lower edge crosses)."""
-    causal = fa._causal_pairs(32, 32, 512, 512, True, (0, 0))
-    banded = fa._causal_pairs(32, 32, 512, 512, True, (0, 0), 4096)
+    causal = fa._causal_pairs(32, 32, 512, 512, fa.CAUSAL, (0, 0))
+    banded = fa._causal_pairs(32, 32, 512, 512, fa.Mask(window=4096),
+                              (0, 0))
     assert int(causal.sum()) == 528
     assert int(banded.sum()) == 252 == sum(range(1, 9)) + 24 * 9
     assert not (banded & ~causal).any()
     assert banded.sum(axis=1).tolist() == list(range(1, 9)) + [9] * 24
     # a window of one more key reaches a tenth block from a block's first row
-    assert int(fa._causal_pairs(32, 32, 512, 512, True, (0, 0),
-                                4097).sum()) == 252
-    assert int(fa._causal_pairs(32, 32, 512, 512, True, (0, 0),
-                                4098).sum()) == 252 + 23
+    assert int(fa._causal_pairs(32, 32, 512, 512, fa.Mask(window=4097),
+                                (0, 0)).sum()) == 252
+    assert int(fa._causal_pairs(32, 32, 512, 512, fa.Mask(window=4098),
+                                (0, 0)).sum()) == 252 + 23
     for k_major in (False, True):
         qi, kj, ends = fa._pair_tables(banded, k_major)
         assert len(qi) == 252 and int((np.asarray(ends) & 1).sum()) == 32
@@ -908,3 +912,244 @@ def test_lane_sums_and_across_keep_a_rows_sum_and_a_rows_statistic():
                                   np.asarray(jnp.broadcast_to(p[:, :1],
                                                               (8, 512))))
     assert fa._across(stat, 24).shape == (8, 1)
+
+
+# ---------------------------------------------------------------------------
+# a stated mask (PR 46): runs of key columns, each under a rule of its own —
+# the pair list and the kernels' tile made from the ONE statement — against
+# scores written out under a mask written out
+# ---------------------------------------------------------------------------
+
+
+def _pooled(k, v, phi, mu, chunk):
+    """Chunk summaries, written out: softmax over the chunk of k·φ/√d."""
+    B, L, H, d = k.shape
+    kc, vc = (x.reshape(B, L // chunk, chunk, H, d) for x in (k, v))
+    w = jax.nn.softmax(jnp.einsum("bmchd,hd->bmch", kc, phi) / np.sqrt(d),
+                       axis=2)
+    return (jnp.einsum("bmch,bmchd->bmhd", w, kc) + mu,
+            jnp.einsum("bmch,bmchd->bmhd", w, vc))
+
+
+def _eva_plain(q, k, v, phi, mu, window, chunk):
+    """EVA attention as one dense softmax over [every summary ‖ every key]
+    under the mask in words: summary m iff its chunk lies in an earlier
+    window than the row's, key j iff in the row's window and j <= i."""
+    L, d = q.shape[1], q.shape[-1]
+    ks, vs = _pooled(k, v, phi, mu, chunk)
+    i = np.arange(L)[:, None]
+    seen = np.concatenate(
+        [(np.arange(L // chunk)[None] * chunk) // window < i // window,
+         (np.arange(L)[None] // window == i // window)
+         & (np.arange(L)[None] <= i)], axis=1)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q,
+                   jnp.concatenate([ks, k], axis=1)) / np.sqrt(d)
+    p = jax.nn.softmax(jnp.where(seen[None, None], s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, jnp.concatenate([vs, v], axis=1))
+
+
+_EVA_CASES = {}
+_EVA_NAMES = ("out", "dq", "dk", "dv", "dphi", "dmu")
+
+
+def _eva_case(block):
+    """{name: (kernels', plain)} on 64 positions, windows of 16, chunks of
+    4 — the first window has no summary, a window's last chunk ends on its
+    edge — in ``block``-blocks (8: two q blocks a window and a padded,
+    half-empty second summary block; 16: one), NaN-filled memory."""
+    from paddle_tpu.ops import eva
+
+    if block not in _EVA_CASES:
+        rng = np.random.default_rng(51)
+        q, k, v, w = (jnp.asarray(rng.normal(size=(1, 64, 2, 8)).astype(
+            np.float32)) for _ in range(4))
+        phi, mu = (jnp.asarray(rng.normal(size=(2, 8)).astype(np.float32))
+                   for _ in range(2))
+        kw = dict(interpret=NAN_FILLED, precision="highest")
+
+        def kernels(q, k, v, phi, mu):
+            if block == 16:         # ``eva_attention``'s own: a window's
+                return eva.eva_attention(q, k, v, phi, mu, 16, 4, **kw)
+            keys, values = eva._with_summaries(k, v, phi, mu, 16, 4,
+                                               8 ** -0.5)
+            return flash_attention(q, keys, values,
+                                   mask=eva.eva_mask(64, 16, 4),
+                                   block_q=block, block_k=block, **kw)
+
+        plain = lambda *a: _eva_plain(*a, 16, 4)
+        got, want = [
+            (f(q, k, v, phi, mu),) + jax.grad(
+                lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2, 3, 4))(
+                    q, k, v, phi, mu) for f in (kernels, plain)]
+        _EVA_CASES[block] = dict(zip(_EVA_NAMES, zip(got, want)))
+    return _EVA_CASES[block]
+
+
+@pytest.mark.parametrize("block", [8, 16])
+@pytest.mark.parametrize("what", _EVA_NAMES)
+def test_eva_mask_matches_the_plain_softmax(block, what):
+    """The forward and the three cotangents — and what flows on through
+    the pooling to φ and μ — under the two-run mask, one running softmax
+    over summaries and keys."""
+    got, want = _eva_case(block)[what]
+    assert np.isfinite(np.asarray(got)).all()       # every block written
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=3e-6)
+
+
+def test_eva_pair_list_walks_what_the_mask_leaves():
+    """8192 positions in 512-blocks, windows of 2048, chunks of 16: one
+    block of 384 summaries and 16 of keys. A window's own lower triangle
+    (4 x 10 pairs) and, for the q blocks of windows 1..3, the summary
+    block (12): 52 of the 16 x 17 rectangle, no q block of the first
+    window in the summaries' run."""
+    from paddle_tpu.ops import eva
+
+    mask = eva.eva_mask(8192, 2048, 16)
+    assert mask == fa.Mask((fa.Keys(count=384, stride=16, earlier=True),
+                            fa.Keys(count=8192)), aligned=2048)
+    keep = fa._causal_pairs(16, 17, 512, 512, mask, (0, 0))
+    assert int(keep.sum()) == 52 and keep.size == 272
+    assert keep[:, 0].tolist() == [False] * 4 + [True] * 12
+    local = keep[:, 1:]
+    assert int(local.sum()) == 40
+    for i in range(16):
+        assert local[i].nonzero()[0].tolist() == list(
+            range(i - i % 4, i + 1))
+    qi, kj, ends = (np.asarray(t) for t in fa._pair_tables(keep, True))
+    assert kj[:12].tolist() == [0] * 12 and qi[:12].tolist() == list(
+        range(4, 16))                       # the summaries' run, k-major
+    # the body's own mask on the same blocks: the one statement
+    for i, j in ((0, 0), (3, 0), (4, 0), (5, 3), (5, 5), (5, 6), (8, 5)):
+        assert bool(fa._reached(mask, (0, 0, 0), i, j, 512, 512,
+                                np.where).some) == bool(keep[i, j])
+    assert eva.eva_mask(2048, 2048, 16) == fa.Mask((fa.Keys(count=2048),),
+                                                   aligned=2048)
+
+
+@pytest.mark.parametrize("mask,reference", [
+    (fa.Mask(aligned=16), "aligned"),
+    (fa.Mask(window=5), "band"), (fa.CAUSAL, "causal")])
+def test_a_stated_mask_of_one_run_matches_the_einsum(mask, reference):
+    """``mask=`` with the keys whole: aligned windows alone (a
+    block-diagonal causal mask), and the two masks ``causal`` and
+    ``window`` state — which trace to the program those keywords give."""
+    rng = np.random.default_rng(52)
+    q, k, v = _qkv(rng, B=1, L=64, H=2, D=8)
+    kw = dict(block_q=16, block_k=16, interpret=NAN_FILLED,
+              precision="highest")
+    stated = lambda q, k, v: flash_attention(q, k, v, mask=mask, **kw)
+    if reference == "aligned":
+        i = np.arange(64)
+        seen = (i[None] // 16 == i[:, None] // 16) & (i[None] <= i[:, None])
+
+        def want(q, k, v):
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(8)
+            p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+            return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+        assert int(fa._causal_pairs(4, 4, 16, 16, mask, (0, 0)).sum()) == 4
+    else:
+        keyword = dict(causal=True, window=5 if reference == "band" else None)
+        want = lambda q, k, v: flash_attention(q, k, v, **keyword, **kw)
+        assert str(jax.make_jaxpr(stated)(q, k, v)) == str(
+            jax.make_jaxpr(want)(q, k, v))
+    loss = lambda f: lambda *a: jnp.sum(f(*a) ** 2)
+    np.testing.assert_allclose(np.asarray(stated(q, k, v)),
+                               np.asarray(want(q, k, v)), atol=3e-6)
+    for a, b in zip(jax.grad(loss(stated), argnums=(0, 1, 2))(q, k, v),
+                    jax.grad(loss(want), argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+
+
+@pytest.mark.parametrize("case,kw,match", [
+    ("traced offsets", dict(mask=fa.CAUSAL, traced=True), "Python ints"),
+    ("causal beside a mask", dict(mask=fa.CAUSAL, causal=True),
+     "whole statement"),
+    ("a window beside a mask", dict(mask=fa.CAUSAL, window=4),
+     "whole statement"),
+    ("runs that do not cover the keys",
+     dict(mask=fa.Mask((fa.Keys(count=8, stride=4, earlier=True),
+                        fa.Keys(count=32)), aligned=16)), "cover the keys"),
+    ("runs at an offset",
+     dict(mask=fa.Mask((fa.Keys(count=16), fa.Keys(count=48)), aligned=16),
+          q_offset=16), "count positions from 0"),
+    ("a q block across two windows", dict(mask=fa.Mask(aligned=24)),
+     "share one window"),
+    ("a stride of nought",
+     dict(mask=fa.Mask((fa.Keys(count=64, stride=0),))), "at least 1"),
+    ("earlier without aligned",
+     dict(mask=fa.Mask((fa.Keys(earlier=True),))), "needs `aligned`"),
+    ("a tuple of runs", dict(mask=(fa.Keys(),)), "flash_attention.Mask"),
+    ("no Keys", dict(mask=fa.Mask((True,))), "flash_attention.Keys")])
+def test_what_a_stated_mask_does_not_accept_is_refused(case, kw, match):
+    from paddle_tpu.core.enforce import EnforceNotMet
+
+    rng = np.random.default_rng(53)
+    q, k, v = _qkv(rng, B=1, L=64, H=1, D=8)
+    kw = dict(kw, block_q=16, block_k=16, interpret=True)
+    with pytest.raises(EnforceNotMet, match=match):
+        if kw.pop("traced", False):
+            jax.jit(lambda o: flash_attention(q, k, v, q_offset=o, **kw))(
+                jnp.int32(0))
+        else:
+            flash_attention(q, k, v, **kw)
+
+
+#: sha256[:16] of ``str(jax.make_jaxpr(...))`` of value_and_grad of each
+#: call — the kernels' bodies, grids and tables with it — taken from the
+#: PARENT of PR 46 (``git archive``) and from this tree, and equal: the
+#: stated mask changed no program that existed
+_JAXPR_OF_THE_PARENT = {
+    "causal": (dict(causal=True), (2, 4096, 16, 128), 128,
+               "ea21123abd593017"),
+    "causal_small_blocks": (dict(causal=True, block_q=16, block_k=16),
+                            (1, 64, 2, 8), 8, "4773fcc73deb4fbd"),
+    "window": (dict(causal=True, window=4096), (1, 16384, 28, 128), 128,
+               "2ece8861c33d8eae"),
+    "window_small": (dict(causal=True, window=17, block_q=16, block_k=16),
+                     (1, 64, 2, 8), 8, "9fbe2f655a179b4d"),
+    "window_offsets": (dict(causal=True, window=24, q_offset=32, k_offset=16,
+                            block_q=16, block_k=16), (1, 32, 2, 8), 8,
+                       "bed7eb8e5766d8e8"),
+    "bidirectional": (dict(causal=False), (32, 512, 12, 64), 64,
+                      "72f5f71327e81263"),
+    "latent": (dict(causal=True), (1, 4096, 32, 192), 128,
+               "53b13e271d6660eb"),
+    "rectangle_for_an_unnamed_block": (
+        dict(causal=True, q_offset=0, k_offset=32, block_q=16, block_k=16),
+        (1, 64, 2, 8), 8, "f11a88806ffe5ccc"),
+    "highest": (dict(causal=True, precision="highest"), (1, 1024, 4, 64), 64,
+                "3533d1af433cbfeb"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_JAXPR_OF_THE_PARENT))
+def test_existing_calls_trace_to_the_program_they_had(case):
+    import hashlib
+
+    kw, shape, dv, want = _JAXPR_OF_THE_PARENT[case]
+    q, v = jnp.zeros(shape, jnp.float32), jnp.zeros(shape[:3] + (dv,),
+                                                    jnp.float32)
+    fwd_bwd = lambda q, k, v: jax.value_and_grad(
+        lambda q, k, v: (flash_attention(q, k, v, interpret=False, **kw)
+                         ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+    text = str(jax.make_jaxpr(fwd_bwd)(q, q, v))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+
+
+def test_the_cp_rings_call_traces_to_the_program_it_had():
+    """Traced offsets and an lse cotangent (``parallel/ring_attention``'s
+    call): the rectangle, as in the parent of PR 46."""
+    import hashlib
+
+    z = jnp.zeros((1, 256, 2, 16), jnp.float32)
+
+    def g(q, k, v, o):
+        out, lse = flash_attention_with_lse(q, k, v, causal=True, q_offset=o,
+                                            k_offset=0, interpret=False)
+        return (out ** 2).sum() + lse.sum()
+
+    text = str(jax.make_jaxpr(jax.grad(g, argnums=(0, 1, 2)))(
+        z, z, z, jnp.int32(128)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == "a1b6e13c0b9151f0"

@@ -202,6 +202,33 @@ def _smallthinker_step_text():
     return text
 
 
+def _evabyte_step_text():
+    from paddle_tpu.executor import Trainer
+    from paddle_tpu.models.evabyte import (EvaByte, EvaByteConfig,
+                                           evabyte_loss)
+
+    model = EvaByte(EvaByteConfig(
+        vocab_size=320, hidden_size=64, num_heads=4, intermediate_size=160,
+        num_layers=2, window_size=32, chunk_size=4, num_pred_heads=2,
+        max_seq_len=128, attn_impl="flash", recompute="blocks"))
+    trainer = Trainer(model, optimizer.AdamW(1e-3, weight_decay=0.1),
+                      evabyte_loss, amp=True)
+    ids = np.zeros((2, 128), np.int32)
+    profiler.start_timeline()
+    text = trainer.compiled_text(ids, ids)
+    spans = [s.counts for s in profiler.host_spans()
+             if s.name == "pt.eva.layers"]
+    assert spans == [{"layers": 2, "window": 32, "chunk": 4,
+                      "summaries": 24, "pred_heads": 2}]     # once a trace
+    # one flash call a layer (its recomputation is the same call's trace):
+    # 4 windows of one 32-block each and the 24 summaries in a block of
+    # their own — 4 local pairs + 3 of summaries, of the 4 x 5 rectangle
+    assert {(s.counts["summary_keys"], s.counts["pairs_walked"],
+             s.counts["pairs_rectangle"]) for s in profiler.host_spans()
+            if s.name == "pt.flash.operands"} == {(24, 7, 20)}
+    return text
+
+
 _PUSH = {"pt.push.accumulate", "pt.push.update"}
 STEPS = {
     "pass_slab": (_pass_step_text, {"pt.unpack", "pt.probe", "pt.pull",
@@ -239,6 +266,10 @@ STEPS = {
         "pt.embed", "pt.attn", "pt.gqa.qkv", "pt.rope", "pt.gqa.repeat",
         "pt.ffn", "pt.moe.route", "pt.moe.dispatch", "pt.moe.experts",
         "pt.moe.combine", "pt.head_loss", "pt.loss", "pt.dense_opt",
+        "pt.flash_fwd", "pt.flash_bwd_dq", "pt.flash_bwd_dkv"}),
+    "evabyte": (_evabyte_step_text, {
+        "pt.embed", "pt.attn", "pt.eva.qkv", "pt.rope", "pt.eva.prep",
+        "pt.ffn.dense", "pt.head_loss", "pt.loss", "pt.dense_opt",
         "pt.flash_fwd", "pt.flash_bwd_dq", "pt.flash_bwd_dkv"}),
 }
 # what computes nothing (and what XLA inserts without metadata), and the

@@ -332,6 +332,94 @@ def test_a_window_that_binds_nowhere_compiles_to_the_causal_program(
     assert _fingerprint(text) == "81fbae24ce38b455"
 
 
+@pytest.mark.parametrize("precision,operand", [("default", "bf16"),
+                                               ("highest", "f32")])
+def test_eva_mask_kernels_lower_at_the_cells_shape(v5e, precision, operand):
+    """``evabyte_6b5_seq8192``'s attention call (PR 46) — one 8192-byte
+    sequence, 32 heads of 128, windows of 2048 in chunks of 16 — as Mosaic
+    compiles it under the stated mask: the three kernels read the 52 pairs
+    the mask leaves of the 16 x 17 rectangle from their tables, over 8704
+    key columns (one block of 384 summaries, padded, then the keys). With
+    float32 operands too: what the cell's check runs its float32 side
+    through."""
+    import re
+
+    from paddle_tpu.ops import eva
+
+    q, p = _z(1, 8192, 32, 128), _z(32, 128)
+
+    def fwd_bwd(q, k, v, phi, mu):
+        loss = lambda *a: (eva.eva_attention(
+            *a, 2048, 16, interpret=False, precision=precision) ** 2).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(
+            q, k, v, phi, mu)
+
+    hlo = _compile(fwd_bwd, SingleDeviceSharding(v5e[0]), q, q, q, p,
+                   p).as_text()
+    calls = dict(re.findall(
+        r"%(flash_[a-z_]+)[.\d]* = .*?operand_layout_constraints=\{(.*?)\}, \w+=",
+        hlo))
+    assert set(calls) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    for name, operands in calls.items():
+        got = re.findall(r"(\w+\[[\d,]*\])", operands)
+        assert got[:4] == ["s32[4]"] + ["s32[52]"] * 3, (name, got)
+        assert got[4:7] == [f"{operand}[32,8192,128]"] \
+            + [f"{operand}[32,8704,128]"] * 2, (name, got)
+    assert "pt.eva.prep" in hlo
+
+
+def _evabyte_step(v5e, cfg, seq):
+    from paddle_tpu import nn, optimizer
+    from paddle_tpu.executor import make_train_step
+    from paddle_tpu.models.evabyte import EvaByte, evabyte_loss
+
+    model = EvaByte(cfg)
+    opt = optimizer.AdamW(learning_rate=3e-4, weight_decay=0.1, beta2=0.95)
+    step = make_train_step(model, opt, evabyte_loss, amp=True)
+    state = nn.get_state(model)
+    ids = (_z(1, seq, dtype=jnp.int32),)
+    s = SingleDeviceSharding(v5e[0])
+    return step.lower(
+        _shapes(state, s),
+        _shapes(jax.eval_shape(opt.init, state["params"]), s), _rng_key(s),
+        _shapes(ids, s), _shapes(ids, s)).compile()
+
+
+def test_evabyte_step_compiles_small(v5e, as_tpu):
+    """The EvaByte train step for the chip at small widths with the
+    published head, window and chunk sizes over two windows: four kernel
+    calls a layer (the forward, its recomputation, the two backward
+    kernels), every new scope in the text, the 3 + 1 pairs of the 2 x 3
+    rectangle in the tables."""
+    from paddle_tpu.models.evabyte import EvaByteConfig
+
+    text = _evabyte_step(v5e, EvaByteConfig(
+        hidden_size=256, num_heads=2, intermediate_size=512, num_layers=2,
+        num_pred_heads=8, recompute="blocks"), seq=4096).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * 4
+    for scope in ("pt.eva.qkv", "pt.eva.prep", "pt.rope", "pt.ffn.dense",
+                  "pt.head_loss"):
+        assert scope in text, scope
+    # blocks of 512 in windows of 2048: 2 x 10 local pairs and the second
+    # window's 4 q blocks on the one summary block, of the 8 x 9 rectangle
+    assert "s32[24]" in text
+
+
+@pytest.mark.slow
+def test_evabyte_cell_step_compiles(v5e, as_tpu):
+    """The benchmark cell's step at full widths (four layers, one
+    8192-byte sequence, blocks recomputed): 12.45 GiB for a v5e, 9.18 of
+    it parameters and moments (PERF.md section 6, PR 46)."""
+    from paddle_tpu.models.evabyte import EvaByteConfig
+
+    m = _evabyte_step(v5e, EvaByteConfig(
+        num_layers=4, total_layers=32, recompute="blocks"),
+        seq=8192).memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 12.0 * 2**30 < live < 13.5 * 2**30
+
+
 def test_smallthinker_step_compiles_small(v5e, as_tpu):
     """The SmallThinker train step for the chip at small widths with the
     published head shape (14 query heads on 2 key-value heads of 128) and
