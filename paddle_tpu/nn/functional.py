@@ -7,6 +7,7 @@ attention paths have Pallas kernels under ``paddle_tpu.ops.pallas``.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
@@ -14,6 +15,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.enforce import InvalidArgumentError, enforce_eq
+from ..core.profiler import RecordEvent
 from .layer import next_rng_key
 
 __all__ = [
@@ -81,20 +83,95 @@ def dropout(
     return jnp.where(keep, x / (1.0 - p), 0.0).astype(x.dtype)
 
 
+# ``linear``'s amp branch states its backward where both sides of the
+# weight are at least this wide, and leaves a narrower weight's to the
+# transpose of the forward. Stated, a layer pays a few passes over
+# ``[tokens, in]`` and ``[tokens, out]`` (x16 and the narrow cotangent
+# written out, ``dx`` finished in float32 before anything reads it) that
+# the transposed form fuses away, and gains where XLA would otherwise
+# rebuild an operand's producer inside the weight gradient's matmul (a
+# gated FFN's product, a head's softmax gradient). On the chip the wide
+# layers of a decoder come out ahead and its narrow projections behind
+# (PERF.md section 6, PR 48)
+_STATED_BACKWARD_MIN_WIDTH = 4096
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _amp_matmul(x: jax.Array, weight: jax.Array, dt) -> jax.Array:
+    """``x @ W`` under amp: operands in ``dt``, float32 accumulation and
+    result. The backward is stated like the forward (:func:`linear`)."""
+    return _amp_matmul_fwd(x, weight, dt)[0]
+
+
+def _amp_matmul_fwd(x, weight, dt):
+    x16 = x.astype(dt)
+    y = jnp.matmul(x16, weight.astype(dt), preferred_element_type=jnp.float32)
+    return y, (x16, weight)
+
+
+def _amp_matmul_bwd(dt, res, g):
+    x16, weight = res
+    # What each line is for was read off XLA:TPU's compiled steps and the
+    # chip (PERF.md section 6, PR 48). Left to the transpose of the two
+    # ``astype``s, the weight gradient's convolution rebuilds x16's and
+    # g's float32 producers a tile and its result is rounded to ``dt`` and
+    # back. x16 behind a barrier is a buffer that convolution READS; ONE
+    # narrow cotangent feeds both matmuls.
+    x16 = lax.optimization_barrier(x16)
+    g16 = g.astype(dt)
+    dx = jnp.matmul(g16, weight.astype(dt).T,
+                    preferred_element_type=jnp.float32)
+    dw = jnp.matmul(x16.reshape(-1, x16.shape[-1]).T,
+                    g16.reshape(-1, g16.shape[-1]),
+                    preferred_element_type=jnp.float32)
+    # dx leaves through a barrier it shares with dW: the layer below
+    # cannot start its backward before this weight gradient is done. Left
+    # free, the scheduler parks the weight gradients at the END of the
+    # step and keeps the bf16 operands they read until then (+1% to +3%
+    # of a decoder cell's memory)
+    dx = lax.optimization_barrier((dx, dw))[0]
+    return dx, dw.astype(weight.dtype)
+
+
+_amp_matmul.defvjp(_amp_matmul_fwd, _amp_matmul_bwd)
+
+
 def linear(x: jax.Array, weight: jax.Array, bias: Optional[jax.Array] = None) -> jax.Array:
     """x @ W (+ b). Weight layout [in, out] (paddle convention).
 
     Under ``amp.auto_cast`` (checked at trace time, like the context's
-    contract says) the matmul runs in the amp dtype — bf16 feeds the
-    MXU at full rate with f32 accumulation on TPU — and the result is
-    cast back to the input dtype, so parameters, bias math, and
-    everything downstream stay f32."""
+    contract says) with a float32 ``x``, all THREE matmuls of the layer
+    read operands in the amp dtype — bf16 feeds the MXU at full rate on
+    TPU — and accumulate and leave in float32: the forward ``x16 @ w16``,
+    and in the backward ``dx = g @ w16.T`` and ``dW = x16.T @ g``
+    (parameters, bias math, both gradients and everything downstream stay
+    float32). Where both sides of the weight are wide (4096 and up: the
+    layers whose matmuls dominate a step) the backward is STATED, a
+    ``jax.custom_vjp``: ``g`` is cast to the amp dtype ONCE for both
+    matmuls, ``x16`` is the forward's own cast, a buffer the weight
+    gradient reads, ``dW`` is never rounded, and ``dx`` is handed on only
+    once ``dW`` is made, so a step's weight gradients are computed layer
+    by layer and not at its end. Such a layer differentiates in reverse
+    mode only: ``jax.jvp`` of it raises, where a narrower layer's, whose
+    backward is the transpose of the forward (its float32 cotangent
+    rounded by the MXU's default precision), does not. One
+    ``pt.linear.amp`` host span a traced call (``profiler.host_spans()``:
+    in, out, the operands' bits, ``stated_backward`` 1 | 0) records the
+    branch and the choice, none on the step path. An ``x`` that is not
+    float32, and amp off, multiply as given."""
     from .. import amp
 
     if amp.amp_enabled() and x.dtype == jnp.float32:
         dt = amp.amp_dtype()
-        y = jnp.matmul(x.astype(dt), weight.astype(dt),
-                       preferred_element_type=jnp.float32)
+        stated = min(weight.shape) >= _STATED_BACKWARD_MIN_WIDTH
+        with RecordEvent("pt.linear.amp", in_features=weight.shape[0],
+                         out_features=weight.shape[-1],
+                         bits=8 * jnp.dtype(dt).itemsize,
+                         stated_backward=int(stated)):
+            if stated:
+                y = _amp_matmul(x, weight, dt)
+            else:
+                y = _amp_matmul_fwd(x, weight, dt)[0]
     else:
         y = jnp.matmul(x, weight)
     if bias is not None:

@@ -139,3 +139,189 @@ def test_auto_cast_linear_and_conv_compute_bf16():
     assert outc.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(outc), np.asarray(refc),
                                rtol=5e-2, atol=5e-2)
+
+
+def _parent_amp_linear(x, w, b=None):
+    """``linear``'s amp branch before its backward was stated: the
+    gradient left to the transpose of the two ``astype``s."""
+    y = jnp.matmul(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
+    return y if b is None else y + b
+
+
+def _linear_case(name):
+    """(x, w, b, wrap): ``wrap(linear)`` is the function differentiated.
+    The weight is 4096 wide on both sides: ``linear`` states the backward
+    of such a layer, and leaves a narrower one's to ``jax.grad``."""
+    rng = np.random.default_rng(7)
+    f32 = lambda *s: jnp.asarray(rng.normal(size=s).astype(np.float32))
+    # y of unit size, so that sin's slope does not turn on y's rounding
+    w, b = f32(4096, 4096) / 64, f32(4096)
+    plain = lambda linear: linear
+    if name == "rank2":
+        return f32(8, 4096), w, None, plain
+    if name == "rank2_bias":
+        return f32(8, 4096), w, b, plain
+    if name == "batch_length_hidden":
+        return f32(2, 4, 4096), w, None, plain
+    if name == "batch_length_hidden_bias":
+        return f32(2, 4, 4096), w, b, plain
+    if name == "checkpoint":
+        return f32(2, 4, 4096), w, b, lambda linear: jax.checkpoint(
+            lambda *a: linear(*a))
+    assert name == "vmap"
+    # a batch of inputs AND of weights: the rule's own batching
+    return (f32(2, 3, 4096), f32(2, 4096, 4096) / 64, None,
+            lambda linear: jax.vmap(lambda x, w: linear(x, w)))
+
+
+@pytest.mark.parametrize("case", ["rank2", "rank2_bias", "batch_length_hidden",
+                                  "batch_length_hidden_bias", "checkpoint",
+                                  "vmap"])
+def test_amp_linear_backward_is_stated(case):
+    """Under ``auto_cast`` the backward of ``linear`` is its own statement
+    (one bf16 cotangent for both matmuls, the forward's bf16 ``x``,
+    float32 out): every gradient is float32, agrees with the float32
+    gradient within bf16's rounding of the operands and with the parent's
+    expression closer than that — and the weight's gradient is NOT a bf16
+    number widened, as the parent's was."""
+    from paddle_tpu import amp
+    from paddle_tpu.nn import functional as F
+
+    x, w, b, wrap = _linear_case(case)
+    args = (x, w) if b is None else (x, w, b)
+    # a smooth function of y whose cotangent is no constant
+    loss = lambda linear: lambda *a: jnp.sum(jnp.sin(wrap(linear)(*a)))
+    argnums = tuple(range(len(args)))
+    with amp.auto_cast(enable=True):
+        out = wrap(lambda *a: F.linear(*a))(*args)
+        got = jax.grad(loss(lambda *a: F.linear(*a)), argnums)(*args)
+    parent = jax.grad(loss(_parent_amp_linear), argnums)(*args)
+    exact = jax.grad(loss(lambda x, w, b=None: F.linear(x, w, b)),
+                     argnums)(*args)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(
+        wrap(_parent_amp_linear)(*args)))          # the forward: to the bit
+    for g, p, e in zip(got, parent, exact):
+        assert g.dtype == jnp.float32
+        scale = float(jnp.abs(e).max())
+        # operands rounded to 8 bits of mantissa, sums of 8 to 4096 terms;
+        # the float32 gradient is also taken at an unrounded y
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e),
+                                   atol=0.05 * scale, rtol=0.05)
+        # against the parent only the cotangent's rounding differs
+        np.testing.assert_allclose(np.asarray(g), np.asarray(p),
+                                   atol=0.01 * scale, rtol=0.02)
+    widened = lambda a: np.asarray(a.astype(jnp.bfloat16).astype(jnp.float32))
+    np.testing.assert_array_equal(np.asarray(parent[1]), widened(parent[1]))
+    assert (np.asarray(got[1]) != widened(got[1])).mean() > 0.9
+
+
+def test_linear_without_amp_or_with_bf16_input_is_the_plain_matmul():
+    """Amp off, and a bf16 ``x`` under amp, take the branch they took:
+    ``matmul(x, w) + b`` to the bit, out and gradients, with no custom
+    rule in the trace."""
+    from paddle_tpu import amp
+    from paddle_tpu.nn import functional as F
+
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(size=(5, 16, 32)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(32, 8)).astype(np.float32))
+    b = jnp.asarray(rng.normal(size=(8,)).astype(np.float32))
+    plain = lambda x, w, b: jnp.sum(jnp.sin(jnp.matmul(x, w) + b))
+    ours = lambda x, w, b: jnp.sum(jnp.sin(F.linear(x, w, b)))
+
+    def same(args):
+        for got, want in zip(
+                jax.tree_util.tree_leaves(
+                    jax.value_and_grad(ours, (0, 1, 2))(*args)),
+                jax.tree_util.tree_leaves(
+                    jax.value_and_grad(plain, (0, 1, 2))(*args))):
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(
+                np.asarray(got.astype(jnp.float32)),
+                np.asarray(want.astype(jnp.float32)))
+        assert "custom_vjp" not in str(jax.make_jaxpr(
+            lambda *a: ours(*a))(*args))
+
+    same((x, w, b))
+    with amp.auto_cast(enable=True):
+        same((x.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
+              b.astype(jnp.bfloat16)))
+
+
+def test_amp_linear_leaves_one_span_a_traced_call():
+    """``pt.linear.amp`` (in, out, the operands' bits, whether the backward
+    is stated) is recorded where the branch is taken: once a traced call —
+    a layer under ``jax.checkpoint`` and a gradient included — and never
+    on the step path."""
+    from paddle_tpu import amp
+    from paddle_tpu.core import profiler
+    from paddle_tpu.nn import functional as F
+
+    x = jnp.ones((4, 4096), jnp.float32)
+    w1, w2 = jnp.ones((4096, 4096), jnp.float32), jnp.ones((4096, 8), jnp.float32)
+
+    def loss(w1, w2):
+        with amp.auto_cast(enable=True):
+            h = jax.checkpoint(lambda x, w: F.linear(x, w))(x, w1)
+            return jnp.sum(F.linear(h, w2))
+
+    step = jax.jit(jax.grad(loss, (0, 1)))
+    spans = lambda: [s.counts for s in profiler.host_spans()
+                     if s.name == "pt.linear.amp"]
+    profiler.start_timeline()
+    jax.block_until_ready(step(w1, w2))
+    assert spans() == [
+        {"in_features": 4096, "out_features": 4096, "bits": 16,
+         "stated_backward": 1},
+        {"in_features": 4096, "out_features": 8, "bits": 16,
+         "stated_backward": 0}]
+    jax.block_until_ready(step(w1, w2))
+    assert len(spans()) == 2
+    profiler.start_timeline()
+    F.linear(x, w1)                     # amp off: not in the branch
+    assert spans() == []
+
+
+def test_amp_linear_with_a_stated_backward_is_reverse_mode_only():
+    """A stated backward is a ``custom_vjp``: forward mode of such a layer
+    raises (jax's own TypeError). A narrower layer's, and amp off, still
+    differentiate forward."""
+    from paddle_tpu import amp
+    from paddle_tpu.nn import functional as F
+
+    x = jnp.ones((4, 4096), jnp.float32)
+    wide, narrow = jnp.ones((4096, 4096), jnp.float32), jnp.ones((4096, 8), jnp.float32)
+    jax.jvp(lambda x: F.linear(x, wide), (x,), (x,))
+    with amp.auto_cast(enable=True):
+        jax.jvp(lambda x: F.linear(x, narrow), (x,), (x,))
+        with pytest.raises(TypeError, match="custom_vjp"):
+            jax.jvp(lambda x: F.linear(x, wide), (x,), (x,))
+
+
+def test_amp_linear_states_its_backward_from_a_width_up():
+    """The choice is made from the weight's shape, where the branch is
+    taken, and the span says which way: with a side narrower than 4096 the
+    trace is the parent's expression to the letter (the gradient left to
+    ``jax.grad``), else the stated rule's."""
+    from paddle_tpu import amp
+    from paddle_tpu.core import profiler
+    from paddle_tpu.nn import functional as F
+
+    x = jnp.ones((4, 4096), jnp.float32)
+    shapes = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+
+    def grad_text(linear, w):
+        return str(jax.make_jaxpr(jax.grad(
+            lambda x, w: jnp.sum(linear(x, w) ** 2), (0, 1)))(x, w))
+
+    profiler.start_timeline()
+    with amp.auto_cast(enable=True):
+        ours = [grad_text(lambda x, w: F.linear(x, w), shapes(4096, out))
+                for out in (4095, 2560, 4096, 11008)]
+    for text, out in zip(ours[:2], (4095, 2560)):
+        assert text == grad_text(_parent_amp_linear, shapes(4096, out))
+        assert "optimization_barrier" not in text
+    assert all("optimization_barrier" in text for text in ours[2:])
+    assert [s.counts["stated_backward"] for s in profiler.host_spans()
+            if s.name == "pt.linear.amp"] == [0, 0, 1, 1]

@@ -16,6 +16,7 @@ takes the branch the chip takes.
 """
 
 import os
+import re
 import sys
 
 import jax
@@ -647,6 +648,73 @@ def test_olmoe_cell_step_compiles(v5e, as_tpu):
 
 
 # ---------------------------------------------------------------------------
+def _linear_bwd_bench():
+    """``tools/linear_bwd_bench.py`` under a name of its own (a bare
+    ``import`` of a tool collides across test files)."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "_linear_bwd_bench_tool",
+        os.path.join(REPO, "tools", "linear_bwd_bench.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("variant,f32_operands,round_trips",
+                         [("tree", False, 0), ("parent", True, 14)])
+def test_amp_linear_backward_matmuls_read_bf16_buffers(
+        v5e, variant, f32_operands, round_trips):
+    """The tool's two-block decoder stack at EvaByte's widths (4096 /
+    11008; a sequence of 2048, blocks recomputed, AdamW) as the chip
+    compiles its step: with ``F.linear``'s stated backward every matmul of
+    the step — the forward's, both of the backward — has bf16 operands,
+    and no weight gradient is rounded to bf16 and widened again before
+    AdamW reads it. The parent's expression, beside it, shows what the
+    parser sees where that is not so: float32 cotangents into the backward
+    matmuls and every weight's gradient through a bf16 round trip."""
+    tool = _linear_bwd_bench()
+    step, arguments, weights = tool.build("evabyte_block", 2048, 4096, 11008,
+                                          variant)
+    shapes = _shapes(jax.eval_shape(arguments, jax.random.key(0)),
+                     SingleDeviceSharding(v5e[0]))
+    text = step.lower(*shapes).compile().as_text()
+    found = tool.fusions(text)
+    matmuls = [f for f in found.values() if f["convolutions"]]
+    # a block: 7 forward, 7 recomputed, 7 dx, 7 dW
+    assert len(matmuls) >= 2 * 28 - 2, len(matmuls)
+    operands = {o.split(":")[0] for f in matmuls
+                for conv in f["convolutions"] for o in conv}
+    assert operands == ({"bf16", "f32"} if f32_operands else {"bf16"})
+    assert sum(f["round_trips"] for f in matmuls) == round_trips
+    assert len(tool.weight_gradient_fusions(found, weights)) == 2 * 7
+
+
+@pytest.mark.parametrize("wanted,weight_gradients", [((0,), 0), ((0, 1), 2)])
+def test_amp_linear_frozen_weight_costs_no_weight_gradient(
+        v5e, wanted, weight_gradients):
+    """The stated backward hands ``dx`` on through a barrier it shares
+    with ``dW``. Where nobody asks for the weight's gradient (a frozen
+    layer, a gradient with respect to the input alone) that barrier does
+    NOT keep the ``dW`` matmul in the program: two layers of 4096 x 4096
+    compile to their forward and ``dx`` matmuls and nothing of a weight's
+    shape."""
+    from paddle_tpu import amp
+    from paddle_tpu.nn import functional as F
+
+    def loss(x, w):
+        with amp.auto_cast(enable=True):
+            return jnp.sum(jnp.sin(F.linear(jnp.tanh(F.linear(x, w)), w)))
+
+    sharding = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((2048, 4096), jnp.float32, sharding=sharding)
+    w = jax.ShapeDtypeStruct((4096, 4096), jnp.float32, sharding=sharding)
+    text = jax.jit(jax.grad(loss, wanted)).lower(x, w).compile().as_text()
+    matmuls = re.findall(r"= (\w+\[[0-9,]*\])\S* convolution\(", text)
+    assert len(matmuls) == 4 + weight_gradients, matmuls
+    assert matmuls.count("f32[4096,4096]") == weight_gradients
+
+
 # the steps chip_smoke.py runs, at its widths, as the chip compiles them
 # ---------------------------------------------------------------------------
 
