@@ -215,6 +215,10 @@ class EvaByte(Layer):
             else:
                 x = block(x)
         with jax.named_scope("pt.head_loss"):
+            # ``linear``, not ``lm_head``: the parent already reads a bf16
+            # cotangent buffer here (eight softmaxes of 320 behind a
+            # reshape do not fuse into the matmul), so the statement only
+            # adds passes: -0.23% (PERF.md section 6, PRs 48 and 49)
             logits = F.linear(self.norm_f(x), self.heads)
         return logits.reshape(B, L, cfg.num_pred_heads, cfg.vocab_size)
 

@@ -49,7 +49,7 @@ RMSNorm everywhere, no bias anywhere, untied embedding and head.
   shapes stay static. ``forward(ids)`` returns ``(logits, logits')``;
   ``joyai_loss`` is the loss over both.
 
-Matmuls go through ``nn.functional.linear`` and
+Matmuls go through ``nn.functional.linear`` (the head: ``lm_head``) and
 ``parallel.moe.grouped_matmul``: ``Trainer(amp=True)`` means bf16 operands
 with float32 accumulation; norms, rotary, softmax and the router stay
 float32. Counters leave the forward in buffers: ``expert_counts``
@@ -407,11 +407,11 @@ class Joyai(Layer):
                 routes.append(route)
         with jax.named_scope("pt.head_loss"):
             trunk = self.norm_f(x)
-            logits = F.linear(trunk, self.head_w)
+            logits = F.lm_head(trunk, self.head_w)
         y, route = self.mtp(nxt, trunk)
         routes.append(route)
         with jax.named_scope("pt.head_loss"):
-            logits_mtp = F.linear(y, self.head_w)
+            logits_mtp = F.lm_head(y, self.head_w)
         stack = lambda key: jnp.stack([r[key] for r in routes])
         self._buffers["expert_counts"] = stack("counts")
         self._buffers["held_assignments"] = stack(

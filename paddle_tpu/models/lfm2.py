@@ -348,6 +348,11 @@ class Lfm2(Layer):
             if route is not None:
                 routes.append(route)
         with jax.named_scope("pt.head_loss"):
+            # ``linear``, not ``lm_head``: this step has no recomputation
+            # and compiles through XLA's own rematerialisation pass, which
+            # stops 0.39 GiB higher with the head's backward stated (13.479
+            # -> 13.870 GiB, 1% allowed; PERF.md section 6, PR 49) for a
+            # tied head's 3 ms
             logits = F.linear(self.norm_f(x), self.embed.T)
         stack = lambda key: jnp.stack([r[key] for r in routes])
         self._buffers["expert_counts"] = stack("counts")

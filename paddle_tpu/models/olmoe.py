@@ -204,6 +204,12 @@ class Olmoe(Layer):
         self._buffers["tokens_dropped"] = jnp.sum(stack("dropped")).astype(
             jnp.int32)
         with jax.named_scope("pt.head_loss"):
+            # ``linear``, not ``lm_head``: the stated backward pays only
+            # with its bf16 cotangent as a buffer, here 786 MiB beside the
+            # float32 logits at the step's memory peak (+6% compiled);
+            # left to choose, XLA rebuilds it inside both backward matmuls
+            # and the weight gradient reads 17.1 -> 21.7 ms, the cell
+            # -1.27% (PERF.md section 6, PR 49)
             logits = F.linear(self.norm_f(x), self.head_w)
         if output_routing:
             return logits, {"logits": stack("logits"), "index": stack("index")}

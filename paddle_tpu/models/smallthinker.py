@@ -38,7 +38,7 @@ head are two matrices (untied).
   (first, count)``: one expert-parallel rank's part, nothing standing in
   for the others).
 
-Matmuls go through ``nn.functional.linear`` and
+Matmuls go through ``nn.functional.linear`` (the head: ``lm_head``) and
 ``parallel.moe.grouped_matmul``: ``Trainer(amp=True)`` means bf16 operands
 with float32 accumulation; norms, rotary, softmax and the router stay
 float32. Counters leave the forward in buffers as ``models/lfm2.py``'s do:
@@ -357,7 +357,7 @@ class SmallThinker(Layer):
                 x, route = block(x)
             routes.append(route)
         with jax.named_scope("pt.head_loss"):
-            logits = F.linear(self.norm_f(x), self.head)
+            logits = F.lm_head(self.norm_f(x), self.head)
         stack = lambda key: jnp.stack([r[key] for r in routes])
         self._buffers["expert_counts"] = stack("counts")
         self._buffers["held_assignments"] = stack(

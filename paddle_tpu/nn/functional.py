@@ -27,6 +27,7 @@ __all__ = [
     "log_softmax",
     "dropout",
     "linear",
+    "lm_head",
     "conv2d",
     "max_pool2d",
     "avg_pool2d",
@@ -136,6 +137,26 @@ def _amp_matmul_bwd(dt, res, g):
 _amp_matmul.defvjp(_amp_matmul_fwd, _amp_matmul_bwd)
 
 
+def _matmul(x: jax.Array, weight: jax.Array, stated: bool) -> jax.Array:
+    """``x @ W`` as :func:`linear` and :func:`lm_head` multiply: under
+    ``amp.auto_cast`` with a float32 ``x`` in the amp dtype (one
+    ``pt.linear.amp`` span a traced call), else as given. The backward is
+    stated where the caller says so and for a weight wide on both sides."""
+    from .. import amp
+
+    if not (amp.amp_enabled() and x.dtype == jnp.float32):
+        return jnp.matmul(x, weight)
+    dt = amp.amp_dtype()
+    stated = stated or min(weight.shape) >= _STATED_BACKWARD_MIN_WIDTH
+    with RecordEvent("pt.linear.amp", in_features=weight.shape[0],
+                     out_features=weight.shape[-1],
+                     bits=8 * jnp.dtype(dt).itemsize,
+                     stated_backward=int(stated)):
+        if stated:
+            return _amp_matmul(x, weight, dt)
+        return _amp_matmul_fwd(x, weight, dt)[0]
+
+
 def linear(x: jax.Array, weight: jax.Array, bias: Optional[jax.Array] = None) -> jax.Array:
     """x @ W (+ b). Weight layout [in, out] (paddle convention).
 
@@ -158,25 +179,28 @@ def linear(x: jax.Array, weight: jax.Array, bias: Optional[jax.Array] = None) ->
     ``pt.linear.amp`` host span a traced call (``profiler.host_spans()``:
     in, out, the operands' bits, ``stated_backward`` 1 | 0) records the
     branch and the choice, none on the step path. An ``x`` that is not
-    float32, and amp off, multiply as given."""
-    from .. import amp
-
-    if amp.amp_enabled() and x.dtype == jnp.float32:
-        dt = amp.amp_dtype()
-        stated = min(weight.shape) >= _STATED_BACKWARD_MIN_WIDTH
-        with RecordEvent("pt.linear.amp", in_features=weight.shape[0],
-                         out_features=weight.shape[-1],
-                         bits=8 * jnp.dtype(dt).itemsize,
-                         stated_backward=int(stated)):
-            if stated:
-                y = _amp_matmul(x, weight, dt)
-            else:
-                y = _amp_matmul_fwd(x, weight, dt)[0]
-    else:
-        y = jnp.matmul(x, weight)
+    float32, and amp off, multiply as given. A vocabulary head is
+    :func:`lm_head`'s."""
+    y = _matmul(x, weight, stated=False)
     if bias is not None:
         y = y + bias
     return y
+
+
+def lm_head(x: jax.Array, weight: jax.Array) -> jax.Array:
+    """Logits ``x @ W`` of a decoder's head, weight ``[hidden, vocab]``:
+    :func:`linear` without a bias and with the backward stated whatever the
+    weight's widths. A head's cotangent is the loss's softmax gradient, a
+    float32 ``[tokens, vocab]`` expression that the transposed form
+    rebuilds a tile of the weight gradient's matmul and the stated form
+    casts once, into a buffer both backward matmuls read. The model says
+    which matmul is its head, and whether the statement pays in its step:
+    ``linear`` cannot see a layer's neighbours, and neither function the
+    step it is part of (a head keeps ``linear`` where its cell read a
+    loss; each such call site says why). Same span (``stated_backward``
+    1), same reverse-mode-only rule; amp off and an ``x`` that is not
+    float32 multiply as given."""
+    return _matmul(x, weight, stated=True)
 
 
 def _pair(v: Union[int, Sequence[int]]) -> Tuple[int, int]:

@@ -661,6 +661,24 @@ def _linear_bwd_bench():
     return mod
 
 
+def _tool_step_fusions(v5e, case, batch, hidden, other, variant):
+    """(every fusion of the tool's ``case`` step under ``variant`` as the
+    described chip compiles it, its weight gradients' fusions); ``batch``:
+    (sequences, length)."""
+    tool = _linear_bwd_bench()
+    step, arguments, weights = tool.build(case, batch, hidden, other,
+                                          variant)
+    shapes = _shapes(jax.eval_shape(arguments, jax.random.key(0)),
+                     SingleDeviceSharding(v5e[0]))
+    found = tool.fusions(step.lower(*shapes).compile().as_text())
+    return found, tool.weight_gradient_fusions(found, weights)
+
+
+def _operand_dtypes(fusions):
+    return {o.split(":")[0] for f in fusions for conv in f["convolutions"]
+            for o in conv}
+
+
 @pytest.mark.parametrize("variant,f32_operands,round_trips",
                          [("tree", False, 0), ("parent", True, 14)])
 def test_amp_linear_backward_matmuls_read_bf16_buffers(
@@ -673,21 +691,33 @@ def test_amp_linear_backward_matmuls_read_bf16_buffers(
     AdamW reads it. The parent's expression, beside it, shows what the
     parser sees where that is not so: float32 cotangents into the backward
     matmuls and every weight's gradient through a bf16 round trip."""
-    tool = _linear_bwd_bench()
-    step, arguments, weights = tool.build("evabyte_block", 2048, 4096, 11008,
-                                          variant)
-    shapes = _shapes(jax.eval_shape(arguments, jax.random.key(0)),
-                     SingleDeviceSharding(v5e[0]))
-    text = step.lower(*shapes).compile().as_text()
-    found = tool.fusions(text)
+    found, weight_gradients = _tool_step_fusions(
+        v5e, "evabyte_block", (1, 2048), 4096, 11008, variant)
     matmuls = [f for f in found.values() if f["convolutions"]]
     # a block: 7 forward, 7 recomputed, 7 dx, 7 dW
     assert len(matmuls) >= 2 * 28 - 2, len(matmuls)
-    operands = {o.split(":")[0] for f in matmuls
-                for conv in f["convolutions"] for o in conv}
-    assert operands == ({"bf16", "f32"} if f32_operands else {"bf16"})
+    assert _operand_dtypes(matmuls) == (
+        {"bf16", "f32"} if f32_operands else {"bf16"})
     assert sum(f["round_trips"] for f in matmuls) == round_trips
-    assert len(tool.weight_gradient_fusions(found, weights)) == 2 * 7
+    assert len(weight_gradients) == 2 * 7
+
+
+@pytest.mark.parametrize("variant,operands,round_trips",
+                         [("tree", {"bf16"}, 0), ("parent", {"bf16", "f32"}, 1)])
+def test_lm_head_weight_gradient_reads_two_bf16_operands(
+        v5e, variant, operands, round_trips):
+    """The tool's ``smallthinker_head`` (the final norm, the ``[2560,
+    18992]`` head, the float32 cross-entropy, AdamW; 16,384 tokens) as the
+    chip compiles its step: through ``F.lm_head`` the weight gradient's
+    matmul reads two bf16 operands and its result meets AdamW unrounded;
+    the parent's expression rebuilds the float32 softmax gradient inside
+    that matmul and rounds the result to bf16 and back."""
+    _, weight_gradients = _tool_step_fusions(
+        v5e, "smallthinker_head", (1, 16384), 2560, 18992, variant)
+    (dw,) = weight_gradients.values()
+    assert dw["result"] == "f32[2560,18992]"
+    assert _operand_dtypes([dw]) == operands
+    assert dw["round_trips"] == round_trips
 
 
 @pytest.mark.parametrize("wanted,weight_gradients", [((0,), 0), ((0, 1), 2)])

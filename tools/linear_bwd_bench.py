@@ -1,12 +1,14 @@
 """A linear layer's weight-gradient fusions on the chip, several backward
 statements of ``nn.functional.linear``'s amp branch beside each other:
-the measurement behind its stated backward (``PERF.md`` section 6, PR 48).
+the measurement behind its stated backward (``PERF.md`` section 6, PR 48)
+and behind ``nn.functional.lm_head`` (PR 49).
 
     python3 tools/linear_bwd_bench.py [--calls 10] [--out chiprun_out/linear_bwd_bench.json]
+    python3 tools/linear_bwd_bench.py --cases smallthinker_head olmoe_head --variants parent tree
     python3 tools/linear_bwd_bench.py --described     # no chip: the compiler's own model
     python3 tools/linear_bwd_bench.py --rehearse      # tiny shapes on the CPU, no times
 
-Two programs, each one jitted train step (value and gradient, AdamW of
+Six programs, each one jitted train step (value and gradient, AdamW of
 ``paddle_tpu.optimizer``, parameters and moments donated) in float32 with
 bf16 matmul operands, as the decoder cells run:
 
@@ -17,9 +19,19 @@ bf16 matmul operands, as the decoder cells run:
   attention kernels (``q * sigmoid(k) + v``: the kernels have their own
   backward and are not this tool's), then RMSNorm and the SwiGLU FFN with
   its residual. The first block's cotangent is the second block's backward.
-- ``smallthinker_head``: SmallThinker's head, ``[16384, 2560] x [2560,
-  18992]`` behind the final RMSNorm with the float32 cross-entropy behind
-  it.
+- the decoder cells' heads, each behind the final RMSNorm with the
+  float32 cross-entropy behind it, at the cell's (batch x length, hidden,
+  vocab) — the batch as the cell's traffic has it, for a batch of two
+  compiles to another program than one sequence of twice the length
+  (``PERF.md`` section 6, PR 49): ``smallthinker_head`` (1 x 16384, 2560,
+  18992); ``olmoe_head`` (2 x 4096, 2048, 50304); ``joyai_heads`` (2 x
+  4096, 2048, 16160), ONE weight called twice as ``models/joyai.py`` calls
+  it (the trunk's logits and the prediction module's, an elementwise
+  stand-in for the module between them); ``lfm2_tied_head`` (4 x 4096,
+  2048, 16384), the head ``embed.T`` of the table the stream was gathered
+  from, so the weight's gradient is the embedding's; ``evabyte_heads`` (1
+  x 8192, 4096, 2560), eight byte heads of 320 in one weight, a
+  cross-entropy a head.
 
 Each is built with the linear layer stated these ways (``VARIANTS``):
 
@@ -33,13 +45,15 @@ Each is built with the linear layer stated these ways (``VARIANTS``):
 - ``parent_leaf_barrier``: ``parent`` with every gradient leaf behind a
   barrier of its own before AdamW (the optimizer's layer: the update held
   out of the matmul's fusion) — not landed, the next step's reading;
-- ``tree``: this checkout's own ``F.linear`` under ``amp.auto_cast`` — what
-  landed: for a weight 4096 wide on both sides ``stated_x16_held`` with
-  ``dx`` handed on through a barrier it shares with ``dW`` (left free,
-  XLA's scheduler parks the weight-gradient matmuls, and the bf16 operands
-  they read, at the end of a whole decoder's step), for a narrower weight
-  (the head's) ``parent``; ``tree_is`` names the variant whose jaxpr it
-  equals, if any.
+- ``tree``: this checkout's own functions under ``amp.auto_cast`` —
+  ``F.linear`` in the block, ``F.lm_head`` for every head (a model whose
+  head keeps ``F.linear`` runs ``parent`` there: the head's weight is
+  narrower than 4096): for a weight 4096 wide on both sides, and for a
+  head, ``stated_x16_held`` with ``dx`` handed on through a barrier it
+  shares with ``dW`` (left free, XLA's scheduler parks the weight-gradient
+  matmuls, and the bf16 operands they read, at the end of a whole
+  decoder's step), for any other weight ``parent``; ``tree_is`` names the
+  variant whose jaxpr it equals, if any.
 
 Per variant: milliseconds a call of every device operation whose first
 result is a weight's ``f32[in, out]`` (the weight-gradient fusions, read
@@ -71,13 +85,27 @@ sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 
 VARIANTS = ("parent", "stated", "stated_x16_held", "parent_leaf_barrier",
             "tree")
-#: (case, tokens, hidden, the other width): EvaByte's block, SmallThinker's
-#: head. The rehearsal keeps the ratios and nothing of the size.
-CASES = (("evabyte_block", 8192, 4096, 11008),
-         ("smallthinker_head", 16384, 2560, 18992))
-REHEARSAL = (("evabyte_block", 256, 128, 384),
-             ("smallthinker_head", 512, 128, 640))
+#: (case, (batch, length), hidden, the other width): EvaByte's block, then
+#: the five decoder cells' heads (the other width is the vocabulary's), the
+#: batch as the cell's configuration has it (``batch_per_chip``). The
+#: rehearsal keeps the batch and the ratios and nothing of the size.
+CASES = (("evabyte_block", (1, 8192), 4096, 11008),
+         ("smallthinker_head", (1, 16384), 2560, 18992),
+         ("olmoe_head", (2, 4096), 2048, 50304),
+         ("joyai_heads", (2, 4096), 2048, 16160),
+         ("lfm2_tied_head", (4, 4096), 2048, 16384),
+         ("evabyte_heads", (1, 8192), 4096, 2560))
+REHEARSAL = (("evabyte_block", (1, 256), 128, 384),
+             ("smallthinker_head", (1, 512), 128, 640),
+             ("olmoe_head", (2, 128), 128, 1536),
+             ("joyai_heads", (2, 128), 128, 512),
+             ("lfm2_tied_head", (4, 128), 128, 512),
+             ("evabyte_heads", (1, 256), 256, 160))
 BLOCKS = 2
+#: ``evabyte_heads``: byte heads in the one weight (``models/evabyte.py``)
+PRED_HEADS = 8
+#: ``joyai_heads``: the prediction module's loss beside the trunk's
+MTP_LOSS_WEIGHT = 0.3
 
 _FUSION_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\(?[a-z0-9]+\[[0-9,]*\].*?)\s+"
@@ -172,8 +200,9 @@ def weight_gradient_fusions(found: dict, weights) -> dict:
             if f["result"] in want and f["convolutions"]}
 
 
-def linear_of(variant: str):
-    """(linear(x, w), whether every gradient leaf goes behind a barrier)."""
+def linear_of(variant: str, head: bool = False):
+    """(linear(x, w), whether every gradient leaf goes behind a barrier);
+    ``head``: the call is a model's vocabulary head."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -213,7 +242,7 @@ def linear_of(variant: str):
         from paddle_tpu.nn import functional as F
 
         with amp.auto_cast(enable=True):
-            return F.linear(x, w)
+            return F.lm_head(x, w) if head else F.linear(x, w)
 
     if variant == "tree":
         return tree, False
@@ -222,9 +251,10 @@ def linear_of(variant: str):
     return stated(variant == "stated_x16_held"), False
 
 
-def build(case: str, T: int, h: int, other: int, variant: str):
+def build(case: str, batch, h: int, other: int, variant: str):
     """(jitted step, its arguments' shapes as a function of a key, the
-    weights' shapes) of ``case`` under ``variant``."""
+    weights' shapes) of ``case`` under ``variant``; ``batch``: the input's
+    (sequences, length)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -232,7 +262,7 @@ def build(case: str, T: int, h: int, other: int, variant: str):
     from paddle_tpu import optimizer
     from paddle_tpu.nn import functional as F
 
-    linear, leaf_barrier = linear_of(variant)
+    linear, leaf_barrier = linear_of(variant, head=case != "evabyte_block")
     opt = optimizer.AdamW(learning_rate=3e-4, weight_decay=0.1, beta2=0.95)
     normal = lambda key, *shape: 0.02 * jax.random.normal(
         key, shape, jnp.float32)
@@ -251,7 +281,7 @@ def build(case: str, T: int, h: int, other: int, variant: str):
                        "w_down": normal(next(ks), other, h)}
                       for _ in range(BLOCKS)]
             return {"blocks": blocks}, (
-                jax.random.normal(next(ks), (1, T, h), jnp.float32),)
+                jax.random.normal(next(ks), batch + (h,), jnp.float32),)
 
         def block(p, x):
             u = F.rms_norm(x, 1.0 + p["norm_attn"], 1e-5)
@@ -266,21 +296,45 @@ def build(case: str, T: int, h: int, other: int, variant: str):
                 x = jax.checkpoint(lambda x, p=p: block(p, x))(x)
             return jnp.mean(jnp.square(x))
     else:
-        weights = [(h, other)]
+        # a head: ``other`` is the vocabulary. The scale stands for the
+        # stack below: the input's gradient has a reader, so the dx matmul
+        # stays in the program
+        tied = case == "lfm2_tied_head"
+        # tied: the gradient is the table's, made as the head's [h, vocab]
+        # where dx waits for it (``tree``) and transposed after
+        weights = [(other, h), (h, other)] if tied else [(h, other)]
 
         def init(key):
             ks = jax.random.split(key, 3)
-            return ({"scale": jnp.ones((h,)), "norm_f": jnp.ones((h,)),
-                     "head": normal(ks[0], h, other)},
-                    (jax.random.normal(ks[1], (1, T, h), jnp.float32),
-                     jax.random.randint(ks[2], (1, T), 0, other)))
+            params = {"scale": jnp.ones((h,)), "norm_f": jnp.ones((h,))}
+            if tied:
+                params["embed"] = normal(ks[0], *weights[0])
+                first = jax.random.randint(ks[1], batch, 0, other)
+            else:
+                params["head"] = normal(ks[0], *weights[0])
+                first = jax.random.normal(ks[1], batch + (h,), jnp.float32)
+            if case == "joyai_heads":
+                params["norm_mtp"] = jnp.ones((h,))
+            labels, vocab = batch, other
+            if case == "evabyte_heads":
+                labels, vocab = batch + (PRED_HEADS,), other // PRED_HEADS
+            return params, (first, jax.random.randint(ks[2], labels, 0,
+                                                      vocab))
 
         def loss_fn(params, x, labels):
-            # the scale stands for the stack below: the input's gradient
-            # has a reader, so the dx matmul stays in the program
+            w = params["embed"].T if tied else params["head"]
+            if tied:
+                x = jnp.take(params["embed"], x, axis=0)
             u = F.rms_norm(x * params["scale"], params["norm_f"], 1e-5)
-            return F.cross_entropy(linear(u, params["head"]), labels,
-                                   ignore_index=-1)
+            logits = linear(u, w)
+            if case == "evabyte_heads":
+                logits = logits.reshape(labels.shape + (-1,))
+            loss = F.cross_entropy(logits, labels, ignore_index=-1)
+            if case == "joyai_heads":
+                y = F.rms_norm(jax.nn.silu(u), params["norm_mtp"], 1e-5)
+                loss = loss + MTP_LOSS_WEIGHT * F.cross_entropy(
+                    linear(y, w), labels, ignore_index=-1)
+            return loss
 
     def step(params, opt_state, *batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, *batch)
@@ -320,10 +374,13 @@ def measure(args) -> dict:
                          else "TPU v5 lite")
     rec = {"device_kind": dev.device_kind, "described": args.described,
            "calls": args.calls, "cases": {}}
-    for case, T, h, other in (REHEARSAL if args.rehearse else CASES):
+    for case, batch, h, other in (REHEARSAL if args.rehearse else CASES):
+        if case not in args.cases:
+            continue
+        T = batch[0] * batch[1]
         rows, jaxprs = {}, {}
         for variant in args.variants:
-            step, arguments, weights = build(case, T, h, other, variant)
+            step, arguments, weights = build(case, batch, h, other, variant)
             shapes = jax.eval_shape(arguments, jax.random.key(0))
             if args.described:
                 sharding = jax.sharding.SingleDeviceSharding(dev)
@@ -354,8 +411,8 @@ def measure(args) -> dict:
             print(json.dumps({case: {variant: row}}), flush=True)
         tree_is = [v for v in jaxprs
                    if v != "tree" and jaxprs[v] == jaxprs.get("tree")]
-        rec["cases"][case] = {"tokens": T, "variants": rows,
-                              "tree_is": tree_is}
+        rec["cases"][case] = {"tokens": T, "batch": list(batch),
+                              "variants": rows, "tree_is": tree_is}
     return rec
 
 
@@ -407,7 +464,8 @@ def table(rec: dict) -> str:
     lines = []
     for case, c in rec["cases"].items():
         variants = list(c["variants"])
-        lines += [f"### {case} (T = {c['tokens']}; tree is "
+        lines += [f"### {case} (T = {c['batch'][0]} x {c['batch'][1]}; "
+                  f"tree is "
                   f"{', '.join(c['tree_is']) or 'none of these'})", "",
                   "| | floor ms | " + " | ".join(variants) + " |",
                   "| --- | --- |" + " --- |" * len(variants)]
@@ -454,6 +512,8 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--variants", nargs="+", default=list(VARIANTS),
                     choices=VARIANTS)
+    ap.add_argument("--cases", nargs="+", default=[c[0] for c in CASES],
+                    choices=[c[0] for c in CASES])
     ap.add_argument("--described", action="store_true",
                     help="compile for a described v5e, run nothing")
     ap.add_argument("--rehearse", action="store_true")
