@@ -421,10 +421,10 @@ def leg_dense(sz: Sizes) -> Dict:
     assert err_fast <= 3e-2, f"flash(default) vs local_attention: {err_fast}"
 
     # the same, causal under a sliding window at grouped-query heads (k and
-    # v repeated first, as ``models/smallthinker.py`` hands them over): the
+    # v repeated first, as ``models/transformer.py`` hands them over): the
     # pair list's second bound and the kernels' second mask, compiled. The
     # bounds are the bidirectional call's.
-    from paddle_tpu.models.lfm2 import repeat_kv
+    from paddle_tpu.models.transformer import repeat_kv
 
     H, G, Dw = sz.window_heads
     W = sz.window

@@ -45,6 +45,7 @@ from ..parallel.mp_layers import _axis_active
 from ..parallel.moe import top1_gate, top2_gate
 from ..parallel.ring_attention import (local_attention, ring_attention,
                                        ring_flash_attention)
+from .transformer import attention_impl
 
 __all__ = ["ErnieConfig", "ErnieEmbedding", "ErnieBlock", "ErnieStage",
            "ErnieHead", "Ernie", "parallel_cross_entropy", "partition_spec"]
@@ -179,9 +180,7 @@ class _SelfAttention(Layer):
         lead = x.shape[:-2]            # arbitrary leading dims
         L = x.shape[-2]
         x2 = x.reshape((-1, L, cfg.hidden_size))
-        impl = cfg.attn_impl
-        if impl == "auto":
-            impl = "flash" if jax.default_backend() == "tpu" else "einsum"
+        impl = attention_impl(cfg.attn_impl)
         y = x2 @ self.qkv_w + self.qkv_b            # [B, L, H_local*3*D]
         if impl == "flash":
             # the flash kernels read q, k, v in their MXU dtype: round here,

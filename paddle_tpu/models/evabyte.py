@@ -13,7 +13,7 @@ from 0); no bias anywhere; embedding and heads are separate matrices.
     logits[r] = N_f(y_last) @ heads[:, r]                  r = 0..P-1
 
 - ``Eva``: ``num_heads`` heads of ``head_dim``, rotary positions on q and
-  k (half-split, ``models.olmoe.rotary``), then ``ops.eva``: per head a
+  k (half-split, ``transformer.rotary``), then ``ops.eva``: per head a
   learned ``adaptive_phi`` pools each ``chunk_size`` keys (and values) by
   the softmax of ``k·φ / sqrt(d)`` over the chunk, ``adaptive_mu_k`` is
   added to the pooled key, and query ``i`` attends to the keys ``j <= i``
@@ -35,7 +35,6 @@ rotary, the pooling softmax, the kernels' statistics, the residual adds
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Optional
 
 import jax
@@ -47,8 +46,8 @@ from ..core.profiler import RecordEvent
 from ..nn import functional as F
 from ..nn.layer import Layer
 from ..ops.eva import eva_attention, eva_attention_einsum
-from .joyai import _SwiGLU, _normal
-from .olmoe import rotary
+from .transformer import (SwiGLU, attention_impl, normal_init,
+                          residual_out_std, rotary)
 
 __all__ = ["EvaByteConfig", "EvaByteAttention", "EvaByteBlock", "EvaByte",
            "evabyte_loss"]
@@ -87,11 +86,9 @@ class EvaByteConfig:
 
     @property
     def out_std(self) -> float:
-        """std of the projections that write into the residual stream (W_o
-        and W_down): ``init_std / sqrt(2 * layers)``, as
-        ``SmallThinkerConfig.out_std``."""
-        return self.init_std / math.sqrt(
-            2 * (self.total_layers or self.num_layers))
+        """std of W_o and W_down (``residual_out_std``)."""
+        return residual_out_std(self.init_std,
+                                self.total_layers or self.num_layers)
 
     def parameter_count(self) -> int:
         """Parameters of the model as configured, from the shapes alone."""
@@ -109,10 +106,11 @@ class EvaByteAttention(Layer):
         super().__init__()
         self.cfg = cfg
         h, H, d = cfg.hidden_size, cfg.num_heads, cfg.head_dim
-        init = _normal(cfg.init_std)
+        init = normal_init(cfg.init_std)
         for name in ("wq", "wk", "wv"):
             self.create_parameter(name, (h, h), initializer=init)
-        self.create_parameter("wo", (h, h), initializer=_normal(cfg.out_std))
+        self.create_parameter("wo", (h, h),
+                              initializer=normal_init(cfg.out_std))
         self.create_parameter("adaptive_phi", (H, d), initializer=init)
         self.create_parameter("adaptive_mu_k", (H, d), initializer=init)
 
@@ -126,9 +124,7 @@ class EvaByteAttention(Layer):
             v = F.linear(u, self.wv).reshape(heads)
         with jax.named_scope("pt.rope"):
             q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
-        impl = cfg.attn_impl
-        if impl == "auto":
-            impl = "flash" if jax.default_backend() == "tpu" else "einsum"
+        impl = attention_impl(cfg.attn_impl)
         operands = (q, k, v, self.adaptive_phi, self.adaptive_mu_k,
                     cfg.window_size, cfg.chunk_size)
         out = eva_attention(*operands, precision=cfg.attn_precision) \
@@ -147,7 +143,7 @@ class EvaByteBlock(Layer):
         self.attn = EvaByteAttention(cfg)
         self.norm_ffn = nn.RMSNorm(cfg.hidden_size, cfg.rms_eps,
                                    unit_offset=True)
-        self.mlp = _SwiGLU(cfg.hidden_size, cfg.intermediate_size,
+        self.mlp = SwiGLU(cfg.hidden_size, cfg.intermediate_size,
                            cfg.init_std, cfg.out_std)
 
     def forward(self, x: jax.Array) -> jax.Array:
@@ -176,7 +172,7 @@ class EvaByte(Layer):
         enforce(cfg.recompute in ("none", "blocks"),
                 f"recompute {cfg.recompute!r}: none or blocks")
         self.cfg = cfg
-        init = _normal(cfg.init_std)
+        init = normal_init(cfg.init_std)
         self.create_parameter("embed", (cfg.vocab_size, cfg.hidden_size),
                               initializer=init)
         self.blocks = nn.LayerList(

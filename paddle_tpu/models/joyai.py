@@ -52,38 +52,31 @@ RMSNorm everywhere, no bias anywhere, untied embedding and head.
 Matmuls go through ``nn.functional.linear`` (the head: ``lm_head``) and
 ``parallel.moe.grouped_matmul``: ``Trainer(amp=True)`` means bf16 operands
 with float32 accumulation; norms, rotary, softmax and the router stay
-float32. Counters leave the forward in buffers: ``expert_counts``
-[layers, num_experts] (as routed, all experts), ``held_assignments``
-[layers], ``dispatch_rung`` [layers] (rows of the form that ran: the bounded
-buffer, or every held expert on every token), ``dispatch_rows_walked``
-[layers] (the rows that form's row movement passed over: whole chunks up to
-the buffer's last live row, ``parallel.moe.held_moe``),
-``tokens_dropped`` (held assignments less those the form that ran counted
-as computed: 0 unless the dispatch is at fault); the layer axis runs over the
-expert layers, the prediction module's last.
+float32. Counters leave the forward in buffers
+(``transformer.RoutingRecord``); the layer axis runs over the expert layers,
+the prediction module's last.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
-from typing import Dict, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from .. import nn
 from ..core.enforce import enforce, enforce_eq
-from ..core.profiler import RecordEvent
 from ..nn import functional as F
 from ..nn.layer import Layer
 from ..ops.flash_attention import flash_attention
-from ..parallel.moe import held_moe
+from .transformer import (HeldExperts, RoutingRecord, SwiGLU,
+                          _causal_attention, attention_impl, next_token_loss,
+                          normal_init, record_held, residual_out_std,
+                          rotary_pairs, routing_outputs)
 
 __all__ = ["JoyaiConfig", "JoyaiAttention", "JoyaiExperts", "JoyaiBlock",
-           "Joyai", "joyai_loss", "joyai_losses", "rotary_pairs",
-           "MTP_LOSS_WEIGHT"]
+           "Joyai", "joyai_loss", "joyai_losses", "MTP_LOSS_WEIGHT"]
 
 #: weight of the prediction module's loss (DeepSeek-V3 section 4.2: 0.3
 #: for most of pre-training)
@@ -130,59 +123,9 @@ class JoyaiConfig:
 
     @property
     def out_std(self) -> float:
-        """std of the projections that write into the residual stream (W_o
-        and every FFN's down matrix): ``init_std / sqrt(2 * layers)``, the
-        scaled initialisation of GPT-2 / Megatron-LM, so that the stream's
-        scale does not grow with depth. The rest is ``init_std``."""
-        return self.init_std / math.sqrt(
-            2 * (self.total_layers or self.num_layers))
-
-
-def _normal(std: float):
-    return lambda key, shape, dtype: jax.random.normal(key, shape, dtype) * std
-
-
-def rotary_pairs(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary position embedding on adjacent pairs, positions 0..L-1.
-    ``x`` [B, L, H, D]: pair (2i, 2i+1) of every head turns by
-    ``pos * theta^(-2i/D)``. Float32; the cos / sin tables are constants of
-    the traced step, computed in float64."""
-    L, D = x.shape[1], x.shape[-1]
-    inv_freq = float(theta) ** (-np.arange(0, D, 2, dtype=np.float64) / D)
-    angle = np.arange(L, dtype=np.float64)[:, None] * inv_freq[None, :]
-    cos = jnp.asarray(np.repeat(np.cos(angle), 2, axis=1),
-                      jnp.float32)[None, :, None]
-    sin = jnp.asarray(np.repeat(np.sin(angle), 2, axis=1),
-                      jnp.float32)[None, :, None]
-    pairs = x.reshape(*x.shape[:-1], D // 2, 2)
-    turned = jnp.stack([-pairs[..., 1], pairs[..., 0]], axis=-1)
-    return x * cos + turned.reshape(x.shape) * sin
-
-
-#: heads whose [L, L] scores are alive at once in the einsum attention
-_HEAD_GROUP = 8
-
-
-def _causal_attention(q, k, v):
-    """Einsum attention over the full score matrix, q.k and v at their own
-    widths: the off-TPU stand-in for the kernel, and the float32 side of
-    the benchmark's check. [B, L, H, .]. A group of heads at a time,
-    rebuilt in the backward pass: 32 heads' [4096, 4096] scores and
-    probabilities of six blocks would be 25 GB of residuals."""
-    L, H = q.shape[1], q.shape[2]
-    causal = jnp.tril(jnp.ones((L, L), bool))[None, None]
-    scale = float(q.shape[-1]) ** -0.5
-
-    @jax.checkpoint
-    def group(q, k, v):
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-        p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
-
-    return jnp.concatenate(
-        [group(q[:, :, g:g + _HEAD_GROUP], k[:, :, g:g + _HEAD_GROUP],
-               v[:, :, g:g + _HEAD_GROUP])
-         for g in range(0, H, _HEAD_GROUP)], axis=2)
+        """std of W_o and every FFN's down matrix (``residual_out_std``)."""
+        return residual_out_std(self.init_std,
+                                self.total_layers or self.num_layers)
 
 
 class JoyaiAttention(Layer):
@@ -193,7 +136,7 @@ class JoyaiAttention(Layer):
         super().__init__()
         self.cfg = cfg
         h, H = cfg.hidden_size, cfg.num_heads
-        init = _normal(cfg.init_std)
+        init = normal_init(cfg.init_std)
         qk = cfg.nope_dim + cfg.rope_dim
         self.create_parameter("w_qa", (h, cfg.q_rank), initializer=init)
         self.q_norm = nn.RMSNorm(cfg.q_rank, cfg.rms_eps)
@@ -205,7 +148,7 @@ class JoyaiAttention(Layer):
                                         H * (cfg.nope_dim + cfg.v_dim)),
                               initializer=init)
         self.create_parameter("w_o", (H * cfg.v_dim, h),
-                              initializer=_normal(cfg.out_std))
+                              initializer=normal_init(cfg.out_std))
 
     def forward(self, x: jax.Array) -> jax.Array:
         cfg = self.cfg
@@ -229,68 +172,21 @@ class JoyaiAttention(Layer):
             k = jnp.concatenate(
                 [kv[..., :nope], jnp.broadcast_to(k_rope, (B, L, H, rope))],
                 axis=-1)
-        impl = cfg.attn_impl
-        if impl == "auto":
-            impl = "flash" if jax.default_backend() == "tpu" else "einsum"
-        if impl == "flash":
+        if attention_impl(cfg.attn_impl) == "flash":
             out = flash_attention(q, k, v, causal=True)
         else:
             out = _causal_attention(q, k, v)
         return F.linear(out.reshape(B, L, H * cfg.v_dim), self.w_o)
 
 
-class _SwiGLU(Layer):
-    """``down(silu(gate(u)) * up(u))``, no bias."""
-
-    def __init__(self, hidden: int, width: int, std: float,
-                 out_std: float) -> None:
-        super().__init__()
-        init = _normal(std)
-        self.create_parameter("w_gate", (hidden, width), initializer=init)
-        self.create_parameter("w_up", (hidden, width), initializer=init)
-        self.create_parameter("w_down", (width, hidden),
-                              initializer=_normal(out_std))
-
-    def forward(self, u: jax.Array) -> jax.Array:
-        return F.linear(jax.nn.silu(F.linear(u, self.w_gate))
-                        * F.linear(u, self.w_up), self.w_down)
-
-
-class JoyaiExperts(Layer):
-    """Router over all ``num_experts``, the banks of the experts held, and
-    the shared expert; ``forward`` returns the layer's output and the
-    router's record (``parallel.moe.held_moe``)."""
+class JoyaiExperts(HeldExperts):
+    """Router over all ``num_experts`` with its ``e_score_correction_bias``,
+    the banks of the experts held, and the shared expert."""
 
     def __init__(self, cfg: JoyaiConfig) -> None:
-        super().__init__()
-        self.cfg = cfg
-        h, f, E = cfg.hidden_size, cfg.expert_size, cfg.num_experts
-        count = cfg.held[1]
-        init = _normal(cfg.init_std)
-        self.create_parameter("router_w", (h, E), initializer=init)
-        self.create_parameter("w_gate", (count, h, f), initializer=init)
-        self.create_parameter("w_up", (count, h, f), initializer=init)
-        self.create_parameter("w_down", (count, f, h),
-                              initializer=_normal(cfg.out_std))
-        self.shared = _SwiGLU(h, f * cfg.num_shared, cfg.init_std,
-                              cfg.out_std)
-        self.register_buffer("e_score_correction_bias",
-                             jnp.zeros((E,), jnp.float32))
-
-    def forward(self, x: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-        cfg = self.cfg
-        lead = x.shape[:-1]
-        bias = self._buffers["e_score_correction_bias"]
-        out, route = held_moe(
-            x.reshape(-1, x.shape[-1]), self.router_w, bias, self.w_gate,
-            self.w_up, self.w_down, cfg.experts_per_token, cfg.held,
-            cfg.routed_scale)
-        counts = route["counts"].astype(jnp.float32)
-        self._buffers["e_score_correction_bias"] = (
-            bias + cfg.bias_update_rate * jnp.sign(jnp.mean(counts) - counts))
-        with jax.named_scope("pt.moe.shared"):
-            out = out.reshape(*lead, out.shape[-1]) + self.shared(x)
-        return out, route
+        super().__init__(cfg, cfg.num_experts,
+                         bias="e_score_correction_bias",
+                         shared=cfg.expert_size * cfg.num_shared)
 
 
 class JoyaiBlock(Layer):
@@ -302,7 +198,7 @@ class JoyaiBlock(Layer):
         self.attn = JoyaiAttention(cfg)
         self.norm2 = nn.RMSNorm(cfg.hidden_size, cfg.rms_eps)
         if dense:
-            self.mlp = _SwiGLU(cfg.hidden_size, cfg.dense_size, cfg.init_std,
+            self.mlp = SwiGLU(cfg.hidden_size, cfg.dense_size, cfg.init_std,
                                cfg.out_std)
         else:
             self.moe = JoyaiExperts(cfg)
@@ -332,7 +228,7 @@ class JoyaiPredictor(Layer):
         self.norm_e = nn.RMSNorm(h, cfg.rms_eps)
         self.norm_h = nn.RMSNorm(h, cfg.rms_eps)
         self.create_parameter("w_eh", (2 * h, h),
-                              initializer=_normal(cfg.init_std))
+                              initializer=normal_init(cfg.init_std))
         self.block = JoyaiBlock(cfg, dense=False)
         self.norm_f = nn.RMSNorm(h, cfg.rms_eps)
 
@@ -364,12 +260,8 @@ class Joyai(Layer):
         enforce(cfg.experts_per_token <= cfg.num_experts,
                 "more experts a token than experts")
         enforce_eq(cfg.rope_dim % 2, 0, "rotary pairs")
-        first, count = cfg.held
-        enforce(0 <= first and count >= 1
-                and first + count <= cfg.num_experts,
-                f"held experts {cfg.held} outside 0..{cfg.num_experts}")
         self.cfg = cfg
-        init = _normal(cfg.init_std)
+        init = normal_init(cfg.init_std)
         self.create_parameter("embed", (cfg.vocab_size, cfg.hidden_size),
                               initializer=init)
         self.blocks = nn.LayerList([JoyaiBlock(cfg, i < cfg.first_dense)
@@ -378,24 +270,13 @@ class Joyai(Layer):
         self.mtp = JoyaiPredictor(cfg)
         self.create_parameter("head_w", (cfg.hidden_size, cfg.vocab_size),
                               initializer=init)
-        n = cfg.expert_layers
-        self.register_buffer("expert_counts",
-                             jnp.zeros((n, cfg.num_experts), jnp.int32))
-        self.register_buffer("held_assignments", jnp.zeros((n,), jnp.int32))
-        self.register_buffer("dispatch_rung", jnp.zeros((n,), jnp.int32))
-        self.register_buffer("dispatch_rows_walked",
-                             jnp.zeros((n,), jnp.int32))
-        self.register_buffer("tokens_dropped", jnp.zeros((), jnp.int32))
+        RoutingRecord.register(self, cfg.expert_layers, cfg.num_experts)
 
     def forward(self, ids: jax.Array, output_routing: bool = False):
         cfg = self.cfg
         enforce(ids.shape[-1] <= cfg.max_seq_len,
                 f"sequence of {ids.shape[-1]} over max_seq_len {cfg.max_seq_len}")
-        # what this layer holds, read off the configuration: one host span
-        # a trace (``profiler.host_spans()``), none on the step path
-        with RecordEvent("pt.moe.held", first=cfg.held[0], count=cfg.held[1],
-                         experts=cfg.num_experts):
-            pass
+        record_held(cfg.held, cfg.num_experts)
         with jax.named_scope("pt.embed"):
             x = jnp.take(self.embed, ids, axis=0)
             # the next token's embedding; the last position repeats its own
@@ -412,18 +293,10 @@ class Joyai(Layer):
         routes.append(route)
         with jax.named_scope("pt.head_loss"):
             logits_mtp = F.lm_head(y, self.head_w)
-        stack = lambda key: jnp.stack([r[key] for r in routes])
-        self._buffers["expert_counts"] = stack("counts")
-        self._buffers["held_assignments"] = stack(
-            "held_assignments").astype(jnp.int32)
-        self._buffers["dispatch_rung"] = stack("rung").astype(jnp.int32)
-        self._buffers["dispatch_rows_walked"] = stack("rows_walked").astype(
-            jnp.int32)
-        self._buffers["tokens_dropped"] = jnp.sum(stack("dropped")).astype(
-            jnp.int32)
+        RoutingRecord.store(self, routes)
         if output_routing:
-            return (logits, logits_mtp), {"logits": stack("logits"),
-                                          "index": stack("index")}
+            return (logits, logits_mtp), routing_outputs(
+                routes, ("logits", "index"))
         return logits, logits_mtp
 
 
@@ -435,8 +308,7 @@ def joyai_losses(outputs, labels: jax.Array) -> Tuple[jax.Array, jax.Array]:
     logits, logits_mtp = outputs
     ahead = jnp.concatenate(
         [labels[:, 1:], jnp.full_like(labels[:, :1], -1)], axis=1)
-    return (F.cross_entropy(logits, labels, ignore_index=-1),
-            F.cross_entropy(logits_mtp, ahead, ignore_index=-1))
+    return next_token_loss(logits, labels), next_token_loss(logits_mtp, ahead)
 
 
 def joyai_loss(outputs, labels: jax.Array) -> jax.Array:

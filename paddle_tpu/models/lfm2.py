@@ -20,7 +20,7 @@ transposed (tied).
   ``full_attention``: q = u W_q (``num_heads`` x ``head_dim``), k = u W_k,
   v = u W_v (``num_kv_heads`` x ``head_dim``); RMSNorm over each head of q
   and of k (one learned weight of ``head_dim`` each); rotary positions in
-  the half-split convention (``models.olmoe.rotary``), positions 0..L-1;
+  the half-split convention (``transformer.rotary``), positions 0..L-1;
   key-value head j serves the ``num_heads / num_kv_heads`` consecutive
   query heads from ``j * that``; causal softmax at ``1/sqrt(head_dim)``;
   W_o. The kernel (``ops/flash_attention``) takes one k and one v a query
@@ -35,24 +35,22 @@ transposed (tied).
   expert-parallel rank's part, nothing standing in for the others). No
   shared expert. After the forward ``b <- b + bias_update_rate *
   sign(mean(c) - c)``, ``c`` this step's assignment counts over all
-  experts: a buffer update the train step carries out (the rule
-  ``models/joyai.py`` runs; the source's own rule and rate are not
+  experts: a buffer update the train step carries out (DeepSeek-V3's
+  rule, ``transformer.HeldExperts``; the source's own rule and rate are not
   published with its configuration).
 
 Matmuls go through ``nn.functional.linear`` and
 ``parallel.moe.grouped_matmul``: ``Trainer(amp=True)`` means bf16 operands
 with float32 accumulation; norms, the convolution's gates and taps, rotary,
 softmax and the router stay float32. Counters leave the forward in buffers
-as ``models/joyai.py``'s do: ``expert_counts`` [expert layers,
-num_experts], ``held_assignments``, ``dispatch_rung``,
-``dispatch_rows_walked`` [expert layers], ``tokens_dropped``.
+(``transformer.RoutingRecord``, one row an expert layer).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,14 +60,13 @@ from ..core.enforce import enforce, enforce_eq
 from ..core.profiler import RecordEvent
 from ..nn import functional as F
 from ..nn.layer import Layer
-from ..ops.flash_attention import flash_attention
 from ..ops.short_conv import gated_short_conv
-from ..parallel.moe import held_moe
-from .joyai import _SwiGLU, _causal_attention, _normal
-from .olmoe import rotary
+from .transformer import (GroupedQueryAttention, HeldExperts, RoutingRecord,
+                          SwiGLU, next_token_loss, normal_init, record_held,
+                          residual_out_std, routing_outputs)
 
 __all__ = ["Lfm2Config", "Lfm2ShortConv", "Lfm2Attention", "Lfm2Experts",
-           "Lfm2Block", "Lfm2", "lfm2_loss", "LAYER_TYPES", "repeat_kv"]
+           "Lfm2Block", "Lfm2", "lfm2_loss", "LAYER_TYPES"]
 
 #: the published mixer of each of the 24 layers
 LAYER_TYPES = tuple(
@@ -117,11 +114,9 @@ class Lfm2Config:
 
     @property
     def out_std(self) -> float:
-        """std of the projections that write into the residual stream
-        (W_out, W_o and every FFN's down matrix): ``init_std / sqrt(2 *
-        layers)``, as ``JoyaiConfig.out_std``."""
-        return self.init_std / math.sqrt(
-            2 * (self.total_layers or self.num_layers))
+        """std of W_out, W_o and every FFN's down (``residual_out_std``)."""
+        return residual_out_std(self.init_std,
+                                self.total_layers or self.num_layers)
 
     def parameter_count(self) -> int:
         """Parameters of the model as configured (the held experts' banks,
@@ -139,18 +134,6 @@ class Lfm2Config:
         return total
 
 
-def repeat_kv(k: jax.Array, v: jax.Array,
-              heads: int) -> Tuple[jax.Array, jax.Array]:
-    """k and v [B, L, G, d] copied to ``heads`` heads, key-value head j to
-    the ``heads / G`` consecutive query heads from ``j * that``, under
-    ``pt.gqa.repeat``: the kernels take one k and one v a query head
-    (ROADMAP R8). The one repeat of the grouped-query models (this file's
-    8 -> 32, ``models/smallthinker.py``'s 4 -> 28)."""
-    with jax.named_scope("pt.gqa.repeat"):
-        groups = heads // k.shape[2]
-        return (jnp.repeat(k, groups, axis=2), jnp.repeat(v, groups, axis=2))
-
-
 class Lfm2ShortConv(Layer):
     """The gated short convolution between its two projections."""
 
@@ -159,12 +142,12 @@ class Lfm2ShortConv(Layer):
         h, K = cfg.hidden_size, cfg.conv_kernel
         bound = 1.0 / math.sqrt(K)
         self.create_parameter("w_in", (h, 3 * h),
-                              initializer=_normal(cfg.init_std))
+                              initializer=normal_init(cfg.init_std))
         self.create_parameter(
             "w_conv", (h, K), initializer=lambda key, shape, dtype:
             jax.random.uniform(key, shape, dtype, -bound, bound))
         self.create_parameter("w_out", (h, h),
-                              initializer=_normal(cfg.out_std))
+                              initializer=normal_init(cfg.out_std))
 
     def forward(self, u: jax.Array) -> jax.Array:
         with jax.named_scope("pt.conv.in"):
@@ -175,76 +158,21 @@ class Lfm2ShortConv(Layer):
             return F.linear(y, self.w_out)
 
 
-class Lfm2Attention(Layer):
+class Lfm2Attention(GroupedQueryAttention):
     """Causal grouped-query attention with a norm over each head of q and
     of k, and rotary positions."""
 
     def __init__(self, cfg: Lfm2Config) -> None:
-        super().__init__()
-        self.cfg = cfg
-        h, d = cfg.hidden_size, cfg.head_dim
-        init = _normal(cfg.init_std)
-        self.create_parameter("wq", (h, cfg.num_heads * d), initializer=init)
-        self.create_parameter("wk", (h, cfg.num_kv_heads * d),
-                              initializer=init)
-        self.create_parameter("wv", (h, cfg.num_kv_heads * d),
-                              initializer=init)
-        self.create_parameter("wo", (cfg.num_heads * d, h),
-                              initializer=_normal(cfg.out_std))
-        self.q_norm = nn.RMSNorm(d, cfg.rms_eps)
-        self.k_norm = nn.RMSNorm(d, cfg.rms_eps)
-
-    def forward(self, x: jax.Array) -> jax.Array:
-        cfg = self.cfg
-        B, L, _ = x.shape
-        H, G, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        with jax.named_scope("pt.gqa.qkv"):
-            q = self.q_norm(F.linear(x, self.wq).reshape(B, L, H, d))
-            k = self.k_norm(F.linear(x, self.wk).reshape(B, L, G, d))
-            v = F.linear(x, self.wv).reshape(B, L, G, d)
-        with jax.named_scope("pt.rope"):
-            q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
-        k, v = repeat_kv(k, v, H)
-        impl = cfg.attn_impl
-        if impl == "auto":
-            impl = "flash" if jax.default_backend() == "tpu" else "einsum"
-        if impl == "flash":
-            out = flash_attention(q, k, v, causal=True)
-        else:
-            out = _causal_attention(q, k, v)
-        return F.linear(out.reshape(B, L, H * d), self.wo)
+        super().__init__(cfg, qk_norm=True, rope=True, window=None)
 
 
-class Lfm2Experts(Layer):
-    """Router over all ``num_experts`` and the banks of the experts held;
-    ``forward`` returns the held experts' part and the router's record
-    (``parallel.moe.held_moe``)."""
+class Lfm2Experts(HeldExperts):
+    """Router over all ``num_experts`` with its ``expert_bias`` and the
+    banks of the experts held; no shared expert."""
 
     def __init__(self, cfg: Lfm2Config) -> None:
-        super().__init__()
-        self.cfg = cfg
-        h, f, E = cfg.hidden_size, cfg.expert_size, cfg.num_experts
-        count = cfg.held[1]
-        init = _normal(cfg.init_std)
-        self.create_parameter("router_w", (h, E), initializer=init)
-        self.create_parameter("w_gate", (count, h, f), initializer=init)
-        self.create_parameter("w_up", (count, h, f), initializer=init)
-        self.create_parameter("w_down", (count, f, h),
-                              initializer=_normal(cfg.out_std))
-        self.register_buffer("expert_bias", jnp.zeros((E,), jnp.float32))
-
-    def forward(self, x: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-        cfg = self.cfg
-        lead = x.shape[:-1]
-        bias = self._buffers["expert_bias"]
-        out, route = held_moe(
-            x.reshape(-1, x.shape[-1]), self.router_w, bias, self.w_gate,
-            self.w_up, self.w_down, cfg.experts_per_token, cfg.held,
-            cfg.routed_scale)
-        counts = route["counts"].astype(jnp.float32)
-        self._buffers["expert_bias"] = (
-            bias + cfg.bias_update_rate * jnp.sign(jnp.mean(counts) - counts))
-        return out.reshape(*lead, out.shape[-1]), route
+        super().__init__(cfg, cfg.num_experts, bias="expert_bias",
+                         shared=None)
 
 
 class Lfm2Block(Layer):
@@ -260,7 +188,7 @@ class Lfm2Block(Layer):
             self.attn = Lfm2Attention(cfg)
         self.norm_ffn = nn.RMSNorm(cfg.hidden_size, cfg.rms_eps)
         if dense:
-            self.mlp = _SwiGLU(cfg.hidden_size, cfg.dense_size, cfg.init_std,
+            self.mlp = SwiGLU(cfg.hidden_size, cfg.dense_size, cfg.init_std,
                                cfg.out_std)
         else:
             self.moe = Lfm2Experts(cfg)
@@ -304,25 +232,14 @@ class Lfm2(Layer):
         enforce_eq(cfg.head_dim % 2, 0, "rotary halves")
         enforce(cfg.experts_per_token <= cfg.num_experts,
                 "more experts a token than experts")
-        first, count = cfg.held
-        enforce(0 <= first and count >= 1
-                and first + count <= cfg.num_experts,
-                f"held experts {cfg.held} outside 0..{cfg.num_experts}")
         self.cfg = cfg
         self.create_parameter("embed", (cfg.vocab_size, cfg.hidden_size),
-                              initializer=_normal(cfg.init_std))
+                              initializer=normal_init(cfg.init_std))
         self.blocks = nn.LayerList(
             [Lfm2Block(cfg, kind, i < cfg.num_dense_layers)
              for i, kind in enumerate(cfg.layer_types)])
         self.norm_f = nn.RMSNorm(cfg.hidden_size, cfg.rms_eps)
-        n = cfg.expert_layers
-        self.register_buffer("expert_counts",
-                             jnp.zeros((n, cfg.num_experts), jnp.int32))
-        self.register_buffer("held_assignments", jnp.zeros((n,), jnp.int32))
-        self.register_buffer("dispatch_rung", jnp.zeros((n,), jnp.int32))
-        self.register_buffer("dispatch_rows_walked",
-                             jnp.zeros((n,), jnp.int32))
-        self.register_buffer("tokens_dropped", jnp.zeros((), jnp.int32))
+        RoutingRecord.register(self, cfg.expert_layers, cfg.num_experts)
 
     def forward(self, ids: jax.Array, output_routing: bool = False):
         cfg = self.cfg
@@ -337,9 +254,7 @@ class Lfm2(Layer):
                          dense=cfg.num_dense_layers,
                          experts=cfg.expert_layers):
             pass
-        with RecordEvent("pt.moe.held", first=cfg.held[0], count=cfg.held[1],
-                         experts=cfg.num_experts):
-            pass
+        record_held(cfg.held, cfg.num_experts)
         with jax.named_scope("pt.embed"):
             x = jnp.take(self.embed, ids, axis=0)
         routes = []
@@ -354,22 +269,11 @@ class Lfm2(Layer):
             # -> 13.870 GiB, 1% allowed; PERF.md section 6, PR 49) for a
             # tied head's 3 ms
             logits = F.linear(self.norm_f(x), self.embed.T)
-        stack = lambda key: jnp.stack([r[key] for r in routes])
-        self._buffers["expert_counts"] = stack("counts")
-        self._buffers["held_assignments"] = stack(
-            "held_assignments").astype(jnp.int32)
-        self._buffers["dispatch_rung"] = stack("rung").astype(jnp.int32)
-        self._buffers["dispatch_rows_walked"] = stack("rows_walked").astype(
-            jnp.int32)
-        self._buffers["tokens_dropped"] = jnp.sum(stack("dropped")).astype(
-            jnp.int32)
+        RoutingRecord.store(self, routes)
         if output_routing:
-            return logits, {"logits": stack("logits"),
-                            "index": stack("index")}
+            return logits, routing_outputs(routes, ("logits", "index"))
         return logits
 
 
-def lfm2_loss(logits: jax.Array, labels: jax.Array) -> jax.Array:
-    """``Trainer``'s ``loss_fn``: next-token cross-entropy, mean over the
-    positions (a label of -1 is no position)."""
-    return F.cross_entropy(logits, labels, ignore_index=-1)
+#: ``Trainer``'s ``loss_fn``
+lfm2_loss = next_token_loss

@@ -34,7 +34,6 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from .. import nn
 from ..core.enforce import enforce, enforce_eq
@@ -43,9 +42,11 @@ from ..nn.layer import Layer
 from ..ops.flash_attention import flash_attention
 from ..parallel.moe import dropless_moe
 from ..parallel.ring_attention import local_attention
+from .transformer import (attention_impl, normal_init, rotary,
+                          routing_outputs, stack_routes)
 
 __all__ = ["OlmoeConfig", "OlmoeAttention", "OlmoeExperts", "OlmoeBlock",
-           "Olmoe", "rotary"]
+           "Olmoe"]
 
 
 @dataclasses.dataclass
@@ -71,25 +72,6 @@ class OlmoeConfig:
         return self.hidden_size // self.num_heads
 
 
-def _normal(std: float):
-    return lambda key, shape, dtype: jax.random.normal(key, shape, dtype) * std
-
-
-def rotary(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary position embedding, rotate-half form, positions 0..L-1.
-    ``x`` [B, L, H, D]: pair (i, i + D/2) of every head turns by
-    ``pos * theta^(-2i/D)``. Float32; the cos / sin tables are constants
-    of the traced step, computed in float64 — a float32 angle at position
-    4095 is already off by 2e-4 rad."""
-    L, D = x.shape[1], x.shape[-1]
-    inv_freq = float(theta) ** (-np.arange(0, D, 2, dtype=np.float64) / D)
-    angle = np.arange(L, dtype=np.float64)[:, None] * inv_freq[None, :]
-    cos = jnp.asarray(np.tile(np.cos(angle), 2), jnp.float32)[None, :, None]
-    sin = jnp.asarray(np.tile(np.sin(angle), 2), jnp.float32)[None, :, None]
-    x1, x2 = x[..., :D // 2], x[..., D // 2:]
-    return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
-
-
 class OlmoeAttention(Layer):
     """Causal multi-head attention with QK-norm and rotary positions."""
 
@@ -99,7 +81,7 @@ class OlmoeAttention(Layer):
         h = cfg.hidden_size
         for name in ("wq", "wk", "wv", "wo"):
             self.create_parameter(name, (h, h),
-                                  initializer=_normal(cfg.init_std))
+                                  initializer=normal_init(cfg.init_std))
         self.q_norm = nn.RMSNorm(h, cfg.rms_eps)
         self.k_norm = nn.RMSNorm(h, cfg.rms_eps)
 
@@ -112,10 +94,7 @@ class OlmoeAttention(Layer):
         with jax.named_scope("pt.rope"):
             q = rotary(self.q_norm(q).reshape(heads), cfg.rope_theta)
             k = rotary(self.k_norm(k).reshape(heads), cfg.rope_theta)
-        impl = cfg.attn_impl
-        if impl == "auto":
-            impl = "flash" if jax.default_backend() == "tpu" else "einsum"
-        if impl == "flash":
+        if attention_impl(cfg.attn_impl) == "flash":
             out = flash_attention(q, k, v, causal=True)
         else:
             out = local_attention(q, k, v, causal=True)
@@ -130,7 +109,7 @@ class OlmoeExperts(Layer):
         super().__init__()
         self.cfg = cfg
         h, f, E = cfg.hidden_size, cfg.expert_size, cfg.num_experts
-        init = _normal(cfg.init_std)
+        init = normal_init(cfg.init_std)
         self.create_parameter("router_w", (h, E), initializer=init)
         self.create_parameter("w_gate", (E, h, f), initializer=init)
         self.create_parameter("w_up", (E, h, f), initializer=init)
@@ -174,7 +153,7 @@ class Olmoe(Layer):
         enforce(cfg.experts_per_token <= cfg.num_experts,
                 "more experts a token than experts")
         self.cfg = cfg
-        init = _normal(cfg.init_std)
+        init = normal_init(cfg.init_std)
         self.create_parameter("embed", (cfg.vocab_size, cfg.hidden_size),
                               initializer=init)
         self.blocks = nn.LayerList([OlmoeBlock(cfg)
@@ -197,12 +176,12 @@ class Olmoe(Layer):
         for block in self.blocks:
             x, route = block(x)
             routes.append(route)
-        stack = lambda key: jnp.stack([r[key] for r in routes])
-        self._buffers["aux_loss"] = (cfg.lb_coef * jnp.sum(stack("lb"))
-                                     + cfg.z_coef * jnp.sum(stack("z")))
-        self._buffers["expert_counts"] = stack("counts")
-        self._buffers["tokens_dropped"] = jnp.sum(stack("dropped")).astype(
-            jnp.int32)
+        self._buffers["aux_loss"] = (
+            cfg.lb_coef * jnp.sum(stack_routes(routes, "lb"))
+            + cfg.z_coef * jnp.sum(stack_routes(routes, "z")))
+        self._buffers["expert_counts"] = stack_routes(routes, "counts")
+        self._buffers["tokens_dropped"] = jnp.sum(
+            stack_routes(routes, "dropped")).astype(jnp.int32)
         with jax.named_scope("pt.head_loss"):
             # ``linear``, not ``lm_head``: the stated backward pays only
             # with its bf16 cotangent as a buffer, here 786 MiB beside the
@@ -212,5 +191,5 @@ class Olmoe(Layer):
             # -1.27% (PERF.md section 6, PR 49)
             logits = F.linear(self.norm_f(x), self.head_w)
         if output_routing:
-            return logits, {"logits": stack("logits"), "index": stack("index")}
+            return logits, routing_outputs(routes, ("logits", "index"))
         return logits

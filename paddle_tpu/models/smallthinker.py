@@ -28,12 +28,12 @@ head are two matrices (untied).
 - ``Attn_l``: ``num_heads`` query / ``num_kv_heads`` key-value heads of
   ``head_dim``, no QK-norm; key-value head j serves the ``num_heads /
   num_kv_heads`` consecutive query heads from ``j * that`` (repeated to
-  ``num_heads`` before the kernel, ``models.lfm2.repeat_kv``).
+  ``num_heads`` before the kernel, ``transformer.repeat_kv``).
   ``sliding_window_layout[l]`` 1: query i sees the keys ``i -
   sliding_window_size < j <= i`` (``ops.flash_attention(window=...)``: the
   pair list leaves out what lies below the band); 0: every ``j <= i``.
   ``rope_layout[l]`` 1: rotary positions on q and k, half-split
-  (``models.olmoe.rotary``); 0: NO positional encoding at all.
+  (``transformer.rotary``); 0: NO positional encoding at all.
 - The sum is over the chosen experts THAT THIS LAYER HOLDS (``cfg.held =
   (first, count)``: one expert-parallel rank's part, nothing standing in
   for the others).
@@ -41,31 +41,27 @@ head are two matrices (untied).
 Matmuls go through ``nn.functional.linear`` (the head: ``lm_head``) and
 ``parallel.moe.grouped_matmul``: ``Trainer(amp=True)`` means bf16 operands
 with float32 accumulation; norms, rotary, softmax and the router stay
-float32. Counters leave the forward in buffers as ``models/lfm2.py``'s do:
-``expert_counts`` [layers, router_width], ``held_assignments``,
-``dispatch_rung``, ``dispatch_rows_walked`` [layers], ``tokens_dropped``.
+float32. Counters leave the forward in buffers
+(``transformer.RoutingRecord``, one row a layer).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from .. import nn
 from ..core.enforce import enforce, enforce_eq
 from ..core.profiler import RecordEvent
 from ..nn import functional as F
 from ..nn.layer import Layer
-from ..ops.flash_attention import flash_attention
 from ..parallel.moe import held_moe, router_logits, topk_route
-from .joyai import _normal
-from .lfm2 import repeat_kv
-from .olmoe import rotary
+from .transformer import (GroupedQueryAttention, HeldExperts, RoutingRecord,
+                          next_token_loss, normal_init, record_held,
+                          residual_out_std, routing_outputs)
 
 __all__ = ["SmallThinkerConfig", "SmallThinkerAttention",
            "SmallThinkerExperts", "SmallThinkerBlock", "SmallThinker",
@@ -74,10 +70,6 @@ __all__ = ["SmallThinkerConfig", "SmallThinkerAttention",
 #: the published ``sliding_window_layout`` and ``rope_layout`` alike: every
 #: fourth layer, from layer 0, is global and position-free
 LAYOUT = tuple(int(i % 4 != 0) for i in range(52))
-
-#: queries a block, and heads a group, of the einsum attention
-_QUERY_BLOCK = 2048
-_HEAD_GROUP = 7
 
 
 @dataclasses.dataclass
@@ -124,11 +116,9 @@ class SmallThinkerConfig:
 
     @property
     def out_std(self) -> float:
-        """std of the projections that write into the residual stream (W_o
-        and every expert's down matrix): ``init_std / sqrt(2 * layers)``,
-        as ``Lfm2Config.out_std``."""
-        return self.init_std / math.sqrt(
-            2 * (self.total_layers or self.num_layers))
+        """std of W_o and every expert's down (``residual_out_std``)."""
+        return residual_out_std(self.init_std,
+                                self.total_layers or self.num_layers)
 
     def parameter_count(self) -> int:
         """Parameters of the model as configured (the held experts' banks,
@@ -141,99 +131,23 @@ class SmallThinkerConfig:
                 + 2 * self.vocab_size * h + h)
 
 
-def _banded_attention(q, k, v, window):
-    """Einsum attention, causal and under ``window`` banded: the off-TPU
-    stand-in for the kernel, and the float32 side of the benchmark's
-    check. [B, L, H, d]. A group of heads and a block of queries at a
-    time, rebuilt in the backward pass: seven heads' [2048, 16384] scores
-    are 0.9 GB, 28 heads' [16384, 16384] would be 30."""
-    B, L, H, d = q.shape
-    scale = float(d) ** -0.5
-    bq = _QUERY_BLOCK if L % _QUERY_BLOCK == 0 else L
-    cols = jnp.arange(L)[None, :]
-
-    def group(q, k, v):
-        g = q.shape[2]
-
-        @jax.checkpoint
-        def block(args):
-            qb, start = args
-            rows = start + jnp.arange(bq)[:, None]
-            mask = cols <= rows
-            if window is not None:
-                mask = mask & (cols > rows - window)
-            s = jnp.einsum("bqhd,bkhd->bhqk", qb, k) * scale
-            p = jax.nn.softmax(jnp.where(mask[None, None], s, -jnp.inf),
-                               axis=-1)
-            return jnp.einsum("bhqk,bkhd->bqhd", p, v)
-
-        blocks = jnp.moveaxis(q.reshape(B, L // bq, bq, g, d), 1, 0)
-        out = lax.map(block, (blocks, jnp.arange(L // bq) * bq))
-        return jnp.moveaxis(out, 0, 1).reshape(B, L, g, d)
-
-    return jnp.concatenate(
-        [group(q[:, :, g:g + _HEAD_GROUP], k[:, :, g:g + _HEAD_GROUP],
-               v[:, :, g:g + _HEAD_GROUP])
-         for g in range(0, H, _HEAD_GROUP)], axis=2)
-
-
-class SmallThinkerAttention(Layer):
-    """Causal grouped-query attention: under ``window`` a sliding one,
-    with ``rope`` rotary positions, else none."""
+class SmallThinkerAttention(GroupedQueryAttention):
+    """Causal grouped-query attention without QK-norm: under ``window`` a
+    sliding one, with ``rope`` rotary positions, else none."""
 
     def __init__(self, cfg: SmallThinkerConfig, window: Optional[int],
                  rope: bool) -> None:
-        super().__init__()
-        self.cfg, self.window, self.rope = cfg, window, rope
-        h, d = cfg.hidden_size, cfg.head_dim
-        init = _normal(cfg.init_std)
-        self.create_parameter("wq", (h, cfg.num_heads * d), initializer=init)
-        self.create_parameter("wk", (h, cfg.num_kv_heads * d),
-                              initializer=init)
-        self.create_parameter("wv", (h, cfg.num_kv_heads * d),
-                              initializer=init)
-        self.create_parameter("wo", (cfg.num_heads * d, h),
-                              initializer=_normal(cfg.out_std))
-
-    def forward(self, x: jax.Array) -> jax.Array:
-        cfg = self.cfg
-        B, L, _ = x.shape
-        H, G, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        with jax.named_scope("pt.gqa.qkv"):
-            q = F.linear(x, self.wq).reshape(B, L, H, d)
-            k = F.linear(x, self.wk).reshape(B, L, G, d)
-            v = F.linear(x, self.wv).reshape(B, L, G, d)
-        if self.rope:
-            with jax.named_scope("pt.rope"):
-                q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
-        k, v = repeat_kv(k, v, H)
-        impl = cfg.attn_impl
-        if impl == "auto":
-            impl = "flash" if jax.default_backend() == "tpu" else "einsum"
-        if impl == "flash":
-            out = flash_attention(q, k, v, causal=True, window=self.window)
-        else:
-            out = _banded_attention(q, k, v, self.window)
-        return F.linear(out.reshape(B, L, H * d), self.wo)
+        super().__init__(cfg, qk_norm=False, rope=rope, window=window)
 
 
-class SmallThinkerExperts(Layer):
-    """Router over all ``router_width`` and the banks of the experts held.
-    ``route`` is made from the layer's input; ``forward`` takes it with
-    the rows to dispatch and returns the held experts' part and the
-    router's record (``parallel.moe.held_moe``)."""
+class SmallThinkerExperts(HeldExperts):
+    """Router over all ``router_width``, no bias, and the banks of the
+    ReLU-gated experts held. ``route`` is made from the layer's input;
+    ``forward`` takes it with the rows to dispatch and returns the held
+    experts' part and the router's record (``parallel.moe.held_moe``)."""
 
     def __init__(self, cfg: SmallThinkerConfig) -> None:
-        super().__init__()
-        self.cfg = cfg
-        h, f, count = cfg.hidden_size, cfg.expert_size, cfg.held[1]
-        init = _normal(cfg.init_std)
-        self.create_parameter("router_w", (h, cfg.router_width),
-                              initializer=init)
-        self.create_parameter("w_gate", (count, h, f), initializer=init)
-        self.create_parameter("w_up", (count, h, f), initializer=init)
-        self.create_parameter("w_down", (count, f, h),
-                              initializer=_normal(cfg.out_std))
+        super().__init__(cfg, cfg.router_width, bias=None, shared=None)
 
     def route(self, x: jax.Array) -> Dict[str, jax.Array]:
         with jax.named_scope("pt.moe.route"):
@@ -245,6 +159,8 @@ class SmallThinkerExperts(Layer):
 
     def forward(self, u: jax.Array, route: Dict[str, jax.Array]
                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        # through THIS module's ``held_moe``: the benchmark's fault control
+        # plants its wrong gate there (benchmarks/tests/swa_fault_control.py)
         cfg = self.cfg
         out, route = held_moe(
             u.reshape(-1, u.shape[-1]), None, None, self.w_gate, self.w_up,
@@ -306,27 +222,16 @@ class SmallThinker(Layer):
                 f"recompute {cfg.recompute!r}: none, experts or blocks")
         enforce(cfg.experts_per_token <= cfg.router_width,
                 "more experts a token than experts")
-        first, count = cfg.held
-        enforce(0 <= first and count >= 1
-                and first + count <= cfg.router_width,
-                f"held experts {cfg.held} outside 0..{cfg.router_width}")
         self.cfg = cfg
         self.create_parameter("embed", (cfg.vocab_size, cfg.hidden_size),
-                              initializer=_normal(cfg.init_std))
+                              initializer=normal_init(cfg.init_std))
         self.blocks = nn.LayerList(
             [SmallThinkerBlock(cfg, window, rope)
              for window, rope in cfg.layer_kinds])
         self.norm_f = nn.RMSNorm(cfg.hidden_size, cfg.rms_eps)
         self.create_parameter("head", (cfg.hidden_size, cfg.vocab_size),
-                              initializer=_normal(cfg.init_std))
-        n = cfg.num_layers
-        self.register_buffer("expert_counts",
-                             jnp.zeros((n, cfg.router_width), jnp.int32))
-        self.register_buffer("held_assignments", jnp.zeros((n,), jnp.int32))
-        self.register_buffer("dispatch_rung", jnp.zeros((n,), jnp.int32))
-        self.register_buffer("dispatch_rows_walked",
-                             jnp.zeros((n,), jnp.int32))
-        self.register_buffer("tokens_dropped", jnp.zeros((), jnp.int32))
+                              initializer=normal_init(cfg.init_std))
+        RoutingRecord.register(self, cfg.num_layers, cfg.router_width)
 
     def forward(self, ids: jax.Array, output_routing: bool = False):
         cfg = self.cfg
@@ -341,9 +246,7 @@ class SmallThinker(Layer):
                          experts=cfg.num_layers,
                          window_size=cfg.sliding_window_size):
             pass
-        with RecordEvent("pt.moe.held", first=cfg.held[0], count=cfg.held[1],
-                         experts=cfg.router_width):
-            pass
+        record_held(cfg.held, cfg.router_width)
         with jax.named_scope("pt.embed"):
             x = jnp.take(self.embed, ids, axis=0)
         routes = []
@@ -358,23 +261,12 @@ class SmallThinker(Layer):
             routes.append(route)
         with jax.named_scope("pt.head_loss"):
             logits = F.lm_head(self.norm_f(x), self.head)
-        stack = lambda key: jnp.stack([r[key] for r in routes])
-        self._buffers["expert_counts"] = stack("counts")
-        self._buffers["held_assignments"] = stack(
-            "held_assignments").astype(jnp.int32)
-        self._buffers["dispatch_rung"] = stack("rung").astype(jnp.int32)
-        self._buffers["dispatch_rows_walked"] = stack("rows_walked").astype(
-            jnp.int32)
-        self._buffers["tokens_dropped"] = jnp.sum(stack("dropped")).astype(
-            jnp.int32)
+        RoutingRecord.store(self, routes)
         if output_routing:
-            return logits, {"logits": stack("logits"),
-                            "index": stack("index"), "lb": stack("lb")}
+            return logits, routing_outputs(routes, ("logits", "index", "lb"))
         return logits
 
 
-def smallthinker_loss(logits: jax.Array, labels: jax.Array) -> jax.Array:
-    """``Trainer``'s ``loss_fn``: next-token cross-entropy ALONE, mean over
-    the positions (a label of -1 is no position); the published
-    configuration names no auxiliary loss."""
-    return F.cross_entropy(logits, labels, ignore_index=-1)
+#: ``Trainer``'s ``loss_fn``: next-token cross-entropy ALONE; the published
+#: configuration names no auxiliary loss
+smallthinker_loss = next_token_loss

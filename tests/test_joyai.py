@@ -22,8 +22,8 @@ from paddle_tpu import nn, optimizer
 from paddle_tpu.core.enforce import EnforceNotMet
 from paddle_tpu.executor import Trainer, make_train_step
 from paddle_tpu.models import Joyai, JoyaiConfig, joyai_loss
-from paddle_tpu.models.joyai import (MTP_LOSS_WEIGHT, joyai_losses,
-                                     rotary_pairs)
+from paddle_tpu.models.joyai import MTP_LOSS_WEIGHT, joyai_losses
+from paddle_tpu.models.transformer import rotary_pairs
 from paddle_tpu.ops.flash_attention import flash_attention
 from paddle_tpu.parallel import moe
 
@@ -407,7 +407,7 @@ def test_rotary_pairs_closed_form_and_the_sources_deinterleave():
     source de-interleaves (2i -> i, 2i+1 -> D/2+i) and rotates halves:
     that is this rotation followed by one fixed permutation, so q.k is the
     same for every pair of positions."""
-    from paddle_tpu.models.olmoe import rotary as rotate_half
+    from paddle_tpu.models.transformer import rotary as rotate_half
 
     r = np.random.default_rng(0)
     D, theta = 8, 32000000.0

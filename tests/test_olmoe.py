@@ -19,7 +19,7 @@ import paddle_tpu as pt
 from paddle_tpu import nn, optimizer
 from paddle_tpu.executor import Trainer, auxiliary_loss, make_train_step
 from paddle_tpu.models import Olmoe, OlmoeConfig
-from paddle_tpu.models.olmoe import rotary
+from paddle_tpu.models.transformer import rotary
 from paddle_tpu.ops.flash_attention import flash_attention
 from paddle_tpu.parallel import moe
 from paddle_tpu.parallel.ring_attention import local_attention

@@ -28,7 +28,7 @@ from paddle_tpu.core.enforce import EnforceNotMet
 from paddle_tpu.executor import Trainer, make_train_step
 from paddle_tpu.models import (SmallThinker, SmallThinkerConfig,
                                smallthinker_loss)
-from paddle_tpu.models import smallthinker as st
+from paddle_tpu.models.transformer import _banded_attention, repeat_kv
 from paddle_tpu.ops.flash_attention import flash_attention
 from paddle_tpu.parallel import moe
 
@@ -218,8 +218,8 @@ def test_attention_layer_flash_and_einsum_agree(window):
     q = jnp.asarray(r.normal(size=(2, 32, 4, 8)), jnp.float32)
     k, v = (jnp.asarray(r.normal(size=(2, 32, 2, 8)), jnp.float32)
             for _ in range(2))
-    k, v = st.repeat_kv(k, v, 4)
-    want = st._banded_attention(q, k, v, window)
+    k, v = repeat_kv(k, v, 4)
+    want = _banded_attention(q, k, v, window)
     got = flash_attention(q, k, v, causal=True, window=window, block_q=16,
                           block_k=16, precision="highest")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
