@@ -86,6 +86,11 @@ class Sizes:
     eva_seq: int = 4096
     eva_window: int = 2048
     eva_chunk: int = 16
+    # one hyper-connected sublayer at Xing4.0's shape: 4 residual streams
+    # of 3584 a token over 4096 tokens, 20 Sinkhorn steps
+    hc_streams: int = 4
+    hc_hidden: int = 3584
+    hc_seq: int = 4096
 
 
 def make_ctr_dataset(sz: Sizes, n_batches: int, seed: int):
@@ -474,6 +479,55 @@ def leg_dense(sz: Sizes) -> Dict:
     eva_fast = _max_diff(fast, ref, relative=True)
     assert eva_exact <= 5e-4, f"EVA flash(highest) vs einsum: {eva_exact}"
     assert eva_fast <= 3e-2, f"EVA flash(default) vs einsum: {eva_fast}"
+
+    # one hyper-connected sublayer (``transformer.HyperConnected`` round a
+    # tanh of the collected stream), forward and backward to the streams
+    # and to phi, b and alpha, against the same equations as two einsums
+    # with the norm made before the projection: the mappings are float32
+    # on the chip too (the projection at precision highest), so the bound
+    # is the float32 one.
+    from paddle_tpu.models.transformer import HyperConnected
+    from paddle_tpu.ops.hyper_connection import sinkhorn
+
+    class _Streams:
+        hc_mult, hidden_size = sz.hc_streams, sz.hc_hidden
+        hc_sinkhorn_iters, hc_eps, hc_clamp = 20, 1e-6, (-30.0, 30.0)
+        rms_eps, init_std = 1e-6, 0.006
+
+    n, C = sz.hc_streams, sz.hc_hidden
+    layer = HyperConnected(_Streams)
+    xs = jnp.asarray(rng.normal(size=(1, sz.hc_seq, n, C)), jnp.float32)
+    hc_params = (layer.phi, layer.b, jnp.full((3,), 0.3, jnp.float32))
+
+    def through_the_layer(xs, params):
+        out, _, err = nn.functional_call(
+            layer, {"params": dict(zip(("phi", "b", "alpha"), params)),
+                    "buffers": {}}, xs, jnp.tanh)[0]
+        return jnp.sum(out ** 2), err
+
+    def by_einsum(xs, params):
+        phi, b, alpha = params
+        flat = xs.reshape(1, sz.hc_seq, n * C)
+        z = (flat / jnp.sqrt(jnp.mean(flat * flat, -1, keepdims=True)
+                             + 1e-6)) @ phi
+        h_pre = jax.nn.sigmoid(alpha[0] * z[..., :n] + b[:n])
+        h_post = 2 * jax.nn.sigmoid(alpha[1] * z[..., n:2 * n] + b[n:2 * n])
+        h_res = sinkhorn(jnp.clip(
+            alpha[2] * z[..., 2 * n:].reshape(1, sz.hc_seq, n, n)
+            + b[2 * n:].reshape(n, n), -30.0, 30.0), 20, 1e-6)
+        y = jnp.tanh(jnp.einsum("bln,blnc->blc", h_pre, xs))
+        out = jnp.einsum("blij,bljc->blic", h_res, xs) \
+            + h_post[..., None] * y[:, :, None, :]
+        return jnp.sum(out ** 2), jnp.zeros(())
+
+    both = lambda f: jax.jit(jax.value_and_grad(f, argnums=(0, 1),
+                                                has_aux=True))
+    with jax.default_matmul_precision("highest"):
+        (want, _), want_grads = both(by_einsum)(xs, hc_params)
+    (got, hc_err), got_grads = both(through_the_layer)(xs, hc_params)
+    hc_rel = _max_diff((got, got_grads), (want, want_grads), relative=True)
+    assert hc_rel <= 5e-4, f"hyper-connected sublayer vs einsum: {hc_rel}"
+    assert float(hc_err) <= 1e-4, f"H_res off doubly stochastic: {hc_err}"
     return {"loss": [round(l, 4) for l in losses],
             "attn_impl": "flash" if on_tpu else "einsum",
             "mosaic_calls": mosaic_calls,
@@ -481,7 +535,8 @@ def leg_dense(sz: Sizes) -> Dict:
                               "window_highest": win_exact,
                               "window_default": win_fast,
                               "eva_highest": eva_exact,
-                              "eva_default": eva_fast}}
+                              "eva_default": eva_fast},
+            "hc_rel_err": hc_rel, "hc_res_err": float(hc_err)}
 
 
 # ---------------------------------------------------------------------------

@@ -72,6 +72,7 @@ DEVICE_SCOPES = (
     "pt.gqa.qkv", "pt.gqa.repeat",
     "pt.attn.full", "pt.attn.window",
     "pt.eva.qkv", "pt.eva.prep",
+    "pt.hc.map", "pt.hc.collect", "pt.hc.scatter",
 )
 
 #: completed spans kept in memory (newest win): a pass is about a dozen

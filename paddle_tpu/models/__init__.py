@@ -1,6 +1,8 @@
 from .lenet import LeNet
 from .ernie import Ernie, ErnieConfig
 from .olmoe import Olmoe, OlmoeConfig
+# Xing4.0-29B-A4B is ``Joyai`` too: ``JoyaiConfig(hc_mult=4, rope_scaling=
+# {"type": "yarn", ...}, num_mtp=0)`` with ``transformer.next_token_loss``
 from .joyai import Joyai, JoyaiConfig, joyai_loss
 from .lfm2 import Lfm2, Lfm2Config, lfm2_loss
 from .smallthinker import (SmallThinker, SmallThinkerConfig,

@@ -49,6 +49,37 @@ RMSNorm everywhere, no bias anywhere, untied embedding and head.
   shapes stay static. ``forward(ids)`` returns ``(logits, logits')``;
   ``joyai_loss`` is the loss over both.
 
+Xing4.0-29B-A4B (``XingChen-AGI/Xing4.0-29B-A4B``, ``model_type``
+``xing4_0``) is this file under other keys — the same DeepSeek-V3 block at
+3584 / 9216 / 1024, two leading dense layers, 4 of 64 experts — with three
+things JoyAI has not, each an option that leaves JoyAI's program what it is:
+
+- ``hc_mult`` > 1: the state between sublayers is ``hc_mult`` residual
+  streams a token (manifold-constrained hyper-connections, arXiv:2512.24880;
+  ``transformer.HyperConnected``, ``ops/hyper_connection.py``). Entry:
+  every stream is ``embed[ids]``; each sublayer reads ``u = H_pre X``,
+  computes ``y = F(N(u))`` with the norm, attention and feed-forward above,
+  and leaves ``X' = H_res X + H_postᵀ y``; exit: ``N_f(sum_i X_i) W_head``.
+  The streams are float32 between blocks, the step's activation dtype
+  (bfloat16 streams would let two sequences fit: PERF.md section 7), the
+  mappings float32 whatever ``amp`` says.
+- ``rope_scaling`` of ``type`` ``yarn`` (arXiv:2309.00071, as DeepSeek-V3's
+  public rotary embedding computes it): blended frequencies
+  (``transformer.yarn_frequencies``) at EVERY length, and the softmax scale
+  ``1/sqrt(nope + rope)`` times ``m²``, ``m = yarn_mscale(factor,
+  mscale_all_dim)`` — applied to q where it is assembled (q.k is bilinear),
+  so the kernels and the einsum stand-in keep their own ``1/sqrt(D)``.
+- ``num_mtp`` 0: no prediction module; ``forward(ids)`` returns ``logits``
+  alone and the loss is ``transformer.next_token_loss``. How a module meets
+  several streams is in no source: ``hc_mult`` > 1 wants ``num_mtp`` 0.
+
+``recompute`` ``"blocks"`` rebuilds every block in the backward pass
+(``jax.checkpoint``); a rebuilt block writes no buffer, so its route, its
+moved router bias and its ``H_res`` error leave it as outputs and the model
+stores them, once (``HeldExperts``' text). Why one file and not a model of
+its own: a model file imports no other model's (``tests/test_layering.py``)
+and there is to be ONE latent attention and one expert layer.
+
 Matmuls go through ``nn.functional.linear`` (the head: ``lm_head``) and
 ``parallel.moe.grouped_matmul``: ``Trainer(amp=True)`` means bf16 operands
 with float32 accumulation; norms, rotary, softmax and the router stay
@@ -60,7 +91,7 @@ the prediction module's last.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -70,10 +101,11 @@ from ..core.enforce import enforce, enforce_eq
 from ..nn import functional as F
 from ..nn.layer import Layer
 from ..ops.flash_attention import flash_attention
-from .transformer import (HeldExperts, RoutingRecord, SwiGLU,
+from ..core.profiler import RecordEvent
+from .transformer import (HeldExperts, HyperConnected, RoutingRecord, SwiGLU,
                           _causal_attention, attention_impl, next_token_loss,
                           normal_init, record_held, residual_out_std,
-                          rotary_pairs, routing_outputs)
+                          rotary_pairs, routing_outputs, yarn_mscale)
 
 __all__ = ["JoyaiConfig", "JoyaiAttention", "JoyaiExperts", "JoyaiBlock",
            "Joyai", "joyai_loss", "joyai_losses", "MTP_LOSS_WEIGHT"]
@@ -116,10 +148,53 @@ class JoyaiConfig:
     total_layers: Optional[int] = None
     # attention impl: "auto" = Pallas flash kernel on TPU, einsum elsewhere
     attn_impl: str = "auto"
+    # ``rope_scaling``: None, or a dict of ``type`` "yarn" with ``factor``,
+    # ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    # ``mscale``, ``mscale_all_dim``
+    rope_scaling: Optional[Dict[str, Any]] = None
+    # residual streams a token (``hc_mult``): 1 = the plain block
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: Tuple[float, float] = (-30.0, 30.0)   # ``mhc_h_res_clamp_*``
+    # what the backward pass rebuilds (``jax.checkpoint``): "none", or
+    # "blocks": every block whole
+    recompute: str = "none"
 
     @property
     def expert_layers(self) -> int:
         return self.num_layers - self.first_dense + self.num_mtp
+
+    @property
+    def softmax_gain(self) -> float:
+        """What the scores are multiplied by beside ``1/sqrt(nope +
+        rope)``: YaRN's ``m²``, 1 without scaling."""
+        if self.rope_scaling is None:
+            return 1.0
+        return yarn_mscale(self.rope_scaling["factor"],
+                           self.rope_scaling["mscale_all_dim"]) ** 2
+
+    def parameter_count(self) -> int:
+        """Parameters of the model as configured (the held experts' banks,
+        not the absent ones'), from the shapes alone."""
+        h, H = self.hidden_size, self.num_heads
+        attn = (h * self.q_rank + self.q_rank
+                + self.q_rank * H * (self.nope_dim + self.rope_dim)
+                + h * (self.kv_rank + self.rope_dim) + self.kv_rank
+                + self.kv_rank * H * (self.nope_dim + self.v_dim)
+                + H * self.v_dim * h)
+        n = self.hc_mult
+        paths = 0 if n == 1 else 2 * (n * h * (2 * n + n * n)
+                                      + 2 * n + n * n + 3)
+        block = attn + 2 * h + paths
+        expert = 3 * h * self.expert_size
+        dense = block + 3 * h * self.dense_size
+        experts = block + h * self.num_experts \
+            + (self.num_shared + self.held[1]) * expert
+        return (2 * self.vocab_size * h + h
+                + self.first_dense * dense
+                + (self.num_layers - self.first_dense) * experts
+                + self.num_mtp * (2 * h * h + 3 * h + experts))
 
     @property
     def out_std(self) -> float:
@@ -163,11 +238,14 @@ class JoyaiAttention(Layer):
             kv = kv.reshape(B, L, H, nope + cfg.v_dim)
             v = kv[..., nope:]
         with jax.named_scope("pt.rope"):
-            q_rope = rotary_pairs(q[..., nope:], cfg.rope_theta)
+            q_rope = rotary_pairs(q[..., nope:], cfg.rope_theta,
+                                  cfg.rope_scaling)
             k_rope = rotary_pairs(kva[..., None, cfg.kv_rank:],
-                                  cfg.rope_theta)
+                                  cfg.rope_theta, cfg.rope_scaling)
         with jax.named_scope("pt.mla.q"):
             q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+            if cfg.rope_scaling is not None:
+                q = q * cfg.softmax_gain
         with jax.named_scope("pt.mla.kv"):
             k = jnp.concatenate(
                 [kv[..., :nope], jnp.broadcast_to(k_rope, (B, L, H, rope))],
@@ -190,7 +268,12 @@ class JoyaiExperts(HeldExperts):
 
 
 class JoyaiBlock(Layer):
-    """Attention then a feed-forward: the dense SwiGLU or the experts."""
+    """Attention then a feed-forward: the dense SwiGLU or the experts. On
+    one stream ``forward(x)`` is ``(x', route)``, ``route`` None for the
+    dense kind; with ``cfg.hc_mult`` streams ``x`` is [B, L, n, C], each
+    sublayer runs inside its ``HyperConnected`` (``hc_attn``, ``hc_ffn``)
+    and ``forward`` returns ``(x', route, err)``, ``err`` the larger of the
+    two sublayers' ``H_res`` errors. ``keep_bias``: ``HeldExperts``'."""
 
     def __init__(self, cfg: JoyaiConfig, dense: bool) -> None:
         super().__init__()
@@ -203,8 +286,28 @@ class JoyaiBlock(Layer):
         else:
             self.moe = JoyaiExperts(cfg)
         self.dense = dense
+        self.streams = cfg.hc_mult > 1
+        if self.streams:
+            self.hc_attn = HyperConnected(cfg)
+            self.hc_ffn = HyperConnected(cfg)
 
-    def forward(self, x: jax.Array):
+    def _over_streams(self, x: jax.Array, keep_bias: bool):
+        with jax.named_scope("pt.attn"):
+            x, _, err_attn = self.hc_attn(
+                x, lambda u: self.attn(self.norm1(u)))
+        if self.dense:
+            with jax.named_scope("pt.ffn.dense"):
+                x, route, err = self.hc_ffn(
+                    x, lambda u: self.mlp(self.norm2(u)))
+        else:
+            with jax.named_scope("pt.ffn"):
+                x, route, err = self.hc_ffn(
+                    x, lambda u: self.moe(self.norm2(u), keep_bias))
+        return x, route, jnp.maximum(err_attn, err)
+
+    def forward(self, x: jax.Array, keep_bias: bool = False):
+        if self.streams:
+            return self._over_streams(x, keep_bias)
         # each sublayer's scope takes its norm and its residual add; the
         # latent projections, the rotary, the kernels and the expert
         # layer's stages sit in scopes of their own inside
@@ -214,7 +317,7 @@ class JoyaiBlock(Layer):
             with jax.named_scope("pt.ffn.dense"):
                 return x + self.mlp(self.norm2(x)), None
         with jax.named_scope("pt.ffn"):
-            y, route = self.moe(self.norm2(x))
+            y, route = self.moe(self.norm2(x), keep_bias)
             return x + y, route
 
 
@@ -246,15 +349,33 @@ class Joyai(Layer):
     """Whole model. ``forward(ids)`` returns ``(logits, logits')``, both
     [B, L, vocab]: the next token's and, from the prediction module, the
     one after (its last position is no prediction: ``joyai_loss`` masks
-    it). With ``output_routing`` also the routers' ``logits``
-    [expert layers, B*L, num_experts] and ``index``."""
+    it); with ``num_mtp`` 0 it returns ``logits`` alone
+    (``next_token_loss``). With ``output_routing`` also the routers'
+    ``logits`` [expert layers, B*L, num_experts] and ``index``. With
+    ``hc_mult`` streams the buffer ``hc_res_err`` holds the step's largest
+    ``H_res`` error (``ops.hyper_connection.hc_res_err``) over every token
+    and sublayer."""
 
     def __init__(self, cfg: JoyaiConfig) -> None:
         super().__init__()
         enforce(cfg.n_group == 1 and cfg.topk_group == 1,
                 f"group-limited routing (n_group {cfg.n_group}, topk_group "
                 f"{cfg.topk_group}) is not implemented: both must be 1")
-        enforce_eq(cfg.num_mtp, 1, "one prediction module")
+        enforce(cfg.num_mtp in (0, 1),
+                f"num_mtp {cfg.num_mtp}: one prediction module (JoyAI) or "
+                "none (Xing4.0 as it is cut)")
+        enforce(cfg.hc_mult >= 1 and (cfg.hc_mult == 1 or cfg.num_mtp == 0),
+                f"hc_mult {cfg.hc_mult} with num_mtp {cfg.num_mtp}: 1 is the "
+                "plain block, 2 and more are that many residual streams — "
+                "and then no prediction module, for how one is handed "
+                "several streams is in no source")
+        enforce(cfg.rope_scaling is None
+                or cfg.rope_scaling.get("type") == "yarn",
+                f"rope_scaling {cfg.rope_scaling!r}: None, or a dict of type "
+                "yarn (linear, dynamic, llama3 and longrope are not "
+                "implemented)")
+        enforce(cfg.recompute in ("none", "blocks"),
+                f"recompute {cfg.recompute!r}: none or blocks")
         enforce(cfg.num_layers > cfg.first_dense >= 0,
                 "at least one expert layer")
         enforce(cfg.experts_per_token <= cfg.num_experts,
@@ -267,37 +388,75 @@ class Joyai(Layer):
         self.blocks = nn.LayerList([JoyaiBlock(cfg, i < cfg.first_dense)
                                     for i in range(cfg.num_layers)])
         self.norm_f = nn.RMSNorm(cfg.hidden_size, cfg.rms_eps)
-        self.mtp = JoyaiPredictor(cfg)
+        if cfg.num_mtp:
+            self.mtp = JoyaiPredictor(cfg)
         self.create_parameter("head_w", (cfg.hidden_size, cfg.vocab_size),
                               initializer=init)
         RoutingRecord.register(self, cfg.expert_layers, cfg.num_experts)
+        if cfg.hc_mult > 1:
+            self.register_buffer("hc_res_err", jnp.zeros((), jnp.float32))
+
+    def _run_blocks(self, x: jax.Array):
+        """``x`` through every block: ``(x', routes, errs)``. Under
+        ``recompute: "blocks"`` each block is a ``jax.checkpoint``ed
+        function of the stream — a function of its own each call:
+        ``jax.checkpoint`` keeps a function's trace, and the parameters a
+        Layer closes over are another trace's the next time it is called
+        (``models/smallthinker.py``) — whose route, with the moved bias in
+        it, and error are its OUTPUTS; the bias is stored here."""
+        rebuilt = self.cfg.recompute == "blocks"
+        routes, errs = [], []
+        for block in self.blocks:
+            run = lambda x, block=block: block(x, keep_bias=rebuilt)
+            x, route, *err = (jax.checkpoint(run) if rebuilt else run)(x)
+            errs += err
+            if route is not None:
+                if rebuilt:
+                    block.moe.store_bias(route)
+                routes.append(route)
+        return x, routes, errs
 
     def forward(self, ids: jax.Array, output_routing: bool = False):
         cfg = self.cfg
         enforce(ids.shape[-1] <= cfg.max_seq_len,
                 f"sequence of {ids.shape[-1]} over max_seq_len {cfg.max_seq_len}")
         record_held(cfg.held, cfg.num_experts)
+        streams = cfg.hc_mult > 1
+        if streams:
+            # what the residual path is made of, read off the
+            # configuration: one host span a trace, none on the step path
+            with RecordEvent("pt.hc.layers", layers=cfg.num_layers,
+                             streams=cfg.hc_mult,
+                             sinkhorn_iters=cfg.hc_sinkhorn_iters,
+                             sublayers=2 * cfg.num_layers):
+                pass
         with jax.named_scope("pt.embed"):
             x = jnp.take(self.embed, ids, axis=0)
-            # the next token's embedding; the last position repeats its own
-            nxt = jnp.concatenate([x[:, 1:], x[:, -1:]], axis=1)
-        routes = []
-        for block in self.blocks:
-            x, route = block(x)
-            if route is not None:
-                routes.append(route)
+            if cfg.num_mtp:
+                # the next token's embedding; the last position repeats
+                # its own
+                nxt = jnp.concatenate([x[:, 1:], x[:, -1:]], axis=1)
+            if streams:         # every stream starts as the embedding
+                x = jnp.broadcast_to(
+                    x[:, :, None, :],
+                    (*x.shape[:2], cfg.hc_mult, x.shape[-1]))
+        x, routes, errs = self._run_blocks(x)
         with jax.named_scope("pt.head_loss"):
+            if streams:         # and the streams leave as their sum
+                x = jnp.sum(x.astype(jnp.float32), axis=2)
+                self._buffers["hc_res_err"] = jnp.max(jnp.stack(errs))
             trunk = self.norm_f(x)
             logits = F.lm_head(trunk, self.head_w)
-        y, route = self.mtp(nxt, trunk)
-        routes.append(route)
-        with jax.named_scope("pt.head_loss"):
-            logits_mtp = F.lm_head(y, self.head_w)
+        outputs = logits
+        if cfg.num_mtp:
+            y, route = self.mtp(nxt, trunk)
+            routes.append(route)
+            with jax.named_scope("pt.head_loss"):
+                outputs = (logits, F.lm_head(y, self.head_w))
         RoutingRecord.store(self, routes)
         if output_routing:
-            return (logits, logits_mtp), routing_outputs(
-                routes, ("logits", "index"))
-        return logits, logits_mtp
+            return outputs, routing_outputs(routes, ("logits", "index"))
+        return outputs
 
 
 def joyai_losses(outputs, labels: jax.Array) -> Tuple[jax.Array, jax.Array]:

@@ -1,8 +1,15 @@
-"""What the decoder models share — OLMoE, JoyAI-LLM-Flash, LFM2, SmallThinker
-and EvaByte (``models/<name>.py``: a configuration and an assembly, nothing
+"""What the decoder models share — OLMoE, JoyAI-LLM-Flash (and Xing4.0,
+which is ``models/joyai.py`` under other keys), LFM2, SmallThinker and
+EvaByte (``models/<name>.py``: a configuration and an assembly, nothing
 another model imports) — each thing ONCE, under a public name. Arrows point
 one way: ``ops/`` (kernels) <- ``nn/`` <- ``parallel/`` (layers over
 kernels) <- this module <- the models (``tests/test_layering.py``).
+
+A block is ``x -> x + f(N(x))`` on ONE residual stream in every model but
+one: ``HyperConnected`` is the residual path as a thing of its own, ``n``
+streams a token mixed round a sublayer by mappings made from the streams
+(``ops/hyper_connection.py``); a model whose configuration asks for it
+hands its sublayers to it instead of adding their results itself.
 
 The einsum stand-ins for the flash kernels, ``_causal_attention`` (JoyAI's:
 q.k at 192, v at 128) and ``_banded_attention`` (grouped-query attention's),
@@ -17,7 +24,8 @@ between two (the benchmark's float32 check does).
 from __future__ import annotations
 
 import math
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, List, Mapping, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -30,12 +38,16 @@ from ..core.profiler import RecordEvent
 from ..nn import functional as F
 from ..nn.layer import Layer
 from ..ops.flash_attention import flash_attention
+from ..ops.hyper_connection import (hc_collect, hc_mappings, hc_res_err,
+                                    hc_scatter)
 from ..parallel.moe import held_moe
 
 __all__ = ["normal_init", "residual_out_std", "rotary", "rotary_pairs",
+           "yarn_frequencies", "yarn_mscale",
            "repeat_kv", "SwiGLU", "attention_impl", "GroupedQueryAttention",
            "HeldExperts", "RoutingRecord", "record_held", "stack_routes",
-           "routing_outputs", "next_token_loss"]
+           "routing_outputs", "next_token_loss", "HyperConnected",
+           "HC_ALPHA_INIT", "hc_res_bias_init"]
 
 
 def normal_init(std: float):
@@ -66,17 +78,60 @@ def rotary(x: jax.Array, theta: float) -> jax.Array:
     return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
 
 
-def rotary_pairs(x: jax.Array, theta: float) -> jax.Array:
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor (arXiv:2309.00071 section 3.4, as
+    DeepSeek-V3's ``yarn_get_mscale``): ``0.1 * mscale * ln(factor) + 1``
+    past a factor of 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(theta: float, dim: int, scaling: Mapping[str, float]
+                     ) -> np.ndarray:
+    """The ``dim / 2`` rotary frequencies under YaRN (``rope_scaling`` of
+    ``type`` ``yarn``), float64, as DeepSeek-V3's public
+    ``DeepseekV3YarnRotaryEmbedding`` blends them: pair ``i`` turns by
+    ``pos * theta^(-2i/dim) * ((1 - r_i) + r_i / factor)``, the ramp ``r_i =
+    clip((i - low) / (high - low), 0, 1)`` between the pairs that make
+    ``beta_fast`` (``low``, rounded down) and ``beta_slow`` (``high``,
+    rounded up) turns over ``original_max_position_embeddings``. It holds
+    at every length, not only past the original one."""
+    base = float(theta) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def pair_making(turns):
+        return dim * math.log(
+            scaling["original_max_position_embeddings"]
+            / (turns * 2 * math.pi)) / (2 * math.log(float(theta)))
+
+    low = max(math.floor(pair_making(scaling["beta_fast"])), 0)
+    high = min(math.ceil(pair_making(scaling["beta_slow"])), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 0.001), 0.0, 1.0)
+    return base * ((1.0 - ramp) + ramp / float(scaling["factor"]))
+
+
+def rotary_pairs(x: jax.Array, theta: float,
+                 scaling: Optional[Mapping[str, float]] = None) -> jax.Array:
     """Rotary position embedding on adjacent pairs, positions 0..L-1.
     ``x`` [B, L, H, D]: pair (2i, 2i+1) of every head turns by
     ``pos * theta^(-2i/D)``. Float32; the cos / sin tables are constants of
-    the traced step, computed in float64."""
+    the traced step, computed in float64. ``scaling``: a ``rope_scaling``
+    of ``type`` ``yarn`` — the frequencies are ``yarn_frequencies``' and cos
+    and sin are times ``yarn_mscale(factor, mscale) / yarn_mscale(factor,
+    mscale_all_dim)``; None is no scaling, and no other type is known."""
     L, D = x.shape[1], x.shape[-1]
-    inv_freq = float(theta) ** (-np.arange(0, D, 2, dtype=np.float64) / D)
+    gain = 1.0
+    if scaling is None:
+        inv_freq = float(theta) ** (-np.arange(0, D, 2, dtype=np.float64) / D)
+    else:
+        enforce(scaling.get("type") == "yarn",
+                f"rope_scaling type {scaling.get('type')!r}: yarn or none")
+        inv_freq = yarn_frequencies(theta, D, scaling)
+        gain = yarn_mscale(scaling["factor"], scaling["mscale"]) \
+            / yarn_mscale(scaling["factor"], scaling["mscale_all_dim"])
     angle = np.arange(L, dtype=np.float64)[:, None] * inv_freq[None, :]
-    cos = jnp.asarray(np.repeat(np.cos(angle), 2, axis=1),
+    cos = jnp.asarray(np.repeat(np.cos(angle) * gain, 2, axis=1),
                       jnp.float32)[None, :, None]
-    sin = jnp.asarray(np.repeat(np.sin(angle), 2, axis=1),
+    sin = jnp.asarray(np.repeat(np.sin(angle) * gain, 2, axis=1),
                       jnp.float32)[None, :, None]
     pairs = x.reshape(*x.shape[:-1], D // 2, 2)
     turned = jnp.stack([-pairs[..., 1], pairs[..., 0]], axis=-1)
@@ -255,7 +310,16 @@ class HeldExperts(Layer):
     states its own ``forward`` (SmallThinker). ``shared``: the width of a
     shared expert's SwiGLU, which every rank computes alike, or None. Reads
     ``hidden_size``, ``expert_size``, ``experts_per_token``, ``held``,
-    ``init_std``, ``out_std``."""
+    ``init_std``, ``out_std``.
+
+    Who stores the moved bias: ``forward`` itself, in its buffer, where the
+    block runs in the step's own trace (JoyAI, LFM2). A block that the
+    backward pass rebuilds (``jax.checkpoint``) may write no buffer — the
+    value would be a tracer of the rebuilt function, leaked — so its model
+    calls ``forward(x, keep_bias=True)``: the moved bias then leaves with the
+    route (``route["bias"]``), an output of the checkpointed function like
+    the rest of the record, and the model hands the route to ``store_bias``
+    once, outside (``models/joyai.py`` under ``recompute: "blocks"``)."""
 
     def __init__(self, cfg, experts: int, bias: Optional[str],
                  shared: Optional[int]) -> None:
@@ -276,7 +340,8 @@ class HeldExperts(Layer):
         if bias is not None:
             self.register_buffer(bias, jnp.zeros((experts,), jnp.float32))
 
-    def forward(self, x: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    def forward(self, x: jax.Array, keep_bias: bool = False
+                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         cfg = self.cfg
         lead = x.shape[:-1]
         bias = self._buffers[self.bias]
@@ -285,13 +350,22 @@ class HeldExperts(Layer):
             self.w_up, self.w_down, cfg.experts_per_token, cfg.held,
             cfg.routed_scale)
         counts = route["counts"].astype(jnp.float32)
-        self._buffers[self.bias] = (
-            bias + cfg.bias_update_rate * jnp.sign(jnp.mean(counts) - counts))
+        moved = bias + cfg.bias_update_rate * jnp.sign(
+            jnp.mean(counts) - counts)
+        if keep_bias:
+            route = dict(route, bias=moved)
+        else:
+            self._buffers[self.bias] = moved
         if self.shared is None:
             return out.reshape(*lead, out.shape[-1]), route
         with jax.named_scope("pt.moe.shared"):
             out = out.reshape(*lead, out.shape[-1]) + self.shared(x)
         return out, route
+
+    def store_bias(self, route: Dict[str, jax.Array]) -> None:
+        """The moved bias a ``forward(x, keep_bias=True)`` left in its
+        route, into the buffer: the caller's, outside what is rebuilt."""
+        self._buffers[self.bias] = route["bias"]
 
 
 def record_held(held: Tuple[int, int], experts: int) -> None:
@@ -353,3 +427,66 @@ def next_token_loss(logits: jax.Array, labels: jax.Array) -> jax.Array:
     """``Trainer``'s ``loss_fn`` of a decoder: next-token cross-entropy,
     mean over the positions (a label of -1 is no position)."""
     return F.cross_entropy(logits, labels, ignore_index=-1)
+
+
+#: the three gates ``alpha`` of a sublayer's mappings start here: the
+#: mappings then begin as their biases say, a hundredth of the streams'
+#: projection on top (hyper-connections section 3 starts its dynamic part
+#: small; the row gives no value)
+HC_ALPHA_INIT = 0.01
+
+
+def hc_res_bias_init(n: int) -> np.ndarray:
+    """``b_res`` [n, n] at step 0: 2 on the diagonal, 1 above it, 0 below.
+    ``H_res = SK(b_res)`` is then 0.594 on the diagonal and 0.103 / 0.133 /
+    0.170 off it at n = 4: a stream mostly keeps itself, and the matrix is
+    neither uniform, nor the identity, nor symmetric, nor one step from
+    doubly stochastic — one Sinkhorn step leaves it 0.060 from where twenty
+    do (a symmetric start such as ``c * I`` is doubly stochastic after ONE
+    step, and a check on it could not tell 1 step from 20, nor ``H_res``
+    from its transpose). The row gives no initialisation."""
+    return 2.0 * np.eye(n) + np.triu(np.ones((n, n)), 1)
+
+
+class HyperConnected(Layer):
+    """One sublayer's residual path over ``cfg.hc_mult`` streams
+    (``ops/hyper_connection.py`` has the equations): owns the sublayer's
+    ``phi`` [nC, 2n + n²] (at ``init_std``), ``b`` (zeros, but ``b_res`` =
+    ``hc_res_bias_init``) and ``alpha`` (``HC_ALPHA_INIT``), and wraps a
+    callable. ``forward(X, sublayer)``: ``X`` [..., n, C] -> ``(X', route,
+    err)`` where ``sublayer(u)`` maps [..., C] float32 to ``y`` or to ``(y,
+    route)`` — its own norm inside it —, ``route`` (None without one) is
+    carried through, and ``err`` is ``hc_res_err`` of this call's ``H_res``.
+    Scopes ``pt.hc.map`` / ``pt.hc.collect`` / ``pt.hc.scatter``, opened
+    inside whatever scope the caller has open (the sublayer's ``pt.attn`` /
+    ``pt.ffn`` / ``pt.ffn.dense``). Reads ``hidden_size``, ``hc_mult``,
+    ``hc_sinkhorn_iters``, ``hc_eps``, ``hc_clamp``, ``rms_eps``,
+    ``init_std``."""
+
+    def __init__(self, cfg) -> None:
+        super().__init__()
+        n, c = cfg.hc_mult, cfg.hidden_size
+        enforce(n >= 2, f"hc_mult {n}: a hyper-connected sublayer has at "
+                "least two streams (1 is the plain block, not a case of "
+                "this one)")
+        self.cfg = cfg
+        self.create_parameter("phi", (n * c, 2 * n + n * n),
+                              initializer=normal_init(cfg.init_std))
+        bias = np.concatenate([np.zeros(2 * n), hc_res_bias_init(n).ravel()])
+        self.create_parameter("b", bias.shape, init_value=bias)
+        self.create_parameter("alpha", (3,),
+                              init_value=np.full(3, HC_ALPHA_INIT))
+
+    def forward(self, x: jax.Array, sublayer: Callable):
+        cfg = self.cfg
+        with jax.named_scope("pt.hc.map"):
+            h_pre, h_post, h_res = hc_mappings(
+                x, self.phi, self.b, self.alpha, cfg.hc_sinkhorn_iters,
+                cfg.hc_eps, cfg.hc_clamp, cfg.rms_eps)
+            err = hc_res_err(h_res)
+        with jax.named_scope("pt.hc.collect"):
+            u = hc_collect(x, h_pre)
+        out = sublayer(u)
+        y, route = out if isinstance(out, tuple) else (out, None)
+        with jax.named_scope("pt.hc.scatter"):
+            return hc_scatter(x, y, h_post, h_res), route, err

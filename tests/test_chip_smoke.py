@@ -20,7 +20,7 @@ TINY = chip_smoke.Sizes(
     stream_batches=4, vocab=64, hidden=32, heads=2, ffn=64, layers=2,
     seq=16, ernie_batch=2, ernie_steps=3, window_heads=(4, 2, 8),
     window_seq=48, window=16, eva_heads=(2, 8), eva_seq=64, eva_window=16,
-    eva_chunk=4)
+    eva_chunk=4, hc_streams=4, hc_hidden=32, hc_seq=24)
 
 
 def test_script_refuses_a_cpu_platform_by_name():
@@ -58,6 +58,7 @@ def test_leg_dense_tiny():
     assert set(facts["flash_rel_err"]) == {
         "highest", "default", "window_highest", "window_default",
         "eva_highest", "eva_default"}
+    assert facts["hc_rel_err"] <= 5e-4 and 0 < facts["hc_res_err"] <= 1e-4
 
 
 def test_leg_four_tiny():

@@ -434,20 +434,28 @@ def test_benchmark_lists_the_new_metrics_in_every_cell():
         assert m["layer"] == "entry points" and m["better"] == "lower"
     assert by["moe_rows_walked_share"]["workloads"] == [
         "joyai_flash_seq4096", "lfm2_8b_a1b_seq4096",
-        "smallthinker_21b_seq16384"]                     # PR 44: appended
+        "smallthinker_21b_seq16384",                     # PR 44: appended
+        "xing4_29b_a4b_seq4096"]                         # PR 51: appended
     walked = by["flash_pairs_walked_share"]              # PR 41
     assert walked["workloads"] == [
         "ernie_base_seq512", "olmoe_1b7b_seq4096", "joyai_flash_seq4096",
         "lfm2_8b_a1b_seq4096", "smallthinker_21b_seq16384",
-        "evabyte_6b5_seq8192"]                           # PR 46: appended
+        "evabyte_6b5_seq8192",                           # PR 46: appended
+        "xing4_29b_a4b_seq4096"]                         # PR 51: appended
     assert (walked["layer"], walked["moves"], walked["source"]) == (
         "kernels", "tokens_per_s_per_chip", "program_counter")
-    # PR 44's seven come after them, PR 46's six after those: nothing was
-    # put in the middle
-    assert [m["name"] for m in bench["per_layer"][-21:-13]] == list(
+    # PR 44's seven come after them, PR 46's six after those, PR 51's
+    # five after those: nothing was put in the middle
+    assert [m["name"] for m in bench["per_layer"][-26:-18]] == list(
         SPAN_READERS) + ["setup_devices_s", "moe_rows_walked_share",
                          "flash_pairs_walked_share"]
     assert all(m["workloads"] == ["smallthinker_21b_seq16384"]
-               for m in bench["per_layer"][-13:-6])
+               for m in bench["per_layer"][-18:-11])
     assert all(m["workloads"] == ["evabyte_6b5_seq8192"]
-               for m in bench["per_layer"][-6:])
+               for m in bench["per_layer"][-11:-5])
+    assert [m["name"] for m in bench["per_layer"][-5:]] == [
+        "mhc_mfu", "mhc_residual_share", "mhc_map_share",
+        "mhc_hbm_roofline", "mhc_res_err"]
+    assert all(m["workloads"] == ["xing4_29b_a4b_seq4096"]
+               and m["moves"] == "tokens_per_s_per_chip"
+               for m in bench["per_layer"][-5:])

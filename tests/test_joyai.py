@@ -566,6 +566,50 @@ def test_what_the_model_cannot_run_is_refused(bad):
         Joyai(JoyaiConfig(**dict(SMALL, **bad)))
 
 
+@pytest.mark.parametrize("option", [dict(rope_scaling=None), dict(hc_mult=1),
+                                    dict(recompute="none"),
+                                    dict(hc_sinkhorn_iters=3)],
+                         ids=lambda o: next(iter(o)))
+def test_the_new_options_at_rest_leave_the_step_its_jaxpr(option):
+    """PR 51 gave ``JoyaiConfig`` a residual path, YaRN and recomputation
+    as options: each at rest (and a Sinkhorn count that no one-stream model
+    reads) traces JoyAI's train step to the jaxpr the defaults give,
+    equation for equation — same state paths, same program."""
+    def step_text(**kw):
+        pt.seed(0)
+        model = Joyai(JoyaiConfig(**SMALL, held=(2, 4), **kw))
+        opt = optimizer.AdamW(1e-3, weight_decay=0.1)
+        step = make_train_step(model, opt, joyai_loss, amp=True)
+        state = nn.get_state(model)
+        ids, labels = _batch(model.cfg, 2, 0)
+        return list(state["params"]) + list(state["buffers"]), str(
+            step.trace(state, opt.init(state["params"]), jax.random.key(0),
+                       (jnp.asarray(ids),), (jnp.asarray(labels),)).jaxpr)
+
+    assert step_text(**option) == step_text()
+
+
+def test_a_recomputed_one_stream_step_is_the_plain_step():
+    """``recompute: "blocks"`` on JoyAI itself (one stream, the prediction
+    module outside the rebuilt blocks): loss, gradients, the routing record
+    and the moved biases are the plain step's."""
+    outs = []
+    for recompute in ("none", "blocks"):
+        pt.seed(2)
+        model = Joyai(JoyaiConfig(**SMALL, held=(2, 4), recompute=recompute))
+        ids, labels = _batch(model.cfg, 2, 5)
+        loss, grads, buffers, _ = _sgd_step(model, ids, labels)
+        outs.append((loss, grads, jax.device_get(dict(buffers))))
+    (l0, g0, b0), (l1, g1, b1) = outs
+    assert l0 == l1 and set(b0) == set(b1)
+    for k in g0:
+        np.testing.assert_allclose(g1[k], g0[k], rtol=0, atol=2e-7,
+                                   err_msg=k)
+    for k in b0:
+        np.testing.assert_array_equal(b1[k], b0[k], err_msg=k)
+    assert any(np.abs(b1[k]).max() > 0 for k in b1 if k.endswith(_BIAS))
+
+
 def test_configuration_file_keeps_the_published_widths():
     """Every number of the catalog row's ``config`` under the same key;
     only depth, the experts held and the vocabulary are cut, with the

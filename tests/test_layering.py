@@ -53,6 +53,24 @@ def test_kernels_and_nn_import_nothing_above_them():
             assert not above, (str(path.relative_to(PKG)), above)
 
 
+def test_the_residual_path_is_an_op_under_one_shared_layer():
+    """``ops/hyper_connection.py`` (PR 51) is plain ``jnp`` that imports
+    nothing of the package but ``core``; ``HyperConnected`` sits in the
+    shared module, and the one model that asks for it (``models/joyai.py``
+    as Xing4.0 runs it) takes it from there."""
+    ops = _imports(PKG / "ops" / "hyper_connection.py")
+    assert [m for m in ops if m.startswith("paddle_tpu.")
+            and not m.startswith("paddle_tpu.core")] == []
+    assert "pallas" not in (PKG / "ops" / "hyper_connection.py").read_text()
+    shared = _imports(PKG / "models" / "transformer.py")
+    for name in ("hc_mappings", "hc_collect", "hc_scatter", "hc_res_err"):
+        assert f"paddle_tpu.ops.hyper_connection.{name}" in shared, name
+    joyai = _imports(PKG / "models" / "joyai.py")
+    for name in ("HyperConnected", "yarn_mscale", "rotary_pairs"):
+        assert f"paddle_tpu.models.transformer.{name}" in joyai, name
+    assert not [m for m in joyai if "hyper_connection" in m]
+
+
 def test_the_expert_layers_file_names_no_kernel_library():
     assert (PKG / "ops" / "grouped_matmul.py").exists()
     assert "pallas" not in (PKG / "parallel" / "moe.py").read_text()
@@ -72,6 +90,15 @@ def _joyai():
         vocab_size=1024, hidden_size=256, num_heads=2, num_layers=2,
         dense_size=512, q_rank=192, kv_rank=128, num_experts=16,
         experts_per_token=4, expert_size=768, held=(4, 2), max_seq_len=512))
+
+
+def _xing4():
+    from paddle_tpu.models.joyai import Joyai, JoyaiConfig
+    return Joyai(JoyaiConfig(
+        vocab_size=1024, hidden_size=256, num_heads=2, num_layers=2,
+        dense_size=512, q_rank=192, kv_rank=128, num_experts=16,
+        experts_per_token=4, expert_size=768, held=(4, 2), max_seq_len=512,
+        num_mtp=0, hc_mult=4, recompute="blocks"))
 
 
 def _lfm2():
@@ -103,6 +130,7 @@ def _evabyte():
 @pytest.mark.parametrize("build,entries,want", [
     (_olmoe, 30, "21f8b10d5da913af"),
     (_joyai, 58, "b8ba875b76bf57d5"),
+    (_xing4, 50, "2579d1cf01f7b150"),
     (_lfm2, 38, "8698a32a62ccaaef"),
     (_smallthinker, 28, "fd35acd5aba9377d"),
     (_evabyte, 25, "62705c4137a4ae9b")])
@@ -110,7 +138,10 @@ def test_decoder_state_keeps_the_parents_paths(build, entries, want):
     """Each decoder model at ``tests/test_tpu_lowering.py``'s small widths:
     the parameter paths, then the buffer paths, of ``nn.get_state``, with
     their shapes, in order — counted and hashed on PR 49's tree (``git
-    archive``) before anything moved. A renamed, reordered or reshaped
+    archive``) before anything moved (``_xing4``: the same file over four
+    residual streams without a prediction module, taken on PR 51's tree,
+    so that what it ADDED — ``blocks.N.hc_attn.*``, ``blocks.N.hc_ffn.*``,
+    ``hc_res_err`` — keeps its place too). A renamed, reordered or reshaped
     entry is a checkpoint that no longer loads and a benchmark adapter
     that no longer finds ``blocks.N.moe.router_w``."""
     from paddle_tpu import nn

@@ -229,6 +229,32 @@ def _evabyte_step_text():
     return text
 
 
+def _xing4_step_text():
+    from paddle_tpu.executor import Trainer
+    from paddle_tpu.models.joyai import Joyai, JoyaiConfig
+    from paddle_tpu.models.transformer import next_token_loss
+
+    model = Joyai(JoyaiConfig(
+        vocab_size=128, hidden_size=64, num_heads=2, num_layers=2,
+        dense_size=96, q_rank=48, kv_rank=32, nope_dim=16, rope_dim=8,
+        v_dim=16, num_experts=8, experts_per_token=2, expert_size=32,
+        held=(2, 2), max_seq_len=128, attn_impl="flash", num_mtp=0,
+        hc_mult=4, recompute="blocks", rope_theta=10000.0,
+        rope_scaling={"type": "yarn", "factor": 64, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                      "original_max_position_embeddings": 8}))
+    trainer = Trainer(model, optimizer.AdamW(1e-3, weight_decay=0.1),
+                      next_token_loss, amp=True)
+    ids = np.zeros((2, 128), np.int32)
+    profiler.start_timeline()
+    text = trainer.compiled_text(ids, ids)
+    spans = [s.counts for s in profiler.host_spans()
+             if s.name == "pt.hc.layers"]
+    assert spans == [{"layers": 2, "streams": 4, "sinkhorn_iters": 20,
+                      "sublayers": 4}]                       # once a trace
+    return text
+
+
 _PUSH = {"pt.push.accumulate", "pt.push.update"}
 STEPS = {
     "pass_slab": (_pass_step_text, {"pt.unpack", "pt.probe", "pt.pull",
@@ -251,6 +277,17 @@ STEPS = {
                                  "pt.moe.experts", "pt.moe.combine",
                                  "pt.moe.shared", "pt.mtp", "pt.head_loss",
                                  "pt.loss", "pt.dense_opt", "pt.flash_fwd",
+                                 "pt.flash_bwd_dq", "pt.flash_bwd_dkv"}),
+    # the residual path's three scopes inside each sublayer's own; no
+    # prediction module, so no ``pt.mtp``
+    "xing4": (_xing4_step_text, {"pt.embed", "pt.attn", "pt.mla.q",
+                                 "pt.mla.kv", "pt.rope", "pt.ffn",
+                                 "pt.ffn.dense", "pt.moe.route",
+                                 "pt.moe.dispatch", "pt.moe.experts",
+                                 "pt.moe.combine", "pt.moe.shared",
+                                 "pt.hc.map", "pt.hc.collect",
+                                 "pt.hc.scatter", "pt.head_loss", "pt.loss",
+                                 "pt.dense_opt", "pt.flash_fwd",
                                  "pt.flash_bwd_dq", "pt.flash_bwd_dkv"}),
     "lfm2": (_lfm2_step_text, {"pt.embed", "pt.conv", "pt.conv.in",
                                "pt.conv.mix", "pt.conv.out", "pt.attn",

@@ -507,6 +507,101 @@ def test_joyai_step_compiles_small(v5e, as_tpu):
     assert _fingerprint(text) == "0ff95bd6e8144bd1"
 
 
+def test_xing4_step_compiles_small(v5e, as_tpu):
+    """``models/joyai.py`` as Xing4.0 runs it — four residual streams,
+    YaRN, no prediction module, every block recomputed — compiles for the
+    chip at small widths with the published head widths (192 / 128): the
+    forward kernel twice a block (forward and its recomputation) and each
+    backward kernel once, the two forms' ``conditional`` forward,
+    recomputed and backward in the expert layer, the residual path's three
+    scopes in the text, no bf16 in the mappings' projection."""
+    import re
+
+    from paddle_tpu import nn, optimizer
+    from paddle_tpu.executor import make_train_step
+    from paddle_tpu.models.joyai import Joyai, JoyaiConfig
+    from paddle_tpu.models.transformer import next_token_loss
+
+    model = Joyai(JoyaiConfig(
+        vocab_size=1024, hidden_size=256, num_heads=2, num_layers=2,
+        dense_size=512, q_rank=192, kv_rank=128, num_experts=16,
+        experts_per_token=4, expert_size=768, held=(4, 2), max_seq_len=512,
+        num_mtp=0, hc_mult=4, recompute="blocks", rope_theta=10000.0,
+        rope_scaling={"type": "yarn", "factor": 64, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                      "original_max_position_embeddings": 4096}))
+    opt = optimizer.AdamW(learning_rate=4e-4, weight_decay=0.1, beta2=0.95)
+    step = make_train_step(model, opt, next_token_loss, amp=True)
+    state = nn.get_state(model)
+    ids = (_z(2, 512, dtype=jnp.int32),)
+    s = SingleDeviceSharding(v5e[0])
+    text = step.lower(
+        _shapes(state, s),
+        _shapes(jax.eval_shape(opt.init, state["params"]), s), _rng_key(s),
+        _shapes(ids, s), _shapes(ids, s)).compile().as_text()
+    calls = re.findall(r"%(flash_[a-z_]+)[.\d]* = ", text)
+    assert sorted(calls) == ["flash_bwd_dkv"] * 2 + ["flash_bwd_dq"] * 2 \
+        + ["flash_fwd"] * 4
+    assert text.count(" conditional(") == 3
+    for scope in ("pt.hc.map", "pt.hc.collect", "pt.hc.scatter", "pt.mla.q",
+                  "pt.mla.kv", "pt.rope", "pt.moe.shared", "pt.moe.experts",
+                  "pt.ffn.dense"):
+        assert scope in text, scope
+    assert "pt.mtp" not in text
+    # the mappings' projection [1024 tokens, 4 x 256] x [1024, 24] reads
+    # float32 operands, forward, recomputed and transposed
+    dots = [line for line in text.splitlines()
+            if "pt.hc.map" in line and re.search(r"\b(dot|convolution)\(",
+                                                  line)]
+    assert dots and not any("bf16" in line for line in dots), dots[:3]
+
+
+@pytest.mark.slow
+def test_xing4_cell_step_fits_the_chip(v5e, as_tpu):
+    """The cell's own step (``benchmarks/configs/xing4.0-29b-a4b.json``
+    through its adapter's ``_model_cfg``: published widths, 4096 tokens,
+    blocks recomputed, float32 streams) compiles for a described v5e to
+    12.44 GiB (CPU, PR 51) — under ISSUE 51's 15.0 GiB rule. The model is
+    built under ``jax.eval_shape``: shapes alone, no 3 GB of weights."""
+    import json
+    import os
+    import sys
+
+    from paddle_tpu import nn, optimizer
+    from paddle_tpu.executor import make_train_step
+    from paddle_tpu.models.joyai import Joyai
+    from paddle_tpu.models.transformer import next_token_loss
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    sys.path.insert(0, bench)
+    from harness import spec
+
+    with open(os.path.join(bench, "configs", "xing4.0-29b-a4b.json")) as f:
+        cfg = json.load(f)
+    model_cfg = spec.load_module(
+        "adapters", "causal_mhc_mla_moe_lm")._model_cfg(cfg)
+    made = []
+
+    def make():
+        made.append(Joyai(model_cfg))
+        return nn.get_state(made[0])
+
+    state = jax.eval_shape(make)
+    opt = optimizer.AdamW(learning_rate=1e-8, weight_decay=0.1, beta2=0.95)
+    step = make_train_step(made[0], opt, next_token_loss, amp=True)
+    s = SingleDeviceSharding(v5e[0])
+    ids = (_z(cfg["sizes"]["batch_per_chip"], 4096, dtype=jnp.int32),)
+    compiled = step.lower(
+        _shapes(state, s),
+        _shapes(jax.eval_shape(opt.init, state["params"]), s), _rng_key(s),
+        _shapes(ids, s), _shapes(ids, s)).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 11.5 * 2**30 < total <= 15.0 * 2**30, total / 2**30
+
+
 def test_lfm2_step_compiles_small(v5e, as_tpu):
     """The LFM2 train step for the chip at small widths with the published
     head shape (4 query heads on 1 key-value head of 64, causal: the
