@@ -481,7 +481,8 @@ def leg_dense(sz: Sizes) -> Dict:
     assert eva_fast <= 3e-2, f"EVA flash(default) vs einsum: {eva_fast}"
 
     # one hyper-connected sublayer (``transformer.HyperConnected`` round a
-    # tanh of the collected stream), forward and backward to the streams
+    # tanh of the collected stream; the streams side by side as it takes
+    # them: its four kernels), forward and backward to the streams
     # and to phi, b and alpha, against the same equations as two einsums
     # with the norm made before the projection: the mappings are float32
     # on the chip too (the projection at precision highest), so the bound
@@ -502,7 +503,8 @@ def leg_dense(sz: Sizes) -> Dict:
     def through_the_layer(xs, params):
         out, _, err = nn.functional_call(
             layer, {"params": dict(zip(("phi", "b", "alpha"), params)),
-                    "buffers": {}}, xs, jnp.tanh)[0]
+                    "buffers": {}}, xs.reshape(1, sz.hc_seq, n * C),
+            jnp.tanh)[0]
         return jnp.sum(out ** 2), err
 
     def by_einsum(xs, params):

@@ -62,7 +62,11 @@ things JoyAI has not, each an option that leaves JoyAI's program what it is:
   and leaves ``X' = H_res X + H_postᵀ y``; exit: ``N_f(sum_i X_i) W_head``.
   The streams are float32 between blocks, the step's activation dtype
   (bfloat16 streams would let two sequences fit: PERF.md section 7), the
-  mappings float32 whatever ``amp`` says.
+  mappings float32 whatever ``amp`` says. They travel side by side in the
+  last axis, [B, L, n·C] with stream j in columns jC..(j+1)C — the form
+  the path's kernels read, so that no block's boundary lays them out anew
+  (a [.., n, C] array with n = 4 is tiled (4, 128) on the chip and every
+  way to or from [tokens, n·C] is a copy of the four streams).
 - ``rope_scaling`` of ``type`` ``yarn`` (arXiv:2309.00071, as DeepSeek-V3's
   public rotary embedding computes it): blended frequencies
   (``transformer.yarn_frequencies``) at EVERY length, and the softmax scale
@@ -270,7 +274,7 @@ class JoyaiExperts(HeldExperts):
 class JoyaiBlock(Layer):
     """Attention then a feed-forward: the dense SwiGLU or the experts. On
     one stream ``forward(x)`` is ``(x', route)``, ``route`` None for the
-    dense kind; with ``cfg.hc_mult`` streams ``x`` is [B, L, n, C], each
+    dense kind; with ``cfg.hc_mult`` streams ``x`` is [B, L, n·C], each
     sublayer runs inside its ``HyperConnected`` (``hc_attn``, ``hc_ffn``)
     and ``forward`` returns ``(x', route, err)``, ``err`` the larger of the
     two sublayers' ``H_res`` errors. ``keep_bias``: ``HeldExperts``'."""
@@ -437,13 +441,12 @@ class Joyai(Layer):
                 # its own
                 nxt = jnp.concatenate([x[:, 1:], x[:, -1:]], axis=1)
             if streams:         # every stream starts as the embedding
-                x = jnp.broadcast_to(
-                    x[:, :, None, :],
-                    (*x.shape[:2], cfg.hc_mult, x.shape[-1]))
+                x = jnp.concatenate([x] * cfg.hc_mult, axis=-1)
         x, routes, errs = self._run_blocks(x)
         with jax.named_scope("pt.head_loss"):
             if streams:         # and the streams leave as their sum
-                x = jnp.sum(x.astype(jnp.float32), axis=2)
+                x = sum(s.astype(jnp.float32)
+                        for s in jnp.split(x, cfg.hc_mult, axis=-1))
                 self._buffers["hc_res_err"] = jnp.max(jnp.stack(errs))
             trunk = self.norm_f(x)
             logits = F.lm_head(trunk, self.head_w)

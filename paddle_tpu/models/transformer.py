@@ -38,8 +38,7 @@ from ..core.profiler import RecordEvent
 from ..nn import functional as F
 from ..nn.layer import Layer
 from ..ops.flash_attention import flash_attention
-from ..ops.hyper_connection import (hc_collect, hc_mappings, hc_res_err,
-                                    hc_scatter)
+from ..ops.hyper_connection import hc_gates, hc_post, hc_pre, hc_res_err
 from ..parallel.moe import held_moe
 
 __all__ = ["normal_init", "residual_out_std", "rotary", "rotary_pairs",
@@ -450,18 +449,25 @@ def hc_res_bias_init(n: int) -> np.ndarray:
 
 class HyperConnected(Layer):
     """One sublayer's residual path over ``cfg.hc_mult`` streams
-    (``ops/hyper_connection.py`` has the equations): owns the sublayer's
-    ``phi`` [nC, 2n + n²] (at ``init_std``), ``b`` (zeros, but ``b_res`` =
-    ``hc_res_bias_init``) and ``alpha`` (``HC_ALPHA_INIT``), and wraps a
-    callable. ``forward(X, sublayer)``: ``X`` [..., n, C] -> ``(X', route,
-    err)`` where ``sublayer(u)`` maps [..., C] float32 to ``y`` or to ``(y,
-    route)`` — its own norm inside it —, ``route`` (None without one) is
-    carried through, and ``err`` is ``hc_res_err`` of this call's ``H_res``.
-    Scopes ``pt.hc.map`` / ``pt.hc.collect`` / ``pt.hc.scatter``, opened
-    inside whatever scope the caller has open (the sublayer's ``pt.attn`` /
-    ``pt.ffn`` / ``pt.ffn.dense``). Reads ``hidden_size``, ``hc_mult``,
-    ``hc_sinkhorn_iters``, ``hc_eps``, ``hc_clamp``, ``rms_eps``,
-    ``init_std``."""
+    (``ops/hyper_connection.py`` has the equations and the kernels): owns
+    the sublayer's ``phi`` [nC, 2n + n²] (at ``init_std``), ``b`` (zeros,
+    but ``b_res`` = ``hc_res_bias_init``) and ``alpha``
+    (``HC_ALPHA_INIT``), and wraps a callable. ``forward(X, sublayer)``:
+    ``X`` [..., n·C], the streams side by side (stream j in columns
+    jC..(j+1)C: the kernels' own form, so nothing is laid out anew between
+    two sublayers) -> ``(X', route, err)`` where ``sublayer(u)`` maps
+    [..., C] float32 to ``y`` or to ``(y, route)`` — its own norm inside it
+    —, ``route`` (None without one) is carried through, and ``err`` is
+    ``hc_res_err`` of this call's ``H_res``. What is the size of a stream
+    runs in the four token-tiled kernels, at every shape: ``hc_pre`` (the
+    norm, the projection and ``u``; ``pt.hc.collect``) and ``hc_post``
+    (``X'``; ``pt.hc.scatter``), which open their scopes themselves,
+    forward and in their stated backward; ``pt.hc.map``, opened here, keeps
+    the [tokens, 2n + n²] work — gates, sigmoids, the Sinkhorn steps, the
+    error — which JAX differentiates. All inside whatever scope the caller
+    has open (the sublayer's ``pt.attn`` / ``pt.ffn`` / ``pt.ffn.dense``).
+    Reads ``hidden_size``, ``hc_mult``, ``hc_sinkhorn_iters``, ``hc_eps``,
+    ``hc_clamp``, ``rms_eps``, ``init_std``."""
 
     def __init__(self, cfg) -> None:
         super().__init__()
@@ -479,14 +485,15 @@ class HyperConnected(Layer):
 
     def forward(self, x: jax.Array, sublayer: Callable):
         cfg = self.cfg
+        side_by_side = x.shape
+        x = x.reshape(*x.shape[:-1], cfg.hc_mult, cfg.hidden_size)
+        u, z, x = hc_pre(x, self.phi, self.b, self.alpha, cfg.rms_eps)
         with jax.named_scope("pt.hc.map"):
-            h_pre, h_post, h_res = hc_mappings(
-                x, self.phi, self.b, self.alpha, cfg.hc_sinkhorn_iters,
-                cfg.hc_eps, cfg.hc_clamp, cfg.rms_eps)
+            _, h_post, h_res = hc_gates(
+                z, self.b, self.alpha, cfg.hc_mult, cfg.hc_sinkhorn_iters,
+                cfg.hc_eps, cfg.hc_clamp)
             err = hc_res_err(h_res)
-        with jax.named_scope("pt.hc.collect"):
-            u = hc_collect(x, h_pre)
         out = sublayer(u)
         y, route = out if isinstance(out, tuple) else (out, None)
-        with jax.named_scope("pt.hc.scatter"):
-            return hc_scatter(x, y, h_post, h_res), route, err
+        return (hc_post(x, y, h_post, h_res).reshape(side_by_side), route,
+                err)

@@ -54,16 +54,17 @@ def test_kernels_and_nn_import_nothing_above_them():
 
 
 def test_the_residual_path_is_an_op_under_one_shared_layer():
-    """``ops/hyper_connection.py`` (PR 51) is plain ``jnp`` that imports
-    nothing of the package but ``core``; ``HyperConnected`` sits in the
-    shared module, and the one model that asks for it (``models/joyai.py``
-    as Xing4.0 runs it) takes it from there."""
+    """``ops/hyper_connection.py`` (PR 51; its four kernels PR 52) imports
+    nothing of the package but ``core`` and is the one file of the path
+    that names the kernel library; ``HyperConnected`` sits in the shared
+    module and calls the fused entry points, and the one model that asks
+    for it (``models/joyai.py`` as Xing4.0 runs it) takes it from there."""
     ops = _imports(PKG / "ops" / "hyper_connection.py")
     assert [m for m in ops if m.startswith("paddle_tpu.")
             and not m.startswith("paddle_tpu.core")] == []
-    assert "pallas" not in (PKG / "ops" / "hyper_connection.py").read_text()
+    assert "pallas" not in (PKG / "models" / "transformer.py").read_text()
     shared = _imports(PKG / "models" / "transformer.py")
-    for name in ("hc_mappings", "hc_collect", "hc_scatter", "hc_res_err"):
+    for name in ("hc_pre", "hc_gates", "hc_post", "hc_res_err"):
         assert f"paddle_tpu.ops.hyper_connection.{name}" in shared, name
     joyai = _imports(PKG / "models" / "joyai.py")
     for name in ("HyperConnected", "yarn_mscale", "rotary_pairs"):

@@ -438,7 +438,8 @@ def test_every_pallas_call_is_named():
             assert re.search(r"\bname=\"\w+\"", text[m.end():i]), (
                 path, text[m.start():m.start() + 80])
             calls += 1
-    assert calls == 3      # the three flash-attention kernels
+    # the three flash-attention kernels and the residual path's four
+    assert calls == 7
 
 
 # -- (b) RecordEvent: ids, parents, counts, a bounded ring ----------------

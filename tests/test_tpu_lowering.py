@@ -514,7 +514,8 @@ def test_xing4_step_compiles_small(v5e, as_tpu):
     forward kernel twice a block (forward and its recomputation) and each
     backward kernel once, the two forms' ``conditional`` forward,
     recomputed and backward in the expert layer, the residual path's three
-    scopes in the text, no bf16 in the mappings' projection."""
+    scopes in the text and its four kernels compiled by Mosaic at their
+    counts, the streams never laid out anew between them."""
     import re
 
     from paddle_tpu import nn, optimizer
@@ -548,12 +549,59 @@ def test_xing4_step_compiles_small(v5e, as_tpu):
                   "pt.ffn.dense"):
         assert scope in text, scope
     assert "pt.mtp" not in text
-    # the mappings' projection [1024 tokens, 4 x 256] x [1024, 24] reads
-    # float32 operands, forward, recomputed and transposed
-    dots = [line for line in text.splitlines()
-            if "pt.hc.map" in line and re.search(r"\b(dot|convolution)\(",
-                                                  line)]
-    assert dots and not any("bf16" in line for line in dots), dots[:3]
+    # the residual path of 4 sublayers: each kernel a Mosaic call under its
+    # own scope, forward, rebuilt and backward (the last sublayer's scatter
+    # of a block is not rebuilt: nothing in the backward reads it)
+    kernels = re.findall(
+        r'%(hc_[a-z_]+)[.\d]* = .*custom_call_target="tpu_custom_call".*'
+        r'op_name="[^"]*/(pt\.hc\.[a-z]+)/hc_[a-z_]+/pallas_call"', text)
+    assert sorted(set(kernels)) == [
+        ("hc_post_bwd", "pt.hc.scatter"), ("hc_post_fwd", "pt.hc.scatter"),
+        ("hc_pre_bwd", "pt.hc.collect"), ("hc_pre_fwd", "pt.hc.collect")]
+    count = lambda name: sum(k == name for k, _ in kernels)
+    assert [count(k) for k in ("hc_pre_fwd", "hc_post_fwd", "hc_post_bwd",
+                               "hc_pre_bwd")] == [8, 6, 4, 4]
+    # the streams stay [tokens, n C] from kernel to kernel: nothing lays
+    # them out anew (as [.., 4, 256] they would be tiled (4, 128))
+    assert "f32[2,512,4,256]" not in text
+    assert not re.search(r"f32\[(1024,1024|2,512,1024)\]\S* "
+                         r"(copy|transpose|reshape)\(", text)
+    # and no matmul is left under pt.hc.map: the projection is the
+    # kernels', float32 at precision highest
+    assert not [line for line in text.splitlines() if "pt.hc.map" in line
+                and re.search(r"\b(dot|convolution)\(", line)]
+
+
+@pytest.mark.parametrize("tokens", [4096, 4100])
+def test_hyper_connection_kernels_compile_at_the_cells_widths(v5e, as_tpu,
+                                                              tokens):
+    """The residual path's four kernels as ``xing4_29b_a4b_seq4096`` runs
+    them — 4,096 tokens, four float32 streams of 3,584 — through Mosaic
+    for the described v5e: the projection a float32 matmul at precision
+    ``highest`` inside ``hc_pre_fwd`` and twice inside ``hc_pre_bwd`` (Phi
+    as [24, n C], its gradient summed over the token grid), the tiles'
+    VMEM under the limit the kernels state; and at 4,100 tokens, where the
+    last tile is a partial one and ``hc_pre_bwd`` leaves its rows out."""
+    from paddle_tpu.ops.hyper_connection import hc_gates, hc_post, hc_pre
+
+    n, c = 4, 3584
+
+    def both_ways(x, phi, b, alpha, ct):
+        def path(x, phi, b, alpha):
+            u, z, x = hc_pre(x, phi, b, alpha, 1e-6)
+            _, h_post, h_res = hc_gates(z, b, alpha, n, 20, 1e-6,
+                                        (-30.0, 30.0))
+            return hc_post(x, u, h_post, h_res)
+
+        out, back = jax.vjp(path, x, phi, b, alpha)
+        return out, back(ct)
+
+    x = _z(tokens, n, c)
+    text = _compile(both_ways, SingleDeviceSharding(v5e[0]), x,
+                    _z(n * c, 24), _z(24), _z(3), x).as_text()
+    calls = re.findall(r"%(hc_[a-z_]+)[.\d]* = ", text)
+    assert sorted(calls) == ["hc_post_bwd", "hc_post_fwd", "hc_pre_bwd",
+                             "hc_pre_fwd"], calls
 
 
 @pytest.mark.slow

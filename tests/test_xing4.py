@@ -1,17 +1,21 @@
 """Xing4.0-29B-A4B on the dense path: ``models.Joyai`` under ``hc_mult`` 4,
 YaRN and no prediction module, through ``executor.make_train_step`` against
 the plain reference that sits beside the benchmark's configuration; the
-Sinkhorn normalisation alone; the hyper-connections paper's equivalence with
-the one-stream block; YaRN's frequencies and ``m²`` by hand; the eight
-shares adding up to the uncut layer; routes and the bias update leaving a
+Sinkhorn normalisation alone; the residual path's four kernels against the
+plain definitions, their count of stream-widths in a sublayer's jaxpr and
+their scopes in the compiled step; the hyper-connections paper's
+equivalence with the one-stream block; YaRN's frequencies and ``m²`` by
+hand; the eight shares adding up to the uncut layer; routes and the bias update leaving a
 recomputed block once; the parameter counts; the benchmark's FLOP and byte
 counts by hand; the planted faults of the cell's ``correct``; the cell's
 rehearsal end to end."""
 
+import contextlib
 import importlib.util
 import json
 import math
 import os
+import re
 import subprocess
 import sys
 
@@ -30,7 +34,8 @@ from paddle_tpu.models import transformer
 from paddle_tpu.models.transformer import (HyperConnected, next_token_loss,
                                            rotary_pairs, yarn_frequencies,
                                            yarn_mscale)
-from paddle_tpu.ops.hyper_connection import (hc_collect, hc_mappings,
+from paddle_tpu.ops.hyper_connection import (hc_collect, hc_gates,
+                                             hc_mappings, hc_post, hc_pre,
                                              hc_res_err, hc_scatter,
                                              sinkhorn)
 from paddle_tpu.parallel import moe
@@ -366,6 +371,189 @@ def test_scatter_and_collect_by_hand():
     assert hc_collect(x.astype(jnp.bfloat16), h_pre).dtype == jnp.float32
 
 
+# -- the four kernels against the plain definitions -----------------------
+
+_MAP = (20, 1e-6, (-30.0, 30.0))
+_RMS_EPS = 1e-6
+
+
+def _plain_path(x, y, phi, b, alpha):
+    h_pre, h_post, h_res = hc_mappings(x, phi, b, alpha, *_MAP, _RMS_EPS)
+    u = hc_collect(x, h_pre)
+    return hc_scatter(x, jnp.tanh(u) + y, h_post, h_res), u
+
+
+def _fused_path(x, y, phi, b, alpha):
+    u, z, x = hc_pre(x, phi, b, alpha, _RMS_EPS)
+    _, h_post, h_res = hc_gates(z, b, alpha, x.shape[-2], *_MAP)
+    return hc_post(x, jnp.tanh(u) + y, h_post, h_res), u
+
+
+def _path_case(lead, n, c, dtype, seed=5):
+    rng = np.random.default_rng(seed)
+    m = 2 * n + n * n
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    leaves = (jnp.asarray(rng.normal(size=(*lead, n, c)), dtype),
+              f32(rng.normal(size=(*lead, c))),
+              f32(rng.normal(size=(n * c, m)) * 0.2),
+              f32(rng.normal(size=(m,))), f32([0.5, 0.7, 0.9]))
+    return leaves, f32(rng.normal(size=(*lead, n, c)))
+
+
+# (tokens' shape, streams, width): small widths, and one case at four
+# streams of 128 lanes whose 300 tokens are a whole tile of 256 and a
+# partial one (what Phi's gradient sums over the grid has rows to leave out)
+@pytest.mark.parametrize("lead,n,c", [((2, 9), 4, 32), ((3, 5), 2, 16),
+                                      ((300,), 4, 128)])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("recompute", ["none", "blocks"])
+def test_fused_path_is_the_plain_path(lead, n, c, dtype, recompute):
+    """``hc_pre`` / ``hc_gates`` / ``hc_post`` (the four kernels,
+    interpreted) against ``hc_mappings`` / ``hc_collect`` / ``hc_scatter``:
+    ``X'`` and ``u`` to float32 rounding (to the streams' own where they
+    are bf16), and ``jax.grad`` through the stated backward against
+    ``jax.grad`` through the plain definitions for every leaf — the
+    streams, a result added to the sublayer's, ``phi``, ``b``, ``alpha`` —
+    with the path plain and under ``jax.checkpoint`` as a recomputed block
+    has it."""
+    leaves, ct = _path_case(lead, n, c, jnp.dtype(dtype))
+    low = dtype == "bfloat16"
+
+    def loss(path):
+        if recompute == "blocks":
+            path = jax.checkpoint(path)
+
+        def total(*leaves):
+            out, u = path(*leaves)
+            return jnp.sum(out.astype(jnp.float32) * ct) + 0.1 * jnp.sum(u * u)
+        return total
+
+    want = jax.jit(_plain_path)(*leaves)
+    got = jax.jit(_fused_path)(*leaves)
+    assert got[0].dtype == leaves[0].dtype and got[1].dtype == jnp.float32
+    for g, w, tol in zip(got, want, (2.0 ** -7 if low else 3e-6, 3e-6)):
+        w = np.asarray(w, np.float32)
+        np.testing.assert_allclose(np.asarray(g, np.float32), w, rtol=0,
+                                   atol=tol * np.abs(w).max())
+    every = tuple(range(len(leaves)))
+    want = jax.jit(jax.grad(loss(_plain_path), argnums=every))(*leaves)
+    got = jax.jit(jax.grad(loss(_fused_path), argnums=every))(*leaves)
+    for name, g, w in zip(("x", "y", "phi", "b", "alpha"), got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        w = np.asarray(w, np.float32)
+        # bf16 streams: the plain path rounds the streams' cotangent
+        # where each of its three readers hands it back, the kernel once
+        tol = 2.0 ** -6 if low and name == "x" else 2e-5
+        np.testing.assert_allclose(np.asarray(g, np.float32), w, rtol=0,
+                                   atol=tol * np.abs(w).max(), err_msg=name)
+
+
+def _stream_sized(eqn, tokens, c):
+    """Elements / (tokens x c) of every operand and result of ``eqn`` that
+    is at least one stream wide."""
+    return [int(np.prod(v.aval.shape)) / (tokens * c)
+            for v in (*eqn.invars, *eqn.outvars)
+            if getattr(getattr(v, "aval", None), "shape", None) is not None
+            and int(np.prod(v.aval.shape)) >= tokens * c]
+
+
+def test_a_sublayer_moves_its_streams_in_four_kernels():
+    """The design's count, held on the CPU: in the jaxpr of one
+    ``HyperConnected`` sublayer's value and gradient every equation with an
+    operand the size of a stream is one of the four kernels, or a reshape
+    between [.., n·C], [.., n, C] and [tokens, n·C] (which copies nothing:
+    tokens stay rows, a stream's columns stay together), and the kernels'
+    stream-sized operands come to 14 stream-widths forward (5 + 9) and 27
+    backward (14 + 13). A change that makes XLA read the streams once more
+    fails here, not on the chip."""
+    cfg = JoyaiConfig(**dict(SMALL, hidden_size=64))
+    # 128 tokens: more than Phi's 24 n rows, so Phi is no stream's size
+    n, c, lead = cfg.hc_mult, cfg.hidden_size, (2, 64)
+    tokens = lead[0] * lead[1]
+    pt.seed(3)
+    layer = HyperConnected(cfg)
+    state = nn.get_state(layer)
+    x = jnp.ones((*lead, n * c), jnp.float32)
+
+    def path(x, params):
+        (out, _, err), _ = nn.functional_call(
+            layer, {"params": params, "buffers": state["buffers"]}, x,
+            lambda u: u)                    # the sublayer adds no equation
+        return out, err
+
+    def both_ways(x, params, ct):
+        out, back = jax.vjp(path, x, params)
+        return out, back((ct, jnp.ones((), jnp.float32)))
+
+    jaxpr = jax.make_jaxpr(both_ways)(x, state["params"], x)
+    moved, others = {}, []
+
+    def walk(eqns):
+        for eqn in eqns:
+            wide = _stream_sized(eqn, tokens, c)
+            if eqn.primitive.name == "pallas_call":
+                name = eqn.params["name"]
+                moved[name] = moved.get(name, 0) + sum(wide)
+            elif eqn.primitive.name == "scan":
+                continue    # the Sinkhorn steps' stack: 20 x [tokens, 16]
+            elif any(hasattr(v, "jaxpr") or hasattr(v, "eqns")
+                     for v in eqn.params.values()):
+                for v in eqn.params.values():   # pjit, custom_vjp, remat
+                    inner = getattr(v, "jaxpr", v)
+                    if hasattr(inner, "eqns"):
+                        walk(inner.eqns)
+            elif wide and eqn.primitive.name != "reshape":
+                others.append((eqn.primitive.name, wide))
+
+    walk(jaxpr.jaxpr.eqns)
+    assert not others, others
+    assert moved == {"hc_pre_fwd": 5, "hc_post_fwd": 9, "hc_post_bwd": 14,
+                     "hc_pre_bwd": 13}, moved
+    assert sum(moved.values()) <= 41
+
+
+def test_compiled_step_scopes_the_backward_kernels_too():
+    """``harness/scopes.scope_of_ops`` on a small model's
+    ``Trainer.compiled_text`` (CPU: the kernels interpreted, their
+    operations inlined under the kernels' names): the backward kernels'
+    operations belong to ``pt.hc.collect`` and ``pt.hc.scatter`` as the
+    forward ones do — ``mhc_hbm_roofline`` divides by the time under those
+    two — and nothing shaped like a stream ([.., C] or [.., n·C] over all
+    the tokens) belongs to ``pt.hc.map``."""
+    from harness import scopes
+
+    cfg = JoyaiConfig(**SMALL, held=(0, 8), recompute="blocks")
+    pt.seed(4)
+    trainer = Trainer(Joyai(cfg), optimizer.AdamW(1e-3, weight_decay=0.1),
+                      next_token_loss, amp=True)
+    ids, labels = _batch(cfg, 2, 6)
+    text = trainer.compiled_text(ids, labels)
+    scope_of = scopes.scope_of_ops(text)
+    names = {}
+    for line in text.splitlines():
+        found = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\S+)\s", line)
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if found and op_name:
+            names[found.group(1)] = (found.group(2), op_name.group(1))
+    for kernel, scope in (("hc_pre_fwd", "pt.hc.collect"),
+                          ("hc_pre_bwd", "pt.hc.collect"),
+                          ("hc_post_fwd", "pt.hc.scatter"),
+                          ("hc_post_bwd", "pt.hc.scatter")):
+        inside = [k for k, (_, op) in names.items() if f"/{kernel}/" in op]
+        assert inside, kernel
+        assert {scope_of[k] for k in inside} == {scope}, kernel
+    tokens = ids.shape[0] * ids.shape[1]
+    widths = {cfg.hidden_size, cfg.hc_mult * cfg.hidden_size}
+    for k, (shape, _) in names.items():
+        if scope_of.get(k) != "pt.hc.map":
+            continue
+        for dims in re.findall(r"\[([\d,]+)\]", shape):
+            dims = [int(d) for d in dims.split(",")]
+            assert not (dims[-1] in widths
+                        and int(np.prod(dims)) >= tokens * cfg.hidden_size
+                        ), (k, shape)
+
+
 # -- YaRN -----------------------------------------------------------------
 
 
@@ -642,16 +830,56 @@ def planted_case():
     return model, state, ids, labels, ref
 
 
+def _plant(monkeypatch, model, fault):
+    """``fault`` in the program ``model`` runs, for the context returned.
+    The configuration's faults as the chip's control plants them; the
+    three of the residual path on the names ``HyperConnected`` calls since
+    PR 52 — ``hc_post`` for the control's ``hc_scatter``, ``hc_gates`` and
+    ``hc_pre`` for its ``hc_mappings``, which the fused path no longer
+    calls (the control plants on ``transformer.hc_scatter`` /
+    ``transformer.hc_mappings`` and is a ``benchmark`` PR's to move)."""
+    sound_post, sound_gates = transformer.hc_post, transformer.hc_gates
+    if fault == "h_res_transposed":
+        monkeypatch.setattr(
+            transformer, "hc_post", lambda x, y, h_post, h_res: sound_post(
+                x, y, h_post, jnp.swapaxes(h_res, -1, -2)))
+    elif fault == "h_post_without_its_2":
+        def halved(*args):
+            h_pre, h_post, h_res = sound_gates(*args)
+            return h_pre, 0.5 * h_post, h_res
+
+        monkeypatch.setattr(transformer, "hc_gates", halved)
+    elif fault == "mappings_in_bf16":
+        # the control's own bf16 mappings, handed over as the fused path
+        # takes them: u of its H_pre, and its H_post / H_res in place of
+        # what hc_gates makes of z
+        made = []
+
+        def pre(x, phi, b, alpha, rms_eps):
+            cfg = model.cfg
+            made.append(CONTROL.mappings_in_bf16(
+                x, phi, b, alpha, cfg.hc_sinkhorn_iters, cfg.hc_eps,
+                cfg.hc_clamp, rms_eps))
+            return hc_collect(x, made[-1][0]), None, x
+
+        monkeypatch.setattr(transformer, "hc_pre", pre)
+        monkeypatch.setattr(transformer, "hc_gates", lambda *args: made.pop())
+    else:
+        return CONTROL.planted(model, fault)
+    return contextlib.nullcontext()
+
+
 @pytest.mark.parametrize("fault", [f for f in CONTROL.FAULTS
                                    if f != "reference_in_float8"])
-def test_planted_faults_read_not_correct(planted_case, fault):
+def test_planted_faults_read_not_correct(planted_case, monkeypatch, fault):
     """The five wrong programs ISSUE 51 names, planted as the chip's
-    control plants them (``benchmarks/tests/mhc_fault_control.py``), at
-    the model's INITIAL mappings (``alpha`` 0.01, ``b_res`` as
+    control plants them (``benchmarks/tests/mhc_fault_control.py``; the
+    residual path's on the fused path's names, ``_plant``), at the
+    model's INITIAL mappings (``alpha`` 0.01, ``b_res`` as
     ``hc_res_bias_init``): each is refused by the float32 limits, the
     sound program is not."""
     model, state, ids, labels, ref = planted_case
-    with CONTROL.planted(model, fault):
+    with _plant(monkeypatch, model, fault):
         got = _f32_got(model, state, ids, labels)
     out = REF.compare(got, ref, "f32")
     assert out["ok"] == (fault == "none"), (fault, out)
